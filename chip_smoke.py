@@ -1,0 +1,651 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that the scheduling path runs on the chip.
+
+One process drives the system's main path once, through the entry points a
+user would call, at sizes users of a scheduler would call real, and checks
+what comes out by the repo's own means (oracle comparison, flight-recorder
+replay, in-cell audits). It makes no performance claim: the seconds it prints
+say where a cold run spends its time, nothing else.
+
+Stages, all in this one process (a chip belongs to one process):
+
+- drain   the BASELINE.json headline shape (15,000 nodes in 3 zones, 10,000
+          pending pods) through Store -> informers -> queue ->
+          factory.create_scheduler (the CLI's path) -> schedule_burst until
+          empty. Every pod bound exactly once; the first 100 bindings equal
+          an oracle scheduler's on an identically built store.
+- lanes   plain / anti-affinity / affinity / node-affinity / spread at
+          1000 nodes / 1000 existing / 1000 pods, the gang cell, one
+          preemption pressure wave and two single-preemptor scans, one
+          stage each, so every kernel family compiles and executes; each
+          lane's op counter must rise and a bounded burst per lane is
+          replayed through the oracle.
+- serial  single-pod cycles with serial_path="device"; prints the warm
+          round trip of the cycle program and of a trivial program.
+- serve   perf.harness.run_serve_cell: arrivals -> admission gate ->
+          ServeLoop windows -> commit -> watch, with its two audits.
+- mesh    only with more than one device: the drain again with the node
+          axis sharded over all of them; shards and bindings are checked.
+
+After every stage: no device fault was absorbed, no circuit opened, the store
+runs the native commit core and the native heap is loaded. Any failed check
+exits non-zero. Without an accelerator the script refuses to run and prints
+no result; `--rehearse-cpu` is the one explicit way to run it on the CPU
+backend, at shrunken sizes, stamped as a rehearsal that can never read as a
+pass on the chip.
+
+The last line of standard output is one JSON object:
+{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": 1}, ...,
+ "claim": null}.
+"""
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import statistics
+import sys
+import time
+import traceback
+
+REAL = {
+    "drain_nodes": 15000, "drain_pods": 10000, "oracle_prefix": 100,
+    "lane_nodes": 1000, "lane_existing": 1000, "lane_pods": 1000,
+    "parity_pods": 32, "gang_size": 64,
+    "preempt_victims": 10000, "preemptors": 128,
+    "serial_nodes": 1000, "serial_cycles": 12,
+    "serve_nodes": 1000, "serve_rate": 2000.0, "serve_seconds": 5.0,
+    "serve_window": 2048, "serve_parity_pods": 256,
+}
+REHEARSAL = {
+    "drain_nodes": 300, "drain_pods": 600, "oracle_prefix": 40,
+    "lane_nodes": 60, "lane_existing": 60, "lane_pods": 50,
+    "parity_pods": 12, "gang_size": 8,
+    "preempt_victims": 320, "preemptors": 8,
+    "serial_nodes": 60, "serial_cycles": 6,
+    "serve_nodes": 90, "serve_rate": 300.0, "serve_seconds": 2.0,
+    "serve_window": 128, "serve_parity_pods": 48,
+}
+
+LANES = ("plain", "anti-affinity", "affinity", "node-affinity", "spread")
+# the burst kernel each workload lane is built to ride (a lane that drops to
+# another path, or to the oracle, fails its check)
+LANE_OP = {"plain": "burst_uniform", "anti-affinity": "burst_uniform",
+           "affinity": "burst_uniform", "node-affinity": "burst_uniform",
+           "spread": "burst_scan"}
+
+COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+}
+CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "hits",
+    "/jax/compilation_cache/cache_misses": "misses",
+}
+
+
+class Smoke:
+    def __init__(self, sizes: dict, rehearsal: bool):
+        self.sizes = sizes
+        self.rehearsal = rehearsal
+        self.checks: dict[str, bool] = {}
+        self.stages: dict[str, dict] = {}
+        self.compile_seconds = {v: 0.0 for v in COMPILE_EVENTS.values()}
+        self.cache_events = {v: 0 for v in CACHE_EVENTS.values()}
+        # the single-device drain's bindings, the mesh stage's referee
+        self.drain_bindings: dict[str, str] = {}
+
+    # -- bookkeeping ---------------------------------------------------------
+    def check(self, name: str, ok, detail="") -> None:
+        ok = bool(ok)
+        self.checks[name] = ok
+        print(f"  [{'ok' if ok else 'FAIL'}] {name}"
+              + (f": {detail}" if detail or not ok else ""), flush=True)
+
+    def listen(self) -> None:
+        from jax import monitoring
+
+        def on_duration(event, seconds, **_kw):
+            key = COMPILE_EVENTS.get(event)
+            if key is not None:
+                self.compile_seconds[key] += seconds
+
+        def on_event(event, **_kw):
+            key = CACHE_EVENTS.get(event)
+            if key is not None:
+                self.cache_events[key] += 1
+
+        monitoring.register_event_duration_secs_listener(on_duration)
+        monitoring.register_event_listener(on_event)
+
+    def stage(self, name: str, fn) -> None:
+        """Run one stage, time it, split its compile seconds out, then run
+        the after-every-stage health checks."""
+        print(f"== {name}", flush=True)
+        fb0 = fallback_counts()
+        c0 = dict(self.compile_seconds)
+        h0 = dict(self.cache_events)
+        t0 = time.perf_counter()
+        info = fn() or {}
+        wall = time.perf_counter() - t0
+        comp = {k: round(self.compile_seconds[k] - c0[k], 3)
+                for k in c0}
+        fb = delta(fallback_counts(), fb0)
+        self.stages[name] = {
+            "wall_seconds": round(wall, 3),
+            "compile_seconds": round(sum(comp.values()), 3),
+            "compile_split": comp,
+            "cache": {k: self.cache_events[k] - h0[k] for k in h0},
+            "oracle_fallbacks": fb,
+            **info,
+        }
+        print(f"  wall {wall:.1f}s, compile {sum(comp.values()):.1f}s, "
+              f"fallbacks {fb or 'none'}", flush=True)
+        self.health(name)
+
+    def health(self, stage: str) -> None:
+        from kubernetes_tpu import chaos, native
+        from kubernetes_tpu.core import breaker
+        from kubernetes_tpu.store.store import COMMIT_WAVES, Store
+        fb = fallback_counts()
+        self.check(f"{stage}.no_device_fault_fallback",
+                   fb.get("device-fault", 0) == 0
+                   and fb.get("circuit-open", 0) == 0, fb)
+        self.check(f"{stage}.no_breaker_faults",
+                   family_total(breaker.DEVICE_FAULTS) == 0
+                   and breaker.CIRCUIT_STATE.value == breaker.CLOSED)
+        twin_waves = family(COMMIT_WAVES).get("twin", 0)
+        demoted = family_total(chaos.DEMOTIONS)
+        self.check(f"{stage}.store_core_native",
+                   Store(watch_log_size=16).core_impl == "native"
+                   and twin_waves == 0 and demoted == 0,
+                   f"twin_waves={twin_waves} demotions={demoted}")
+        self.check(f"{stage}.native_heap_loaded",
+                   native.load("heapcore") is not None)
+
+
+# -- metric helpers ----------------------------------------------------------
+def family(fam) -> dict:
+    """{first label value: count} of a labelled counter family."""
+    return {k[0]: c.value for k, c in fam._children.items()}
+
+
+def family_total(fam) -> float:
+    return sum(c.value for c in fam._children.values())
+
+
+def fallback_counts() -> dict:
+    from kubernetes_tpu.core.tpu_scheduler import ORACLE_FALLBACKS
+    return family(ORACLE_FALLBACKS)
+
+
+def dispatch_counts() -> dict:
+    from kubernetes_tpu.core.tpu_scheduler import DEVICE_DISPATCH
+    return family(DEVICE_DISPATCH)
+
+
+def delta(now: dict, before: dict) -> dict:
+    """The counters that moved, and by how much."""
+    return {k: int(v - before.get(k, 0))
+            for k, v in now.items() if v - before.get(k, 0)}
+
+
+def dispatch_delta(before: dict) -> dict:
+    return delta(dispatch_counts(), before)
+
+
+def replayed(run_fn) -> tuple[int, list]:
+    """Run `run_fn` with the flight recorder in replay mode and re-derive
+    every captured launch through the serial oracle (the repo's referee).
+    Returns (launches replayed, mismatches)."""
+    from kubernetes_tpu.obs import flight
+    flight.RECORDER.configure(mode="replay", capacity=8)
+    flight.RECORDER.clear()
+    try:
+        run_fn()
+        n = sum(1 for r in flight.RECORDER.records()
+                if r.capture is not None
+                and r.kind in ("uniform", "scan", "fused"))
+        return n, flight.RECORDER.replay_all()
+    finally:
+        flight.RECORDER.configure(mode="digest", capacity=8)
+        flight.RECORDER.clear()
+
+
+# -- stages ------------------------------------------------------------------
+def drain(smoke: Smoke, tag: str, device: bool, n_pods: int, **sched_kw):
+    """Headline-shaped cluster through the CLI's path, drained; checks
+    every pod bound exactly once and returns (scheduler, store,
+    {pod key: node})."""
+    import bench
+    from kubernetes_tpu.apis.config import SchedulerConfiguration
+    from kubernetes_tpu.factory import create_scheduler
+    from kubernetes_tpu.store.store import MODIFIED, PODS, Store
+    s = smoke.sizes
+    store = Store(watch_log_size=1 << 20)        # cmd/scheduler.py's size
+    bench.build_cluster(store, s["drain_nodes"])
+    # the headline scores every node (bench.py run_bench), which is also
+    # what routes a uniform backlog onto the K-batch kernel
+    cfg = SchedulerConfiguration(percentage_of_nodes_to_score=100)
+    if not device:
+        cfg.feature_gates = {"TPUScoring": False}
+    sched = create_scheduler(store, cfg, **sched_kw)
+    if not device:
+        # every oracle cycle at this width is "slow"; keep its per-cycle
+        # step traces out of the output
+        sched.slow_cycle_threshold = float("inf")
+    sched.sync()
+    watch = store.watch(PODS)
+    bench.make_pods(store, n_pods)
+    sched.pump()
+    bound = 0
+    if device:
+        while True:
+            n = sched.schedule_burst(max_pods=s["drain_pods"])
+            if n == 0:
+                break
+            bound += n
+    else:
+        while sched.schedule_one(timeout=0.0):
+            bound += 1
+    sched.pump()
+    binds: dict[str, int] = {}
+    for ev in watch.drain():
+        if ev.type == MODIFIED and ev.obj.node_name:
+            binds[ev.obj.key] = binds.get(ev.obj.key, 0) + 1
+    watch.stop()
+    placed = {p.key: p.node_name for p in store.list(PODS)[0]}
+    smoke.check(f"{tag}.all_bound",
+                bound == n_pods and len(placed) == n_pods
+                and all(placed.values()),
+                f"bound={bound} of {n_pods}")
+    smoke.check(f"{tag}.bound_exactly_once",
+                len(binds) == n_pods and set(binds.values()) == {1},
+                f"{len(binds)} pods saw bind events")
+    return sched, store, placed
+
+
+def stage_drain(smoke: Smoke):
+    import jax
+    from kubernetes_tpu.core.tpu_scheduler import TPUScheduler
+    s = smoke.sizes
+    d0 = dispatch_counts()
+    # with several devices this stage is the single-device referee for the
+    # mesh stage; on one device mesh="auto" is single-device already
+    kw = {"mesh": None} if len(jax.devices()) > 1 else {}
+    sched, store, placed = drain(smoke, "drain", True, s["drain_pods"], **kw)
+    smoke.check("drain.algorithm_is_tpu",
+                isinstance(sched.algorithm, TPUScheduler),
+                type(sched.algorithm).__name__)
+    ops = dispatch_delta(d0)
+    smoke.check("drain.op_burst_uniform", ops.get("burst_uniform", 0) > 0,
+                ops)
+    smoke.check("drain.store_core_native_at_exit",
+                store.core_impl == "native", store.core_impl)
+    t0 = time.perf_counter()
+    _o, _st, want = drain(smoke, "drain.oracle", False, s["oracle_prefix"])
+    oracle_s = time.perf_counter() - t0
+    diff = {k: (placed.get(k), v) for k, v in want.items()
+            if placed.get(k) != v}
+    smoke.check("drain.oracle_prefix_identical", not diff,
+                f"{len(want)} pods compared"
+                + (f", first diffs {dict(list(diff.items())[:3])}"
+                   if diff else ""))
+    smoke.drain_bindings = placed
+    return {"nodes": s["drain_nodes"], "pods": s["drain_pods"],
+            "device_ops": ops, "oracle_prefix": len(want),
+            "oracle_seconds": round(oracle_s, 3)}
+
+
+def lane_stage(lane: str):
+    """One workload lane: a full pass, then a bounded burst replayed
+    through the oracle."""
+    def fn(smoke: Smoke):
+        from kubernetes_tpu.perf.harness import PerfConfig, run
+        s = smoke.sizes
+        d0 = dispatch_counts()
+        res = run(PerfConfig(nodes=s["lane_nodes"],
+                             existing_pods=s["lane_existing"],
+                             pods=s["lane_pods"], workload=lane))
+        ops = dispatch_delta(d0)
+        smoke.check(f"lanes.{lane}.all_scheduled",
+                    res.scheduled == s["lane_pods"],
+                    f"{res.scheduled} of {s['lane_pods']}")
+        smoke.check(f"lanes.{lane}.op_{LANE_OP[lane]}",
+                    ops.get(LANE_OP[lane], 0) > 0, ops)
+        small = PerfConfig(nodes=s["lane_nodes"],
+                           existing_pods=s["lane_existing"],
+                           pods=s["parity_pods"], workload=lane)
+        n, mism = replayed(lambda: run(small, warmup=0))
+        smoke.check(f"lanes.{lane}.replay_parity", n > 0 and not mism,
+                    f"{n} launches replayed" + (f", {mism[:2]}" if mism
+                                                else ""))
+        return {"device_ops": ops}
+    return fn
+
+
+def stage_gang(smoke: Smoke):
+    """All-or-nothing groups through the fused segmented scan."""
+    from kubernetes_tpu.perf.harness import run_gang_cell
+    s = smoke.sizes
+    d0 = dispatch_counts()
+    res = run_gang_cell(nodes=s["lane_nodes"], gang_size=s["gang_size"],
+                        pods=s["lane_pods"])
+    ops = dispatch_delta(d0)
+    groups = max(1, s["lane_pods"] // s["gang_size"])
+    smoke.check("lanes.gang.all_scheduled",
+                res.scheduled == groups * s["gang_size"],
+                f"{res.scheduled} of {groups * s['gang_size']}")
+    smoke.check("lanes.gang.op_burst_fused", ops.get("burst_fused", 0) > 0,
+                ops)
+    n, mism = replayed(lambda: run_gang_cell(
+        nodes=s["lane_nodes"], gang_size=s["gang_size"],
+        pods=s["gang_size"]))
+    smoke.check("lanes.gang.replay_parity", n > 0 and not mism,
+                f"{n} launches replayed" + (f", {mism[:2]}" if mism else ""))
+    return {"device_ops": ops}
+
+
+def stage_preempt(smoke: Smoke):
+    """One preemption pressure wave; run_preempt_cell asserts the device's
+    decisions equal the serial oracle's before it returns."""
+    import bench
+    s = smoke.sizes
+    d0 = dispatch_counts()
+    bench.run_preempt_bench(s["lane_nodes"], s["preempt_victims"],
+                            s["preemptors"])
+    ops = dispatch_delta(d0)
+    smoke.check("lanes.preempt.oracle_identical", True,
+                "asserted inside run_preempt_cell")
+    smoke.check("lanes.preempt.op_pressure_batch",
+                ops.get("pressure_batch", 0) > 0
+                and ops.get("vic_upload", 0) > 0, ops)
+    return {"device_ops": ops}
+
+
+def stage_preempt_scan(smoke: Smoke):
+    """Two single-preemptor victim scans against the oracle Preemptor, the
+    second after a node changed, so preempt_scan compiles and the victim
+    table's dirty-row scatter runs. Priorities and start times vary, which
+    is what the staged node pick ranks on."""
+    import numpy as np
+    from kubernetes_tpu.api.types import Container, Node, Pod
+    from kubernetes_tpu.cache.node_info import NodeInfo
+    from kubernetes_tpu.core.tpu_scheduler import TPUScheduler
+    from kubernetes_tpu.oracle.generic_scheduler import FitError
+    from kubernetes_tpu.oracle.predicates import insufficient_resource
+    from kubernetes_tpu.oracle.preemption import Preemptor
+    s = smoke.sizes
+    rng = np.random.RandomState(0)
+    n_nodes = s["lane_nodes"]
+    per_node = max(2, s["preempt_victims"] // n_nodes)
+    infos, names = {}, []
+    for i in range(n_nodes):
+        node = Node(name=f"node-{i}",
+                    allocatable={"cpu": 4000, "memory": 32 << 30,
+                                 "pods": 110})
+        ni = NodeInfo(node)
+        for j in range(per_node):
+            ni.add_pod(Pod(
+                name=f"victim-{i}-{j}", node_name=node.name,
+                priority=int(rng.randint(0, 4)),
+                start_time=1.7e9 + float(rng.randint(0, 10 ** 6)) / 8.0,
+                containers=(Container.make(
+                    name="c", requests={"cpu": 4000 // per_node}),)))
+        infos[node.name] = ni
+        names.append(node.name)
+    d0 = dispatch_counts()
+    tpu = TPUScheduler(percentage_of_nodes_to_score=100)
+    same = True
+    for k in range(2):
+        incoming = Pod(name=f"hi-{k}", priority=10, containers=(
+            Container.make(name="c", requests={"cpu": 2500}),))
+        err = FitError(incoming, len(names), {
+            n: [insufficient_resource("cpu")] for n in names})
+        want = Preemptor().preempt(incoming, infos, names, err)
+        got = tpu.preempt(incoming, infos, names, err, [])
+        if got is None or want.node is None:
+            same = False
+            break
+        same &= (got.node.name == want.node.name
+                 and sorted(p.key for p in got.victims)
+                 == sorted(p.key for p in want.victims))
+        # the world changes under the resident victim table: the winner's
+        # victims leave, so the next scan re-sorts and scatters that row
+        ni = infos[want.node.name].clone()
+        for p in want.victims:
+            ni.remove_pod(p)
+        infos = {**infos, want.node.name: ni}
+    ops = dispatch_delta(d0)
+    smoke.check("lanes.preempt_scan.oracle_identical", same)
+    smoke.check("lanes.preempt_scan.op_preempt_scan",
+                ops.get("preempt_scan", 0) >= 2
+                and ops.get("vic_scatter", 0) > 0, ops)
+    return {"device_ops": ops}
+
+
+def stage_serial(smoke: Smoke):
+    import jax
+    import jax.numpy as jnp
+    import bench
+    from kubernetes_tpu.obs import trace as obs_trace
+    from kubernetes_tpu.scheduler import Scheduler
+    from kubernetes_tpu.store.store import PODS, Store
+    s = smoke.sizes
+    store = Store(watch_log_size=1 << 16)
+    bench.build_cluster(store, s["serial_nodes"])
+    sched = Scheduler(store, use_tpu=True, percentage_of_nodes_to_score=100)
+    sched.algorithm.serial_path = "device"
+    sched.sync()
+    bench.make_pods(store, s["serial_cycles"])
+    sched.pump()
+    d0 = dispatch_counts()
+    obs_trace.clear()
+    cycle_ms = []
+    while True:
+        t0 = time.perf_counter()
+        if not sched.schedule_one(timeout=0.0):
+            break
+        cycle_ms.append((time.perf_counter() - t0) * 1e3)
+    sched.wait_for_binds()
+    sched.pump()
+    ops = dispatch_delta(d0)
+    smoke.check("serial.all_bound",
+                all(p.node_name for p in store.list(PODS)[0]))
+    smoke.check("serial.op_cycle",
+                ops.get("cycle", 0) == s["serial_cycles"], ops)
+    fetch_ms = [e["dur"] / 1e3 for e in obs_trace.events(cat="device")
+                if e.get("name") == "cycle.fetch"]
+    # the floor under every launch: a trivial program, dispatch + fetch
+    bump = jax.jit(lambda x: x + 1)
+    x = jnp.zeros((), jnp.int32)
+    int(bump(x))
+    tiny_ms = []
+    for _ in range(50):
+        t0 = time.perf_counter()
+        int(bump(x))
+        tiny_ms.append((time.perf_counter() - t0) * 1e3)
+    warm = cycle_ms[1:] or cycle_ms
+    out = {
+        "nodes": s["serial_nodes"], "cycles": len(cycle_ms),
+        "first_cycle_ms": round(cycle_ms[0], 3) if cycle_ms else None,
+        # whole schedule_one (snapshot, encode, dispatch, fetch, bind)
+        "warm_cycle_ms_median": round(statistics.median(warm), 3),
+        # device_get wait of the cycle program: execution + readback
+        "warm_cycle_fetch_ms_median": (
+            round(statistics.median(fetch_ms[1:] or fetch_ms), 3)
+            if fetch_ms else None),
+        "tiny_program_round_trip_ms_median": round(
+            statistics.median(tiny_ms), 4),
+    }
+    print(f"  warm single-pod cycle {out['warm_cycle_ms_median']} ms "
+          f"(fetch wait {out['warm_cycle_fetch_ms_median']} ms); trivial "
+          f"program dispatch+fetch {out['tiny_program_round_trip_ms_median']}"
+          f" ms", flush=True)
+    return out
+
+
+def stage_serve(smoke: Smoke):
+    from kubernetes_tpu.perf.harness import run_serve_cell
+    s = smoke.sizes
+    d0 = dispatch_counts()
+    r = run_serve_cell(n_nodes=s["serve_nodes"],
+                       arrival_rate=s["serve_rate"],
+                       duration=s["serve_seconds"],
+                       window=s["serve_window"], depth=3,
+                       parity_pods=s["serve_parity_pods"], seed=0)
+    ops = dispatch_delta(d0)
+    smoke.check("serve.audit_all_admitted_or_429",
+                r["audit_all_admitted_or_429"])
+    smoke.check("serve.parity_violations_zero",
+                r["parity_violations"] == 0, r["parity_errors"])
+    smoke.check("serve.pods_completed", r["pods_completed"] > 0,
+                r["pods_completed"])
+    smoke.check("serve.op_burst", ops.get("burst_uniform", 0) > 0, ops)
+    return {"nodes": s["serve_nodes"], "arrival_rate": s["serve_rate"],
+            "windows_cut": r["windows_cut"],
+            "pods_completed": r["pods_completed"],
+            "admission": {k: r["admission"].get(k)
+                          for k in ("admitted", "rejected")},
+            "device_ops": ops}
+
+
+def stage_mesh(smoke: Smoke):
+    import jax
+    from kubernetes_tpu.parallel import sharding as S
+    s = smoke.sizes
+    d = len(jax.devices())
+    sched, _store, placed = drain(smoke, "mesh", True, s["drain_pods"])
+    algo = sched.algorithm
+    smoke.check("mesh.mesh_spans_all_devices",
+                algo.mesh is not None and int(algo.mesh.devices.size) == d)
+    n_pad = algo.encoder._batch.n_pad
+    bad = []
+    for k in S._SHARDED_1D:
+        arr = algo._dev_nodes[k]
+        shards = arr.addressable_shards
+        if len({sh.device for sh in shards}) != d or any(
+                sh.data.shape[0] != n_pad // d for sh in shards):
+            bad.append((k, [tuple(sh.data.shape) for sh in shards]))
+    smoke.check("mesh.node_axis_sharded", not bad,
+                bad or f"{len(S._SHARDED_1D)} leaves, {n_pad // d} rows "
+                       f"per device on {d} devices")
+    want = smoke.drain_bindings
+    diff = [k for k, v in want.items() if placed.get(k) != v]
+    smoke.check("mesh.bindings_equal_single_device",
+                len(want) == len(placed) and not diff,
+                f"{len(diff)} of {len(want)} differ")
+    return {"devices": d, "rows_per_device": n_pad // d}
+
+
+# -- entry -------------------------------------------------------------------
+def cache_entries(path: str) -> int:
+    try:
+        return len(os.listdir(path))
+    except FileNotFoundError:
+        return 0
+
+
+def versions() -> dict:
+    import importlib.metadata as md
+    import jax
+    import jaxlib
+    out = {"jax": jax.__version__, "jaxlib": jaxlib.__version__}
+    try:
+        out["libtpu"] = md.version("libtpu")
+    except md.PackageNotFoundError:
+        out["libtpu"] = None
+    return out
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--rehearse-cpu", action="store_true",
+                    help="run on the CPU backend at shrunken sizes; the "
+                         "output is stamped a rehearsal and is never ok")
+    args = ap.parse_args(argv)
+
+    t_start = time.perf_counter()
+    # the package places the compile cache and enables x64 on import,
+    # before anything compiles; jax itself is first touched here
+    import kubernetes_tpu.ops as ops
+    import jax
+    dev = jax.devices()
+    device = {"platform": dev[0].platform, "kind": dev[0].device_kind,
+              "count": len(dev)}
+    if device["platform"] != "tpu" and not args.rehearse_cpu:
+        print(f"chip_smoke: refusing to run: jax reports platform "
+              f"{device['platform']!r} ({device['kind']}), not 'tpu'. This "
+              f"script only proves anything on the chip.", file=sys.stderr)
+        return 2
+    if args.rehearse_cpu and device["platform"] != "cpu":
+        print("chip_smoke: --rehearse-cpu is for the CPU backend "
+              "(JAX_PLATFORMS=cpu)", file=sys.stderr)
+        return 2
+    cache_dir = (os.environ.get(ops.COMPILE_CACHE_ENV)
+                 or ops.DEFAULT_COMPILE_CACHE_DIR)
+    cache_before = cache_entries(cache_dir)
+    print(f"device: {device}  versions: {versions()}", flush=True)
+    print(f"compile cache: {cache_dir} ({cache_before} entries; "
+          f"{'from ' + ops.COMPILE_CACHE_ENV if os.environ.get(ops.COMPILE_CACHE_ENV) else 'in-checkout default'})",
+          flush=True)
+
+    smoke = Smoke(REHEARSAL if args.rehearse_cpu else REAL,
+                  rehearsal=args.rehearse_cpu)
+    smoke.listen()
+
+    # the native cores build here, loudly: the smoke does not run on twins
+    from kubernetes_tpu import native
+    for name in ("commitcore", "heapcore"):
+        if native.load(name) is None:
+            print(f"chip_smoke: native extension {name!r} did not build or "
+                  f"import:\n{native.load_error(name)}", file=sys.stderr)
+            return 3
+
+    stages = [("drain", stage_drain)]
+    stages += [(f"lanes.{lane}", lane_stage(lane)) for lane in LANES]
+    stages += [("lanes.gang", stage_gang), ("lanes.preempt", stage_preempt),
+               ("lanes.preempt_scan", stage_preempt_scan),
+               ("serial", stage_serial), ("serve", stage_serve)]
+    if len(dev) > 1:
+        stages.append(("mesh", stage_mesh))
+    for name, fn in stages:
+        try:
+            smoke.stage(name, lambda fn=fn: fn(smoke))
+        except Exception:
+            # a stage that raises fails the smoke; the later stages still
+            # run, so one call to the chip reports every broken path
+            traceback.print_exc()
+            smoke.check(f"{name}.completed", False)
+    if len(dev) == 1:
+        print("== mesh\n  did not run: one device visible", flush=True)
+
+    passed = all(smoke.checks.values())
+    summary = {
+        "ok": passed and not smoke.rehearsal,
+        "device": device,
+        "versions": versions(),
+        "rehearsal": smoke.rehearsal,
+        "checks_passed": passed,
+        "failed_checks": sorted(k for k, v in smoke.checks.items() if not v),
+        "checks": smoke.checks,
+        "stages": smoke.stages,
+        "mesh_stage": ("ran" if "mesh" in smoke.stages
+                       else "did not run: one device visible"),
+        "wall_seconds": round(time.perf_counter() - t_start, 3),
+        "compile_seconds": round(sum(smoke.compile_seconds.values()), 3),
+        "compile_cache": {
+            "dir": cache_dir,
+            "from_env": bool(os.environ.get(ops.COMPILE_CACHE_ENV)),
+            "entries_before": cache_before,
+            "entries_after": cache_entries(cache_dir),
+            **smoke.cache_events},
+        "claim": None,
+    }
+    print(json.dumps(summary), flush=True)
+    return 0 if passed else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())

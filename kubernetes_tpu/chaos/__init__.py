@@ -2,7 +2,7 @@
 
 The paper's claim is 50x throughput WITH identical binding decisions, and
 that contract is only worth anything if it survives the failure modes a
-sustained soak actually produces: tunnel hiccups mid-burst, store write
+sustained soak actually produces: device faults mid-burst, store write
 failures, slow watchers, native-extension faults, and scheduler restarts.
 This module is the single switchboard for injecting those failures
 DETERMINISTICALLY (per-seam seeded RNG streams — trial N of a chaos sweep
@@ -12,8 +12,8 @@ degradation path in the repo is testable, reproducible, and benchmarkable.
 Named seams (each consumer calls `chaos.check(seam)` / `chaos.take(seam)`
 at the exact point the real failure would surface):
 
-- ``device.dispatch`` / ``device.fetch`` — the TPU drivers raise a
-  tunnel-style fault before a kernel launch / packed-block readback
+- ``device.dispatch`` / ``device.fetch`` — the TPU drivers raise an
+  injected DeviceFault before a kernel launch / packed-block readback
   (core/tpu_scheduler.py; the device circuit breaker consumes these).
 - ``store.commit_wave`` — Store.commit_wave fails BEFORE the core write
   lands (the retry loop re-runs the wave).
@@ -132,9 +132,7 @@ DEMOTIONS = obs.counter(
 
 class InjectedFault(Exception):
     """Base of every chaos-injected failure; `seam` names the injection
-    point. Messages deliberately avoid the bench's transient-error markers
-    so an injected fault is never silently retried by machinery that was
-    not built to consume it."""
+    point."""
 
     def __init__(self, seam: str, message: Optional[str] = None):
         super().__init__(message or f"chaos: injected fault at seam {seam}")
@@ -142,8 +140,11 @@ class InjectedFault(Exception):
 
 
 class DeviceFault(InjectedFault):
-    """Tunnel-style device failure (the JaxRuntimeError stand-in): raised
-    at the dispatch/fetch seams; consumed by the device circuit breaker."""
+    """Injected device failure: raised at the dispatch/fetch seams and
+    consumed by the device circuit breaker. It is the ONLY type the
+    breaker absorbs — a real jax runtime error on a local chip is a
+    compile failure, an out-of-memory or a dead device, all deterministic,
+    so it propagates to the caller instead of degrading to the host."""
 
 
 class StoreFault(InjectedFault):
@@ -194,16 +195,9 @@ _FAULT_FOR = {
 
 
 def device_fault_types() -> tuple:
-    """Exception classes the device circuit breaker treats as a tunnel
-    fault: the injected DeviceFault plus jax's runtime error (the type a
-    real dropped dispatch/readback surfaces as)."""
-    types: tuple = (DeviceFault,)
-    try:
-        from jax.errors import JaxRuntimeError
-        types = types + (JaxRuntimeError,)
-    except Exception:   # pragma: no cover — ancient jax without the alias
-        pass
-    return types
+    """Exception classes the device circuit breaker absorbs: the injected
+    DeviceFault only. Real device errors propagate (see DeviceFault)."""
+    return (DeviceFault,)
 
 
 class ChaosPlan:

@@ -292,10 +292,21 @@ def create_scheduler(store, cfg: Optional[SchedulerConfiguration] = None,
         SchedulerExtender(ec, endpoints=(extender_endpoints or {}).get(
             ec.url_prefix))
         for ec in policy.extenders]
-    use_tpu = bool(cfg.feature_gates.get("TPUScoring")) \
-        and tpu_kernel_weights(prio_weights) is not None \
-        and tpu_supports_predicates(pred_names) \
-        and not extenders
+    use_tpu = bool(cfg.feature_gates.get("TPUScoring"))
+    if use_tpu:
+        # the gate asks for the device; say so when the config can't have it
+        refused = [why for why, hit in (
+            ("a priority with no kernel implementation",
+             tpu_kernel_weights(prio_weights) is None),
+            ("a predicate outside the kernel's set",
+             not tpu_supports_predicates(pred_names)),
+            ("scheduler extenders", bool(extenders))) if hit]
+        if refused:
+            import warnings
+            warnings.warn("TPUScoring is on but scheduling runs on the "
+                          "oracle path, not the TPU kernel path: the "
+                          "configuration has " + ", ".join(refused))
+            use_tpu = False
     kw.setdefault("extenders", extenders)
     # production wiring shards the node axis across every visible chip;
     # direct Scheduler construction stays single-chip unless asked

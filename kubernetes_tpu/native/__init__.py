@@ -3,13 +3,17 @@
 The compute path is JAX/XLA; the runtime around it follows the reference's
 stance of natively-compiled infrastructure (the reference is Go throughout).
 Components live here as single-file CPython extensions compiled lazily into
-this directory (no pip, no network): `load(name)` rebuilds when the source
-is newer than the cached .so and returns None on ANY failure — every
-consumer keeps a pure-Python twin with identical semantics, so a missing
-toolchain degrades performance, never behavior.
+this directory (no pip, no network): `load(name)` rebuilds when the hash of
+the source differs from the one stored beside the cached .so (mtimes say
+nothing in a copied tree) and returns None when the build or the import
+fails — every consumer keeps a pure-Python twin with identical semantics,
+so a missing toolchain degrades performance, never behavior. The failure
+is never silent: `load_error(name)` returns what went wrong, compiler
+stderr included, for callers that must not run on the twin.
 """
 from __future__ import annotations
 
+import hashlib
 import importlib.machinery
 import importlib.util
 import os
@@ -21,6 +25,7 @@ import threading
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _lock = threading.Lock()
 _cache: dict[str, object] = {}
+_errors: dict[str, str] = {}
 
 
 def _asan() -> bool:
@@ -40,22 +45,38 @@ def _so_path(name: str) -> str:
     return os.path.join(_DIR, f"_{name}{variant}{tag}")
 
 
+def _source_digest(src: str) -> str:
+    with open(src, "rb") as f:
+        return hashlib.sha256(f.read()).hexdigest()
+
+
 def _build(name: str, force: bool = False) -> str:
-    """Compile `name`.cpp to its .so when the source is newer than the
-    cached artifact (or unconditionally with `force`, for a cached .so
-    that exists but won't import — stale or ABI-mismatched on this
-    machine, e.g. checked in from a different Python build)."""
+    """Compile `name`.cpp to its .so unless the digest stored beside the
+    cached artifact matches the source (or unconditionally with `force`,
+    for a cached .so that matches but won't import — ABI-mismatched on
+    this machine, e.g. built by a different Python)."""
     src = os.path.join(_DIR, f"{name}.cpp")
     out = _so_path(name)
-    if not force and os.path.exists(out) \
-            and os.path.getmtime(out) >= os.path.getmtime(src):
-        return out
+    stamp = out + ".sha256"
+    digest = _source_digest(src)
+    if not force and os.path.exists(out) and os.path.exists(stamp):
+        with open(stamp) as f:
+            if f.read().strip() == digest:
+                return out
     include = sysconfig.get_paths()["include"]
+    tmp = f"{out}.{os.getpid()}.tmp"
     cmd = ["g++", "-O2", "-std=c++17", "-shared", "-fPIC",
-           f"-I{include}", src, "-o", out]
+           f"-I{include}", src, "-o", tmp]
     if _asan():
         cmd[1:1] = ["-fsanitize=address", "-fno-omit-frame-pointer", "-g"]
-    subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+    try:
+        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+        os.replace(tmp, out)     # a concurrent process never sees half a .so
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
+    with open(stamp, "w") as f:
+        f.write(digest + "\n")
     return out
 
 
@@ -68,22 +89,43 @@ def _import_so(name: str, path: str):
     return mod
 
 
+_LOAD_FAILURES = (OSError, subprocess.SubprocessError, ImportError)
+
+
+def _describe(exc: BaseException) -> str:
+    text = f"{type(exc).__name__}: {exc}"
+    stderr = getattr(exc, "stderr", None)
+    if stderr:
+        if isinstance(stderr, bytes):
+            stderr = stderr.decode(errors="replace")
+        text += "\n" + stderr.strip()
+    return text
+
+
 def load(name: str):
     """Import native module `_name`, building it first if needed. An
     import failure of an up-to-date-looking .so forces one rebuild from
-    source and retries (mtime can't see ABI mismatches). Returns the
+    source and retries (the digest can't see ABI mismatches). Returns the
     module, or None when building/loading fails — g++ absence included —
-    so every consumer degrades to its pure-Python twin."""
+    so every consumer degrades to its pure-Python twin; `load_error` says
+    why."""
     with _lock:
         if name in _cache:
             return _cache[name]
         mod = None
         try:
             mod = _import_so(name, _build(name))
-        except Exception:
+        except _LOAD_FAILURES:
             try:
                 mod = _import_so(name, _build(name, force=True))
-            except Exception:
-                mod = None
+            except _LOAD_FAILURES as e:
+                _errors[name] = _describe(e)
         _cache[name] = mod
         return mod
+
+
+def load_error(name: str):
+    """Why `load(name)` returned None (exception text plus the compiler's
+    stderr), or None when the module loaded or was never requested."""
+    with _lock:
+        return _errors.get(name)

@@ -353,7 +353,7 @@ class TestDevicePipelineCounters:
         assert family_total(T.DEVICE_DISPATCH) > before_disp
         assert family_total(T.DEVICE_FETCHED_BYTES) > before_bytes
         # device-cost attribution: host encode and device dispatch+fetch
-        # are separate spans (fetch-timed, per the tunnel contract)
+        # are separate spans (the fetch span waits for the device)
         cats = {e["name"]: e["cat"] for e in obs.trace.events()}
         assert cats.get("burst.encode") == "host"
         assert cats.get("burst.fetch") == "device"

@@ -582,9 +582,7 @@ class TestBackpressure429:
 
 
 class TestRetryPolicyTable:
-    """Round-18 satellite pin, the client-side sibling of the
-    TRANSIENT_ERROR_MARKERS table test (tests/test_chaos_plane.py): the
-    per-verb-class retry budget is a correctness surface, not a tuning
+    """Round-18 satellite pin: the per-verb-class retry budget is a correctness surface, not a tuning
     knob. In particular: a 409 (ConflictError, FencedError included) is
     a DEFINITIVE answer on every class, and Lease CAS writes (leader
     election acquire/renew/claim) get exactly ONE attempt even for
