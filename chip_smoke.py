@@ -34,9 +34,12 @@ no result; `--rehearse-cpu` is the one explicit way to run it on the CPU
 backend, at shrunken sizes, stamped as a rehearsal that can never read as a
 pass on the chip.
 
-The last line of standard output is one JSON object:
-{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": 1}, ...,
- "claim": null}.
+The last two lines of standard output are one JSON object each: the report
+(versions, every check by name, per-stage wall and compile seconds, the
+compile cache, "claim": null), then the verdict, which holds exactly
+{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": 1}}
+with the device as jax reports it. A rehearsal prints the report and no
+verdict.
 """
 from __future__ import annotations
 
@@ -622,8 +625,7 @@ def main(argv=None) -> int:
         print("== mesh\n  did not run: one device visible", flush=True)
 
     passed = all(smoke.checks.values())
-    summary = {
-        "ok": passed and not smoke.rehearsal,
+    report = {
         "device": device,
         "versions": versions(),
         "rehearsal": smoke.rehearsal,
@@ -643,7 +645,10 @@ def main(argv=None) -> int:
             **smoke.cache_events},
         "claim": None,
     }
-    print(json.dumps(summary), flush=True)
+    print(json.dumps(report), flush=True)
+    if not smoke.rehearsal:
+        # the verdict: these two keys and nothing else, last on stdout
+        print(json.dumps({"ok": passed, "device": device}), flush=True)
     return 0 if passed else 1
 
 
