@@ -99,6 +99,10 @@ ICI_BYTES_PER_ROW = 16
 PRESSURE_GATES = obs.counter(
     "tpu_pressure_gate_rejections_total",
     "preempt_pressure_burst refusals, by gate.", ("gate",))
+SCATTER_ROWS = obs.counter(
+    "tpu_scatter_rows_total",
+    "Dirty node rows re-uploaded by the row scatter: the rows that "
+    "changed, without the padding to the scatter's power-of-two bucket.")
 DISCARDED_FOLDS = obs.counter(
     "tpu_burst_folds_discarded_total",
     "Device-resident burst folds dropped after a mid-burst failure.")
@@ -108,18 +112,47 @@ GANG_REWIND_FOLDS = obs.counter(
     "trial-placed gang that missed minMember dropped its in-flight folds "
     "and the carries rewound to the pre-gang checkpoint.")
 
-# span names for the burst phase markers ("kernel" is the async dispatch,
-# which returns before the device finishes; "fetch" waits for the result,
-# so it holds the device's execution time plus the readback)
-_PHASE_SPANS = {"encode": ("burst.encode", "host"),
-                "kernel": ("burst.dispatch", "device"),
-                "fetch": ("burst.fetch", "device")}
-# phase -> pod-lifecycle ledger stamp slot: the same boundary that closes
-# a burst phase span stamps every in-flight pod of the burst (one clock
-# read + O(pods) dict writes; committed pods already left the ledger)
-_PHASE_SLOTS = {"encode": obs_ledger.ENCODE,
-                "kernel": obs_ledger.DISPATCH,
-                "fetch": obs_ledger.FETCH}
+# burst phase -> (span name, span category, pod-lifecycle ledger slot).
+# "kernel" is the async dispatch, which returns before the device finishes;
+# "fetch" waits for the result, so it holds the device's execution time
+# plus the readback.
+_PHASES = {"encode": ("burst.encode", "host", obs_ledger.ENCODE),
+           "kernel": ("burst.dispatch", "device", obs_ledger.DISPATCH),
+           "fetch": ("burst.fetch", "device", obs_ledger.FETCH)}
+
+
+class _BurstPhases:
+    """The phase boundaries of one burst. `open(phase)` opens the phase's
+    span; `close()` ends it and, at that same instant, stamps the ledger
+    slot of every in-flight pod of the burst (one clock read + O(pods) dict
+    writes; committed pods already left the ledger) and observes the phase
+    histogram. `abandon()` ends a phase that a refusal or a fault cut
+    short: the span stands (the host spent that time), nothing is stamped."""
+
+    __slots__ = ("_metrics", "_keys", "_phase", "_span")
+
+    def __init__(self, metrics, keys: list):
+        self._metrics = metrics
+        self._keys = keys
+        self._phase = self._span = None
+
+    def open(self, phase: str) -> None:
+        name, cat, _slot = _PHASES[phase]
+        self._phase = phase
+        self._span = obs_trace.begin(name, cat=cat)
+
+    def close(self) -> None:
+        phase, span = self._phase, self._span
+        self._phase = self._span = None
+        now = span.end()
+        if self._metrics is not None:
+            self._metrics.observe_phase(phase, now - span.t0)
+        obs_ledger.LEDGER.stamp_many(self._keys, _PHASES[phase][2], t=now)
+
+    def abandon(self) -> None:
+        if self._span is not None:
+            self._span.end()
+            self._phase = self._span = None
 
 # every reason the victim-table eligibility gate can refuse a preemption
 # for (the old single "victims-not-inert" label, split per class so
@@ -393,12 +426,15 @@ class TPUScheduler:
         node axis is split across the chips at upload time."""
         key = (b.n_pad, len(b.scalar_names), id(b))
         if self._dev_nodes is None or self._dev_key != key or b.dirty_rows is None:
-            host = {k: np.asarray(getattr(b, k)) for k in self._NODE_FIELDS}
-            if self.mesh is not None:
-                from kubernetes_tpu.parallel import sharding as S
-                self._dev_nodes = S.shard_node_arrays(self.mesh, host)
-            else:
-                self._dev_nodes = {k: jnp.asarray(v) for k, v in host.items()}
+            with obs_trace.span("burst.upload", cat="device", rows=b.n_pad):
+                host = {k: np.asarray(getattr(b, k))
+                        for k in self._NODE_FIELDS}
+                if self.mesh is not None:
+                    from kubernetes_tpu.parallel import sharding as S
+                    self._dev_nodes = S.shard_node_arrays(self.mesh, host)
+                else:
+                    self._dev_nodes = {k: jnp.asarray(v)
+                                       for k, v in host.items()}
             DEVICE_DISPATCH.labels("upload").inc()
             self._dev_epoch += 1
             self._dev_key = key
@@ -409,12 +445,16 @@ class TPUScheduler:
             # (duplicate writes of identical values are harmless) so the
             # scatter compiles per bucket, not per row count
             rows = np.asarray(sorted(set(b.dirty_rows)), dtype=np.int32)
-            bucket = _pad_pow2(len(rows), 16)
-            rows = np.concatenate(
-                [rows, np.full(bucket - len(rows), rows[0], dtype=np.int32)])
-            upd = {k: getattr(b, k)[rows] for k in self._NODE_FIELDS}
-            self._dev_nodes = _scatter_rows(self._dev_nodes, rows, upd)
+            n_rows = len(rows)
+            with obs_trace.span("burst.scatter", cat="device", rows=n_rows):
+                bucket = _pad_pow2(n_rows, 16)
+                rows = np.concatenate(
+                    [rows,
+                     np.full(bucket - n_rows, rows[0], dtype=np.int32)])
+                upd = {k: getattr(b, k)[rows] for k in self._NODE_FIELDS}
+                self._dev_nodes = _scatter_rows(self._dev_nodes, rows, upd)
             DEVICE_DISPATCH.labels("scatter").inc()
+            SCATTER_ROWS.inc(n_rows)
             self._dev_epoch += 1
             b.dirty_rows = []
         return self._dev_nodes
@@ -750,17 +790,15 @@ class TPUScheduler:
             fetch.update(kept=out["kept"], total=out["total"],
                          fail_first=out["fail_first"],
                          general_bits=out["general_bits"])
-        t_fetch = obs_trace.now()
-        chaos.node_dead_point("dispatch-fetch")
-        chaos.check("device.fetch")
-        h = jax.device_get(fetch)
-        chaos.node_dead_point("fetch-commit")
+        with obs_trace.span("cycle.fetch", cat="device"):
+            chaos.node_dead_point("dispatch-fetch")
+            chaos.check("device.fetch")
+            h = jax.device_get(fetch)
+            chaos.node_dead_point("fetch-commit")
         self.breaker.record_success()
         DEVICE_DISPATCH.labels("cycle").inc()
         DEVICE_FETCHES.labels("cycle").inc()
         DEVICE_FETCHED_BYTES.labels("cycle").inc(_fetched_nbytes(h))
-        obs_trace.add_span("cycle.fetch", t_fetch, obs_trace.now(),
-                           cat="device")
         found = int(h["found"])
         evaluated = int(h["evaluated"])
         start = self.last_index
@@ -1250,25 +1288,28 @@ class TPUScheduler:
             # schedule() picks the host twin under the same open circuit
             ORACLE_FALLBACKS.labels("circuit-open").inc()
             return None
-        import time as _time
-        _t0 = _time.perf_counter()
-        _keys = [p.key for p in pods]
+        ph = _BurstPhases(self.metrics, [p.key for p in pods])
+        ph.open("encode")
+        try:
+            return self._burst_phases(ph, pods, node_infos, all_node_names,
+                                      bucket, commit)
+        finally:
+            ph.abandon()   # a refusal or an error inside a phase
 
-        def _obs(phase: str, t_start: float) -> float:
-            now = _time.perf_counter()
-            if self.metrics is not None:
-                self.metrics.observe_phase(phase, now - t_start)
-            name, cat = _PHASE_SPANS[phase]
-            obs_trace.add_span(name, t_start, now, cat=cat)
-            obs_ledger.LEDGER.stamp_many(_keys, _PHASE_SLOTS[phase], t=now)
-            return now
+    def _burst_phases(self, ph: _BurstPhases, pods: list[Pod],
+                      node_infos: dict[str, NodeInfo],
+                      all_node_names: list[str], bucket: Optional[int],
+                      commit) -> Optional[list[Optional[str]]]:
+        """schedule_burst from the open encode phase on: encode, then the
+        wave driver's dispatch and fetch phases."""
         # stable-axis mode: keep the resident mirror/device axis when this
         # enumeration is a proven rotation of it (cycle 0 rides order id
         # start0) — the serving lane's windows skip the per-window permute
         # + full re-upload entirely
         axis_order, start0 = self._axis_order(all_node_names)
-        b = self.encoder.encode(node_infos, axis_order)
-        nodes = self._node_arrays(b)
+        with obs_trace.span("burst.encode.nodes"):
+            b = self.encoder.encode(node_infos, axis_order)
+        self._node_arrays(b)
         enc = PodEncoder(node_infos, b, self.services_fn(), self.replicasets_fn(),
                          hard_pod_affinity_weight=self.hard_pod_affinity_weight,
                          enabled=self.enabled_predicates,
@@ -1298,8 +1339,9 @@ class TPUScheduler:
             # feature encoding (IPA topology counting in particular) is the
             # dominant host cost for affinity bursts
             if uniform_spec:
-                uniform = self._uniform_class(pods[0], enc.encode(pods[0]),
-                                              b, node_infos)
+                with obs_trace.span("burst.encode.pods"):
+                    uniform = self._uniform_class(
+                        pods[0], enc.encode(pods[0]), b, node_infos)
         if uniform is not None:
             # K-pods-per-pass kernel: dynamic pod count (one compile for any
             # burst size), carried int32 scores, consecutive-tie-rank batch
@@ -1310,9 +1352,9 @@ class TPUScheduler:
             # the cache's NodeInfos (deep capture clones the world here)
             fl = obs_flight.RECORDER.begin("uniform", self, [(pods, False)],
                                            all_node_names, node_infos)
-            _t = _obs("encode", _t0)
+            ph.close()
             sel = self._uniform_waves(pods, b, cls, extra_ok, ban, rotation,
-                                      n, commit, _obs, _t, bucket, fl=fl,
+                                      n, commit, ph, bucket, fl=fl,
                                       pid=pid0)
             if sel is None:
                 # device fault during a commit-less trial: whole-burst
@@ -1334,16 +1376,17 @@ class TPUScheduler:
         # fixed snapshot: encode ONE pod per signature and share (the O(N)
         # python feature loops — spread counting especially — dominate
         # otherwise; interned sigs make the memo an identity-hit dict)
-        if uniform_spec:
-            feats = [enc.encode(pods[0])] * len(pods)
-        else:
-            feat_by_sig: dict = {}
-            feats = []
-            for p, sig in zip(pods, sigs):
-                f = feat_by_sig.get(sig)
-                if f is None:
-                    f = feat_by_sig[sig] = enc.encode(p)
-                feats.append(f)
+        with obs_trace.span("burst.encode.pods"):
+            if uniform_spec:
+                feats = [enc.encode(pods[0])] * len(pods)
+            else:
+                feat_by_sig: dict = {}
+                feats = []
+                for p, sig in zip(pods, sigs):
+                    f = feat_by_sig.get(sig)
+                    if f is None:
+                        f = feat_by_sig[sig] = enc.encode(p)
+                    feats.append(f)
         # selector-spread counts change with every in-burst placement; the
         # scan carries them only for spec-identical pods (one selector set)
         carry_spread = any(f.spread_counts is not None for f in feats)
@@ -1412,14 +1455,14 @@ class TPUScheduler:
                        for i, pp in enumerate(per_pod)]
         fl = obs_flight.RECORDER.begin("scan", self, [(pods, False)],
                                        all_node_names, node_infos)
-        _t = _obs("encode", _t0)
+        ph.close()
         return self._scan_waves(pods, b, per_pod, spread0, rotation,
                                 rotation_pos, num_to_find, n, z_pad, bucket,
-                                commit, _obs, _t, fl=fl)
+                                commit, ph, fl=fl)
 
     def _uniform_waves(self, pods: list[Pod], b: NodeBatch, cls, extra_ok,
-                       ban: bool, rotation, n: int, commit, _obs,
-                       _t: float, bucket: int,
+                       ban: bool, rotation, n: int, commit,
+                       ph: _BurstPhases, bucket: int,
                        fl=None, pid: int = 0) -> Optional[list]:
         """Single-launch driver for the uniform kernel: the ENTIRE burst
         (up to B_CAP; larger bursts chunk, with chunk k's fetch+commit
@@ -1455,7 +1498,8 @@ class TPUScheduler:
         inflight: list[tuple] = []
 
         def dispatch(ci: int) -> None:
-            nonlocal lni_dev, _t
+            nonlocal lni_dev
+            ph.open("kernel")
             lo, chunk = chunks[ci]
             rot = rotation
             if rotation is not None:
@@ -1477,7 +1521,7 @@ class TPUScheduler:
             lni_dev = lni_out
             self._dev_nodes = {**self._dev_nodes, **rows}
             DEVICE_DISPATCH.labels("burst_uniform").inc()
-            _t = _obs("kernel", _t)   # dispatch (async; fetch waits)
+            ph.close()   # dispatch (async; fetch waits)
             inflight.append((ci, lo, chunk, self._submit_fetch(packed),
                              t_d))
             self.inflight_launches = len(inflight)
@@ -1498,6 +1542,7 @@ class TPUScheduler:
                     next_ci += 1
                 ci, lo, chunk, fut, t_d = inflight.pop(0)
                 self.inflight_launches = len(inflight)
+                ph.open("fetch")
                 chaos.node_dead_point("dispatch-fetch")
                 chaos.check("device.fetch")
                 h = fut.result()  # ONE fetch per launch: selections + lni
@@ -1505,10 +1550,15 @@ class TPUScheduler:
                 t_done = obs_trace.now()
                 DEVICE_FETCHES.labels("burst_uniform").inc()
                 DEVICE_FETCHED_BYTES.labels("burst_uniform").inc(h.nbytes)
+                # the launch in flight, from its dispatch to its block on
+                # the host: it overlaps the successor's dispatch and the
+                # predecessor's commit, so it is no scoped region and
+                # lives in the ring alone (the profiler's trace has the
+                # device's own lines for it)
                 obs_trace.add_span("burst.wave.device", t_d, t_done,
                                    cat="device", args={"chunk": ci})
                 obs_flight.RECORDER.note_block(fl, h)
-                _t = _obs("fetch", _t)
+                ph.close()
                 chunk_sel = h[:chunk].tolist()
                 bad = next((i for i, s in enumerate(chunk_sel) if s < 0),
                            chunk)
@@ -1555,15 +1605,13 @@ class TPUScheduler:
                                      else None),
                             "committed0": lo + wlo, "committed1": lo + hi,
                         }
-                        t_c0 = obs_trace.now()
-                        ok = commit(lo + wlo,
-                                    [b.names[s] for s in chunk_sel[wlo:hi]])
-                        t_c1 = obs_trace.now()
-                        obs_trace.add_span("burst.wave.commit", t_c0, t_c1,
-                                           cat="host", args={"chunk": ci})
+                        with obs_trace.span("burst.wave.commit",
+                                            chunk=ci) as sp:
+                            ok = commit(
+                                lo + wlo,
+                                [b.names[s] for s in chunk_sel[wlo:hi]])
                         if inflight:
-                            PIPELINE_OVERLAP.inc(t_c1 - t_c0)
-                        _t = t_c1
+                            PIPELINE_OVERLAP.inc(sp.t1 - sp.t0)
                         if not ok:
                             aborted = True
                             break
@@ -1613,8 +1661,8 @@ class TPUScheduler:
 
     def _scan_waves(self, pods: list[Pod], b: NodeBatch, per_pod: list,
                     spread0, rotation, rotation_pos, num_to_find: int,
-                    n: int, z_pad: int, bucket: int, commit, _obs,
-                    _t: float, fl=None) -> list[Optional[str]]:
+                    n: int, z_pad: int, bucket: int, commit,
+                    ph: _BurstPhases, fl=None) -> list[Optional[str]]:
         """Single-launch driver for the generic lax.scan burst: the whole
         burst runs as ONE scan launch (scan length = the caller's bucket,
         so the warmup burst compiles the same program) and the host
@@ -1645,6 +1693,7 @@ class TPUScheduler:
         elif rotation_pos is not None:
             rotp = (rotation_pos[0],
                     np.asarray(rotation_pos[1][:B], dtype=np.int32))
+        ph.open("kernel")
         t_d = obs_trace.now()
         try:
             chaos.check("device.dispatch")
@@ -1657,7 +1706,8 @@ class TPUScheduler:
                 mesh=self.mesh, wtab=self._wtab() if tensor else None)
             self._note_ici("burst_scan", n_pods, b.n_pad)
             DEVICE_DISPATCH.labels("burst_scan").inc()
-            _t = _obs("kernel", _t)
+            ph.close()
+            ph.open("fetch")
             chaos.node_dead_point("dispatch-fetch")
             chaos.check("device.fetch")
             h = np.asarray(self._submit_fetch(outs["packed"]).result())
@@ -1679,7 +1729,7 @@ class TPUScheduler:
         DEVICE_FETCHED_BYTES.labels("burst_scan").inc(h.nbytes)
         obs_trace.add_span("burst.wave.device", t_d, t_done, cat="device")
         obs_flight.RECORDER.note_block(fl, h)
-        _t = _obs("fetch", _t)
+        ph.close()
         sel_arr = h[:n_pods]
         li_after = h[B:2 * B]
         lni_delta = h[2 * B:3 * B]
@@ -1722,13 +1772,9 @@ class TPUScheduler:
                     "lni1": lni0 + int(lni_delta[hi - 1]),
                     "committed0": wlo, "committed1": hi,
                 }
-                t_c0 = obs_trace.now()
-                ok = commit(wlo,
-                            [b.names[s] for s in sel_arr[wlo:hi].tolist()])
-                t_c1 = obs_trace.now()
-                obs_trace.add_span("burst.wave.commit", t_c0, t_c1,
-                                   cat="host")
-                _t = t_c1
+                with obs_trace.span("burst.wave.commit"):
+                    ok = commit(
+                        wlo, [b.names[s] for s in sel_arr[wlo:hi].tolist()])
                 committed = hi
                 if not ok:
                     aborted = True
@@ -1817,21 +1863,21 @@ class TPUScheduler:
             # reservations) have no segment-rewind story on device
             ORACLE_FALLBACKS.labels("fused-pod-features").inc()
             return None
-        import time as _time
-        _t0 = _time.perf_counter()
-        _keys = [p.key for p in flat]
+        ph = _BurstPhases(self.metrics, [p.key for p in flat])
+        ph.open("encode")
+        try:
+            return self._fused_phases(ph, segments, flat, n_total,
+                                      node_infos, all_node_names, bucket)
+        finally:
+            ph.abandon()   # a refusal or an error inside a phase
 
-        def _obs(phase: str, t_start: float) -> float:
-            now = _time.perf_counter()
-            if self.metrics is not None:
-                self.metrics.observe_phase(phase, now - t_start)
-            name, cat = _PHASE_SPANS[phase]
-            obs_trace.add_span(name, t_start, now, cat=cat)
-            obs_ledger.LEDGER.stamp_many(_keys, _PHASE_SLOTS[phase], t=now)
-            return now
-
+    def _fused_phases(self, ph: _BurstPhases, segments, flat: list[Pod],
+                      n_total: int, node_infos: dict[str, NodeInfo],
+                      all_node_names: list[str], bucket: Optional[int]):
+        """schedule_burst_fused from the open encode phase on."""
         axis_order, start0 = self._axis_order(all_node_names)
-        b = self.encoder.encode(node_infos, axis_order)
+        with obs_trace.span("burst.encode.nodes"):
+            b = self.encoder.encode(node_infos, axis_order)
         nodes = self._node_arrays(b)
         enc = PodEncoder(node_infos, b, self.services_fn(),
                          self.replicasets_fn(),
@@ -1843,24 +1889,27 @@ class TPUScheduler:
         feat_by_sig: dict = {}
         arr_by_sig: dict = {}
         per_pod = []
-        for p, sig in zip(flat, self._signatures(flat)):
-            f = feat_by_sig.get(sig)
-            if f is None:
-                f = feat_by_sig[sig] = enc.encode(p)
-            if f.spread_counts is not None:
-                # selector-spread counts carry through rewinds only with a
-                # checkpointed spread vector the shell's plain-class gate
-                # already excludes; refuse rather than drift
-                ORACLE_FALLBACKS.labels("fused-spread-selectors").inc()
-                return None
-            pp = arr_by_sig.get(sig)
-            if pp is None:
-                # one array dict per signature: repeated specs broadcast
-                # by identity through _stack_pods (same values — equal
-                # sigs imply identical _pod_arrays output)
-                pp = arr_by_sig[sig] = self._pod_arrays(
-                    f, b.n_pad, upd_fields=True, pod=p)
-            per_pod.append(pp)
+        with obs_trace.span("burst.encode.pods"):
+            for p, sig in zip(flat, self._signatures(flat)):
+                f = feat_by_sig.get(sig)
+                if f is None:
+                    f = feat_by_sig[sig] = enc.encode(p)
+                if f.spread_counts is not None:
+                    # selector-spread counts carry through rewinds only
+                    # with a checkpointed spread vector the shell's
+                    # plain-class gate already excludes; refuse rather
+                    # than drift
+                    ORACLE_FALLBACKS.labels("fused-spread-selectors").inc()
+                    return None
+                pp = arr_by_sig.get(sig)
+                if pp is None:
+                    # one array dict per signature: repeated specs
+                    # broadcast by identity through _stack_pods (same
+                    # values — equal sigs imply identical _pod_arrays
+                    # output)
+                    pp = arr_by_sig[sig] = self._pod_arrays(
+                        f, b.n_pad, upd_fields=True, pod=p)
+                per_pod.append(pp)
         pids = self._profile_ids(flat)
         if pids is not None:
             # tensor mode: each pod selects its weight row in-kernel; the
@@ -1901,7 +1950,8 @@ class TPUScheduler:
         # boundaries, rewinds and rotation state all ride one launch
         fl = obs_flight.RECORDER.begin("fused", self, segments,
                                        all_node_names, node_infos)
-        _t = _obs("encode", _t0)
+        ph.close()
+        ph.open("kernel")
         t_d = obs_trace.now()
         try:
             chaos.check("device.dispatch")
@@ -1915,7 +1965,8 @@ class TPUScheduler:
                 gang_score=self._gang_score)
             self._note_ici("burst_fused", n_total, b.n_pad)
             DEVICE_DISPATCH.labels("burst_fused").inc()
-            _t = _obs("kernel", _t)
+            ph.close()
+            ph.open("fetch")
             chaos.node_dead_point("dispatch-fetch")
             chaos.check("device.fetch")
             h = np.asarray(self._submit_fetch(packed).result())
@@ -1938,7 +1989,7 @@ class TPUScheduler:
         DEVICE_FETCHED_BYTES.labels("burst_fused").inc(h.nbytes)
         obs_trace.add_span("burst.wave.device", t_d, t_done, cat="device")
         obs_flight.RECORDER.note_block(fl, h)
-        _obs("fetch", _t)
+        ph.close()
         sel = h[:B]
         li_after = h[B:2 * B]
         lni_delta = h[2 * B:3 * B]
@@ -2121,14 +2172,14 @@ class TPUScheduler:
         pod_in = {"req_cpu": np.int64(req.milli_cpu),
                   "req_mem": np.int64(req.memory),
                   "req_eph": np.int64(req.ephemeral_storage)}
-        t_scan = obs_trace.now()
         try:
-            chaos.check("device.dispatch")
-            chaos.check("device.fetch")
-            out = np.asarray(K.preemption_scan(
-                nodes, vic, pod_in, feas, order_rank, b.n_real,
-                self.check_resources, f.has_request, pod.priority,
-                mesh=self.mesh))
+            with obs_trace.span("preempt.scan", cat="device"):
+                chaos.check("device.dispatch")
+                chaos.check("device.fetch")
+                out = np.asarray(K.preemption_scan(
+                    nodes, vic, pod_in, feas, order_rank, b.n_real,
+                    self.check_resources, f.has_request, pod.priority,
+                    mesh=self.mesh))
             self._note_ici("preempt_scan", 1, b.n_pad)
         except _DEVICE_FAULTS as e:
             # the scan reads resident state and mutates nothing: refuse —
@@ -2141,8 +2192,6 @@ class TPUScheduler:
         DEVICE_DISPATCH.labels("preempt_scan").inc()
         DEVICE_FETCHES.labels("preempt_scan").inc()
         DEVICE_FETCHED_BYTES.labels("preempt_scan").inc(out.nbytes)
-        obs_trace.add_span("preempt.scan", t_scan, obs_trace.now(),
-                           cat="device")
         winner = int(out[0])
         if winner < 0:
             return PreemptionResult(None, [], [])
@@ -2400,6 +2449,8 @@ class TPUScheduler:
         # encode + delta upload; everything below is dispatch + the one
         # fetch that pays the round trip (bench --mode preempt reports it)
         _t_enc = _time.perf_counter()
+        # after the fact and in the ring alone: the gates above return
+        # from the middle of it, and no benchmark cell drives this path
         obs_trace.add_span("pressure.encode", _t0, _t_enc, cat="host")
         outs_chunks = []
         try:
@@ -2419,9 +2470,9 @@ class TPUScheduler:
                 DEVICE_DISPATCH.labels("pressure_batch").inc()
                 outs_chunks.append(outs)
             # ONE fetch for every chunk's outputs + the final counters
-            t_fetch = obs_trace.now()
-            chaos.check("device.fetch")
-            h_chunks, li, lni = jax.device_get((outs_chunks, li, lni))
+            with obs_trace.span("pressure.fetch", cat="device"):
+                chaos.check("device.fetch")
+                h_chunks, li, lni = jax.device_get((outs_chunks, li, lni))
         except _DEVICE_FAULTS as e:
             # everything so far is device-local (the resident matrix,
             # counters, and host mirror are untouched until after the
@@ -2438,8 +2489,6 @@ class TPUScheduler:
         DEVICE_FETCHES.labels("pressure_batch").inc()
         DEVICE_FETCHED_BYTES.labels("pressure_batch").inc(
             _fetched_nbytes(h_chunks))
-        obs_trace.add_span("pressure.fetch", t_fetch, obs_trace.now(),
-                           cat="device")
         self.last_preempt_phases = {
             "encode": _t_enc - _t0,
             "scan": _time.perf_counter() - _t_enc,

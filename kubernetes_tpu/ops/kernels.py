@@ -19,6 +19,13 @@ reference's *sequential* semantics are reproduced exactly:
 The batched variant runs a `lax.scan` over a burst of pending pods against
 one snapshot, folding each decision's resource deltas into the node state on
 device — serially-equivalent decisions at one kernel launch for the burst.
+
+Every core names its stages with `jax.named_scope`, in upstream's words:
+`filter` (feasibility and the adaptive walk; for preemption, the victim
+selection), `score`, `pick` (selectHost; pickOneNodeForPreemption) and `fold`
+(the decision's delta into the carried node state). A scope is op metadata,
+set while tracing and free at run time; a device trace is read by these names
+(`SCOPES`), which survive a refactor that renumbers `fusion.9`.
 """
 from __future__ import annotations
 
@@ -33,6 +40,7 @@ import kubernetes_tpu.ops  # noqa: F401  (enables x64)
 from kubernetes_tpu.ops import exactf64 as xf
 
 MAX_PRIORITY = 10
+SCOPES = ("filter", "score", "pick", "fold")
 MB = 1024 * 1024
 IMAGE_MIN = 23 * MB
 IMAGE_MAX = 1000 * MB
@@ -221,6 +229,7 @@ def _local_total(weights, req_cpu, req_mem, alloc_cpu, alloc_mem,
     return total
 
 
+@jax.named_scope("score")
 def _fit_scores(nodes, pod, kept, weights, z_pad, wrow=None, gang=None):
     """Enabled priorities, masked-normalized over `kept`. Returns total[N] i64.
 
@@ -364,6 +373,7 @@ def _fit_scores(nodes, pod, kept, weights, z_pad, wrow=None, gang=None):
     return total + const
 
 
+@jax.named_scope("filter")
 def _feasibility(nodes, pod):
     """Returns (feasible[N], fail_first[N] i8, general_bits[N] i64).
 
@@ -485,64 +495,67 @@ def _cycle_core(nodes, pod, last_index, last_node_index, num_to_find, n_real,
     feasible, fail_first, general_bits = _feasibility(fnodes, pod)
     feas = feasible & in_range
 
-    if pos is not None:
-        # full-scan regime (num_to_find >= n by caller contract): every
-        # feasible node is kept and the walk always evaluates all n, so no
-        # position-space cumsum machinery is needed at all
-        F = jnp.sum(feas.astype(i32))
-        kept = feas
-        found = jnp.minimum(F, ntf)
-        evaluated = jnp.where(pod["skip"], 0, nr).astype(jnp.int64)
-    else:
-        feas_p = feas if perm is None else feas[perm]
-        S = jnp.cumsum(feas_p.astype(i32))
-        F = S[-1]                                   # total feasible
-        pre = jnp.where(li > 0, S[jnp.maximum(li - 1, 0)], 0)
-        after = i >= li                              # position space
-        rank_p = jnp.where(after, S - pre, F - pre + S)  # rank at position p
-        kept_p = feas_p & (rank_p <= ntf)
-        kept = kept_p if perm is None else kept_p[inv_perm]
-        found = jnp.minimum(F, ntf)
-        reached = F >= ntf
-        # the position where the sequential walk stops: unique feasible p
-        # with rank == num_to_find; evaluated = its rotation offset + 1
-        pstar = jnp.argmax(kept_p & (rank_p == ntf)).astype(i32)
-        stop_pos = jnp.where(pstar >= li, pstar - li, nr - li + pstar)
-        evaluated = jnp.where(reached, stop_pos + 1, nr)
-        # a skip (bucket-padding) pod consumes no rotation state
-        evaluated = jnp.where(pod["skip"], 0, evaluated).astype(jnp.int64)
+    with jax.named_scope("filter"):
+        if pos is not None:
+            # full-scan regime (num_to_find >= n by caller contract): every
+            # feasible node is kept and the walk always evaluates all n, so no
+            # position-space cumsum machinery is needed at all
+            F = jnp.sum(feas.astype(i32))
+            kept = feas
+            found = jnp.minimum(F, ntf)
+            evaluated = jnp.where(pod["skip"], 0, nr).astype(jnp.int64)
+        else:
+            feas_p = feas if perm is None else feas[perm]
+            S = jnp.cumsum(feas_p.astype(i32))
+            F = S[-1]                                   # total feasible
+            pre = jnp.where(li > 0, S[jnp.maximum(li - 1, 0)], 0)
+            after = i >= li                              # position space
+            # rank at position p
+            rank_p = jnp.where(after, S - pre, F - pre + S)
+            kept_p = feas_p & (rank_p <= ntf)
+            kept = kept_p if perm is None else kept_p[inv_perm]
+            found = jnp.minimum(F, ntf)
+            reached = F >= ntf
+            # the position where the sequential walk stops: unique feasible p
+            # with rank == num_to_find; evaluated = its rotation offset + 1
+            pstar = jnp.argmax(kept_p & (rank_p == ntf)).astype(i32)
+            stop_pos = jnp.where(pstar >= li, pstar - li, nr - li + pstar)
+            evaluated = jnp.where(reached, stop_pos + 1, nr)
+            # a skip (bucket-padding) pod consumes no rotation state
+            evaluated = jnp.where(pod["skip"], 0, evaluated).astype(jnp.int64)
 
     wrow = None if wtab is None else wtab[pod["profile_id"]]
     total = _fit_scores(nodes, pod, kept, weights, z_pad, wrow=wrow,
                         gang=gang)
 
-    tmask = jnp.where(kept, total, jnp.iinfo(jnp.int64).min)
-    max_score = jnp.max(tmask)
-    is_tie = kept & (tmask == max_score)
-    num_ties = jnp.maximum(jnp.sum(is_tie.astype(i32)), 1)
-    # round-robin k-th tie in rotation order (selectHost :286-295)
-    k = (last_node_index % num_ties.astype(jnp.int64)).astype(i32)
-    if pos is not None:
-        # k-th tie by enumeration position relative to the walk origin:
-        # one sort replaces the permuted cumsum + two gathers. Positions of
-        # valid nodes are distinct in [0, n); ties exclude invalid rows.
-        rel = jnp.where(pos >= li, pos - li, nr - li + pos)
-        t_pos = jnp.where(is_tie, rel, jnp.int32(2 ** 30))
-        kth = jax.lax.dynamic_slice(jnp.sort(t_pos), (k,), (1,))[0]
-        sel = jnp.argmax(is_tie & (rel == kth)).astype(jnp.int64)
-    elif perm is None:
-        tie_p = is_tie
-        T = jnp.cumsum(tie_p.astype(i32))
-        preT = jnp.where(li > 0, T[jnp.maximum(li - 1, 0)], 0)
-        trank = jnp.where(after, T - preT, T[-1] - preT + T)
-        sel = jnp.argmax(tie_p & (trank == k + 1)).astype(jnp.int64)
-    else:
-        tie_p = is_tie[perm]
-        T = jnp.cumsum(tie_p.astype(i32))
-        preT = jnp.where(li > 0, T[jnp.maximum(li - 1, 0)], 0)
-        trank = jnp.where(after, T - preT, T[-1] - preT + T)
-        sel_p = jnp.argmax(tie_p & (trank == k + 1)).astype(jnp.int64)
-        sel = perm[sel_p].astype(jnp.int64)
+    with jax.named_scope("pick"):
+        tmask = jnp.where(kept, total, jnp.iinfo(jnp.int64).min)
+        max_score = jnp.max(tmask)
+        is_tie = kept & (tmask == max_score)
+        num_ties = jnp.maximum(jnp.sum(is_tie.astype(i32)), 1)
+        # round-robin k-th tie in rotation order (selectHost :286-295)
+        k = (last_node_index % num_ties.astype(jnp.int64)).astype(i32)
+        if pos is not None:
+            # k-th tie by enumeration position relative to the walk origin:
+            # one sort replaces the permuted cumsum + two gathers. Positions of
+            # valid nodes are distinct in [0, n); ties exclude invalid rows.
+            rel = jnp.where(pos >= li, pos - li, nr - li + pos)
+            t_pos = jnp.where(is_tie, rel, jnp.int32(2 ** 30))
+            kth = jax.lax.dynamic_slice(jnp.sort(t_pos), (k,), (1,))[0]
+            sel = jnp.argmax(is_tie & (rel == kth)).astype(jnp.int64)
+        elif perm is None:
+            tie_p = is_tie
+            T = jnp.cumsum(tie_p.astype(i32))
+            preT = jnp.where(li > 0, T[jnp.maximum(li - 1, 0)], 0)
+            trank = jnp.where(after, T - preT, T[-1] - preT + T)
+            sel = jnp.argmax(tie_p & (trank == k + 1)).astype(jnp.int64)
+        else:
+            tie_p = is_tie[perm]
+            T = jnp.cumsum(tie_p.astype(i32))
+            preT = jnp.where(li > 0, T[jnp.maximum(li - 1, 0)], 0)
+            trank = jnp.where(after, T - preT, T[-1] - preT + T)
+            sel_p = jnp.argmax(tie_p & (trank == k + 1)).astype(jnp.int64)
+            sel = perm[sel_p].astype(jnp.int64)
     selected = jnp.where(found > 0, sel, -1)
 
     return {
@@ -617,6 +630,7 @@ def gang_carry_checkpoint(dev_nodes):
     return None if dev_nodes is None else dict(dev_nodes)
 
 
+@jax.named_scope("fold")
 def _fold_state(state, pod, sel, hit):
     """Fold one decision's resource delta into the mutable node state.
 
@@ -1230,12 +1244,14 @@ def _uniform_core(nodes, cls, n_pods, last_node_index, n_real,
     delta_vec = jnp.stack([jnp.asarray(d, jnp.int64) for d in delta])
     I32_MIN = jnp.int32(-2**31)
 
-    tot0 = constrain(_local_total(
-        weights, cls["nz_cpu"] + st0[2], cls["nz_mem"] + st0[3],
-        alloc_cpu, alloc_mem, wrow=wrow).astype(i32))
+    with jax.named_scope("score"):
+        tot0 = constrain(_local_total(
+            weights, cls["nz_cpu"] + st0[2], cls["nz_mem"] + st0[3],
+            alloc_cpu, alloc_mem, wrow=wrow).astype(i32))
     jlane = jnp.arange(k_batch, dtype=i32)
     B = jnp.asarray(n_pods, i32)
 
+    @jax.named_scope("filter")
     def resource_fit(rowvals, idx):
         """PodFitsResources for the incoming pod against row state `rowvals`
         ([R] or [R, K]) at node(s) `idx` — shared by the sweep and the
@@ -1260,9 +1276,11 @@ def _uniform_core(nodes, cls, n_pods, last_node_index, n_real,
     def lane_fit(rowvals, idx):
         """Post-fold score + feasibility of selected rows — shared by the
         lane-0 probe and the batch validation."""
-        nt = _local_total(
-            weights, cls["nz_cpu"] + rowvals[2], cls["nz_mem"] + rowvals[3],
-            alloc_cpu[idx], alloc_mem[idx], wrow=wrow).astype(i32)
+        with jax.named_scope("score"):
+            nt = _local_total(
+                weights, cls["nz_cpu"] + rowvals[2],
+                cls["nz_mem"] + rowvals[3],
+                alloc_cpu[idx], alloc_mem[idx], wrow=wrow).astype(i32)
         return nt, resource_fit(rowvals, idx)
 
     def body(carry):
@@ -1270,117 +1288,119 @@ def _uniform_core(nodes, cls, n_pods, last_node_index, n_real,
         feas = resource_fit(st, None)
         if ban:
             feas &= ~banned
-        tm = jnp.where(feas, tot, I32_MIN)
-        mx = jnp.max(tm)
-        tie = feas & (tm == mx)
-        T = jnp.sum(tie, dtype=i32)
-        F = jnp.sum(feas, dtype=i32)
-        T64 = T.astype(jnp.int64)
-        remaining = B - done
-        # the multi-pod paths need >= 2 ties (a single-tie fold can change
-        # num_ties, shifting the modulo walk) and F > 1 (so lastNodeIndex
-        # advances exactly 1 per pod); F == 0 means every remaining pod is
-        # equally unschedulable -> emit-all -1
-        kbig = (T >= 2) & (F > 1)
-        if rotate:
-            oid = jax.lax.dynamic_slice(oid_seq, (done,), (k_batch,))
-            tie_perm = tie[perm]                     # [L, N1]
-            C_all = jnp.cumsum(tie_perm.astype(i32), axis=1)
-        else:
-            C = jnp.cumsum(tie.astype(i32))
-
-        # -- lane-0 probe: pick STAY vs ELIM batching (identical position
-        # formula at lane 0, so the probe is mode-neutral)
-        if ban:
-            elim = kbig        # a placement always bans its own node
-        else:
-            pos0 = (lni % jnp.maximum(T64, 1)).astype(i32)
+        with jax.named_scope("pick"):
+            tm = jnp.where(feas, tot, I32_MIN)
+            mx = jnp.max(tm)
+            tie = feas & (tm == mx)
+            T = jnp.sum(tie, dtype=i32)
+            F = jnp.sum(feas, dtype=i32)
+            T64 = T.astype(jnp.int64)
+            remaining = B - done
+            # the multi-pod paths need >= 2 ties (a single-tie fold can change
+            # num_ties, shifting the modulo walk) and F > 1 (so lastNodeIndex
+            # advances exactly 1 per pod); F == 0 means every remaining pod is
+            # equally unschedulable -> emit-all -1
+            kbig = (T >= 2) & (F > 1)
             if rotate:
-                c0 = C_all[oid[0]]
-                p0 = jnp.sum(c0 < pos0 + 1, dtype=i32)
-                sel0 = perm[oid[0], jnp.minimum(p0, n_pad)]
+                oid = jax.lax.dynamic_slice(oid_seq, (done,), (k_batch,))
+                tie_perm = tie[perm]                     # [L, N1]
+                C_all = jnp.cumsum(tie_perm.astype(i32), axis=1)
             else:
-                sel0 = jnp.searchsorted(C, pos0 + 1,
-                                        method="compare_all").astype(i32)
-            nt0, fit0 = lane_fit(st[:, sel0] + delta_vec, sel0)
-            elim = ((nt0 != mx) | ~fit0) & kbig
+                C = jnp.cumsum(tie.astype(i32))
 
-        m_stay = jnp.minimum(jnp.minimum(remaining, k_batch), T)
-        # ELIM quotient-0 prefix: lni + i < T - i, i.e. m <= (T - lni + 1)/2;
-        # bans shrink F, so m <= F - 1 keeps found_i > 1 for every lane
-        max_elim = jnp.maximum(((T64 - lni + 1) // 2).astype(i32), 1)
-        m_elim = jnp.minimum(jnp.minimum(remaining, k_batch),
-                             jnp.minimum(max_elim, jnp.maximum(F - 1, 1)))
-        if rotate:
-            # the original-rank formula assumes ONE tie order; limit the
-            # batch to this pass's constant-order prefix (ranks are distinct
-            # within one order, so the rank->node map stays consistent).
-            # Identity-heavy walks — uneven-zone clusters whose cursor sits
-            # at a fixed point — keep FULL ELIM batching this way.
-            same = jnp.cumprod((oid == oid[0]).astype(i32), dtype=i32)
-            m_elim = jnp.minimum(m_elim, jnp.maximum(
-                jnp.sum(same, dtype=i32), 1))
-        m = jnp.where(F == 0, jnp.minimum(remaining, k_batch),
-                      jnp.where(elim, m_elim,
-                                jnp.where(kbig, m_stay, 1)))
-        active = (jlane < m) & (F > 0)
-        j64 = jlane.astype(jnp.int64)
-        pos_stay = ((lni + j64) % jnp.maximum(T64, 1)).astype(i32)
-        pos_elim = jnp.minimum(lni + 2 * j64,
-                               jnp.maximum(T64 - 1, 0)).astype(i32)
-        pos = jnp.where(elim & (m > 1), pos_elim, pos_stay)
-        if not rotate:
-            # stable per-cycle order == the device axis: tie rank -> node via
-            # one cumsum (positions are distinct for the chosen mode's valid
-            # prefix, so active lanes never collide)
-            selq = jnp.searchsorted(C, pos + 1, method="compare_all").astype(i32)
-            sel = jnp.where(active, selq, n_pad)
-        else:
-            # per-cycle rotated orders: lane j ranks ties in the order of ITS
-            # cycle (done + j), one of the <= L distinct zone-interleaved
-            # enumerations in `perm` (NodeTree.order_for_start)
-            crows = C_all[oid]                       # [K, N1]
-            posp = jnp.sum(crows < (pos + 1)[:, None], axis=1, dtype=i32)
-            selq = perm[oid, jnp.minimum(posp, n_pad)]
-            sel = jnp.where(active, selq, n_pad)
-        rows_after = st[:, sel] + delta_vec[:, None]
-        new_tot, fit_after = lane_fit(rows_after, sel)
-        # serial equivalence per lane: STAY needs every earlier fold to leave
-        # its node AT max score and feasible (tie set unchanged); ELIM needs
-        # every earlier fold to REMOVE its node (rank formula). Either way
-        # the first offender's own decision is still exact -> cut after it.
-        leaves = jnp.ones_like(fit_after) if ban \
-            else ((new_tot != mx) | ~fit_after)
-        fail = jnp.where(elim, ~leaves, leaves) & active
-        first_bad = jnp.where(jnp.any(fail), jnp.argmax(fail).astype(i32),
-                              jnp.int32(k_batch))
-        v = jnp.where(F == 0, m, jnp.minimum(first_bad + 1, m))
-        if rotate:
-            # distinct ranks under DIFFERENT orders can name the same node;
-            # the second fold would see stale state — cut the batch before
-            # the first duplicate (it retries next pass)
-            owner = jnp.full(n_pad + 1, k_batch, i32).at[sel].min(
-                jnp.where(active, jlane, k_batch))
-            dup = active & (owner[sel] != jlane)
-            first_dup = jnp.where(jnp.any(dup), jnp.argmax(dup).astype(i32),
+            # -- lane-0 probe: pick STAY vs ELIM batching (identical position
+            # formula at lane 0, so the probe is mode-neutral)
+            if ban:
+                elim = kbig        # a placement always bans its own node
+            else:
+                pos0 = (lni % jnp.maximum(T64, 1)).astype(i32)
+                if rotate:
+                    c0 = C_all[oid[0]]
+                    p0 = jnp.sum(c0 < pos0 + 1, dtype=i32)
+                    sel0 = perm[oid[0], jnp.minimum(p0, n_pad)]
+                else:
+                    sel0 = jnp.searchsorted(C, pos0 + 1,
+                                            method="compare_all").astype(i32)
+                nt0, fit0 = lane_fit(st[:, sel0] + delta_vec, sel0)
+                elim = ((nt0 != mx) | ~fit0) & kbig
+
+            m_stay = jnp.minimum(jnp.minimum(remaining, k_batch), T)
+            # ELIM quotient-0 prefix: lni + i < T - i, i.e. m <= (T - lni + 1)/2;
+            # bans shrink F, so m <= F - 1 keeps found_i > 1 for every lane
+            max_elim = jnp.maximum(((T64 - lni + 1) // 2).astype(i32), 1)
+            m_elim = jnp.minimum(jnp.minimum(remaining, k_batch),
+                                 jnp.minimum(max_elim, jnp.maximum(F - 1, 1)))
+            if rotate:
+                # the original-rank formula assumes ONE tie order; limit the
+                # batch to this pass's constant-order prefix (ranks are distinct
+                # within one order, so the rank->node map stays consistent).
+                # Identity-heavy walks — uneven-zone clusters whose cursor sits
+                # at a fixed point — keep FULL ELIM batching this way.
+                same = jnp.cumprod((oid == oid[0]).astype(i32), dtype=i32)
+                m_elim = jnp.minimum(m_elim, jnp.maximum(
+                    jnp.sum(same, dtype=i32), 1))
+            m = jnp.where(F == 0, jnp.minimum(remaining, k_batch),
+                          jnp.where(elim, m_elim,
+                                    jnp.where(kbig, m_stay, 1)))
+            active = (jlane < m) & (F > 0)
+            j64 = jlane.astype(jnp.int64)
+            pos_stay = ((lni + j64) % jnp.maximum(T64, 1)).astype(i32)
+            pos_elim = jnp.minimum(lni + 2 * j64,
+                                   jnp.maximum(T64 - 1, 0)).astype(i32)
+            pos = jnp.where(elim & (m > 1), pos_elim, pos_stay)
+            if not rotate:
+                # stable per-cycle order == the device axis: tie rank -> node via
+                # one cumsum (positions are distinct for the chosen mode's valid
+                # prefix, so active lanes never collide)
+                selq = jnp.searchsorted(C, pos + 1, method="compare_all").astype(i32)
+                sel = jnp.where(active, selq, n_pad)
+            else:
+                # per-cycle rotated orders: lane j ranks ties in the order of ITS
+                # cycle (done + j), one of the <= L distinct zone-interleaved
+                # enumerations in `perm` (NodeTree.order_for_start)
+                crows = C_all[oid]                       # [K, N1]
+                posp = jnp.sum(crows < (pos + 1)[:, None], axis=1, dtype=i32)
+                selq = perm[oid, jnp.minimum(posp, n_pad)]
+                sel = jnp.where(active, selq, n_pad)
+            rows_after = st[:, sel] + delta_vec[:, None]
+            new_tot, fit_after = lane_fit(rows_after, sel)
+            # serial equivalence per lane: STAY needs every earlier fold to leave
+            # its node AT max score and feasible (tie set unchanged); ELIM needs
+            # every earlier fold to REMOVE its node (rank formula). Either way
+            # the first offender's own decision is still exact -> cut after it.
+            leaves = jnp.ones_like(fit_after) if ban \
+                else ((new_tot != mx) | ~fit_after)
+            fail = jnp.where(elim, ~leaves, leaves) & active
+            first_bad = jnp.where(jnp.any(fail), jnp.argmax(fail).astype(i32),
                                   jnp.int32(k_batch))
-            v = jnp.minimum(v, first_dup)
-            # F==0 emits no selections, so the dup cut (which needs F>0
-            # lanes) cannot zero it: active is all-False there and v stays m
-            v = jnp.where(F == 0, m, jnp.maximum(v, 1))
-        accept = active & (jlane < v)
-        st = st.at[:, sel].add(
-            jnp.where(accept[None, :], delta_vec[:, None], 0))
-        # route non-accepted lanes to the scratch column: under rotation a
-        # rejected lane's sel may DUPLICATE an accepted lane's node, and a
-        # duplicate .set would clobber the accepted score write
-        selw = jnp.where(accept, sel, n_pad)
-        tot = tot.at[selw].set(new_tot)
-        if ban:
-            banned = banned.at[selw].max(accept)
-        emit = jnp.where((jlane < v) & (F > 0), sel, -1)
-        out = jax.lax.dynamic_update_slice(out, emit, (done,))
-        lni = lni + jnp.where(F > 1, v, 0).astype(jnp.int64)
+            v = jnp.where(F == 0, m, jnp.minimum(first_bad + 1, m))
+            if rotate:
+                # distinct ranks under DIFFERENT orders can name the same node;
+                # the second fold would see stale state — cut the batch before
+                # the first duplicate (it retries next pass)
+                owner = jnp.full(n_pad + 1, k_batch, i32).at[sel].min(
+                    jnp.where(active, jlane, k_batch))
+                dup = active & (owner[sel] != jlane)
+                first_dup = jnp.where(jnp.any(dup), jnp.argmax(dup).astype(i32),
+                                      jnp.int32(k_batch))
+                v = jnp.minimum(v, first_dup)
+                # F==0 emits no selections, so the dup cut (which needs F>0
+                # lanes) cannot zero it: active is all-False there and v stays m
+                v = jnp.where(F == 0, m, jnp.maximum(v, 1))
+            accept = active & (jlane < v)
+        with jax.named_scope("fold"):
+            st = st.at[:, sel].add(
+                jnp.where(accept[None, :], delta_vec[:, None], 0))
+            # route non-accepted lanes to the scratch column: under rotation a
+            # rejected lane's sel may DUPLICATE an accepted lane's node, and a
+            # duplicate .set would clobber the accepted score write
+            selw = jnp.where(accept, sel, n_pad)
+            tot = tot.at[selw].set(new_tot)
+            if ban:
+                banned = banned.at[selw].max(accept)
+            emit = jnp.where((jlane < v) & (F > 0), sel, -1)
+            out = jax.lax.dynamic_update_slice(out, emit, (done,))
+            lni = lni + jnp.where(F > 1, v, 0).astype(jnp.int64)
         return (constrain(st), constrain(tot), constrain(banned),
                 lni, done + v, out)
 
@@ -1575,6 +1595,7 @@ def start_order_key(start):
     return np.where(bits < 0, bits ^ np.int64(0x7FFFFFFFFFFFFFFF), bits)
 
 
+@jax.named_scope("filter")
 def _victim_select(nodes, vic, valid_v, req_cpu, req_mem, req_eph,
                    ghost, feas_static, check_res, has_req, constrain=None):
     """selectVictimsOnNode over every node at once (:1054): remove all
@@ -1651,6 +1672,7 @@ def _victim_select(nodes, vic, valid_v, req_cpu, req_mem, req_eph,
                             "earliest_high": earliest_high}
 
 
+@jax.named_scope("pick")
 def _pick_one_node(feas0, agg, order_rank):
     """pickOneNodeForPreemption (:837): zero-victim instant win, then the
     staged 5-criteria reduction, ties broken by first-in-candidate-order

@@ -1123,7 +1123,26 @@ class Scheduler:
         return total
 
     def _schedule_burst_pass(self, max_pods: int) -> tuple[int, int]:
-        """One drain+schedule pass; returns (pods bound, pods drained)."""
+        """One drain+schedule pass; returns (pods bound, pods drained).
+
+        A pass that drains anything is one launch window: its spans share
+        the window's sequence number. `burst.plan` covers the pass, and its
+        self time (what its children — snapshot, encode, dispatch, fetch,
+        the commit waves — do not cover) is the planning: the pop, gang
+        gathering, class detection, refusals and rotation."""
+        obs.trace.next_window()
+        plan = obs.trace.begin("burst.plan")
+        drained = 0
+        try:
+            bound, drained = self._burst_pass_planned(max_pods)
+            return bound, drained
+        finally:
+            if drained:
+                plan.end(pods=drained)
+            else:
+                plan.cancel()   # an idle tick: nothing for the ring
+
+    def _burst_pass_planned(self, max_pods: int) -> tuple[int, int]:
         drained = []
         for pod, cycle in self.queue.pop_burst(max_pods):
             if pod.deleted:
@@ -1776,9 +1795,10 @@ class Scheduler:
     def _burst_segment(self, pods: list[Pod], cycles: list[int],
                        bucket: int) -> int:
         """Schedule one burst segment; returns pods bound."""
-        self._snapshot = self.cache.update_snapshot(self._snapshot)
-        tree_chk = self.cache.node_tree.checkpoint()
-        names = self.cache.node_tree.list_names()
+        with obs.trace.span("burst.snapshot"):
+            self._snapshot = self.cache.update_snapshot(self._snapshot)
+            tree_chk = self.cache.node_tree.checkpoint()
+            names = self.cache.node_tree.list_names()
         self._last_names = names
         self._ctx_open(tree_chk)
         # wave-window sink (tpu_scheduler.schedule_burst `commit`): the
@@ -2038,28 +2058,30 @@ class Scheduler:
                     n_bound += 1
             return n_bound
         t_bind = self.clock.now()
-        assumed_list = []
-        for pod, host in zip(pods, hosts):
-            assumed = pod.clone()
-            assumed.node_name = host
-            assumed_list.append(assumed)
-        if assume:
-            self.cache.assume_pods(assumed_list)    # one lock for the wave
-        note_many = getattr(self.algorithm, "note_burst_assumed_many", None) \
-            if assume else None
-        if note_many is not None:
-            # the device scan already folded these deltas: sync the host
-            # mirror + generation map in one vectorized pass (generations
-            # read once, after every assume of the wave landed)
-            note_many(assumed_list, hosts,
-                      self.cache.node_generations(hosts))
-        elif assume:
-            note = getattr(self.algorithm, "note_burst_assumed", None)
-            if note is not None:
-                for assumed, host in zip(assumed_list, hosts):
-                    gen = self.cache.node_generation(host)
-                    if gen is not None:
-                        note(assumed, host, gen)
+        with obs.trace.span("burst.commit.cache"):
+            assumed_list = []
+            for pod, host in zip(pods, hosts):
+                assumed = pod.clone()
+                assumed.node_name = host
+                assumed_list.append(assumed)
+            if assume:
+                self.cache.assume_pods(assumed_list)  # one lock for the wave
+            note_many = getattr(self.algorithm, "note_burst_assumed_many",
+                                None) if assume else None
+            if note_many is not None:
+                # the device scan already folded these deltas: sync the
+                # host mirror + generation map in one vectorized pass
+                # (generations read once, after every assume of the wave
+                # landed)
+                note_many(assumed_list, hosts,
+                          self.cache.node_generations(hosts))
+            elif assume:
+                note = getattr(self.algorithm, "note_burst_assumed", None)
+                if note is not None:
+                    for assumed, host in zip(assumed_list, hosts):
+                        gen = self.cache.node_generation(host)
+                        if gen is not None:
+                            note(assumed, host, gen)
         # the wave's whole store-write tail — batched binds PLUS the
         # Scheduled audit records for the binds that land — is ONE
         # commit-core call (native/commitcore.cpp or its Python twin);
@@ -2076,12 +2098,13 @@ class Scheduler:
             # cache but NOTHING reached the store — recovery must re-queue
             # every pod of this window
             chaos.check("sched.crash")
-            if commit_wave is not None:
-                missing_list, conflicted = self._commit_wave_retrying(
-                    commit_wave, bindings)
-                missing = set(missing_list)
-            else:
-                missing = set(self.store.bind_pods(bindings))
+            with obs.trace.span("burst.commit.store"):
+                if commit_wave is not None:
+                    missing_list, conflicted = self._commit_wave_retrying(
+                        commit_wave, bindings)
+                    missing = set(missing_list)
+                else:
+                    missing = set(self.store.bind_pods(bindings))
             # crash seam, post-write side: the wave LANDED but the cache
             # finish / metrics / fan-out tail never ran — recovery must
             # adopt every landed binding
@@ -2131,7 +2154,20 @@ class Scheduler:
         finally:
             fanout = getattr(self.store, "fanout_wave", None)
             if fanout is not None:
-                fanout()
+                with obs.trace.span("burst.commit.fanout"):
+                    fanout()
+        with obs.trace.span("burst.commit.finish"):
+            return self._finish_burst(assumed_list, pods, hosts, cycles,
+                                      missing, conflicted, emit_batch,
+                                      t_bind)
+
+    def _finish_burst(self, assumed_list: list, pods: list[Pod],
+                      hosts: list[str], cycles: list[int], missing: set,
+                      conflicted: list, emit_batch: bool,
+                      t_bind: float) -> int:
+        """The tail of a wave's commit: each pod resolved by what landed,
+        one batched cache finish, the aggregated metrics. Returns the
+        number of pods bound."""
         confl_set = set(conflicted)
         bound = []
         for assumed, pod, host, cycle in zip(assumed_list, pods, hosts,

@@ -296,27 +296,39 @@ class SharedInformer:
         """Synchronously apply pending watch events, copied out in
         batches (one core poll per `pump_batch` events; consecutive adds
         dispatch as one batch to handlers that registered on_add_many).
-        Returns count applied."""
+        Returns count applied. A pump that delivers anything is one span,
+        `pump.<kind>`, opened at its first event (an idle pump records
+        nothing) and closed with the events it delivered, by type: the
+        creates, the binds and the deletes of a window are told apart."""
         if self._watch is None:
             self.sync()
         n = 0
-        while max_events is None or n < max_events:
-            limit = self.pump_batch if max_events is None \
-                else min(self.pump_batch, max_events - n)
-            try:
-                evs = self._poll_batch(timeout, limit)
-            except ExpiredError:
-                # the watch outran the server's event log: re-list
-                # (reflector 410 contract); consecutive expirations with
-                # no event applied in between back off
-                WATCH_EXPIRATIONS.labels(self.kind).inc()
-                self._note_expired()
-                self._relist()
-                continue
-            if not evs:
-                break
-            self._apply_batch(evs)
-            n += len(evs)
+        span = None
+        tally: dict = {}
+        try:
+            while max_events is None or n < max_events:
+                limit = self.pump_batch if max_events is None \
+                    else min(self.pump_batch, max_events - n)
+                try:
+                    evs = self._poll_batch(timeout, limit)
+                except ExpiredError:
+                    # the watch outran the server's event log: re-list
+                    # (reflector 410 contract); consecutive expirations
+                    # with no event applied in between back off
+                    WATCH_EXPIRATIONS.labels(self.kind).inc()
+                    self._note_expired()
+                    self._relist()
+                    continue
+                if not evs:
+                    break
+                if span is None:
+                    span = obs.trace.begin("pump." + self.kind)
+                self._apply_batch(evs, tally)
+                n += len(evs)
+        finally:
+            if span is not None:
+                span.end(events=n,
+                         **{t.lower(): c for t, c in tally.items()})
         return n
 
     def _poll_batch(self, timeout: float, limit: int) -> list:
@@ -340,7 +352,9 @@ class SharedInformer:
     def _apply(self, ev: Event) -> None:
         self._apply_batch([ev])
 
-    def _apply_batch(self, evs: list) -> None:
+    def _apply_batch(self, evs: list, tally: Optional[dict] = None) -> None:
+        """`tally` (optional) collects the events delivered by effective
+        type, one addition per run of the batch."""
         # a delivered event ends any consecutive-ExpiredError streak
         self._expired_streak = 0
         prepared = []   # (effective etype, old, new) in delivery order
@@ -369,6 +383,8 @@ class SharedInformer:
             j = i + 1
             while j < n and prepared[j][0] == etype:
                 j += 1
+            if tally is not None:
+                tally[etype] = tally.get(etype, 0) + (j - i)
             if j - i == 1:
                 self._dispatch(etype, old, new)
             elif etype == ADDED:

@@ -1346,7 +1346,12 @@ class Store:
                     recs = [recs[i] for i in live_idx]
                 missing = self._core.commit_wave(pods, PODS, live,
                                                  evs, EVENTS, recs)
-            self._trim_events_locked()   # audit retention (event TTL)
+            # audit retention (event TTL); a span when the wave trims
+            # (one per wave: the other callers trim a record at a time)
+            over = len(evs) - self._events_cap if self._events_cap else 0
+            if over > 0:
+                with obs.trace.span("store.trim_events", records=over):
+                    self._trim_events_locked()
             t_landed = _time.perf_counter()
             if token is not None:
                 self._wave_tokens[token] = (list(missing), list(confl))

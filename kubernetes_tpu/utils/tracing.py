@@ -24,7 +24,9 @@ class Profiler:
     session writing TensorBoard/XPlane dumps (kernel timelines, HLO cost
     breakdowns, host<->device transfers) to a directory. Use either as a
     session (`start()`/`stop()`, the CLI flag path) or as a context manager
-    around a region (`with Profiler(dir).span("burst"): ...`)."""
+    around a region. While a session runs, every span the program opens
+    through `obs.trace.span` is in the dump as an annotation of the same
+    name: that is the one way to annotate a region."""
 
     def __init__(self, log_dir: str):
         self.log_dir = log_dir
@@ -42,11 +44,6 @@ class Profiler:
             jax.profiler.stop_trace()
             self._active = False
             log.warning("profiler trace written to %s", self.log_dir)
-
-    def span(self, name: str):
-        """Annotated sub-region (shows as a named range in the trace)."""
-        import jax
-        return jax.profiler.TraceAnnotation(name)
 
     def __enter__(self):
         self.start()

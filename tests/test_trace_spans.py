@@ -1,0 +1,495 @@
+"""The one span API inside the bind path (`obs.trace.span` / `begin`): a span
+is recorded once and lands in the ring and, while a `jax.profiler` session
+runs, in the profiler's trace under the same name, nesting and window
+number; spans are per pump, per window and per commit wave, never per pod;
+the kernels' stages carry `jax.named_scope` names; compiles are counted by
+program."""
+import glob
+import os
+import re
+
+import pytest
+
+from kubernetes_tpu import obs
+from kubernetes_tpu.api.types import Container, Node, Pod
+from kubernetes_tpu.scheduler import Scheduler
+from kubernetes_tpu.store.store import NODES, PODS, Store
+
+GI = 1024 ** 3
+
+# the program's spans on the bind path of a plain burst, by the layer
+# boundary each sits on (PERF.md section 3 lists them with their metrics)
+BIND_PATH_SPANS = {
+    "pump.pods", "burst.plan", "burst.snapshot", "burst.encode",
+    "burst.encode.nodes", "burst.encode.pods", "burst.dispatch",
+    "burst.fetch", "burst.wave.commit", "burst.commit.cache",
+    "burst.commit.store", "burst.commit.fanout", "burst.commit.finish"}
+
+
+def mknode(name: str) -> Node:
+    return Node(name=name, allocatable={"cpu": 64000, "memory": 256 * GI,
+                                        "pods": 110})
+
+
+def mkpod(name: str, cpu: int = 100, **kw) -> Pod:
+    return Pod(name=name, labels={"app": "x"},
+               containers=(Container.make(name="c", requests={"cpu": cpu}),),
+               **kw)
+
+
+def make_sched(n_nodes: int = 4):
+    store = Store()
+    for i in range(n_nodes):
+        store.create(NODES, mknode(f"n{i}"))
+    sched = Scheduler(store, use_tpu=True, percentage_of_nodes_to_score=100)
+    sched.sync()
+    return store, sched
+
+
+def drain(sched, max_pods: int = 128) -> int:
+    sched.pump()
+    bound = 0
+    while True:
+        n = sched.schedule_burst(max_pods=max_pods)
+        if n == 0:
+            break
+        bound += n
+    sched.pump()
+    return bound
+
+
+def burst(store, sched, tag: str, n: int) -> list[dict]:
+    """One window of `n` identical pods; the ring's spans of it."""
+    for j in range(n):
+        store.create(PODS, mkpod(f"{tag}-{j}"))
+    obs.trace.clear()
+    assert drain(sched) == n
+    return obs.trace.events()
+
+
+class TestSpanApi:
+    def test_begin_end_pair_records_parent_window_and_late_args(self):
+        obs.trace.clear()
+        w = obs.trace.next_window()
+        with obs.trace.span("outer"):
+            sp = obs.trace.begin("inner", cat="device", rows=3)
+            t1 = sp.end(events=7)
+        assert sp.t0 <= t1 == sp.t1
+        inner, outer = obs.trace.events()
+        assert inner["name"] == "inner" and inner["cat"] == "device"
+        assert inner["args"] == {"rows": 3, "events": 7, "parent": "outer",
+                                 "window": w}
+        assert outer["args"] == {"window": w}
+        assert obs.trace.next_window() > w
+
+    def test_cancelled_span_leaves_no_record_and_restores_parent(self):
+        obs.trace.clear()
+        with obs.trace.span("outer"):
+            obs.trace.begin("empty").cancel()
+            with obs.trace.span("after"):
+                pass
+        names = [e["name"] for e in obs.trace.events()]
+        assert names == ["after", "outer"]
+        assert obs.trace.events()[0]["args"]["parent"] == "outer"
+
+    def test_windows_are_per_thread(self):
+        import threading
+        def window_now() -> int:
+            with obs.trace.span("probe") as sp:
+                pass
+            return sp._window
+
+        w = obs.trace.next_window()
+        seen = []
+        t = threading.Thread(
+            target=lambda: seen.append((window_now(),
+                                        obs.trace.next_window())))
+        t.start()
+        t.join(5.0)
+        assert not t.is_alive()
+        assert seen[0][0] == 0 and seen[0][1] > w
+        assert window_now() == w
+
+    def test_span_error_still_closes(self):
+        obs.trace.clear()
+        with pytest.raises(ValueError):
+            with obs.trace.span("outer"):
+                with obs.trace.span("boom"):
+                    raise ValueError("x")
+        assert [e["name"] for e in obs.trace.events()] == ["boom", "outer"]
+        with obs.trace.span("next"):
+            pass
+        assert "parent" not in obs.trace.events()[-1].get("args", {})
+
+
+class TestBindPathSpans:
+    def test_plain_burst_opens_every_layer_boundary_once_per_window(self):
+        store, sched = make_sched()
+        evs = burst(store, sched, "a", 6)
+        names = [e["name"] for e in evs]
+        assert BIND_PATH_SPANS <= set(names), BIND_PATH_SPANS - set(names)
+        # burst.encode host, burst.fetch device (the fetch waits for the
+        # device): the attribution test_obs pins
+        cats = {e["name"]: e["cat"] for e in evs}
+        assert cats["burst.encode"] == "host"
+        assert cats["burst.fetch"] == "device"
+        # one window: every burst.* span carries its number, and the pump
+        # that digests its binds carries it too
+        plan = [e for e in evs if e["name"] == "burst.plan"]
+        assert len(plan) == 1 and plan[0]["args"]["pods"] == 6
+        w = plan[0]["args"]["window"]
+        for e in evs:
+            if e["name"].startswith(("burst.", "store.")):
+                assert e["args"]["window"] == w, e
+        assert [e["args"].get("window", 0) for e in evs
+                if e["name"] == "pump.pods"][-1] >= w    # the binds' pump
+        # nesting, by the recorded parent
+        parent = {e["name"]: e["args"].get("parent") for e in evs}
+        assert parent["burst.plan"] is None
+        assert parent["burst.snapshot"] == "burst.plan"
+        assert parent["burst.encode"] == "burst.plan"
+        assert parent["burst.encode.nodes"] == "burst.encode"
+        assert parent["burst.encode.pods"] == "burst.encode"
+        assert parent["burst.dispatch"] == "burst.plan"
+        assert parent["burst.wave.commit"] == "burst.plan"
+        for leaf in ("cache", "store", "fanout", "finish"):
+            assert parent[f"burst.commit.{leaf}"] == "burst.wave.commit"
+
+    def test_pump_span_tallies_events_by_type_and_idle_pump_is_silent(self):
+        store, sched = make_sched()
+        evs = burst(store, sched, "t", 5)
+        pumps = [e["args"] for e in evs if e["name"] == "pump.pods"]
+        assert pumps[0]["events"] == 5 and pumps[0]["added"] == 5
+        assert sum(p.get("modified", 0) for p in pumps) == 5   # the binds
+        obs.trace.clear()
+        assert sched.pump() == 0
+        assert sched.schedule_burst(max_pods=16) == 0
+        assert obs.trace.events() == []     # idle tick: nothing recorded
+        store.delete_many(PODS, [f"default/t-{j}" for j in range(5)])
+        sched.pump()
+        (gone,) = [e["args"] for e in obs.trace.events()
+                   if e["name"] == "pump.pods"]
+        assert gone["events"] == 5 and gone["deleted"] == 5
+
+    def test_scatter_span_and_rows_counter_count_real_rows(self):
+        from kubernetes_tpu.core import tpu_scheduler as T
+        store, sched = make_sched(n_nodes=40)
+        burst(store, sched, "w", 3)          # full upload, folds resident
+        store.delete_many(PODS, [f"default/w-{j}" for j in range(3)])
+        before = T.SCATTER_ROWS.value
+        evs = burst(store, sched, "s", 3)    # the 3 freed rows are dirty
+        (sc,) = [e for e in evs if e["name"] == "burst.scatter"]
+        assert sc["cat"] == "device"
+        assert sc["args"]["parent"] == "burst.encode"
+        rows = sc["args"]["rows"]
+        assert 1 <= rows <= 3                # not the 16-row bucket
+        assert T.SCATTER_ROWS.value - before == rows
+
+    def test_trim_span_only_when_the_wave_trims(self):
+        store = Store(events_cap=8)
+        for i in range(4):
+            store.create(NODES, mknode(f"n{i}"))
+        sched = Scheduler(store, use_tpu=True,
+                          percentage_of_nodes_to_score=100)
+        sched.sync()
+        evs = burst(store, sched, "u", 6)    # 6 records: under the cap
+        assert not [e for e in evs if e["name"] == "store.trim_events"]
+        evs = burst(store, sched, "v", 6)    # 12 records: trims 4
+        (trim,) = [e for e in evs if e["name"] == "store.trim_events"]
+        assert trim["args"]["records"] == 4
+        assert trim["args"]["parent"] == "burst.commit.store"
+
+    @pytest.mark.parametrize("n_pods", [1, 64])
+    def test_spans_per_window_do_not_depend_on_pods(self, n_pods):
+        """None per pod: a window of 1 pod and a window of 64 open the same
+        spans, the same number of times."""
+        store, sched = make_sched(n_nodes=8)
+        burst(store, sched, "warm", 2)       # upload + compile behind us
+        evs = burst(store, sched, f"k{n_pods}", n_pods)
+        count: dict = {}
+        for e in evs:
+            count[e["name"]] = count.get(e["name"], 0) + 1
+        assert count == {
+            "pump.pods": 2, "burst.plan": 1, "burst.snapshot": 1,
+            "burst.encode": 1, "burst.encode.nodes": 1,
+            "burst.encode.pods": 1, "burst.dispatch": 1, "burst.fetch": 1,
+            "burst.wave.device": 1, "burst.wave.commit": 1,
+            "burst.commit.cache": 1, "burst.commit.store": 1,
+            "burst.commit.fanout": 1, "burst.commit.finish": 1}
+
+    def test_refused_burst_closes_its_phase_and_stamps_nothing(self):
+        """A refusal from the middle of encode ends the span (the host
+        spent that time) without an ENCODE stamp; the next span has no
+        stale parent."""
+        from kubernetes_tpu.cache.node_info import NodeInfo
+        from kubernetes_tpu.core.tpu_scheduler import TPUScheduler
+        from kubernetes_tpu.obs import ledger as L
+        infos = {f"n{i}": NodeInfo(mknode(f"n{i}")) for i in range(3)}
+        from kubernetes_tpu.api.types import ContainerPort
+        pods = [mkpod(f"r{k}", cpu=100 + k) for k in range(3)]
+        pods[1] = Pod(name="r1", labels={"app": "x"}, containers=(
+            Container.make(name="c", requests={"cpu": 100},
+                           ports=[ContainerPort(host_port=80,
+                                                container_port=80)]),))
+        tpu = TPUScheduler(percentage_of_nodes_to_score=100)
+        obs.trace.clear()
+        L.LEDGER.reset()
+        assert tpu.schedule_burst(pods, infos, sorted(infos)) is None
+        names = [e["name"] for e in obs.trace.events()]
+        assert "burst.encode" in names and "burst.dispatch" not in names
+        assert L.LEDGER.snapshot()["phase_split"].get("encode", 0.0) == 0.0
+        with obs.trace.span("after"):
+            pass
+        assert "parent" not in obs.trace.events()[-1].get("args", {})
+
+
+class TestProfilerSeesTheSameSpans:
+    def test_host_plane_has_ring_names_nesting_and_window(self, tmp_path):
+        """A profiler session on the CPU backend around a tiny burst: the
+        `.xplane.pb`'s host plane shows the program's spans with the names,
+        the nesting and the window number the ring has."""
+        import jax
+        from jax.profiler import ProfileData
+        store, sched = make_sched()
+        burst(store, sched, "warm", 2)
+        for j in range(6):
+            store.create(PODS, mkpod(f"p-{j}"))
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        obs.trace.clear()
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            assert drain(sched) == 6
+        finally:
+            jax.profiler.stop_trace()
+        ring = [e for e in obs.trace.events()
+                if e["name"] != "burst.wave.device"]   # ring alone: after
+        assert BIND_PATH_SPANS <= {e["name"] for e in ring}   # the fact
+        (path,) = glob.glob(os.path.join(
+            str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+        names = {e["name"] for e in ring}
+        seen = []      # (start, end, name, window) of the program's spans
+        for plane in ProfileData.from_file(path).planes:
+            if not plane.name.startswith("/host:"):
+                continue
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name in names:
+                        stats = dict(e.stats)
+                        if stats.get("empty"):
+                            continue   # a drain that popped nothing
+                        seen.append((e.start_ns, e.start_ns + e.duration_ns,
+                                     e.name, stats.get("window")))
+        seen.sort(key=lambda r: (r[0], -r[1]))
+        # same spans, same order of opening
+        by_start = sorted(ring, key=lambda e: (e["ts"], -e["dur"]))
+        assert [r[2] for r in seen] == [e["name"] for e in by_start]
+        # same window number
+        assert [r[3] for r in seen] == [e["args"]["window"]
+                                        for e in by_start]
+        # same nesting: the innermost span that contains one is the parent
+        # the ring recorded
+        stack = []
+        for s0, s1, name, _w in seen:
+            while stack and stack[-1][1] < s1:
+                stack.pop()
+            want = next(e for e in by_start if e["name"] == name
+                        )["args"].get("parent")
+            assert (stack[-1][2] if stack else None) == want, name
+            stack.append((s0, s1, name))
+        # what only the end knew rides the annotation too
+        pump = next(dict(e.stats) for plane in
+                    ProfileData.from_file(path).planes
+                    if plane.name.startswith("/host:")
+                    for line in plane.lines for e in line.events
+                    if e.name == "pump.pods")
+        assert pump["events"] == 6
+
+    def test_no_session_no_annotation(self):
+        sp = obs.trace.begin("quiet")
+        assert sp._ann is None
+        sp.end()
+
+
+class TestCompileCounters:
+    @staticmethod
+    def _total(family) -> float:
+        return sum(c.value for c in family._children.values())
+
+    def test_compile_counter_moves_on_first_call_only(self):
+        import jax
+        import jax.numpy as jnp
+        from kubernetes_tpu import ops
+
+        @jax.jit
+        def span_test_program(x):
+            return x * 3 + 1
+
+        x5, x6 = jnp.arange(5), jnp.arange(6)    # their own programs: first
+        n0, s0 = self._total(ops.COMPILES), self._total(ops.COMPILE_SECONDS)
+        span_test_program(x5).block_until_ready()
+        assert self._total(ops.COMPILES) == n0 + 1
+        assert self._total(ops.COMPILE_SECONDS) > s0
+        span_test_program(x5).block_until_ready()
+        assert self._total(ops.COMPILES) == n0 + 1
+        span_test_program(x6).block_until_ready()      # a new shape
+        assert self._total(ops.COMPILES) == n0 + 2
+
+    def test_program_label_is_bounded(self, monkeypatch):
+        """The first programs of a process have a child each; once the
+        bound is reached, new names share `other`."""
+        import jax
+        import jax.numpy as jnp
+        from kubernetes_tpu import ops
+        x7, x8 = jnp.arange(7), jnp.arange(8)
+
+        @jax.jit
+        def bounded_first(v):
+            return v + 11
+
+        @jax.jit
+        def bounded_second(v):
+            return v + 12
+
+        monkeypatch.setattr(ops, "_compile_programs", set())
+        monkeypatch.setattr(ops, "MAX_COMPILE_PROGRAMS", 1)
+        other = ops.COMPILES.labels("other")
+        other0 = other.value
+        bounded_first(x7).block_until_ready()
+        assert ops._compile_programs == {"jit(bounded_first)"}
+        bounded_first(x8).block_until_ready()      # a known name keeps its own
+        assert ops.COMPILES.labels("jit(bounded_first)").value == 2
+        assert other.value == other0
+        bounded_second(x7).block_until_ready()     # past the bound
+        assert ops._compile_programs == {"jit(bounded_first)"}
+        assert other.value == other0 + 1
+
+
+def _record_calls(monkeypatch, jit_name: str) -> list:
+    """Calls of one of kernels.py's jitted programs, as (args, kwargs)."""
+    from kubernetes_tpu.ops import kernels as K
+    real = getattr(K, jit_name)
+    calls: list = []
+
+    def recorder(*a, **kw):
+        calls.append((a, kw))
+        return real(*a, **kw)
+
+    monkeypatch.setattr(K, jit_name, recorder)
+    return calls
+
+
+def _drive_cycle():
+    store, sched = make_sched()
+    sched.algorithm.serial_path = "device"
+    store.create(PODS, mkpod("c0"))
+    sched.pump()
+    assert sched.schedule_one(timeout=0.0)
+    sched.wait_for_binds()
+
+
+def _drive_uniform():
+    store, sched = make_sched()
+    burst(store, sched, "u", 4)
+
+
+def _drive_scan():
+    store, sched = make_sched()
+    for j in range(4):
+        store.create(PODS, mkpod(f"s{j}", cpu=100 + 50 * (j % 2)))
+    assert drain(sched) == 4
+
+
+def _drive_segments():
+    from kubernetes_tpu.coscheduling.types import (
+        LABEL_POD_GROUP, PodGroup)
+    from kubernetes_tpu.store.store import PODGROUPS
+    store, sched = make_sched()
+    store.create(PODGROUPS, PodGroup(name="g", min_member=2))
+    for j in range(2):
+        store.create(PODS, mkpod(f"s{j}"))
+    for j in range(2):
+        p = mkpod(f"m{j}")
+        store.create(PODS, Pod(name=p.name, containers=p.containers,
+                               labels={**p.labels, LABEL_POD_GROUP: "g"}))
+    assert drain(sched) == 4
+
+
+def _pressure_world():
+    from kubernetes_tpu.cache.node_info import NodeInfo
+    infos, names = {}, []
+    for i in range(4):
+        node = Node(name=f"n{i}", allocatable={"cpu": 2000,
+                                               "memory": 8 * GI,
+                                               "pods": 110})
+        ni = NodeInfo(node)
+        for v in range(2):
+            ni.add_pod(Pod(name=f"v{i}-{v}", priority=1,
+                           node_name=node.name,
+                           containers=(Container.make(
+                               name="c", requests={"cpu": 900}),)))
+        infos[node.name] = ni
+        names.append(node.name)
+    return infos, names
+
+
+def _drive_preempt_scan():
+    from kubernetes_tpu.core.tpu_scheduler import TPUScheduler
+    from kubernetes_tpu.oracle import predicates as P
+    from kubernetes_tpu.oracle.generic_scheduler import FitError
+    infos, names = _pressure_world()
+    pod = Pod(name="hi", priority=10, containers=(
+        Container.make(name="c", requests={"cpu": 900}),))
+    err = FitError(pod, len(names), {nm: [P.insufficient_resource("cpu")]
+                                     for nm in names})
+    tpu = TPUScheduler(percentage_of_nodes_to_score=100)
+    assert tpu.preempt(pod, infos, names, err, []) is not None
+
+
+def _drive_pressure():
+    from kubernetes_tpu.core.tpu_scheduler import TPUScheduler
+    infos, names = _pressure_world()
+    pods = [Pod(name=f"hi-{k}", priority=10, containers=(
+        Container.make(name="c", requests={"cpu": 900}),))
+        for k in range(3)]
+    tpu = TPUScheduler(percentage_of_nodes_to_score=100)
+    assert tpu.preempt_pressure_burst(pods, infos, names, []) is not None
+
+
+# core -> (its jitted program, a drive that launches it, the stages it has:
+# one cycle folds nothing; the victim scan neither scores nor folds)
+CORES = {
+    "_cycle_core": ("_schedule_cycle_jit", _drive_cycle,
+                    ("filter", "score", "pick")),
+    "_batch_core": ("_schedule_batch_jit", _drive_scan,
+                    ("filter", "score", "pick", "fold")),
+    "_segments_core": ("_schedule_batch_seg_jit", _drive_segments,
+                       ("filter", "score", "pick", "fold")),
+    "_uniform_core": ("_schedule_batch_uniform_jit", _drive_uniform,
+                      ("filter", "score", "pick", "fold")),
+    "_preempt_scan_core": ("_preemption_scan_jit", _drive_preempt_scan,
+                           ("filter", "pick")),
+    "_pressure_core": ("_pressure_batch_jit", _drive_pressure,
+                       ("filter", "score", "pick", "fold")),
+}
+
+
+class TestKernelScopes:
+    @pytest.mark.parametrize("core", sorted(CORES))
+    def test_lowered_text_carries_the_stage_scopes(self, core, monkeypatch):
+        """Each core's lowered program, with its locations, names the
+        stages it has: the names a device trace is read by."""
+        from kubernetes_tpu.ops import kernels as K
+        jit_name, drive, stages = CORES[core]
+        real = getattr(K, jit_name)
+        calls = _record_calls(monkeypatch, jit_name)
+        drive()
+        assert calls, f"{jit_name} was not launched"
+        args, kwargs = calls[0]
+        text = real.lower(*args, **kwargs).as_text(debug_info=True)
+        assert set(stages) <= set(K.SCOPES)
+        for stage in stages:
+            # a name stack component: `.../filter/add`, or `filter/add`
+            # inside a loop body that is lowered as a function of its own
+            assert re.search(rf'["/]{stage}/', text), (core, stage)
