@@ -129,7 +129,10 @@ class _HistogramChild:
         self.buckets = [0] * len(bounds)
         self.count = 0
         self.sum = 0.0
-        self._lock = threading.Lock()
+        # re-entrant: the collector can run inside any allocation a holder
+        # makes (the scraper copies the buckets under this lock), and its
+        # callback observes python_gc_pause_seconds on the same thread
+        self._lock = threading.RLock()
 
     def observe(self, value: float) -> None:
         self.observe_many(value, 1)

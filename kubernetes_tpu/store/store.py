@@ -24,6 +24,7 @@ import threading
 import time as _time
 from collections import OrderedDict
 from dataclasses import dataclass
+from itertools import islice
 from typing import Any, Callable, Iterable, Optional
 
 import numpy as _np
@@ -809,16 +810,20 @@ class Store:
         bucket = self._objs.get(EVENTS)
         if bucket is None or len(bucket) <= cap:
             return
+        # ONE ordered walk per call, never one per record: a dict keeps
+        # popped entries as tombstones at the head of its entry array until
+        # its next resize, and every fresh iterator walks them again (up
+        # to ~109,000 dead slots at the default cap). The bucket stays a
+        # plain dict: the native core writes it through PyDict_*.
+        over = len(bucket) - cap
         core = self._core
-        trimmed = 0
-        while len(bucket) > cap:
-            key = next(iter(bucket))
+        integrity = self._integrity
+        for key in list(islice(bucket, over)):
             obj = bucket.pop(key)
-            if self._integrity is not None:
-                self._integrity.pop((EVENTS, key), None)
+            if integrity is not None:
+                integrity.pop((EVENTS, key), None)
             core.append(DELETED, EVENTS, obj, core.next_rv())
-            trimmed += 1
-        EVENTS_TRIMMED.inc(trimmed)
+        EVENTS_TRIMMED.inc(over)
 
     def create(self, kind: str, obj: Any, move: bool = False) -> Any:
         """`move=True` transfers ownership: the caller promises never to
@@ -1210,13 +1215,16 @@ class Store:
                 try:
                     stored = self._core.create_batch(
                         self._objs.setdefault(kind, {}), kind, objs, move)
+                    # fingerprints before the trim: a batch larger than
+                    # the cap evicts its own oldest records, and the trim
+                    # pops what it evicts from the integrity map
+                    if self._integrity is not None:
+                        for o in stored:
+                            self._record_entry(kind, _key_of(o), o)
                     if kind == EVENTS:
                         self._trim_events_locked()
                 finally:
                     self._flush()
-                if self._integrity is not None:
-                    for o in stored:
-                        self._record_entry(kind, _key_of(o), o)
             if gate is not None and kind == PODS:
                 # one batched admission stamp for the accepted prefix —
                 # the per-pod path's stamp_admission, amortized

@@ -151,39 +151,70 @@ class TestRESTSurface:
         subscription class server-side — the watch route streams
         pre-encoded lines out of the shared byte ring (wire shape
         unchanged from the per-watcher encode path), and the store books
-        the second stream's lines as shared-ring hits, not re-encodes."""
+        the second stream's lines as shared-ring hits, not re-encodes.
+
+        Waits on the subscription and on delivery, never on the clock.
+        The second stream joins once the first has been served and replays
+        from the first's resourceVersion, so both its lines MUST come out
+        of the ring: two streams woken by one event race for it (the pick
+        is under the core's lock, the encode is not), and which of them
+        encodes is then the scheduler's choice, not the ring's."""
         store, url = server
         got1, got2 = [], []
-        done1, done2 = threading.Event(), threading.Event()
 
-        def watcher(got, done):
+        def watcher(got, since):
+            q = "" if since is None else f"&resourceVersion={since}"
             with urllib.request.urlopen(
-                    f"{url}/api/v1/pods?watch=true&selector=app%3Da") as resp:
+                    f"{url}/api/v1/pods?watch=true&selector=app%3Da{q}"
+                    ) as resp:
                 for raw in resp:
                     line = raw.strip()
                     if line:
                         got.append(json.loads(line))
-                        if len(got) >= 2:
-                            done.set()
+                        if len(got) >= 4:
                             return
 
-        t1 = threading.Thread(target=watcher, args=(got1, done1), daemon=True)
-        t2 = threading.Thread(target=watcher, args=(got2, done2), daemon=True)
-        t1.start()
-        t2.start()
-        import time
-        time.sleep(0.3)
+        def wait_for(pred, what):
+            import time
+            deadline = time.monotonic() + 10
+            while not pred():
+                assert time.monotonic() < deadline, (what, got1, got2)
+                time.sleep(0.002)
+
+        def members():
+            return sum(c["members"]
+                       for c in store.watch_plane_state()["classes"])
+
+        threading.Thread(target=watcher, args=(got1, None),
+                         daemon=True).start()
+        wait_for(lambda: members() == 1, "first stream subscribed")
+        _, rv0 = store.list(PODS)
         store.create(PODS, Pod(name="b0"))
         store.delete(PODS, "default/b0")
-        assert done1.wait(5) and done2.wait(5), (got1, got2)
+        wait_for(lambda: len(got1) == 2, "first stream served")
+        st = store.watch_plane_state()
+        assert (st["shared_hits"], st["line_encodes"]) == (0, 2), st
+        # the classmate: every line a serialize-once cache hit
+        threading.Thread(target=watcher, args=(got2, rv0),
+                         daemon=True).start()
+        wait_for(lambda: len(got2) == 2, "second stream served")
+        assert members() == 2
+        st = store.watch_plane_state()
+        assert (st["shared_hits"], st["line_encodes"]) == (2, 2), st
+        # both live on the same events: the same bytes, and every line
+        # either a ring hit or the encode that filled the ring
+        store.create(PODS, Pod(name="b1"))
+        store.delete(PODS, "default/b1")
+        wait_for(lambda: len(got1) == 4 and len(got2) == 4,
+                 "both streams served")
         assert got1 == got2
-        assert [e["type"] for e in got1] == ["ADDED", "DELETED"]
+        assert [e["type"] for e in got1] == ["ADDED", "DELETED"] * 2
         assert got1[0]["object"]["name"] == "b0"
         assert got1[0]["resourceVersion"] > 0
         st = store.watch_plane_state()
-        # one classmate's lines were serialize-once cache hits
         assert st["shared_hits"] >= 2, st
-        assert st["line_encodes"] >= 2, st
+        assert st["line_encodes"] >= 4, st
+        assert st["shared_hits"] + st["line_encodes"] == 8, st
 
     def test_priority_admission(self, server):
         store, url = server

@@ -105,6 +105,31 @@ class TestScraperConcurrency:
             else:
                 assert 0.0 <= p50 <= p99 <= last + 1e-9
 
+    def test_gc_pause_observed_inside_a_histogram_snapshot(self):
+        """The collector runs inside whatever allocation crosses its
+        threshold, also the bucket copy the scraper makes under the
+        histogram child's lock; its callback then observes the pause
+        histogram on the same thread. With a plain Lock the overhead
+        cell below hung there in 2 of 12 runs. In a process of its own:
+        a regression strands that process, not this worker's collector."""
+        import os
+        import subprocess
+        import sys
+        code = (
+            "from kubernetes_tpu.obs import procmetrics as pm\n"
+            "child = pm.GC_PAUSE.labels('0')\n"
+            "before = child.count\n"
+            "with child._lock:\n"
+            "    pm._gc_callback('start', {'generation': 0})\n"
+            "    pm._gc_callback('stop', {'generation': 0, 'collected': 0})\n"
+            "print(child.count - before >= 1)\n")
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        proc = subprocess.run([sys.executable, "-c", code],
+                              capture_output=True, text=True, cwd=root,
+                              timeout=60)
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        assert proc.stdout.split()[-1] == "True"
+
     def test_raising_gauge_callback_reads_nan_not_crash(self):
         scraper, reg = fresh_scraper()
         g = reg.gauge("bad_gauge", "raising callback")
@@ -467,6 +492,42 @@ class TestWatcherLagSummary:
 # scraper overhead guard (tier-1)
 
 
+_OVERHEAD_CELL = r"""
+import json, time
+from kubernetes_tpu.obs import timeseries as ts
+from kubernetes_tpu.perf.harness import run_commit_cell
+
+def cell():
+    r = run_commit_cell(n_pods=2048, waves=8, n_watchers=8)
+    return r["writes_per_s"]
+
+cell()   # warm the allocator/core build before timing
+interval = 0.05
+off, on = [], []
+for _ in range(3):
+    off.append(cell())
+    ts.SCRAPER.reset(capacity=256)
+    ts.SCRAPER.start(interval=interval)
+    try:
+        on.append(cell())
+    finally:
+        ts.SCRAPER.stop()
+samples = ts.SCRAPER.series()["samples"]
+# seconds per full-registry sample, measured on the same registry the
+# paired runs scraped (CPU seconds of this thread: on a loaded box the
+# wall clock would charge the scraper with the time it was descheduled;
+# the best of five batches, as for any CPU timing)
+per_sample = []
+for _ in range(5):
+    t0 = time.thread_time()
+    for _ in range(20):
+        ts.SCRAPER.sample()
+    per_sample.append((time.thread_time() - t0) / 20)
+duty = min(per_sample) / interval
+print(json.dumps({"off": off, "on": on, "samples": samples, "duty": duty}))
+"""
+
+
 class TestScraperOverheadFloor:
     def test_commit_cell_with_scraper_on_within_5pct(self):
         """The scraper exists to run DURING soaks: the headline-shaped
@@ -477,37 +538,44 @@ class TestScraperOverheadFloor:
         the ratio still dips under the floor, the directly-measured
         sampling duty cycle is the referee: a scraper consuming < 1%
         of the CPU cannot be the cause of a > 5% throughput loss —
-        that is this box's run-to-run noise, not overhead."""
-        from kubernetes_tpu.perf.harness import run_commit_cell
+        that is this box's run-to-run noise, not overhead.
 
-        def cell():
-            r = run_commit_cell(n_pods=2048, waves=8, n_watchers=8)
-            return r["writes_per_s"]
-
-        cell()   # warm the allocator/core build before timing
-        interval = 0.05
-        off, on = [], []
-        for _ in range(3):
-            off.append(cell())
-            ts.SCRAPER.reset(capacity=256)
-            ts.SCRAPER.start(interval=interval)
-            try:
-                on.append(cell())
-            finally:
-                ts.SCRAPER.stop()
-        assert ts.SCRAPER.series()["samples"] >= 1   # it really sampled
-        # seconds per full-registry sample, measured on the same
-        # registry the paired runs scraped
-        t0 = time.perf_counter()
-        for _ in range(20):
-            ts.SCRAPER.sample()
-        duty = ((time.perf_counter() - t0) / 20) / interval
-        m_off, m_on = max(off), max(on)
+        Measured in a process of its own, as a soak is: in a test worker
+        the registry holds the callback gauges of every scheduler and
+        serve loop the worker's earlier files left behind (a sample costs
+        0.1-0.2 ms fresh and 0.8 ms after test_serve.py), so the referee read
+        the worker's history, not the scraper. There the referee is also
+        a floor of its own, held on every run and not only when the ratio
+        dips: it reads 0.24-0.44% in CPU seconds, alone and beside six
+        busy processes alike, so a sample that costs 2.5x as much fails.
+        And noise excuses a dip, not a collapse: beside six busy processes
+        the best-of-3 ratio reads 0.65-1.10 on this box (a 70 ms cell) and
+        the best run with the scraper 0.92-1.56x the WORST run without it;
+        under half of that the scraper stalls the writer, whatever CPU it
+        burns."""
+        import os
+        import subprocess
+        import sys
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        proc = subprocess.run([sys.executable, "-c", _OVERHEAD_CELL],
+                              capture_output=True, text=True, cwd=root,
+                              timeout=300)
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        got = json.loads(proc.stdout.splitlines()[-1])
+        assert got["samples"] >= 1   # it really sampled
+        duty = got["duty"]
+        m_off, m_on = max(got["off"]), max(got["on"])
         ratio = m_on / m_off
-        assert ratio >= 0.95 or duty < 0.01, \
+        assert duty < 0.01, \
+            f"sampling duty cycle {duty:.2%} of one CPU (floor 1%) — " \
+            f"the scraper itself is eating the budget"
+        # under the referee's 1% a ratio below 0.95 is this box's noise,
+        # as far as noise goes
+        assert m_on >= 0.5 * min(got["off"]), \
             f"scraper overhead: on {m_on:.0f}/s vs off {m_off:.0f}/s " \
-            f"({ratio:.3f}x, floor 0.95x) with sampling duty cycle " \
-            f"{duty:.1%} — the scraper itself is eating the budget"
+            f"({ratio:.3f}x, floor 0.95x) and under half the slowest " \
+            f"scraper-off run ({min(got['off']):.0f}/s) with sampling " \
+            f"duty cycle {duty:.1%}: no noise does that"
 
 
 # ---------------------------------------------------------------------------
