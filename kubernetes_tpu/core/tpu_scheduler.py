@@ -103,6 +103,18 @@ SCATTER_ROWS = obs.counter(
     "tpu_scatter_rows_total",
     "Dirty node rows re-uploaded by the row scatter: the rows that "
     "changed, without the padding to the scatter's power-of-two bucket.")
+WALK_NODES = obs.counter(
+    "tpu_walk_nodes_evaluated_total",
+    "Nodes the filter's walk tested (upstream's `evaluated`), summed over "
+    "every decision of schedule_burst's launches, by regime: 'truncated' "
+    "(num_to_find < n: the walk stops at its quota; read from the li_after "
+    "block the scan launch fetches anyway) or 'full' (every node, n per "
+    "pod).", ("regime",))
+SCAN_STEPS = obs.counter(
+    "tpu_scan_steps_total",
+    "Steps of the generic lax.scan launched by schedule_burst, by kind: "
+    "'real' (one per pod) and 'pad' (skip pods that fill the burst up to "
+    "its power-of-two bucket).", ("kind",))
 DISCARDED_FOLDS = obs.counter(
     "tpu_burst_folds_discarded_total",
     "Device-resident burst folds dropped after a mid-burst failure.")
@@ -1521,6 +1533,7 @@ class TPUScheduler:
             lni_dev = lni_out
             self._dev_nodes = {**self._dev_nodes, **rows}
             DEVICE_DISPATCH.labels("burst_uniform").inc()
+            WALK_NODES.labels("full").inc(chunk * n)
             ph.close()   # dispatch (async; fetch waits)
             inflight.append((ci, lo, chunk, self._submit_fetch(packed),
                              t_d))
@@ -1680,19 +1693,20 @@ class TPUScheduler:
         B = bucket
         n_pods = len(pods)
         W = max(1, min(int(self.wave_size), B))
-        wave = list(per_pod)
-        if len(wave) < B:
-            pad = dict(wave[-1])
-            pad["skip"] = self._true
-            wave.extend([pad] * (B - len(wave)))
-        stacked = self._stack_pods(wave)
-        rot = rotp = None
-        if rotation is not None:
-            perms, inv_perms, seq = rotation
-            rot = (perms, inv_perms, np.asarray(seq[:B], dtype=np.int32))
-        elif rotation_pos is not None:
-            rotp = (rotation_pos[0],
-                    np.asarray(rotation_pos[1][:B], dtype=np.int32))
+        with obs_trace.span("burst.stack"):
+            wave = list(per_pod)
+            if len(wave) < B:
+                pad = dict(wave[-1])
+                pad["skip"] = self._true
+                wave.extend([pad] * (B - len(wave)))
+            stacked = self._stack_pods(wave)
+            rot = rotp = None
+            if rotation is not None:
+                perms, inv_perms, seq = rotation
+                rot = (perms, inv_perms, np.asarray(seq[:B], dtype=np.int32))
+            elif rotation_pos is not None:
+                rotp = (rotation_pos[0],
+                        np.asarray(rotation_pos[1][:B], dtype=np.int32))
         ph.open("kernel")
         t_d = obs_trace.now()
         try:
@@ -1706,6 +1720,8 @@ class TPUScheduler:
                 mesh=self.mesh, wtab=self._wtab() if tensor else None)
             self._note_ici("burst_scan", n_pods, b.n_pad)
             DEVICE_DISPATCH.labels("burst_scan").inc()
+            SCAN_STEPS.labels("real").inc(n_pods)
+            SCAN_STEPS.labels("pad").inc(B - n_pods)
             ph.close()
             ph.open("fetch")
             chaos.node_dead_point("dispatch-fetch")
@@ -1734,6 +1750,15 @@ class TPUScheduler:
         li_after = h[B:2 * B]
         lni_delta = h[2 * B:3 * B]
         lni0 = self.last_node_index
+        if num_to_find < n:
+            # a walk tests 1..n nodes and moves last_index by that many
+            # mod n, so a step of 0 is a walk over all n
+            moved = np.diff(li_after[:n_pods].astype(np.int64),
+                            prepend=self.last_index % n) % n
+            WALK_NODES.labels("truncated").inc(
+                int(np.where(moved == 0, n, moved).sum()))
+        else:
+            WALK_NODES.labels("full").inc(n_pods * n)
         neg = sel_arr < 0
         bad = int(np.argmax(neg)) if neg.any() else n_pods
         committed = bad
