@@ -8,10 +8,12 @@ suggested hosts, feasible sets, evaluated counts, and integer scores.
 Two paths:
 - schedule(): one pod per launch — used for parity testing and for pods with
   features the burst path doesn't batch yet.
-- schedule_burst(): a `lax.scan` over many pending pods against one
+- schedule_burst(): one device loop over many pending pods against one
   snapshot, folding each decision's resource delta into device state —
   serially-equivalent decisions at one launch (the throughput path;
   reference equivalent is the serial scheduleOne loop, scheduler.go:438).
+  A launch's operands are padded to a power-of-two bucket (one compile per
+  bucket); its trip count is the number of pods it was given.
 """
 from __future__ import annotations
 
@@ -112,9 +114,11 @@ WALK_NODES = obs.counter(
     "pod).", ("regime",))
 SCAN_STEPS = obs.counter(
     "tpu_scan_steps_total",
-    "Steps of the generic lax.scan launched by schedule_burst, by kind: "
-    "'real' (one per pod) and 'pad' (skip pods that fill the burst up to "
-    "its power-of-two bucket).", ("kind",))
+    "Steps the device ran in schedule_burst's generic scan launches, by "
+    "kind: 'real' (one per pod). 'pad' (skip pods that filled the burst up "
+    "to its power-of-two bucket) reads 0 since the pod count became the "
+    "loop's trip count: the pad rows are never stepped over.", ("kind",))
+SCAN_STEPS.labels("pad")
 DISCARDED_FOLDS = obs.counter(
     "tpu_burst_folds_discarded_total",
     "Device-resident burst folds dropped after a mid-burst failure.")
@@ -1277,6 +1281,11 @@ class TPUScheduler:
         returned placements to its cache (as the scheduler shell does via
         assume + note_burst_assumed) before the next cycle.
 
+        `bucket` sizes the launch's operands (padded to a power of two, so
+        a warm-up burst of any size in the bucket compiles the program a
+        later burst runs); the device steps over `len(pods)` of them, not
+        over the bucket.
+
         `commit(lo, hosts) -> bool` (optional) is the wave-window sink:
         since round 10 the whole burst is ONE dispatch and ONE packed
         fetch, and `commit` is called with consecutive `wave_size` windows
@@ -1676,11 +1685,14 @@ class TPUScheduler:
                     spread0, rotation, rotation_pos, num_to_find: int,
                     n: int, z_pad: int, bucket: int, commit,
                     ph: _BurstPhases, fl=None) -> list[Optional[str]]:
-        """Single-launch driver for the generic lax.scan burst: the whole
-        burst runs as ONE scan launch (scan length = the caller's bucket,
-        so the warmup burst compiles the same program) and the host
+        """Single-launch driver for the generic scan burst: the whole
+        burst runs as ONE launch whose operands have the caller's bucket
+        shape (so the warmup burst compiles the same program) and whose
+        trip count is `len(pods)`, a dynamic operand: the pad rows give
+        the operands their shape and are never stepped over. The host
         fetches ONE packed [3B] block — selections plus the per-pod walk
-        counters. Commit then consumes the block wave-by-wave.
+        counters, rows from `len(pods)` on a fixed fill. Commit then
+        consumes the block wave-by-wave.
 
         Rewind contract, re-derived from slices of the single block: the
         scan keeps deciding after a failed pod, so everything from the
@@ -1717,11 +1729,11 @@ class TPUScheduler:
                 self.last_node_index, num_to_find, n, z_pad,
                 weights=self._union_weights if tensor else self.weights,
                 rotation=rot, spread0=spread0, rotation_pos=rotp,
-                mesh=self.mesh, wtab=self._wtab() if tensor else None)
+                mesh=self.mesh, wtab=self._wtab() if tensor else None,
+                n_pods=n_pods)
             self._note_ici("burst_scan", n_pods, b.n_pad)
             DEVICE_DISPATCH.labels("burst_scan").inc()
             SCAN_STEPS.labels("real").inc(n_pods)
-            SCAN_STEPS.labels("pad").inc(B - n_pods)
             ph.close()
             ph.open("fetch")
             chaos.node_dead_point("dispatch-fetch")

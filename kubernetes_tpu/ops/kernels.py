@@ -10,20 +10,23 @@ reference's *sequential* semantics are reproduced exactly:
   rotation order from last_index* are kept (a cumsum emulates the
   sequential walk's stopping point — same feasible set, same "evaluated"
   count, same last_index advance). Checked on the chip at 15,000 nodes
-  (750 found per decision, 16,384-step scan launches, last_index going
-  round the cluster) against the benchmark's independent reference by the
-  cell `headline-15000n-adaptive.backlog-10k`; walks over full nodes and
-  the perm/inv_perm gather of uneven zones by tests/test_adaptive_walk.py
-  on the CPU.
+  (750 found per decision, 10,000-step scan launches in a 16,384 bucket,
+  last_index going round the cluster) against the benchmark's independent
+  reference by the cell `headline-15000n-adaptive.backlog-10k`; walks over
+  full nodes and the perm/inv_perm gather of uneven zones by
+  tests/test_adaptive_walk.py on the CPU.
 - integer 0-10 scores with the reference's exact int64/float64 formulas
   (the float64 ones as correctly rounded integer arithmetic, ops/exactf64.py;
   no f64 and no vector integer division reaches the device),
   normalized over the kept set only.
 - round-robin tie-break among max-score nodes via last_node_index (:292).
 
-The batched variant runs a `lax.scan` over a burst of pending pods against
+The batched variant runs one serial cycle per pending pod of a burst against
 one snapshot, folding each decision's resource deltas into the node state on
 device — serially-equivalent decisions at one kernel launch for the burst.
+The burst's operands are padded to a bucket for one compile per bucket; the
+pod count is a dynamic trip count, so the launch runs as many steps as it was
+given pods.
 
 Every core names its stages with `jax.named_scope`, in upstream's words:
 `filter` (feasibility and the adaptive walk; for preemption, the victim
@@ -615,7 +618,8 @@ def schedule_cycle(nodes, pod, last_index, last_node_index, num_to_find, n_real,
 
 
 # ---------------------------------------------------------------------------
-# Batched burst: lax.scan over pods, folding decisions into node state
+# Batched burst: a loop over the burst's pods, folding decisions into node
+# state
 # ---------------------------------------------------------------------------
 _MUTABLE = ("req_cpu", "req_mem", "req_eph", "req_scalar",
             "nz_cpu", "nz_mem", "pod_count")
@@ -656,41 +660,52 @@ def _fold_state(state, pod, sel, hit):
     }
 
 
-def _batch_core(nodes, mut0, pods, last_index, last_node_index,
+def _batch_core(nodes, mut0, pods, n_pods, last_index, last_node_index,
                 num_to_find, n_real, perms, inv_perms, oid_seq,
                 spread0, z_pad, weights, rotate, carry_spread,
                 rotate_pos=False, constrain=None, wtab=None):
-    """Body of the generic lax.scan burst kernel. `constrain` (optional)
-    pins the node-axis carry — the mutable state rows and the carried
-    spread vector — to a mesh sharding every iteration, so the O(N) sweep
-    stays split across chips while the scalar select epilogue replicates
-    (parallel/sharding.py wraps this for mesh mode; None = single-chip
-    identity, the exact program the jit wrapper below compiles). `wtab`
-    (tensor mode) makes the scan profile-aware: `pods["profile_id"]` [B]
-    rides the xs, and each step's cycle gathers that pod's weight row —
-    a window MIXING tenants scores in the one launch."""
+    """Body of the generic burst kernel: one serial cycle per pod, each
+    folding its decision into the carried node state.
+
+    The pod count is a DYNAMIC operand of a single loop (as in
+    _segments_core and _uniform_core): the [B, ...] operands keep the
+    caller's bucket shape, so there is one compile per bucket, and the loop
+    runs exactly `n_pods` iterations — 10,000 pods in a 16,384 bucket pay
+    for 10,000 cycles. Rows from `n_pods` on are never read; their output
+    rows keep a fixed fill (-1 in the packed block, 0 elsewhere).
+
+    `constrain` (optional) pins the node-axis carry — the mutable state
+    rows and the carried spread vector — to a mesh sharding every
+    iteration, so the O(N) sweep stays split across chips while the scalar
+    select epilogue replicates (parallel/sharding.py wraps this for mesh
+    mode; None = single-chip identity, the exact program the jit wrapper
+    below compiles). `wtab` (tensor mode) makes the loop profile-aware:
+    `pods["profile_id"]` [B] rides the operands, and each step's cycle
+    gathers that pod's weight row — a window MIXING tenants scores in the
+    one launch."""
     if constrain is None:
         constrain = lambda v: v
+    i32 = jnp.int32
     static = {k: v for k, v in nodes.items() if k not in _MUTABLE}
     # selector-spread counts evolve with in-burst placements: the caller
     # guarantees every pod shares one selector set (spec-identical), so the
     # shared dense base counts (spread0 [N]) are carried and each placement
     # folds +1 on its node (selector_spreading.go:66 counting semantics)
+    if carry_spread:
+        pods = {k: v for k, v in pods.items() if k != "spread_counts"}
+    B = pods["skip"].shape[0]
 
-    def step(carry, xs):
+    def body(i, carry):
+        state, li, lni, spread, packed, aux = carry
+        pod = {k: jax.lax.dynamic_index_in_dim(v, i, keepdims=False)
+               for k, v in pods.items()}
         perm = inv_perm = pos = None
         if rotate_pos:
             # gather-free rotation: perms holds per-order POSITION vectors
-            state, li, lni, spread = carry
-            pod, oid = xs
-            pos = perms[oid]
+            pos = perms[oid_seq[i]]
         elif rotate:
-            state, li, lni, spread = carry
-            pod, oid = xs
+            oid = oid_seq[i]
             perm, inv_perm = perms[oid], inv_perms[oid]
-        else:
-            state, li, lni, spread = carry
-            pod = xs
         if carry_spread:
             pod = {**pod, "spread_counts": spread}
         full = {**static, **state}
@@ -703,63 +718,66 @@ def _batch_core(nodes, mut0, pods, last_index, last_node_index,
         if carry_spread:
             spread = constrain(spread.at[jnp.maximum(sel, 0)].add(
                 jnp.where(hit & ~pod["skip"], 1, 0)))
-        return ((new_state, out["next_last_index"],
-                 out["next_last_node_index"], spread), {
-            "selected": sel,
-            "found": out["found"],
-            "evaluated": out["evaluated"],
-            "max_score": out["max_score"],
-            "li_after": out["next_last_index"].astype(jnp.int32),
-            "lni_after": out["next_last_node_index"],
-        })
+        li, lni = out["next_last_index"], out["next_last_node_index"]
+        packed = packed.at[:, i].set(jnp.stack([
+            sel.astype(i32), li.astype(i32),
+            (lni - last_node_index).astype(i32)]))
+        aux = aux.at[:, i].set(jnp.stack([
+            out["found"], out["evaluated"], out["max_score"], lni]))
+        return new_state, li, lni, spread, packed, aux
 
-    if carry_spread:
-        pods = {k: v for k, v in pods.items() if k != "spread_counts"}
-    xs = (pods, oid_seq) if (rotate or rotate_pos) else pods
-    init = (constrain(mut0), last_index, last_node_index, constrain(spread0))
-    (state, li, lni, spread), outs = jax.lax.scan(step, init, xs)
+    init = (constrain(mut0), last_index, last_node_index, constrain(spread0),
+            jnp.full((3, B), -1, i32), jnp.zeros((4, B), jnp.int64))
+    state, li, lni, spread, packed, aux = jax.lax.fori_loop(
+        jnp.int32(0), jnp.asarray(n_pods, i32), body, init)
     # ONE packed fetch block [3B] i32: selections, then the walk counters
     # AFTER each pod (li absolute — it is < n; lni as a delta from the
     # launch's start so it fits i32) — a mid-burst failure's prefix rewind
     # reads the counters straight out of the single fetched block instead
     # of paying a second round trip for the evaluated/found vectors
-    outs["packed"] = jnp.concatenate([
-        outs["selected"].astype(jnp.int32),
-        outs["li_after"],
-        (outs["lni_after"] - last_node_index).astype(jnp.int32)])
+    outs = {"selected": packed[0].astype(jnp.int64), "li_after": packed[1],
+            "found": aux[0], "evaluated": aux[1], "max_score": aux[2],
+            "lni_after": aux[3], "packed": packed.reshape(3 * B)}
     return state, li, lni, spread, outs
 
 
 @partial(jax.jit, static_argnames=("z_pad", "weights_tuple", "rotate",
                                    "carry_spread", "rotate_pos"))
-def _schedule_batch_jit(nodes, mut0, pods, last_index, last_node_index,
-                        num_to_find, n_real, perms, inv_perms, oid_seq,
-                        spread0, z_pad, weights_tuple, rotate, carry_spread,
-                        rotate_pos=False):
-    return _batch_core(nodes, mut0, pods, last_index, last_node_index,
-                       num_to_find, n_real, perms, inv_perms, oid_seq,
-                       spread0, z_pad, dict(weights_tuple), rotate,
-                       carry_spread, rotate_pos=rotate_pos)
+def _schedule_batch_jit(nodes, mut0, pods, n_pods, last_index,
+                        last_node_index, num_to_find, n_real, perms,
+                        inv_perms, oid_seq, spread0, z_pad, weights_tuple,
+                        rotate, carry_spread, rotate_pos=False):
+    return _batch_core(nodes, mut0, pods, n_pods, last_index,
+                       last_node_index, num_to_find, n_real, perms,
+                       inv_perms, oid_seq, spread0, z_pad,
+                       dict(weights_tuple), rotate, carry_spread,
+                       rotate_pos=rotate_pos)
 
 
 @partial(jax.jit, static_argnames=("z_pad", "weights_tuple", "rotate",
                                    "carry_spread", "rotate_pos"))
-def _schedule_batch_wtab_jit(nodes, mut0, pods, wtab, last_index,
+def _schedule_batch_wtab_jit(nodes, mut0, pods, n_pods, wtab, last_index,
                              last_node_index, num_to_find, n_real, perms,
                              inv_perms, oid_seq, spread0, z_pad,
                              weights_tuple, rotate, carry_spread,
                              rotate_pos=False):
-    return _batch_core(nodes, mut0, pods, last_index, last_node_index,
-                       num_to_find, n_real, perms, inv_perms, oid_seq,
-                       spread0, z_pad, dict(weights_tuple), rotate,
-                       carry_spread, rotate_pos=rotate_pos, wtab=wtab)
+    return _batch_core(nodes, mut0, pods, n_pods, last_index,
+                       last_node_index, num_to_find, n_real, perms,
+                       inv_perms, oid_seq, spread0, z_pad,
+                       dict(weights_tuple), rotate, carry_spread,
+                       rotate_pos=rotate_pos, wtab=wtab)
 
 
 def schedule_batch(nodes, pods, last_index, last_node_index, num_to_find, n_real,
                    z_pad, weights=None, rotation=None, spread0=None,
-                   rotation_pos=None, carry_in=None, mesh=None, wtab=None):
+                   rotation_pos=None, carry_in=None, mesh=None, wtab=None,
+                   n_pods=None):
     """Schedule a burst of pods against one snapshot, decisions serially
-    equivalent to per-pod cycles. `pods` is a dict of [B, ...] arrays.
+    equivalent to per-pod cycles. `pods` is a dict of [B, ...] arrays
+    padded to the caller's bucket (one compile per bucket); `n_pods` is the
+    DYNAMIC real count — the loop runs exactly that many cycles, rows from
+    `n_pods` on are never read, and their rows of the outputs keep a fixed
+    fill (-1 in `packed`). None = all B rows.
 
     `rotation` = (perms[L, n_pad], inv_perms[L, n_pad], oid_seq[B]) supplies
     each in-burst cycle's NodeTree enumeration order when it differs from
@@ -817,6 +835,7 @@ def schedule_batch(nodes, pods, last_index, last_node_index, num_to_find, n_real
             else jnp.zeros((), jnp.int64)
     if wtab is not None:
         wtab = jnp.asarray(wtab, jnp.int64)
+    n_pods = _i64(pods["skip"].shape[0] if n_pods is None else n_pods)
     if mesh is not None:
         from kubernetes_tpu.parallel import sharding as S
         fn = S.sharded_scan_fn(mesh, z_pad, weights_tuple,
@@ -824,21 +843,21 @@ def schedule_batch(nodes, pods, last_index, last_node_index, num_to_find, n_real
                                rotation_pos is not None,
                                use_wtab=wtab is not None)
         if wtab is not None:
-            return fn(nodes, mut0, pods, wtab, _i64(last_index),
+            return fn(nodes, mut0, pods, n_pods, wtab, _i64(last_index),
                       _i64(last_node_index), _i64(num_to_find),
                       _i64(n_real), perms, inv_perms, oid_seq, s0)
-        return fn(nodes, mut0, pods, _i64(last_index),
+        return fn(nodes, mut0, pods, n_pods, _i64(last_index),
                   _i64(last_node_index), _i64(num_to_find), _i64(n_real),
                   perms, inv_perms, oid_seq, s0)
     if wtab is not None:
         return _schedule_batch_wtab_jit(
-            nodes, mut0, pods, wtab, _i64(last_index),
+            nodes, mut0, pods, n_pods, wtab, _i64(last_index),
             _i64(last_node_index), _i64(num_to_find), _i64(n_real), perms,
             inv_perms, oid_seq, s0, z_pad, weights_tuple,
             rotation is not None, carry_spread,
             rotate_pos=rotation_pos is not None)
     return _schedule_batch_jit(
-        nodes, mut0, pods, _i64(last_index), _i64(last_node_index),
+        nodes, mut0, pods, n_pods, _i64(last_index), _i64(last_node_index),
         _i64(num_to_find), _i64(n_real), perms, inv_perms, oid_seq, s0,
         z_pad, weights_tuple, rotation is not None, carry_spread,
         rotate_pos=rotation_pos is not None)

@@ -233,12 +233,13 @@ _PREEMPT_CACHE: dict = {}
 def sharded_scan_fn(mesh: Mesh, z_pad: int, weights_tuple, rotate: bool,
                     carry_spread: bool, rotate_pos: bool,
                     use_wtab: bool = False):
-    """The generic lax.scan burst kernel (kernels._batch_core) with the
+    """The generic burst kernel (kernels._batch_core) with the
     node axis sharded over the mesh — the SAME program single-device runs,
     parameterized by the sharding spec: each chip folds the selected pod's
     deltas into its node rows every step (the carried _MUTABLE state and
     spread vector are pinned to the node sharding), rotation perm rows
-    replicate (they are tiny [L, N] index tables), and the per-node
+    replicate (they are tiny [L, N] index tables), the pod count (the
+    loop's dynamic trip count) is a replicated scalar, and the per-node
     feasibility/score vectors ride XLA collectives (all-gather over ICI)
     into the replicated select epilogue. Decisions are bit-identical to
     the single-device scan (tests/test_sharding.py + the sharded fuzz
@@ -253,20 +254,20 @@ def sharded_scan_fn(mesh: Mesh, z_pad: int, weights_tuple, rotate: bool,
     if use_wtab:
         # profile tensor mode: the replicated [P, K] weight table rides the
         # operands and each step gathers its pod's row (profile_id in pods)
-        def f(nodes, mut0, pods, wtab, last_index, last_node_index,
+        def f(nodes, mut0, pods, n_pods, wtab, last_index, last_node_index,
               num_to_find, n_real, perms, inv_perms, oid_seq, spread0):
             nodes = _constrain_nodes(mesh, nodes)
-            return K._batch_core(nodes, mut0, pods, last_index,
+            return K._batch_core(nodes, mut0, pods, n_pods, last_index,
                                  last_node_index, num_to_find, n_real,
                                  perms, inv_perms, oid_seq, spread0, z_pad,
                                  dict(weights_tuple), rotate, carry_spread,
                                  rotate_pos=rotate_pos, constrain=c,
                                  wtab=wtab)
     else:
-        def f(nodes, mut0, pods, last_index, last_node_index, num_to_find,
-              n_real, perms, inv_perms, oid_seq, spread0):
+        def f(nodes, mut0, pods, n_pods, last_index, last_node_index,
+              num_to_find, n_real, perms, inv_perms, oid_seq, spread0):
             nodes = _constrain_nodes(mesh, nodes)
-            return K._batch_core(nodes, mut0, pods, last_index,
+            return K._batch_core(nodes, mut0, pods, n_pods, last_index,
                                  last_node_index, num_to_find, n_real,
                                  perms, inv_perms, oid_seq, spread0, z_pad,
                                  dict(weights_tuple), rotate, carry_spread,
@@ -386,10 +387,11 @@ def shard_victim_planes(mesh: Mesh, planes: dict) -> dict:
 
 
 def sharded_batch_fn(mesh: Mesh, z_pad: int, weights=None):
-    """The full scheduling *step* over the mesh: a `lax.scan` burst with the
-    node axis sharded and the complete mutable-state fold (kernels._MUTABLE —
-    req_cpu/mem/eph/scalar, nz_cpu/nz_mem, pod_count) constrained back onto
-    the node sharding every iteration.
+    """The full scheduling *step* over the mesh: the generic burst loop with
+    the node axis sharded and the complete mutable-state fold
+    (kernels._MUTABLE — req_cpu/mem/eph/scalar, nz_cpu/nz_mem, pod_count)
+    constrained back onto the node sharding every iteration. `n_pods`, the
+    loop's trip count, rides as a replicated scalar (None = every row).
 
     This is the multi-chip twin of kernels.schedule_batch, now riding the
     SAME _batch_core the single-device jit compiles (one code path
@@ -402,12 +404,16 @@ def sharded_batch_fn(mesh: Mesh, z_pad: int, weights=None):
     inner = sharded_scan_fn(mesh, z_pad, weights_tuple, rotate=False,
                             carry_spread=False, rotate_pos=False)
 
-    def fn(nodes, pods, last_index, last_node_index, num_to_find, n_real):
+    def fn(nodes, pods, last_index, last_node_index, num_to_find, n_real,
+           n_pods=None):
         z = jnp.zeros((1, 1), jnp.int32)
         mut0 = {k: nodes[k] for k in K._MUTABLE}
+        if n_pods is None:
+            n_pods = pods["skip"].shape[0]
         state, li, lni, _spread, outs = inner(
-            nodes, mut0, pods, last_index, last_node_index, num_to_find,
-            n_real, z, z, jnp.zeros(1, jnp.int32), jnp.zeros((), jnp.int64))
+            nodes, mut0, pods, jnp.asarray(n_pods, jnp.int64), last_index,
+            last_node_index, num_to_find, n_real, z, z,
+            jnp.zeros(1, jnp.int32), jnp.zeros((), jnp.int64))
         return state, li, lni, outs
 
     return fn

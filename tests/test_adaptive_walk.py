@@ -356,9 +356,16 @@ def test_skip_decision_waits_for_its_place():
 
 
 # -- (g) counters and the span ------------------------------------------------------
-def test_counters_and_span_of_a_300_pod_burst():
+def test_counters_and_span_of_a_300_pod_burst(monkeypatch):
     from kubernetes_tpu import obs
     from kubernetes_tpu.core import tpu_scheduler as T
+    launched = []
+    orig = T.K.schedule_batch
+
+    def spy(nodes, pods, *a, **kw):
+        launched.append((kw["n_pods"], pods["skip"].shape[0]))
+        return orig(nodes, pods, *a, **kw)
+    monkeypatch.setattr(T.K, "schedule_batch", spy)
 
     def snap():
         return {(f.name, k): c.value for f in (T.WALK_NODES, T.SCAN_STEPS,
@@ -376,15 +383,18 @@ def test_counters_and_span_of_a_300_pod_burst():
     ids = run.cycle(300, max_pods=300)
     assert len(run.bound(ids)) == 240
     got = moved(before)
-    # one launch of the 512-step bucket decides the 240 that fit and fails
-    # the 241st; what the device decided after it is dropped and retried
+    # one 300-step launch in the 512 bucket decides the 240 that fit and
+    # fails the 241st; what the device decided after it is dropped and retried
     launches = got[("tpu_device_dispatch_total", ("burst_scan",))]
-    assert launches >= 1
+    assert launches == len(launched) >= 1
     assert ("tpu_device_dispatch_total", ("burst_uniform",)) not in got
+    # the counter says what the device ran: a step a pod, whatever the bucket;
+    # 'pad' stays declared and reads 0
+    assert launched[0] == (300, 512)
+    assert all(bucket == 512 for _n, bucket in launched)
     real = got[("tpu_scan_steps_total", ("real",))]
-    pad = got[("tpu_scan_steps_total", ("pad",))]
-    assert real >= 300 and (real + pad) % 512 == 0
-    assert (real + pad) // 512 == launches
+    assert real == sum(n for n, _bucket in launched) >= 300
+    assert T.SCAN_STEPS._children[("pad",)].value == 0
     # the reference's walks over the same stream test as many nodes as the
     # first launch counted for its decided prefix; later launches add the
     # walks of pods that found nothing (n nodes each)
