@@ -216,20 +216,35 @@ def replayed(run_fn) -> tuple[int, list]:
         flight.RECORDER.clear()
 
 
+def build_cluster(store, n_nodes: int) -> None:
+    """The headline's nodes: 4 CPU / 32 Gi / 110 pods each, three even
+    zones (scheduler_perf's node shape)."""
+    from kubernetes_tpu.models.hollow import NodeStrategy, populate_store
+    populate_store(store, [NodeStrategy(count=n_nodes, zones=3,
+                                        name_prefix="node")])
+
+
+def make_pods(store, n_pods: int) -> None:
+    """The headline's pods: 100m / 500Mi, one label."""
+    from kubernetes_tpu.models.hollow import PodStrategy, make_pods as _pods
+    from kubernetes_tpu.store.store import PODS
+    for pod in _pods(PodStrategy(count=n_pods)):
+        store.create(PODS, pod)
+
+
 # -- stages ------------------------------------------------------------------
 def drain(smoke: Smoke, tag: str, device: bool, n_pods: int, **sched_kw):
     """Headline-shaped cluster through the CLI's path, drained; checks
     every pod bound exactly once and returns (scheduler, store,
     {pod key: node})."""
-    import bench
     from kubernetes_tpu.apis.config import SchedulerConfiguration
     from kubernetes_tpu.factory import create_scheduler
     from kubernetes_tpu.store.store import MODIFIED, PODS, Store
     s = smoke.sizes
     store = Store(watch_log_size=1 << 20)        # cmd/scheduler.py's size
-    bench.build_cluster(store, s["drain_nodes"])
-    # the headline scores every node (bench.py run_bench), which is also
-    # what routes a uniform backlog onto the K-batch kernel
+    build_cluster(store, s["drain_nodes"])
+    # the headline scores every node (benchmark/configs/headline-15000n),
+    # which is also what routes a uniform backlog onto the K-batch kernel
     cfg = SchedulerConfiguration(percentage_of_nodes_to_score=100)
     if not device:
         cfg.feature_gates = {"TPUScoring": False}
@@ -240,7 +255,7 @@ def drain(smoke: Smoke, tag: str, device: bool, n_pods: int, **sched_kw):
         sched.slow_cycle_threshold = float("inf")
     sched.sync()
     watch = store.watch(PODS)
-    bench.make_pods(store, n_pods)
+    make_pods(store, n_pods)
     sched.pump()
     bound = 0
     if device:
@@ -353,11 +368,10 @@ def stage_gang(smoke: Smoke):
 def stage_preempt(smoke: Smoke):
     """One preemption pressure wave; run_preempt_cell asserts the device's
     decisions equal the serial oracle's before it returns."""
-    import bench
+    from kubernetes_tpu.perf.harness import run_preempt_cell
     s = smoke.sizes
     d0 = dispatch_counts()
-    bench.run_preempt_bench(s["lane_nodes"], s["preempt_victims"],
-                            s["preemptors"])
+    run_preempt_cell(s["lane_nodes"], s["preempt_victims"], s["preemptors"])
     ops = dispatch_delta(d0)
     smoke.check("lanes.preempt.oracle_identical", True,
                 "asserted inside run_preempt_cell")
@@ -431,17 +445,16 @@ def stage_preempt_scan(smoke: Smoke):
 def stage_serial(smoke: Smoke):
     import jax
     import jax.numpy as jnp
-    import bench
     from kubernetes_tpu.obs import trace as obs_trace
     from kubernetes_tpu.scheduler import Scheduler
     from kubernetes_tpu.store.store import PODS, Store
     s = smoke.sizes
     store = Store(watch_log_size=1 << 16)
-    bench.build_cluster(store, s["serial_nodes"])
+    build_cluster(store, s["serial_nodes"])
     sched = Scheduler(store, use_tpu=True, percentage_of_nodes_to_score=100)
     sched.algorithm.serial_path = "device"
     sched.sync()
-    bench.make_pods(store, s["serial_cycles"])
+    make_pods(store, s["serial_cycles"])
     sched.pump()
     d0 = dispatch_counts()
     obs_trace.clear()

@@ -17,7 +17,7 @@ Layout:
   queue/      scheduling queue: activeQ / backoffQ / unschedulableQ
   store/      in-memory versioned object store with list/watch (etcd+apiserver analog)
   models/     workload & cluster models for benchmarks (scheduler_perf / kubemark analog)
-  perf/       benchmark harness
+  perf/       parity cells for the tests and chip_smoke.py (the benchmark is benchmark/run.py)
   utils/      heap, clock, backoff helpers
 """
 

@@ -86,18 +86,6 @@ ORACLE_FALLBACKS = obs.counter(
     "tpu_oracle_fallback_total",
     "Decisions routed off the device path (host twin / serial rerun), "
     "by reason.", ("reason",))
-ICI_ALLGATHER = obs.counter(
-    "tpu_ici_allgather_bytes_total",
-    "Analytic model of the cross-device bytes the sharded kernels ship "
-    "per burst, by op: each scheduling cycle's ICI all-gather moves the "
-    "per-node feasibility bit, the i32 walk cumsum, and the i64 score "
-    "lane (~16B/node-row) to the d-1 peer shards; the replicated select "
-    "epilogue adds nothing per pod. Zero when the mesh is single-device "
-    "or absent. XLA does not expose actual collective bytes, so this is "
-    "the documented traffic model, not a NIC counter.", ("op",))
-# per-cycle cross-device payload of the sharded select epilogue (bytes per
-# node row): feasible bool (4 padded) + i32 rank/cumsum lane + i64 score
-ICI_BYTES_PER_ROW = 16
 PRESSURE_GATES = obs.counter(
     "tpu_pressure_gate_rejections_total",
     "preempt_pressure_burst refusals, by gate.", ("gate",))
@@ -308,7 +296,7 @@ class TPUScheduler:
         self._dev_vic: Optional[dict] = None
         self._dev_vic_key = None
         # encode vs device-scan wall seconds of the last pressure launch
-        # (bench.py --mode preempt reports the split)
+        # (perf.harness.run_preempt_cell returns the split)
         self.last_preempt_phases: Optional[dict] = None
         # upload/scatter epoch: bumps whenever HOST data lands in the
         # device matrix (burst folds do NOT bump it) — a gang checkpoint
@@ -360,19 +348,6 @@ class TPUScheduler:
         if arr is None:
             arr = self._zero_scalars[n] = np.zeros(n, dtype=np.int64)
         return arr
-
-    def _note_ici(self, op: str, n_cycles: int, n_pad: int) -> None:
-        """Book the analytic ICI all-gather traffic of a sharded launch:
-        `n_cycles` scheduling cycles (for the uniform kernel, decisions —
-        an upper bound on O(N) passes), ICI_BYTES_PER_ROW per node row,
-        shipped to the d-1 peer shards. No-op off the mesh."""
-        if self.mesh is None:
-            return
-        d = int(self.mesh.devices.size)
-        if d <= 1:
-            return
-        ICI_ALLGATHER.labels(op).inc(
-            int(n_cycles) * int(n_pad) * ICI_BYTES_PER_ROW * (d - 1) // d)
 
     # -- scheduling profiles (round 19) --------------------------------------
     def set_profiles(self, profiles) -> None:
@@ -1538,7 +1513,6 @@ class TPUScheduler:
                 weights=self._union_weights if tensor else self.weights,
                 rotation=rot, extra_ok=extra_ok, ban=ban, mesh=self.mesh,
                 cap=cap, wtab=self._wtab() if tensor else None, pid=pid)
-            self._note_ici("burst_uniform", chunk, b.n_pad)
             lni_dev = lni_out
             self._dev_nodes = {**self._dev_nodes, **rows}
             DEVICE_DISPATCH.labels("burst_uniform").inc()
@@ -1731,7 +1705,6 @@ class TPUScheduler:
                 rotation=rot, spread0=spread0, rotation_pos=rotp,
                 mesh=self.mesh, wtab=self._wtab() if tensor else None,
                 n_pods=n_pods)
-            self._note_ici("burst_scan", n_pods, b.n_pad)
             DEVICE_DISPATCH.labels("burst_scan").inc()
             SCAN_STEPS.labels("real").inc(n_pods)
             ph.close()
@@ -2000,7 +1973,6 @@ class TPUScheduler:
                 rotation=rotation, rotation_pos=rotation_pos,
                 mesh=self.mesh, wtab=self._wtab() if tensor else None,
                 gang_score=self._gang_score)
-            self._note_ici("burst_fused", n_total, b.n_pad)
             DEVICE_DISPATCH.labels("burst_fused").inc()
             ph.close()
             ph.open("fetch")
@@ -2217,7 +2189,6 @@ class TPUScheduler:
                     nodes, vic, pod_in, feas, order_rank, b.n_real,
                     self.check_resources, f.has_request, pod.priority,
                     mesh=self.mesh))
-            self._note_ici("preempt_scan", 1, b.n_pad)
         except _DEVICE_FAULTS as e:
             # the scan reads resident state and mutates nothing: refuse —
             # the caller falls back to the oracle Preemptor, whose
@@ -2484,7 +2455,7 @@ class TPUScheduler:
                                        all_node_names, node_infos)
         # encode vs device-scan phase boundary: everything above is host
         # encode + delta upload; everything below is dispatch + the one
-        # fetch that pays the round trip (bench --mode preempt reports it)
+        # fetch that pays the round trip (perf.harness.run_preempt_cell)
         _t_enc = _time.perf_counter()
         # after the fact and in the ring alone: the gates above return
         # from the middle of it, and no benchmark cell drives this path
@@ -2503,7 +2474,6 @@ class TPUScheduler:
                 mut0, ghost0, li, lni, outs = K.pressure_batch(
                     nodes, mut0, ghost0, stacked, vic, li, lni, num_to_find,
                     n, z_pad, weights=press_weights, mesh=self.mesh)
-                self._note_ici("pressure_batch", len(chunk), b.n_pad)
                 DEVICE_DISPATCH.labels("pressure_batch").inc()
                 outs_chunks.append(outs)
             # ONE fetch for every chunk's outputs + the final counters

@@ -26,7 +26,7 @@ which waits for the result, and cat="host" spans around encode — host
 encode vs device execution+readback separate in the trace viewer. These
 are host-clock spans; device busy/idle share needs a profiler trace.
 
-Consumers: `GET /debug/traces` on the apiserver, `bench.py --trace out.json`.
+Consumers: `GET /debug/traces` on the apiserver, `benchmark/tools/stalls.py`.
 """
 from __future__ import annotations
 

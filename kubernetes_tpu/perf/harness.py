@@ -1,13 +1,22 @@
-"""scheduler_perf harness — density + benchmark matrix.
+"""Parity cells for the tests and the on-chip smoke. Not the benchmark.
 
-Mirrors test/integration/scheduler_perf:
-- mustSetupScheduler (util.go:34): in-process store + scheduler, no kubelet.
-- TestSchedule100Node3KPods (scheduler_test.go:68): schedule P pods over N
-  hollow nodes, compute minimum observed QPS over 1s-equivalent windows;
-  fail < 30 pods/s, warn < 100 (scheduler_test.go:35-38).
-- BenchmarkScheduling matrices (scheduler_bench_test.go:39-131): plain /
-  PodAntiAffinity / PodAffinity / NodeAffinity workloads over
-  {nodes × existing pods} grids.
+Each cell builds a small cluster in process (`mustSetupScheduler`,
+scheduler_perf/util.go:34: store + scheduler, no kubelet), drives one path
+of the scheduler over it and audits what came out before it returns: the
+workload lanes of scheduler_bench_test.go:39-131 (`run`), gang atomicity
+(`run_gang_cell`), a preemption wave against the serial oracle
+(`run_preempt_cell`), the sharded program against the single-device one
+(`run_shard_cell`), the serve loop's admitted-or-429 and oracle-parity
+audits (`run_serve_cell`), the commit core against its twin
+(`run_commit_cell`), the cluster-in-a-process pipeline (`run_e2e_density`).
+`tests/` and `chip_smoke.py` call them for those audits.
+
+The benchmark is `benchmark/run.py` over the cells of `BENCHMARK.json`, on
+the chip, timed by its own client; the driver records it in
+`PERF_LEDGER.jsonl`. A `throughput`, `pods_per_s` or `scans_per_s` field
+returned here is a count over this process's loop on whatever backend ran
+it (the CPU, in the tests): it says the cell made progress and is never
+quoted as a speed.
 """
 from __future__ import annotations
 
@@ -22,8 +31,6 @@ from kubernetes_tpu.models.hollow import (
 from kubernetes_tpu.store.store import Store, EVENTS, PODS
 from kubernetes_tpu.scheduler import Scheduler
 
-MIN_QPS_THRESHOLD = 30      # scheduler_test.go:35 (fail)
-WARN_QPS_THRESHOLD = 100    # scheduler_test.go:38 (warn)
 
 @dataclass
 class PerfConfig:
@@ -45,10 +52,6 @@ class PerfResult:
     throughput: float           # pods/s over the whole run
     min_qps: float              # worst 1s-window rate (density metric)
     attempts: dict = field(default_factory=dict)
-
-    @property
-    def passes_density_threshold(self) -> bool:
-        return self.min_qps >= MIN_QPS_THRESHOLD
 
 
 def _pod_strategy(cfg: PerfConfig, count: int, prefix: str) -> PodStrategy:
@@ -360,7 +363,7 @@ def run_serve_cell(n_nodes: int = 1000, arrival_rate: float = 2000.0,
                    mesh=None, parity_windows: int = 3,
                    parity_pods: int = 256, seed: int = 0,
                    max_resident: Optional[int] = None) -> dict:
-    """Arrival-driven serving cell (`bench.py --mode serve`): an
+    """Arrival-driven serving cell: an
     ArrivalGenerator feeds pods at `arrival_rate`/s for `duration`
     seconds while a ServeLoop (window_size=`window`, launch-queue depth
     `depth`) cuts fused windows from the live activeQ, with a
@@ -573,19 +576,6 @@ def run_serve_cell(n_nodes: int = 1000, arrival_rate: float = 2000.0,
         "startup_slo_ok_windowed": led["startup_slo_ok_windowed"],
         "slo_burn_rate": led["slo_burn_rate"],
         "phase_split": led["phase_split"],
-        # the round-17 host-prologue score: encode + admission
-        # pod-seconds (the two phases the encode-at-admission row cache
-        # and the batched ingest attack), absolute and per scheduled pod
-        # — test_bench_floors floors the per-pod number against the
-        # round-16 recorded baseline
-        "prologue_phase_split": {
-            "encode_pod_seconds": led["phase_split"]["encode"],
-            "admission_pod_seconds": led["phase_split"]["admission"],
-            "per_scheduled_pod": round(
-                (led["phase_split"]["encode"]
-                 + led["phase_split"]["admission"])
-                / max(1, led["pods_completed"]), 6),
-        },
         "pods_completed": led["pods_completed"],
         "workload_reaped": reaped,
         "resident_target": resident_target,
@@ -595,594 +585,6 @@ def run_serve_cell(n_nodes: int = 1000, arrival_rate: float = 2000.0,
         "parity_violations": len(violations),
         "parity_errors": violations[:3],
     }
-
-
-def run_fleet_cell(n_nodes: int = 1000, instances: int = 2,
-                   arrival_rate: float = 4000.0, duration: float = 20.0,
-                   window: int = 2048, depth: int = 3,
-                   n_shards: Optional[int] = None,
-                   use_tpu: bool = True, seed: int = 0,
-                   max_resident: Optional[int] = None) -> dict:
-    """Active-active fleet cell (`bench.py --mode fleet`, round 18):
-    `instances` FleetInstances — each a full scheduler with its own
-    informers, activeQ, and launch queue — run on their OWN THREADS
-    against ONE shared store, partitioned by namespace-hash Lease claims
-    with fenced writes, while an ArrivalGenerator feeds namespace-spread
-    pods at `arrival_rate`/s for `duration` seconds through one
-    fleet-wide backpressure gate. Scores AGGREGATE sustained pods/s.
-
-    Three in-cell audits gate the number:
-    - zero-double-bind: a BindAuditor folds the shared pod watch for the
-      whole run; any nodeName transition non-empty -> different
-      non-empty fails the cell (the fleet_double_binds_total tripwire);
-    - all-admitted-or-429'd: every generated arrival either landed AND
-      bound, or was shed and accounted — same contract as the serve cell;
-    - partition sanity: live claim sets stay disjoint at every probe.
-
-    A completion reaper (serve-cell pattern) keeps the resident set in
-    steady state so minutes-scale fleet soaks don't fill the cluster."""
-    import threading as _th
-    import time as _t
-    from collections import deque
-    from kubernetes_tpu.api.types import Node, Pod, Container
-    from kubernetes_tpu.fleet import FleetInstance, BindAuditor, shard_of
-    from kubernetes_tpu.obs.ledger import LEDGER
-    from kubernetes_tpu.serve import ArrivalGenerator
-    from kubernetes_tpu.serve.backpressure import fleet_gate
-    from kubernetes_tpu.store.store import MODIFIED, NODES, ExpiredError
-    GI = 1024 ** 3
-    MI = 1024 ** 2
-    n_shards = int(n_shards) if n_shards else max(8, 4 * instances)
-    store = Store(watch_log_size=1 << 17)
-    for i in range(n_nodes):
-        store.create(NODES, Node(
-            name=f"node-{i}",
-            labels={"failure-domain.beta.kubernetes.io/zone":
-                    f"zone-{i % 3}",
-                    "kubernetes.io/hostname": f"node-{i}"},
-            allocatable={"cpu": 4000, "memory": 32 * GI, "pods": 110}))
-    idents = [f"sched-{i}" for i in range(int(instances))]
-    fleet = [FleetInstance(store, ident, idents, use_tpu=use_tpu,
-                           window=window, depth=depth, n_shards=n_shards,
-                           lease_duration=5.0, renew_deadline=3.0,
-                           percentage_of_nodes_to_score=100)
-             for ident in idents]
-    for inst in fleet:
-        inst.sync()
-    # claims settle + jit warmup BEFORE the gate attaches and the clock
-    # starts: feed a handful of ungated pods and drain them
-    n_prefix = "fl-"
-    import zlib as _zlib
-
-    def mkpod(name: str) -> Pod:
-        # namespace spread drives the shard partition (crc32 of the
-        # namespace): 4*shards namespaces cover every shard
-        ns = f"ns-{_zlib.crc32(name.encode()) % (4 * n_shards)}"
-        return Pod(name=name, namespace=ns, labels={"app": "fleet"},
-                   containers=(Container.make(
-                       name="c", requests={"cpu": 100,
-                                           "memory": 500 * MI}),))
-
-    warm = ArrivalGenerator(store, rate=10 ** 9, total=32 * instances,
-                            pod_fn=mkpod, name_prefix="flwarm-", seed=seed)
-    for _ in range(3):
-        warm.tick()
-        for inst in fleet:
-            inst.step()
-    def fleet_idle() -> bool:
-        """Nothing pending anywhere: queues empty AND every instance's
-        pod-informer backlog drained — the queue alone lags creates by
-        one pump, so checking it in isolation races the last arrivals
-        into a stopped thread's undelivered backlog."""
-        for inst in fleet:
-            if inst.sched.queue.num_pending() > 0:
-                return False
-            if inst.sched.informers.informer(PODS).backlog() > 0:
-                return False
-        return True
-
-    deadline_warm = _t.perf_counter() + 60.0
-    while _t.perf_counter() < deadline_warm:
-        if sum(inst.step() for inst in fleet) == 0 and fleet_idle():
-            break
-    auditor = BindAuditor(store)
-    gate = fleet_gate([inst.loop for inst in fleet],
-                      max_depth=max(4 * window, int(2 * arrival_rate)))
-    store.admission_gate = gate
-    LEDGER.reset()
-    gen = ArrivalGenerator(store, rate=arrival_rate, pod_fn=mkpod,
-                           name_prefix=n_prefix, seed=seed)
-    # completion reaper (serve-cell pattern): oldest bound arrivals are
-    # deleted past the resident target so the cell reaches steady state
-    cap = n_nodes * min(110, 4000 // 100)
-    resident_target = (int(max_resident) if max_resident is not None
-                       else max(4 * window, cap // 2))
-    reap_watch = store.watch(PODS)
-    bound_fifo: deque = deque()
-    seen_bound: set = set()
-    reaped = 0
-
-    def reap() -> None:
-        nonlocal reaped
-        try:
-            events = reap_watch.drain()
-        except ExpiredError:
-            events = []
-            bound_fifo.clear()
-            seen_bound.clear()
-            for p in store.list(PODS)[0]:
-                if p.node_name and p.name.startswith(n_prefix):
-                    bound_fifo.append(p.key)
-                    seen_bound.add(p.key)
-        for ev in events:
-            if ev.type == MODIFIED and ev.obj.node_name \
-                    and ev.obj.name.startswith(n_prefix) \
-                    and ev.obj.key not in seen_bound:
-                bound_fifo.append(ev.obj.key)
-                seen_bound.add(ev.obj.key)
-        if len(bound_fifo) > resident_target:
-            batch = []
-            while len(bound_fifo) > resident_target:
-                batch.append(bound_fifo.popleft())
-            reaped += len(store.delete_many(PODS, batch))
-
-    stop = _th.Event()
-
-    def drive(inst: FleetInstance) -> None:
-        while not stop.is_set():
-            if inst.step() == 0:
-                _t.sleep(0.001)
-
-    threads = [_th.Thread(target=drive, args=(inst,), daemon=True,
-                          name=f"fleet-{inst.identity}")
-               for inst in fleet]
-    bound0 = sum(inst.loop.pods_bound for inst in fleet)
-    partition_overlap = False
-    t0 = _t.perf_counter()
-    for th in threads:
-        th.start()
-    t_end = t0 + duration
-    while _t.perf_counter() < t_end:
-        reap()
-        gen.tick()
-        auditor.scan()
-        # partition sanity probe: live claim sets stay disjoint
-        seen: set = set()
-        for inst in fleet:
-            owned = inst.claims.owned()
-            if owned & seen:
-                partition_overlap = True
-            seen |= owned
-        _t.sleep(0.002)
-    elapsed = _t.perf_counter() - t0
-    aggregate = (sum(inst.loop.pods_bound for inst in fleet) - bound0) \
-        / elapsed if elapsed else 0.0
-    # settle: arrivals stop; shed retries, informer backlogs, and the
-    # queues drain. The idle condition must hold over CONSECUTIVE polls:
-    # the drive threads are still stepping, and a single snapshot can
-    # catch a window mid-flight (popped pods make a queue read empty)
-    settle_deadline = _t.perf_counter() + 90.0
-    idle_polls = 0
-    while _t.perf_counter() < settle_deadline:
-        gen.flush_retries(timeout=0.2)
-        reap()
-        auditor.scan()
-        if gen.stats()["pending_retry"] == 0 and fleet_idle():
-            idle_polls += 1
-            if idle_polls >= 3:
-                break
-        else:
-            idle_polls = 0
-        _t.sleep(0.05)
-    stop.set()
-    for th in threads:
-        th.join(timeout=5.0)
-    # post-stop cooperative drain: a step that completed right at the
-    # stop boundary may have re-queued a pod (failed decision) or left
-    # undelivered informer events — finish them sequentially, bounded
-    drain_deadline = _t.perf_counter() + 30.0
-    while not fleet_idle() and _t.perf_counter() < drain_deadline:
-        reap()
-        for inst in fleet:
-            inst.step()
-    auditor.scan()
-    reap_watch.stop()
-    auditor.stop()
-    g = gen.stats()
-    measured = [p for p in store.list(PODS)[0]
-                if p.name.startswith(n_prefix)]
-    unbound = sum(1 for p in measured if not p.node_name)
-    assert len(measured) + reaped == g["created"], \
-        (f"fleet accounting leak: {len(measured)} in store + {reaped} "
-         f"reaped != {g['created']} created")
-    assert unbound == 0, f"{unbound} admitted arrivals never bound"
-    assert not auditor.violations, \
-        f"DOUBLE BINDS observed: {auditor.violations[:5]}"
-    assert not partition_overlap, "live shard claims overlapped"
-    led = LEDGER.snapshot()
-    from kubernetes_tpu.fleet import BIND_CONFLICTS
-    return {
-        "nodes": n_nodes,
-        "instances": int(instances),
-        "shards": n_shards,
-        "arrival_rate": arrival_rate,
-        "duration": round(elapsed, 2),
-        "aggregate_pods_per_s": round(aggregate, 1),
-        "per_instance_pods_bound": {
-            inst.identity: inst.loop.pods_bound for inst in fleet},
-        "fenced_waves": sum(inst.sched.fenced_waves for inst in fleet),
-        "bind_conflicts_requeued":
-            BIND_CONFLICTS.labels("requeued").value,
-        "bind_conflicts_fenced": BIND_CONFLICTS.labels("fenced").value,
-        "double_binds": len(auditor.violations),
-        "partition_disjoint": not partition_overlap,
-        "startup_p50": led["startup_p50"],
-        "startup_p99": led["startup_p99"],
-        "startup_slo_ok": led["startup_slo_ok"],
-        "startup_p50_windowed": led["startup_p50_windowed"],
-        "startup_p99_windowed": led["startup_p99_windowed"],
-        "startup_slo_ok_windowed": led["startup_slo_ok_windowed"],
-        "slo_burn_rate": led["slo_burn_rate"],
-        "workload_reaped": reaped,
-        "arrivals": g,
-        "admission": gate.debug_state(),
-        "audit_all_admitted_or_429": True,   # the asserts above gate it
-        "audit_no_double_bind": True,
-    }
-
-
-#: the shadow profile of the tuner cell (round 22): starts with the
-#: DefaultProvider vector; the tuner writes the candidate row into it
-TUNE_SHADOW_PROFILE = "shadow-tuner"
-
-
-def run_tuner_cell(n_nodes: int = 256, arrival_rate: float = 250.0,
-                   duration: float = 12.0, window: int = 512,
-                   depth: int = 2, use_tpu: bool = True, seed: int = 0,
-                   search_budget: int = 48,
-                   record_worlds: int = 4,
-                   install_at_frac: float = 0.3) -> dict:
-    """Closed-loop learned-scoring cell (`bench.py --mode tune`, round
-    22) — the full tuner loop in one run, three phases:
-
-    A. RECORD: a solo scheduler (replay-mode flight recorder) schedules
-       a mixed-size workload; the recorded bursts become the offline
-       simulator's worlds.
-    B. SEARCH: a seeded CEM (`tuner.tune`) over integer weight rows
-       scores candidates against the worlds; the same search re-run with
-       the same seed must reproduce the winner bit-for-bit (the
-       determinism audit, asserted in-cell).
-    C. SHADOW SERVE: two FleetInstances over one store — the incumbent
-       profile on one, the shadow profile on the other (round-18
-       partitioning by claimed profile = the A/B lane). Two arrival
-       streams (tn-i-* / tn-s-*) feed the lanes at arrival_rate/2 each;
-       MID-RUN the tuner installs the searched row into the shadow via
-       ProfileSet.set_row + reload_profiles (a live tensor-row write).
-       The replay-mode recorder runs the whole phase, so the final
-       parity pass proves records straddling the write still replay
-       bit-identically (the capture pins a ProfileSet snapshot). A
-       ShadowTuner observe tick + timeseries scrape each ~250 ms builds
-       the evidence the PromotionGate judges at the end.
-
-    In-cell audits: zero double-binds (BindAuditor), all arrivals bound,
-    zero flight-replay mismatches while rows churned, deterministic
-    search. The objective readout (windowed per-lane p99 + packing
-    utilization, shadow-vs-incumbent bound ratio) is returned for the
-    bench floor: the tuned lane must win on utilization and/or p99 at
-    >= 0.9x the incumbent lane's throughput."""
-    import random as _random
-    import time as _t
-    import zlib as _zlib
-    from kubernetes_tpu.api.types import Container, Node, Pod
-    from kubernetes_tpu.factory import DEFAULT_PRIORITY_WEIGHTS
-    from kubernetes_tpu.fleet import BindAuditor, FleetInstance
-    from kubernetes_tpu.obs.flight import RECORDER
-    from kubernetes_tpu.obs.ledger import LEDGER
-    from kubernetes_tpu.obs.timeseries import SCRAPER, SeriesView
-    from kubernetes_tpu.profiles import (
-        DEFAULT_PROFILE_NAME, ProfileSet, SchedulingProfile)
-    from kubernetes_tpu.scheduler import Scheduler
-    from kubernetes_tpu.serve import ArrivalGenerator
-    from kubernetes_tpu.store.store import NODES
-    from kubernetes_tpu.tuner import (
-        PromotionGate, ShadowTuner, simulate, tune, worlds_from_recorder)
-    from kubernetes_tpu.tuner.controller import (
-        lane_utilization, prefix_lanes)
-    GI = 1024 ** 3
-    MI = 1024 ** 2
-    cpu_sizes = (100, 150, 250)     # mixed sizes give packing traction
-
-    def mknode(i: int) -> Node:
-        return Node(
-            name=f"node-{i}",
-            labels={"failure-domain.beta.kubernetes.io/zone":
-                    f"zone-{i % 3}",
-                    "kubernetes.io/hostname": f"node-{i}"},
-            allocatable={"cpu": 4000, "memory": 32 * GI, "pods": 110})
-
-    # ---- phase A: record worlds --------------------------------------------
-    RECORDER.configure(mode="replay", capacity=max(8, record_worlds))
-    RECORDER.clear()
-    store_a = Store()
-    for i in range(max(16, n_nodes // 8)):
-        store_a.create(NODES, mknode(i))
-    sched_a = Scheduler(store_a, use_tpu=use_tpu,
-                        percentage_of_nodes_to_score=100)
-    sched_a.sync()
-    rng = _random.Random(seed)
-    for j in range(16 * record_worlds):
-        store_a.create(PODS, Pod(
-            name=f"w{j}", labels={"app": "tune"},
-            containers=(Container.make(
-                name="c", requests={"cpu": rng.choice(cpu_sizes),
-                                    "memory": rng.choice(
-                                        (1, 2, 4)) * GI}),)))
-    sched_a.pump()
-    while sched_a.schedule_burst(max_pods=16):
-        pass
-    sched_a.pump()
-    worlds = worlds_from_recorder(limit=record_worlds)
-    assert worlds, "phase A recorded no replayable worlds"
-
-    # ---- phase B: seeded search + determinism audit ------------------------
-    keys = ["LeastRequestedPriority", "MostRequestedPriority",
-            "BalancedResourceAllocation", "SelectorSpreadPriority"]
-    t_search0 = _t.perf_counter()
-    result = tune(worlds, keys, seed=seed,
-                  incumbent=DEFAULT_PRIORITY_WEIGHTS,
-                  budget=search_budget)
-    search_s = _t.perf_counter() - t_search0
-    twin = tune(worlds, keys, seed=seed,
-                incumbent=DEFAULT_PRIORITY_WEIGHTS, budget=search_budget)
-    assert (twin.best_weights, twin.best_reward) == \
-        (result.best_weights, result.best_reward), \
-        "search is nondeterministic under a fixed seed"
-    incumbent_reward = sum(
-        simulate(w, DEFAULT_PRIORITY_WEIGHTS).reward for w in worlds)
-
-    # ---- phase C: shadow serve + mid-run row write + gate ------------------
-    RECORDER.configure(mode="replay", capacity=16)
-    RECORDER.clear()
-    store = Store(watch_log_size=1 << 16)
-    for i in range(n_nodes):
-        store.create(NODES, mknode(i))
-    pset = ProfileSet([
-        SchedulingProfile(DEFAULT_PROFILE_NAME),
-        SchedulingProfile(TUNE_SHADOW_PROFILE),   # starts = default row
-    ])
-    lanes = ((DEFAULT_PROFILE_NAME, "tn-i-"),
-             (TUNE_SHADOW_PROFILE, "tn-s-"))
-    idents = ["tune-inc", "tune-shd"]
-    fleet = [FleetInstance(store, idents[k], [idents[k]],
-                           profile=lanes[k][0], profiles=pset,
-                           use_tpu=use_tpu, window=window, depth=depth,
-                           n_shards=8, lease_duration=5.0,
-                           renew_deadline=3.0,
-                           percentage_of_nodes_to_score=100)
-             for k in range(2)]
-    for inst in fleet:
-        inst.sync()
-
-    def mkpod_for(profile: str):
-        def mk(name: str) -> Pod:
-            h = _zlib.crc32(name.encode())
-            return Pod(name=name, namespace=f"ns-{h % 32}",
-                       labels={"app": "tune"}, scheduler_name=profile,
-                       containers=(Container.make(
-                           name="c",
-                           requests={"cpu": cpu_sizes[h % len(cpu_sizes)],
-                                     "memory": 500 * MI}),))
-        return mk
-
-    def fleet_idle() -> bool:
-        for inst in fleet:
-            if inst.sched.queue.num_pending() > 0:
-                return False
-            if inst.sched.informers.informer(PODS).backlog() > 0:
-                return False
-        return True
-
-    # warmup (jit + claim settling for both profiles), outside the clock
-    for prof, prefix in lanes:
-        warm = ArrivalGenerator(store, rate=10 ** 9, total=16,
-                                pod_fn=mkpod_for(prof),
-                                name_prefix=f"{prefix}warm-", seed=seed)
-        for _ in range(3):
-            warm.tick()
-            for inst in fleet:
-                inst.step()
-    deadline_warm = _t.perf_counter() + 60.0
-    while _t.perf_counter() < deadline_warm:
-        if sum(inst.step() for inst in fleet) == 0 and fleet_idle():
-            break
-
-    auditor = BindAuditor(store)
-    LEDGER.reset()
-    SCRAPER.reset()
-    lane_match = prefix_lanes("tn-i-", "tn-s-")
-    tuner = ShadowTuner(pset, TUNE_SHADOW_PROFILE,
-                        incumbent=DEFAULT_PROFILE_NAME,
-                        schedulers=fleet, lane_match=lane_match,
-                        window=max(duration, 10.0))
-    gens = [ArrivalGenerator(store, rate=arrival_rate / 2,
-                             pod_fn=mkpod_for(prof), name_prefix=prefix,
-                             seed=seed + k)
-            for k, (prof, prefix) in enumerate(lanes)]
-    installed_at = None
-    last_obs = 0.0
-    bound0 = [inst.loop.pods_bound for inst in fleet]
-    t0 = _t.perf_counter()
-    t_end = t0 + duration
-    # single-threaded round-robin drive: the mid-run set_row +
-    # reload_profiles lands BETWEEN steps, never inside a burst
-    while _t.perf_counter() < t_end:
-        for g in gens:
-            g.tick()
-        for inst in fleet:
-            inst.step()
-        auditor.scan()
-        now = _t.perf_counter()
-        if installed_at is None and now - t0 >= install_at_frac * duration:
-            tuner.install(result.best_weights)      # the live row write
-            installed_at = now - t0
-        if now - last_obs >= 0.25:
-            tuner.observe(fleet[0].sched._snapshot.node_infos)
-            SCRAPER.sample()
-            last_obs = now
-    elapsed = _t.perf_counter() - t0
-    if installed_at is None:          # degenerate short durations
-        tuner.install(result.best_weights)
-        installed_at = elapsed
-    # settle: drain both lanes, then one last observe/scrape
-    settle_deadline = _t.perf_counter() + 60.0
-    while _t.perf_counter() < settle_deadline:
-        for g in gens:
-            g.flush_retries(timeout=0.1)
-        if sum(inst.step() for inst in fleet) == 0 and fleet_idle() \
-                and all(g.stats()["pending_retry"] == 0 for g in gens):
-            break
-    auditor.scan()
-    tuner.observe(fleet[0].sched._snapshot.node_infos)
-    SCRAPER.sample()
-    auditor.stop()
-
-    # parity while rows churn: every recorded burst (both lanes, before
-    # AND after the set_row write) must replay bit-identically — the
-    # flight capture pinned a ProfileSet snapshot per burst
-    parity_errs = RECORDER.replay_all()
-    assert parity_errs == [], \
-        f"flight replay mismatches across the row write: {parity_errs[:5]}"
-    RECORDER.configure(mode="digest")
-    RECORDER.clear()
-
-    measured = [p for p in store.list(PODS)[0]
-                if p.name.startswith("tn-")]
-    unbound = [p.key for p in measured if not p.node_name]
-    assert not unbound, f"{len(unbound)} arrivals never bound"
-    assert not auditor.violations, \
-        f"DOUBLE BINDS observed: {auditor.violations[:5]}"
-
-    # objective readout + the gate's verdict
-    snapshot_infos = fleet[0].sched._snapshot.node_infos
-    now = _t.perf_counter()
-    lane_stats = {}
-    for lane, match in lane_match.items():
-        lane_stats[lane] = {
-            "p99": LEDGER.window_percentile(
-                0.99, window=elapsed + 60.0, now=now, match=match),
-            "utilization": lane_utilization(snapshot_infos, match),
-            "committed": LEDGER.window_count(
-                window=elapsed + 60.0, now=now, match=match),
-        }
-    bound_by = {idents[k]: fleet[k].loop.pods_bound - bound0[k]
-                for k in range(2)}
-    inc_bound = bound_by["tune-inc"]
-    shd_bound = bound_by["tune-shd"]
-    gate = PromotionGate()
-    decision = tuner.apply(gate.decide(SeriesView(SCRAPER.series())))
-    sh, inc = lane_stats["shadow"], lane_stats["incumbent"]
-    util_win = sh["utilization"] > inc["utilization"]
-    p99_win = sh["p99"] < inc["p99"]
-    led = LEDGER.snapshot()
-    return {
-        "nodes": n_nodes,
-        "arrival_rate": arrival_rate,
-        "duration": round(elapsed, 2),
-        "worlds_recorded": len(worlds),
-        "search": result.as_dict(),
-        "search_seconds": round(search_s, 3),
-        "search_deterministic": True,      # asserted above
-        "incumbent_sim_reward": round(incumbent_reward, 3),
-        "tuned_vs_incumbent_reward": round(
-            result.best_reward / incumbent_reward, 4)
-        if incumbent_reward else None,
-        "installed_at_s": round(installed_at, 2),
-        "profile_version": pset.version,
-        "lanes": {l: {"p99": round(s["p99"], 4),
-                      "utilization": (None if s["utilization"] !=
-                                      s["utilization"] else
-                                      round(s["utilization"], 4)),
-                      "committed": s["committed"]}
-                  for l, s in lane_stats.items()},
-        "shadow_bound": shd_bound,
-        "incumbent_bound": inc_bound,
-        "shadow_vs_incumbent_throughput": round(
-            shd_bound / inc_bound, 4) if inc_bound else None,
-        "objective_win_utilization": util_win,
-        "objective_win_p99": p99_win,
-        "objective_win": bool(util_win or p99_win),
-        "gate_decision": decision["decision"],
-        "gate_reason": decision["reason"],
-        "gate_stats": decision["stats"],
-        "parity_violations": 0,            # asserted above
-        "double_binds": len(auditor.violations),
-        "audit_no_double_bind": True,
-        "startup_p99": led["startup_p99"],
-        "startup_p99_windowed": led["startup_p99_windowed"],
-        "pods_completed": led["pods_completed"],
-    }
-
-
-# the benchmark matrices (scheduler_bench_test.go:40-118)
-BENCHMARK_MATRIX = {
-    "plain": [(100, 0), (100, 1000), (1000, 0), (1000, 1000), (5000, 1000)],
-    "anti-affinity": [(500, 250), (500, 5000), (1000, 1000), (5000, 1000)],
-    "affinity": [(500, 250), (500, 5000), (1000, 1000), (5000, 1000)],
-    "node-affinity": [(500, 250), (500, 5000), (1000, 1000), (5000, 1000)],
-    # gang (PodGroup) cells: (nodes, gang_size) — run via run_gang_cell
-    "gang": [(1000, 8), (1000, 64), (5000, 512)],
-    # preemption pressure cells: (nodes, victims, preemptors-per-wave) —
-    # run via run_preempt_cell (warm victim table, one launch per wave;
-    # 128 = one full PRESSURE_B_CAP chunk, the throughput configuration)
-    "preempt": [(1000, 10000, 16), (1000, 10000, 128)],
-    # commit-core cells: (pods-per-wave, waves, watchers) — run via
-    # run_commit_cell (the round-11 store-write + fan-out tail; the
-    # 4096-pod cell is one full default scheduler wave). The round-20
-    # watcher-scaling cells shrink the wave so the cell measures fan-out,
-    # not writes: 1k/10k watchers sharing one subscription class, and the
-    # 100k-watcher north-star cell as the slow tier-2 gate.
-    "commit": [(1024, 8, 8), (4096, 8, 8),
-               (256, 4, 1000), (256, 4, 10000),
-               (64, 2, 100_000)],   # 100k cell: slow tier-2
-    # mesh-sharded scale cells: (nodes, pods) — run via run_shard_cell
-    # over every visible device. These node counts cannot fit one chip's
-    # HBM once the resident planes + victim table are counted (see
-    # run_shard_cell); the 50k cell is the slow-marked tier-2 gate
-    "shard": [(50_000, 2000), (100_000, 2000), (200_000, 1000)],
-    # arrival-driven serving cells: (nodes, arrivals/s, seconds) — run
-    # via run_serve_cell. The 1000n/2000rps/30s cell is the acceptance
-    # gate (startup_p99 <= 5s, zero parity violations, every arrival
-    # admitted-or-429'd); the 4000rps cell is the round-17 raised
-    # sustained-rate gate (the batched prologue must keep up on CPU);
-    # the 5000rps cell probes the shed regime.
-    "serve": [(1000, 2000, 30), (1000, 4000, 30), (1000, 5000, 30),
-              (5000, 2000, 30)],
-    # active-active fleet cells: (nodes, instances, arrivals/s, seconds)
-    # — run via run_fleet_cell. The 2-instance cell is the round-18
-    # acceptance gate (aggregate >= the solo serve baseline with the
-    # zero-double-bind audit); the 4-instance cell probes claim churn
-    # at higher membership.
-    "fleet": [(1000, 2, 4000, 20), (1000, 4, 4000, 20)],
-    # soak scoreboard cells (round 21): (nodes, instances, arrivals/s,
-    # seconds, watchers) — run via perf.soak.run_soak_cell (fleet x
-    # mixed profiles x serve arrivals x churn x chaos with the
-    # time-series scraper + verdict engine attached). The 10k-watcher
-    # cell is the standing gate; the 100k-watcher/120s cell is the
-    # million-object north star (ROADMAP item 1) and slow tier-2 —
-    # ~240k pods through the store, ~480k bind/delete events fanned
-    # through ~64 shared classes.
-    "soak": [(1000, 2, 1500, 45, 10_000),
-             (2000, 2, 2000, 120, 100_000)],   # 100k cell: slow tier-2
-    # closed-loop tuner cells (round 22): (nodes, arrivals/s, seconds)
-    # — run via run_tuner_cell (record worlds -> seeded CEM search with
-    # an in-cell determinism audit -> two-instance shadow A/B serve with
-    # a MID-RUN ProfileSet.set_row write, flight-replay parity across
-    # it, and the promotion gate's verdict). The small cell is the
-    # acceptance gate (tuned lane wins on utilization and/or p99 at
-    # >= 0.9x throughput, zero double-binds, zero parity violations);
-    # the large cell probes the loop at fleet-serve scale.
-    "tune": [(256, 250, 12), (1000, 800, 20)],
-}
 
 
 def run_gang_cell(nodes: int = 1000, gang_size: int = 64,
@@ -1244,7 +646,7 @@ def run_commit_cell(n_pods: int = 4096, waves: int = 8,
                     audit: Optional[list] = None,
                     watch_classes: int = 1,
                     shared_classes: bool = True) -> dict:
-    """Commit-core cell (`bench.py --mode commit`): the store-write +
+    """Commit-core cell: the store-write +
     fan-out tail of a burst wave in isolation — `waves` waves of `n_pods`
     binds each, every wave ONE `commit_wave` call (batched bind + the
     Scheduled audit-record creates) and ONE `fanout_wave` call, with
@@ -1390,13 +792,6 @@ def run_commit_cell(n_pods: int = 4096, waves: int = 8,
         "shared_watch_classes": store.shared_watch_classes,
         "impl": store.core_impl,
     }
-
-
-def run_benchmark_cell(workload: str, nodes: int, existing: int,
-                       pods: int = 1000, use_tpu: bool = True,
-                       burst: int = 1024) -> PerfResult:
-    return run(PerfConfig(nodes=nodes, existing_pods=existing, pods=pods,
-                          workload=workload, use_tpu=use_tpu, burst=burst))
 
 
 def run_e2e_density(n_nodes: int = 50, n_pods: int = 150,

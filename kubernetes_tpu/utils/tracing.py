@@ -84,8 +84,8 @@ class Trace:
     def emit_spans(self, cat: str = "trace") -> None:
         """Fold the step timeline into the obs span ring: one parent span
         for the whole operation plus one child per step slice, so a slow
-        cycle's breakdown shows up in /debug/traces and bench --trace
-        output, not only in the log."""
+        cycle's breakdown shows up in /debug/traces, not only in the
+        log."""
         from kubernetes_tpu.obs import trace as obs_trace
         end = time.perf_counter()
         obs_trace.add_span(self.name, self.start, end, cat=cat)

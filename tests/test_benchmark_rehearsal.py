@@ -1,0 +1,94 @@
+"""Tier-1 guard for the yardstick: `benchmark/run.py`'s `execute`, the
+function every cell of `BENCHMARK.json` is measured by, rehearsed on the CPU
+backend at a tiny size, once per kind of run. A program change that breaks it
+is found here and not on the chip. `correct` is the benchmark's own verdict
+(the client's watch replayed through `benchmark/reference/`); nothing here is
+a speed. `benchmark/tests/` holds the benchmark's own, fuller tests (by hand).
+"""
+import os
+import sys
+
+import pytest
+
+BENCH_DIR = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmark")
+
+BACKLOG = "headline-15000n.backlog-10k"
+ROLLOUT = "density-5000n-150k.rollout-1k"
+ARRIVALS = "headline-15000n.arrivals-steady"
+ADAPTIVE = "headline-15000n-adaptive.backlog-10k"
+
+# (config overlay, traffic overlay): the sizes benchmark/tests rehearses at.
+# The adaptive cell needs more than 100 nodes for the walk to be cut short
+# (num_to_find = 117 of 240).
+AT_50 = {"nodes": {"count": 50},
+         "check": {"first_binds": 200, "sampled_binds": 60}}
+SMALL = {
+    BACKLOG: (AT_50, {"warm_binds": 0, "backlog": 70}),
+    ROLLOUT: ({**AT_50, "resident": {"pods_per_node": 6, "services": 5}},
+              {"warm_binds": 0, "backlog": 70}),
+    ARRIVALS: (AT_50, {"warm_binds": 0, "arrival": {"rate_per_s": 150.0},
+                       "lifetime_s": 0.4, "serve": {"window_size": 64}}),
+    ADAPTIVE: ({"nodes": {"count": 240},
+                "check": {"first_binds": 200, "sampled_binds": 200}},
+               {"warm_binds": 0, "backlog": 150}),
+}
+
+
+@pytest.fixture(scope="module")
+def execute():
+    """`benchmark/run.py`'s `execute`, imported the way the command finds its
+    own modules (`benchmark/` on the path); the path is put back after."""
+    sys.path.insert(0, BENCH_DIR)
+    try:
+        import run
+        yield run.execute
+    finally:
+        sys.path.remove(BENCH_DIR)
+
+
+def rehearse(execute, cell, seed, hook=None):
+    config, traffic = SMALL[cell]
+    return execute(cell, seed, 1.5, False, rehearse=True, hook=hook,
+                   overrides={"config": config, "traffic": traffic})
+
+
+def altered_binding(sched, store):
+    """Break the timed path where an answer is produced: the first window
+    pod's binding is committed to the node of the second."""
+    commit_wave = store.commit_wave
+    done = False
+
+    def altered(bindings, *a, **kw):
+        nonlocal done
+        if not done and len(bindings) > 1 and "/bl-1-" in bindings[0][0] \
+                and bindings[0][1] != bindings[1][1]:
+            bindings = [(bindings[0][0], bindings[1][1]), *bindings[1:]]
+            done = True
+        return commit_wave(bindings, *a, **kw)
+    store.commit_wave = altered
+
+
+@pytest.mark.parametrize("cell,seed,hook", [
+    (BACKLOG, 1, None),               # K-batch kernel
+    (ROLLOUT, 2**31 + 5, None),       # generic scan + spread
+    (ARRIVALS, 3, None),              # serve loop
+    (ADAPTIVE, 2**31 + 17, None),     # truncated walk
+    (BACKLOG, 11, altered_binding),   # the guard can fail
+], ids=["backlog", "rollout", "arrivals", "adaptive", "altered-binding"])
+def test_rehearsed_cell(execute, cell, seed, hook):
+    out = rehearse(execute, cell, seed, hook)
+    res, rep = out["result"], out["report"]
+    assert rep["compared"] > 0
+    if hook is not None:
+        assert res["correct"] is False
+        return
+    assert res["correct"] is True and res["failed"] == 0
+    assert res["attempted"] > 0
+    assert rep["compiles_in_window"] == 0
+    if cell == ADAPTIVE:
+        moved = rep["counters"]
+        # the truncated regime on the generic scan, never the K-batch kernel
+        assert "burst_uniform" not in moved["tpu_device_dispatch_total"]
+        # the pod count is the scan's trip count: one step per pod given
+        assert moved["tpu_scan_steps_total"]["real"] == res["attempted"]

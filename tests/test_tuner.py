@@ -521,20 +521,3 @@ class TestClusterUtilizationGauge:
         # the gauge family reads through the registered callback
         assert float(CLUSTER_UTILIZATION.labels("cpu").value) == \
             pytest.approx(0.25)
-
-
-# ---------------------------------------------------------------------------
-# the whole loop, small (the bench cell's shape)
-# ---------------------------------------------------------------------------
-class TestTunerCellSmoke:
-    @pytest.mark.slow
-    def test_small_cell_end_to_end(self):
-        from kubernetes_tpu.perf.harness import run_tuner_cell
-        r = run_tuner_cell(n_nodes=24, arrival_rate=50, duration=4,
-                           window=64, search_budget=32, record_worlds=2)
-        assert r["search_deterministic"]
-        assert r["parity_violations"] == 0
-        assert r["double_binds"] == 0
-        assert r["lanes"]["shadow"]["committed"] > 0
-        assert r["lanes"]["incumbent"]["committed"] > 0
-        assert r["gate_decision"] in ("promote", "hold", "demote")
