@@ -22,6 +22,7 @@ from kubernetes_tpu import chaos, obs
 from kubernetes_tpu.api.types import (
     Pod, Node, PodCondition, POD_SCHEDULED, CONDITION_FALSE,
     REASON_UNSCHEDULABLE, REASON_SCHEDULER_ERROR,
+    get_container_ports, has_pod_affinity_terms,
 )
 from kubernetes_tpu.coscheduling.types import (
     PHASE_PRESCHEDULING, pod_group_key,
@@ -33,6 +34,7 @@ from kubernetes_tpu.oracle.gang import GangTrial
 from kubernetes_tpu.oracle.generic_scheduler import (
     GenericScheduler, FitError, ScheduleResult, default_priority_configs,
 )
+from kubernetes_tpu.oracle.priorities import get_selectors
 from kubernetes_tpu.queue.scheduling_queue import PriorityQueue
 from kubernetes_tpu.store.store import (
     Store, PODS, NODES, PODGROUPS, SERVICES, REPLICASETS, PDBS, PVS, PVCS,
@@ -47,6 +49,10 @@ from kubernetes_tpu.utils.clock import Clock, RealClock
 from kubernetes_tpu.utils.tracing import Trace, SLOW_CYCLE_THRESHOLD
 
 DEFAULT_SCHEDULER_NAME = "default-scheduler"
+
+#: the burst class of a pod with no in-burst-dynamic feature; a module
+#: constant because segmentation compares classes by identity
+_PLAIN = "plain"
 
 #: per-process scheduler instance sequence: wave dedupe tokens must be
 #: unique PER INSTANCE, not per scheduler name — an active-active fleet
@@ -65,6 +71,13 @@ GANG_ATTEMPTS = obs.counter(
     "rewound, group parked), incomplete (fewer than minMember members "
     "queued), degraded (plugins/volumes force the per-pod path), "
     "error (members vanished between trial and commit).", ("outcome",))
+BURST_CLASS = obs.counter(
+    "scheduler_burst_class_total",
+    "Pods a drain pass gave a burst class, by how: decided (a _burst_class "
+    "evaluation ran for this pod, the first of its class signature in the "
+    "pass), shared (the pod took the class of an earlier pod of its "
+    "signature in the same pass). Booked once per pass with the two "
+    "counts.", ("result",))
 GANG_WAIT = obs.histogram(
     "gang_wait_duration_seconds",
     "Seconds from PodGroup creation (or first scheduler sighting) to the "
@@ -1075,29 +1088,73 @@ class Scheduler:
                 pass
 
     # -- burst mode (TPU throughput path) -------------------------------------
-    def _pod_is_burstable(self, pod: Pod, services=None, replicasets=None) -> bool:
+    def _pod_is_burstable(self, pod: Pod) -> bool:
         """A pod may ride a device burst unless its per-node state depends
         on in-burst placements in a way no burst kernel models yet — only
         volume binding remains. Affinity/port/spread pods are admitted: the
         kernels fold their interactions (self-node bans, carried spread
-        counts) and refuse anything they can't replay exactly."""
-        if pod.volumes:
-            return False
-        return True
+        counts) and refuse anything they can't replay exactly. Asked per
+        pod: `pod.volumes` is not in the class signature."""
+        return not pod.volumes
 
-    def _burst_class(self, pod: Pod, services, replicasets):
+    def _can_burst(self) -> bool:
+        """The burst fold skips the per-pod Reserve/Permit/Prebind points,
+        so any configured plugin forces the serial path (decisions and
+        plugin side effects must not differ by path)."""
+        return (hasattr(self.algorithm, "schedule_burst")
+                and not self.framework.reserve
+                and not self.framework.permit
+                and not self.framework.prebind)
+
+    def _burst_class(self, pod: Pod, sig: tuple, services, replicasets):
         """Segmentation key: pods with in-burst-dynamic features (affinity /
         host ports / selector-spread) burst only with spec-identical peers
-        (the kernels' eligibility contract); plain pods share one generic
-        segment even when heterogeneous."""
-        from kubernetes_tpu.api.types import (
-            has_pod_affinity_terms, get_container_ports)
-        from kubernetes_tpu.oracle.priorities import get_selectors
+        (the kernels' eligibility contract), so their class is their class
+        signature; plain pods share one generic segment even when
+        heterogeneous. Every input it reads (namespace, labels, affinity,
+        containers) is in `sig`, so `_burst_classes`, its one caller, asks
+        once per distinct signature and pass."""
         if has_pod_affinity_terms(pod) or get_container_ports(pod) \
                 or get_selectors(pod, services, replicasets):
+            return sig
+        return _PLAIN
+
+    def _burst_classes(self, pods: list) -> list:
+        """THE place a pod's burst class is decided: one `_burst_class`
+        evaluation on the first pod of each distinct class signature in
+        `pods`, against one snapshot of the Service / ReplicaSet lists;
+        every other pod takes the class of its signature. The cost follows
+        the number of distinct signatures, not pods x Services. Equal
+        classes are the SAME object (the interned signature, or `_PLAIN`),
+        so segmentation compares by identity. The decision lives for the
+        call: a Service created between two drain passes reclassifies the
+        next pass's pods, and nothing has to be invalidated."""
+        if self.pod_rows is not None:
+            sigs = self.pod_rows.signatures(pods)
+        else:
+            # lazily: an oracle-only process never gets here (no burst
+            # algorithm), and must not pull jax in through this module
             from kubernetes_tpu.core.tpu_scheduler import TPUScheduler
-            return TPUScheduler._class_signature(pod)
-        return "plain"
+            sigs = TPUScheduler.class_signatures(pods)
+        services = self._services_fn()
+        replicasets = self._replicasets_fn()
+        by_sig: dict = {}
+        classes = []
+        last_sig = last_cls = None
+        for pod, sig in zip(pods, sigs):
+            # a run of one signature (a rollout, a backlog) costs a pointer
+            # compare a pod; the dict hashes the nested tuple, which Python
+            # does not cache, only where the signature changes
+            if sig is not last_sig:
+                cls = by_sig.get(sig)
+                if cls is None:
+                    cls = by_sig[sig] = self._burst_class(
+                        pod, sig, services, replicasets)
+                last_sig, last_cls = sig, cls
+            classes.append(last_cls)
+        BURST_CLASS.labels("decided").inc(len(by_sig))
+        BURST_CLASS.labels("shared").inc(len(pods) - len(by_sig))
+        return classes
 
     def schedule_burst(self, max_pods: int = 1024) -> int:
         """Drain up to max_pods from the queue and schedule them with device
@@ -1129,7 +1186,9 @@ class Scheduler:
         the window's sequence number. `burst.plan` covers the pass, and its
         self time (what its children — snapshot, encode, dispatch, fetch,
         the commit waves — do not cover) is the planning: the pop, gang
-        gathering, class detection, refusals and rotation."""
+        gathering, the pass's class decision (`_burst_classes`: the
+        window's signatures and one `_burst_class` evaluation per distinct
+        signature), segmentation, refusals and rotation."""
         obs.trace.next_window()
         plan = obs.trace.begin("burst.plan")
         drained = 0
@@ -1181,38 +1240,25 @@ class Scheduler:
         # (plugins, volumes, affinity/port/spread classes, incomplete or
         # missing groups, active nominations) keeps the per-segment
         # machinery, which knows how to park/degrade/serialize.
-        fuse_ok = (getattr(self.algorithm, "supports_fused_segments", False)
-                   and not self.framework.reserve
-                   and not self.framework.permit
-                   and not self.framework.prebind)
-        services = self._services_fn()
-        replicasets = self._replicasets_fn()
-
-        # plain-burstable classification from the pod-row cache: one
-        # np.take per flag field for the whole drain window instead of
-        # per-pod predicate walks (selector-spread needs live service/RS
-        # lists, so any registered selector source keeps the direct path;
-        # flag values are bit-identical to the predicates by the row
-        # contract — has_aff_terms/has_ports/has_volumes ARE those calls)
-        plain_map = None
-        if self.pod_rows is not None and not services and not replicasets:
-            flat_drained = [p for p, _c in drained]
-            g = self.pod_rows.gather(
-                flat_drained, ("has_aff_terms", "has_ports", "has_volumes"))
-            if g is not None:
-                plain = ~(g["has_aff_terms"] | g["has_ports"]
-                          | g["has_volumes"])
-                plain_map = {id(p): bool(v)
-                             for p, v in zip(flat_drained, plain)}
+        can_burst = self._can_burst()
+        fuse_ok = can_burst and getattr(
+            self.algorithm, "supports_fused_segments", False)
+        # the pass's class decision, made here once and read by every
+        # later question about a pod's class (the serial shell asks none)
+        class_of: dict = {}
+        if can_burst:
+            flat = [p for it in items
+                    for p, _c in (it[1] if isinstance(it, list) else (it,))]
+            class_of = dict(zip(map(id, flat), self._burst_classes(flat)))
 
         def plain_burstable(pod: Pod) -> bool:
-            if plain_map is not None:
-                got = plain_map.get(id(pod))
-                if got is not None:
-                    return got
-            return (self._pod_is_burstable(pod)
-                    and self._burst_class(pod, services, replicasets)
-                    == "plain")
+            return self._pod_is_burstable(pod) \
+                and class_of[id(pod)] is _PLAIN
+
+        def singletons(pairs: list) -> int:
+            return self._schedule_singletons_burst(
+                pairs, max_pods,
+                [class_of[id(p)] for p, _c in pairs] if can_burst else None)
 
         bound = 0
         window: list = []   # fused entries in queue order:
@@ -1234,15 +1280,13 @@ class Scheduler:
             else:
                 # no gang segment in the window: the ordinary burst path is
                 # already one launch + one packed fetch per segment
-                pairs = [pr for e in window for pr in e[1]]
-                bound += self._schedule_singletons_burst(pairs, max_pods)
+                bound += singletons([pr for e in window for pr in e[1]])
             window.clear()
 
         def flush_srun() -> None:
             nonlocal bound
             if srun:
-                bound += self._schedule_singletons_burst(list(srun),
-                                                         max_pods)
+                bound += singletons(list(srun))
                 srun.clear()
 
         for it in items:
@@ -1271,37 +1315,34 @@ class Scheduler:
         flush_window()
         return bound, len(drained)
 
-    def _schedule_singletons_burst(self, pairs: list, bucket: int) -> int:
+    def _schedule_singletons_burst(self, pairs: list, bucket: int,
+                                   classes: Optional[list] = None) -> int:
         """Schedule a run of non-gang pods: device burst segments where
-        safe, serial cycles otherwise (the pre-gang schedule_burst body)."""
+        safe, serial cycles otherwise (the pre-gang schedule_burst body).
+        `classes` are the pods' burst classes from the drain pass's
+        decision; the degraded gang paths and the unfused leftovers, which
+        have none to hand, get them from `_burst_classes` here."""
         pods = [p for p, _ in pairs]
         cycles = [c for _, c in pairs]
-        # the burst fold skips the per-pod Reserve/Permit/Prebind points, so
-        # any configured plugin forces the serial path (decisions and plugin
-        # side effects must not differ by path)
-        can_burst = (hasattr(self.algorithm, "schedule_burst")
-                     and not self.framework.reserve
-                     and not self.framework.permit
-                     and not self.framework.prebind)
-        services = self._services_fn()
-        replicasets = self._replicasets_fn()
+        can_burst = self._can_burst()
+        if can_burst and classes is None:
+            classes = self._burst_classes(pods)
         bound = 0
         i = 0
         while i < len(pods):
             # serial path for mask-stale pods and under active nominations
             # (the two-pass ghost check lives on the oracle path)
             if not can_burst or self.queue.nominated.has_any() \
-                    or not self._pod_is_burstable(pods[i], services, replicasets):
+                    or not self._pod_is_burstable(pods[i]):
                 if self._process_one(pods[i], cycles[i]):
                     bound += 1
                 i += 1
                 continue
-            seg_class = self._burst_class(pods[i], services, replicasets)
+            seg_class = classes[i]
             j = i
             while j < len(pods) and not self.queue.nominated.has_any() \
-                    and self._pod_is_burstable(pods[j], services, replicasets) \
-                    and self._burst_class(pods[j], services,
-                                          replicasets) == seg_class:
+                    and self._pod_is_burstable(pods[j]) \
+                    and classes[j] is seg_class:
                 j += 1
             bound += self._burst_segment(pods[i:j], cycles[i:j], bucket)
             i = j

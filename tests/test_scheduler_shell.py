@@ -2,12 +2,24 @@
 (store + informers) driving real scheduling, the analog of
 test/integration/scheduler/ (no kubelet: assertions on spec.nodeName).
 """
+import random
+
 import pytest
 
-from kubernetes_tpu.api.types import Pod, Node, Container
+from kubernetes_tpu import scheduler as scheduler_mod
+from kubernetes_tpu.api.types import (
+    Affinity, Container, ContainerPort, LabelSelector, Node, Pod,
+    PodAffinityTerm, PodAntiAffinity, ReplicaSet, Service, VolumeSource,
+    get_container_ports, has_pod_affinity_terms,
+)
 from kubernetes_tpu.api.quantity import requests
-from kubernetes_tpu.scheduler import Scheduler
-from kubernetes_tpu.store.store import Store, PODS, NODES
+from kubernetes_tpu.coscheduling.types import LABEL_POD_GROUP
+from kubernetes_tpu.ops.pod_rows import pod_class_signature
+from kubernetes_tpu.oracle.priorities import get_selectors
+from kubernetes_tpu.scheduler import BURST_CLASS, Scheduler
+from kubernetes_tpu.store.store import (
+    Store, PODS, NODES, REPLICASETS, SERVICES,
+)
 
 GI = 1024 ** 3
 
@@ -386,3 +398,228 @@ class TestSelfInflictedUpdates:
         # a pop must NOT return it
         assert sched.queue.pop(timeout=0.0) is None
         assert sched.queue.num_pending() == 1
+
+
+class TestBurstClassDecision:
+    """A drain pass decides the burst class once per distinct class
+    signature (`Scheduler._burst_classes`), and every later question about
+    a pod's class in that pass reads that decision. The class only cuts a
+    window into `_burst_segment` calls: every cut must fall where the
+    per-pod walk (kept here as the reference) put it."""
+
+    KINDS = ("plain", "plain-big", "svc-a", "svc-b", "rs", "affinity",
+             "port", "volume")
+
+    @staticmethod
+    def _pod(name, kind):
+        kw = {}
+        if kind == "plain-big":
+            return mkpod(name, cpu="300m")
+        if kind in ("svc-a", "svc-b"):
+            kw["labels"] = {"app": kind[-1]}
+        elif kind == "rs":
+            kw["labels"] = {"tier": "web"}
+        elif kind == "affinity":
+            term = PodAffinityTerm(
+                label_selector=LabelSelector.from_dict({"app": "z"}),
+                topology_key="kubernetes.io/hostname")
+            kw["affinity"] = Affinity(
+                pod_anti_affinity=PodAntiAffinity(required=(term,)))
+        elif kind == "port":
+            return Pod(name=name, containers=(Container.make(
+                name="c", requests=requests(cpu="100m", mem="500Mi"),
+                ports=(ContainerPort(host_port=8080, container_port=80),)),))
+        elif kind == "volume":
+            kw["volumes"] = (VolumeSource(name="v", pvc="data"),)
+        return mkpod(name, **kw)
+
+    @staticmethod
+    def _cluster(n_nodes=4, services=("a", "b"), replicaset=True,
+                 rows=True):
+        store = Store()
+        for i in range(n_nodes):
+            store.create(NODES, mknode(f"n{i}", cpu=64000, pods=1000))
+        for s in services:
+            store.create(SERVICES, Service(name=f"svc-{s}",
+                                           selector={"app": s}))
+        if replicaset:
+            store.create(REPLICASETS, ReplicaSet(
+                name="rs", selector=LabelSelector.from_dict({"tier": "web"})))
+        sched = Scheduler(store, use_tpu=True,
+                          percentage_of_nodes_to_score=100)
+        if not rows:
+            # the shell without the row cache: signatures come from
+            # TPUScheduler.class_signatures, not interned
+            sched.pod_rows = sched.algorithm.pod_rows = None
+        sched.sync()
+        return store, sched
+
+    @staticmethod
+    def _record_cuts(sched):
+        """Replace the two things a cut leads to with recorders: a burst
+        segment's pods, a serial cycle's pod."""
+        cuts = []
+        sched._burst_segment = lambda pods, cycles, bucket: cuts.append(
+            ("burst", [p.name for p in pods])) or 0
+        sched._process_one = lambda pod, cycle: cuts.append(
+            ("serial", [pod.name])) or False
+        return cuts
+
+    @staticmethod
+    def _reference_class(pod, services, replicasets):
+        """`Scheduler._burst_class` as it stood when it was asked per pod."""
+        if has_pod_affinity_terms(pod) or get_container_ports(pod) \
+                or get_selectors(pod, services, replicasets):
+            return pod_class_signature(pod)
+        return "plain"
+
+    @staticmethod
+    def _reference_cuts(pods, services, replicasets):
+        """The per-pod walk the shell made before the per-pass decision:
+        the class function on every pod, twice, compared by value."""
+        def burst_class(pod):
+            return TestBurstClassDecision._reference_class(
+                pod, services, replicasets)
+
+        cuts, i = [], 0
+        while i < len(pods):
+            if pods[i].volumes:
+                cuts.append((i, i + 1, "serial"))
+                i += 1
+                continue
+            seg_class = burst_class(pods[i])
+            j = i
+            while j < len(pods) and not pods[j].volumes \
+                    and burst_class(pods[j]) == seg_class:
+                j += 1
+            cuts.append((i, j, seg_class))
+            i = j
+        return cuts
+
+    @pytest.mark.parametrize("rows", [True, False],
+                             ids=["row-cache", "no-row-cache"])
+    @pytest.mark.parametrize("seed", [3, 11, 2027])
+    def test_cuts_equal_the_per_pod_walk(self, seed, rows):
+        rng = random.Random(seed)
+        store, sched = self._cluster(rows=rows)
+        kinds = []
+        while len(kinds) < 60:
+            kinds += [rng.choice(self.KINDS)] * rng.randint(1, 6)
+        for j, kind in enumerate(kinds):
+            store.create(PODS, self._pod(f"p{j:03d}", kind))
+        sched.pump()
+        cuts = self._record_cuts(sched)
+        window = []
+        pop_burst = sched.queue.pop_burst
+        sched.queue.pop_burst = lambda n: window.extend(pop_burst(n)) \
+            or list(window)
+        sched._burst_pass_planned(len(kinds))
+        pods = [p for p, _c in window]
+        assert len(pods) == len(kinds)
+        services, replicasets = sched._services_fn(), sched._replicasets_fn()
+        assert len(services) == 2 and len(replicasets) == 1
+        ref = self._reference_cuts(pods, services, replicasets)
+        assert len(ref) > 8       # the window really is cut many times
+        # the (i, j, class) cuts of the pass, the class being the one the
+        # per-pass decision gives the segment's first pod
+        classes = sched._burst_classes(pods)
+        names = [p.name for p in pods]
+        got = []
+        for how, seg in cuts:
+            i, j = names.index(seg[0]), names.index(seg[-1]) + 1
+            assert seg == names[i:j]
+            got.append((i, j, "serial" if how == "serial" else classes[i]))
+        assert got == ref
+        # classes are identical objects exactly where the per-pod
+        # function's values are equal
+        old = [self._reference_class(p, services, replicasets) for p in pods]
+        assert classes == old
+        for a in range(len(pods)):
+            for b in range(a + 1, len(pods)):
+                assert (classes[a] is classes[b]) == (old[a] == old[b])
+
+    @pytest.mark.parametrize("rows", [True, False],
+                             ids=["row-cache", "no-row-cache"])
+    def test_selectors_walked_once_per_signature(self, monkeypatch, rows):
+        """50 Services, 64 spec-identical pods of one of them: the Service
+        list is walked once in the pass, not 129 times; and the counter
+        books that one decision and the 63 pods that shared it."""
+        store, sched = self._cluster(
+            services=[f"s{k}" for k in range(50)], replicaset=False,
+            rows=rows)
+        calls = []
+
+        def counting(pod, services, replicasets):
+            calls.append(pod.name)
+            return get_selectors(pod, services, replicasets)
+
+        monkeypatch.setattr(scheduler_mod, "get_selectors", counting)
+        for j in range(64):
+            store.create(PODS, mkpod(f"p{j:02d}", labels={"app": "s7"}))
+        sched.pump()
+        decided0 = BURST_CLASS.labels("decided").value
+        shared0 = BURST_CLASS.labels("shared").value
+        cuts = self._record_cuts(sched)
+        sched._burst_pass_planned(64)
+        assert len(calls) == 1
+        assert [len(seg) for _how, seg in cuts] == [64]
+        assert BURST_CLASS.labels("decided").value - decided0 == 1
+        assert BURST_CLASS.labels("shared").value - shared0 == 63
+
+    def test_counter_decided_is_distinct_signatures(self):
+        store, sched = self._cluster()
+        kinds = ["plain"] * 5 + ["svc-a"] * 4 + ["plain"] * 2 + ["port"]
+        for j, kind in enumerate(kinds):
+            store.create(PODS, self._pod(f"p{j:02d}", kind))
+        sched.pump()
+        self._record_cuts(sched)
+        decided0 = BURST_CLASS.labels("decided").value
+        shared0 = BURST_CLASS.labels("shared").value
+        sched._burst_pass_planned(len(kinds))
+        # three distinct signatures (plain, svc-a, port) among 12 pods
+        assert BURST_CLASS.labels("decided").value - decided0 == 3
+        assert BURST_CLASS.labels("shared").value - shared0 == 9
+
+    def test_service_between_passes_reclassifies(self):
+        """Nothing outlives a pass: the same pod shapes are one plain
+        segment before the Service exists and two segments after."""
+        store, sched = self._cluster(services=(), replicaset=False)
+        cuts = self._record_cuts(sched)
+
+        def one_pass(tag):
+            for j, kind in enumerate(["plain", "plain", "svc-a", "svc-a"]):
+                store.create(PODS, self._pod(f"{tag}{j}", kind))
+            sched.pump()
+            cuts.clear()
+            sched._burst_pass_planned(4)
+            return [len(seg) for _how, seg in cuts]
+
+        assert one_pass("x") == [4]
+        store.create(SERVICES, Service(name="svc-a", selector={"app": "a"}))
+        assert one_pass("y") == [2, 2]
+
+    def test_gang_fallback_without_classes_binds_as_before(self):
+        """`_gang_segment`'s degraded path hands `_schedule_singletons_burst`
+        no classes (members labelled for a PodGroup that does not exist):
+        it decides them itself and binds what the plain path binds."""
+        def run(labels):
+            store, sched = self._cluster(n_nodes=6)
+            for j in range(12):
+                kw = {"labels": dict(labels)} if labels else {}
+                store.create(PODS, mkpod(f"p{j:02d}", cpu="300m", **kw))
+            sched.pump()
+            seen = []
+            inner = sched._schedule_singletons_burst
+            sched._schedule_singletons_burst = \
+                lambda pairs, bucket, classes=None: seen.append(classes) \
+                or inner(pairs, bucket, classes)
+            assert sched.schedule_burst(max_pods=16) == 12
+            sched.pump()
+            return seen, [store.get(PODS, f"default/p{j:02d}").node_name
+                          for j in range(12)]
+
+        seen_gang, gang = run({LABEL_POD_GROUP: "no-such-group"})
+        seen_plain, plain = run(None)
+        assert seen_gang == [None]
+        assert seen_plain != [None] and len(seen_plain[0]) == 12
+        assert all(gang) and gang == plain
