@@ -107,6 +107,15 @@ SCAN_STEPS = obs.counter(
     "to its power-of-two bucket) reads 0 since the pod count became the "
     "loop's trip count: the pad rows are never stepped over.", ("kind",))
 SCAN_STEPS.labels("pad")
+SCAN_ORDER_STEPS = obs.counter(
+    "tpu_scan_order_steps_total",
+    "Steps of schedule_burst's generic scan launches, by how a step finds "
+    "its NodeTree enumeration order: 'axis' (no order shipped: the tree "
+    "never rotates, so every step walks the device axis), 'position' (a "
+    "rotating tree with every node scored: one [N] sort of tie positions a "
+    "step) or 'gather' (a rotating tree under a truncated walk: the step "
+    "permutes its masks through perms/inv_perms). Booked once a launch, "
+    "beside tpu_scan_steps_total.", ("order",))
 DISCARDED_FOLDS = obs.counter(
     "tpu_burst_folds_discarded_total",
     "Device-resident burst folds dropped after a mid-burst failure.")
@@ -1180,6 +1189,30 @@ class TPUScheduler:
         perms, inv = got
         return perms, inv, seq
 
+    def _scan_rotation(self, b: NodeBatch, bucket: int,
+                       start0: Optional[int], full_scan: bool):
+        """(rotation, rotation_pos) for a generic scan launch, already on
+        the device; (None, None) when the tree never rotates. The rotation
+        program is selected from CLUSTER shape (uneven zones), not from
+        whether THIS burst's walk happens to be the identity: the identity
+        is just data (order id 0), while flip-flopping the jit signature
+        between bursts costs a fresh 10s+ XLA compile mid-workload each
+        time the zone cursor lands on a fixed point. With every node scored
+        (`full_scan`: num_to_find >= n) the gather-free position mode
+        applies, one [N] sort a cycle; a truncated walk ships the <= L
+        distinct permutations, their inverses and each cycle's order id, and
+        a step pays three [N] gathers for them: 200 of its 321 us at 5000
+        nodes on a TPU v5e (PERF.md section 5)."""
+        if not self._tree_rotates():
+            return None, None
+        sp = obs_trace.begin("burst.rotation", cycles=bucket)
+        perms, inv, seq = self._generic_rotation(b, bucket, start0)
+        up = [jnp.asarray(a, jnp.int32)
+              for a in ((inv, seq) if full_scan else (perms, inv, seq))]
+        sp.end(orders=int(perms.shape[0]))
+        # with every node scored, inv_perms ARE the positions
+        return (None, tuple(up)) if full_scan else (tuple(up), None)
+
     # -- fused bursts, wave-windowed commit ----------------------------------
     # Round 10 moved the wave chain INTO the kernel: a burst is ONE
     # dispatch and ONE packed fetch (the round-7 pipeline paid one
@@ -1389,24 +1422,9 @@ class TPUScheduler:
         if carry_spread and not uniform_spec:
             ORACLE_FALLBACKS.labels("burst-spread-mixed").inc()
             return None
-        rotation = None
-        rotation_pos = None
-        if self._tree_rotates():
-            # per-cycle rotated enumeration orders: ship the <= L distinct
-            # permutations + each cycle's order id. In the full-scan regime
-            # (num_to_find >= n) the gather-free position mode applies —
-            # one [N] sort per cycle instead of three [N] gathers, which
-            # serialize ~30x slower on TPU at 1k nodes. The rotation program
-            # is selected from CLUSTER shape (uneven zones), not from
-            # whether THIS burst's walk happens to be the identity: the
-            # identity is just data (order id 0), while flip-flopping the
-            # jit signature between bursts costs a fresh 10s+ XLA compile
-            # mid-workload each time the zone cursor lands on a fixed point
-            rot = self._generic_rotation(b, bucket, start0)
-            if num_to_find >= n:
-                rotation_pos = (rot[1], rot[2])   # inv_perms ARE positions
-            else:
-                rotation = rot
+        # per-cycle rotated enumeration orders (uneven zones)
+        rotation, rotation_pos = self._scan_rotation(
+            b, bucket, start0, num_to_find >= n)
         spread0 = None
         if carry_spread:
             # the scan carries ONE [N] count vector; the stacked per-pod
@@ -1686,13 +1704,6 @@ class TPUScheduler:
                 pad["skip"] = self._true
                 wave.extend([pad] * (B - len(wave)))
             stacked = self._stack_pods(wave)
-            rot = rotp = None
-            if rotation is not None:
-                perms, inv_perms, seq = rotation
-                rot = (perms, inv_perms, np.asarray(seq[:B], dtype=np.int32))
-            elif rotation_pos is not None:
-                rotp = (rotation_pos[0],
-                        np.asarray(rotation_pos[1][:B], dtype=np.int32))
         ph.open("kernel")
         t_d = obs_trace.now()
         try:
@@ -1702,11 +1713,16 @@ class TPUScheduler:
                 self._dev_nodes, stacked, self.last_index,
                 self.last_node_index, num_to_find, n, z_pad,
                 weights=self._union_weights if tensor else self.weights,
-                rotation=rot, spread0=spread0, rotation_pos=rotp,
+                rotation=rotation, spread0=spread0,
+                rotation_pos=rotation_pos,
                 mesh=self.mesh, wtab=self._wtab() if tensor else None,
                 n_pods=n_pods)
             DEVICE_DISPATCH.labels("burst_scan").inc()
             SCAN_STEPS.labels("real").inc(n_pods)
+            SCAN_ORDER_STEPS.labels(
+                "gather" if rotation is not None else
+                "position" if rotation_pos is not None else "axis"
+            ).inc(n_pods)
             ph.close()
             ph.open("fetch")
             chaos.node_dead_point("dispatch-fetch")
@@ -1930,16 +1946,11 @@ class TPUScheduler:
         num_to_find = num_feasible_nodes_to_find(
             n, self.percentage_of_nodes_to_score)
         B = _pad_pow2(max(bucket or 16, n_total), 16)
-        rotation = rotation_pos = None
-        if self._tree_rotates():
-            # one burst-wide walk, indexed by enumerations CONSUMED inside
-            # the kernel (the carried t) — a rejected gang rewinds the
-            # cursor, so the walk must NOT be pre-sliced by pod position
-            rot = self._generic_rotation(b, B, start0)
-            if num_to_find >= n:
-                rotation_pos = (rot[1], rot[2])
-            else:
-                rotation = rot
+        # one burst-wide walk, indexed by enumerations CONSUMED inside
+        # the kernel (the carried t) — a rejected gang rewinds the
+        # cursor, so the walk must NOT be pre-sliced by pod position
+        rotation, rotation_pos = self._scan_rotation(
+            b, B, start0, num_to_find >= n)
         seg_start = np.zeros(B, dtype=bool)
         gang = np.zeros(B, dtype=bool)
         idx = 0

@@ -33,7 +33,10 @@ Every core names its stages with `jax.named_scope`, in upstream's words:
 selection), `score`, `pick` (selectHost; pickOneNodeForPreemption) and `fold`
 (the decision's delta into the carried node state). A scope is op metadata,
 set while tracing and free at run time; a device trace is read by these names
-(`SCOPES`), which survive a refactor that renumbers `fusion.9`.
+(`SCOPES`), which survive a refactor that renumbers `fusion.9`. Inside
+`filter` and `pick`, `rotate` names the permutation gathers of a cycle whose
+NodeTree order is shipped as perm/inv_perm (`_cycle_core`): nested, so the
+four stages still add up to what they did.
 """
 from __future__ import annotations
 
@@ -445,6 +448,13 @@ def _feasibility(nodes, pod):
     return feasible, fail_first.astype(jnp.int8), bits
 
 
+@jax.named_scope("rotate")
+def _reorder(x, order):
+    """`x` taken in another order: the gathers between the device axis and
+    a cycle's NodeTree enumeration, under a scope of their own."""
+    return x[order]
+
+
 def _cycle_core(nodes, pod, last_index, last_node_index, num_to_find, n_real,
                 weights, z_pad, perm=None, inv_perm=None, pos=None,
                 ghost=None, wtab=None, gang=None):
@@ -468,8 +478,9 @@ def _cycle_core(nodes, pod, last_index, last_node_index, num_to_find, n_real,
     this cycle's enumeration (the inverse permutation). With a full scan
     kept == feasible and evaluated == n, so the only order-dependent step
     is selectHost's k-th-tie pick — resolved by one [N] sort of tie
-    positions instead of the three [N] gathers of the perm path, which
-    serialize badly on TPU (30x per-cycle cost at 1k nodes).
+    positions instead of the three [N] gathers of the perm path
+    (`_reorder`, scope `rotate`: 200 of a step's 321 us at 5000 nodes on a
+    TPU v5e against the sort's 14, PERF.md section 5).
 
     `wtab` (tensor mode) is the resident [profiles x priorities] weight
     table; this pod's row is gathered by `pod["profile_id"]` and every
@@ -513,7 +524,7 @@ def _cycle_core(nodes, pod, last_index, last_node_index, num_to_find, n_real,
             found = jnp.minimum(F, ntf)
             evaluated = jnp.where(pod["skip"], 0, nr).astype(jnp.int64)
         else:
-            feas_p = feas if perm is None else feas[perm]
+            feas_p = feas if perm is None else _reorder(feas, perm)
             S = jnp.cumsum(feas_p.astype(i32))
             F = S[-1]                                   # total feasible
             pre = jnp.where(li > 0, S[jnp.maximum(li - 1, 0)], 0)
@@ -521,7 +532,7 @@ def _cycle_core(nodes, pod, last_index, last_node_index, num_to_find, n_real,
             # rank at position p
             rank_p = jnp.where(after, S - pre, F - pre + S)
             kept_p = feas_p & (rank_p <= ntf)
-            kept = kept_p if perm is None else kept_p[inv_perm]
+            kept = kept_p if perm is None else _reorder(kept_p, inv_perm)
             found = jnp.minimum(F, ntf)
             reached = F >= ntf
             # the position where the sequential walk stops: unique feasible p
@@ -558,12 +569,12 @@ def _cycle_core(nodes, pod, last_index, last_node_index, num_to_find, n_real,
             trank = jnp.where(after, T - preT, T[-1] - preT + T)
             sel = jnp.argmax(tie_p & (trank == k + 1)).astype(jnp.int64)
         else:
-            tie_p = is_tie[perm]
+            tie_p = _reorder(is_tie, perm)
             T = jnp.cumsum(tie_p.astype(i32))
             preT = jnp.where(li > 0, T[jnp.maximum(li - 1, 0)], 0)
             trank = jnp.where(after, T - preT, T[-1] - preT + T)
             sel_p = jnp.argmax(tie_p & (trank == k + 1)).astype(jnp.int64)
-            sel = perm[sel_p].astype(jnp.int64)
+            sel = _reorder(perm, sel_p).astype(jnp.int64)
     selected = jnp.where(found > 0, sel, -1)
 
     return {

@@ -23,7 +23,7 @@ if BENCH not in sys.path:
     sys.path.insert(0, BENCH)
 
 from lib import check, cluster  # noqa: E402
-from lib.client import Client  # noqa: E402
+from lib.client import BIND, Client  # noqa: E402
 from lib.traffic import PodFactory  # noqa: E402
 from reference import default_provider as full_ref  # noqa: E402
 from reference import default_provider_adaptive as adaptive  # noqa: E402
@@ -211,6 +211,65 @@ def test_uneven_zones_with_services_take_the_rotation_program(monkeypatch):
     assert set(seen) == {(True, False, True)}
     assert sum(c.value for c in T.ORACLE_FALLBACKS._children.values()) \
         == fallbacks0
+
+
+def resident_of(pods_per_node, services):
+    return {"pods_per_node": pods_per_node, "services": services,
+            "requests": {"cpu_milli": 100, "memory_bytes": 500 * MI}}
+
+
+def rollouts(run):
+    """Five rollouts of 150 replicas, each drained in launches of at most 64
+    pods so that last_index, last_node_index and the tree's zone cursor carry
+    from launch to launch; the second and third stay, so nodes fill."""
+    for k in range(5):
+        ids = run.cycle(150, max_pods=64)
+        assert len(run.bound(ids)) == 150
+        if k not in (1, 2):
+            run.delete(ids)
+    c = run.client
+    return {c.keys[c.log_pod[k]]: c.log_node[k]
+            for k in range(len(c.log_kind)) if c.log_kind[k] == BIND}
+
+
+@pytest.mark.parametrize("n,cap,percentage,per_node,services", [
+    (250, 4, 0, 2, 5),      # zones 84/83/83; 120 of 250 found, 450 of 500
+                            # free slots taken: walks pass many full nodes
+    (131, 5, 40, 1, 3),     # 44/44/43; 100 of 131: every second walk wraps
+    (200, 6, 60, 2, 4),     # 67/67/66; 120 of 200
+], ids=["250n-default", "131n-40pct-wraps", "200n-60pct"])
+def test_program_reference_and_oracle_agree_on_uneven_zones(
+        n, cap, percentage, per_node, services):
+    """Uneven zones x Services x truncated walk over consecutive launches:
+    the program (the gather rotation program with carried spread counts),
+    the benchmark's plain reference and the program's serial oracle."""
+    from kubernetes_tpu.core.tpu_scheduler import (ORACLE_FALLBACKS,
+                                                   SCAN_ORDER_STEPS,
+                                                   WALK_NODES)
+    cfg = config(n, cap, percentage, resident=resident_of(per_node, services))
+    seed = 2 ** 31 + n
+    gather0 = SCAN_ORDER_STEPS.labels("gather").value
+    tested0 = WALK_NODES.labels("truncated").value
+    fallbacks0 = sum(c.value for c in ORACLE_FALLBACKS._children.values())
+    run = Run(cfg, SPREAD, seed)
+    on_device = rollouts(run)
+    # every one of the 750 decisions was a step of the gather program
+    assert SCAN_ORDER_STEPS.labels("gather").value - gather0 == 750
+    assert sum(c.value for c in ORACLE_FALLBACKS._children.values()) \
+        == fallbacks0
+    rep, ref = run.replay()
+    assert rep["compared"] == 750 and rep["mismatches"] == []
+    assert rep["over_allocatable"] == 0
+    assert ref.num_to_find == adaptive.num_to_find(n, percentage) < n
+    # some walks passed over full nodes on their way to the quota
+    assert WALK_NODES.labels("truncated").value - tested0 \
+        > 750 * ref.num_to_find
+    algo = run.sched.algorithm
+    assert (algo.last_index, algo.last_node_index) == \
+        (ref.last_index, ref.last_node_index)
+    serial = Run(cfg, SPREAD, seed, tpu=False)
+    assert rollouts(serial) == on_device
+    assert serial.sched.algorithm.last_index == ref.last_index
 
 
 # -- (d) the reference against other statements of the same semantics ------------
@@ -417,3 +476,53 @@ def test_counters_and_span_of_a_300_pod_burst(monkeypatch):
     assert got[("tpu_walk_nodes_evaluated_total", ("full",))] == 300 * 240
     assert not any(k[0] == "tpu_scan_steps_total" for k in got)
     assert ("tpu_walk_nodes_evaluated_total", ("truncated",)) not in got
+
+
+def test_order_counter_and_rotation_span(monkeypatch):
+    """`tpu_scan_order_steps_total` says how a launch's steps found their
+    NodeTree order, and `burst.rotation` is in the ring with its args."""
+    from kubernetes_tpu import obs
+    from kubernetes_tpu.core import tpu_scheduler as T
+
+    def steps():
+        return {k[0]: c.value for k, c in T.SCAN_ORDER_STEPS._children.items()}
+
+    def moved(before):
+        return {k: v - before.get(k, 0) for k, v in steps().items()
+                if v - before.get(k, 0)}
+
+    def rotation_spans():
+        return [e for e in obs.trace.events() if e["name"] == "burst.rotation"]
+
+    resident = resident_of(2, 5)
+    # uneven zones (84/83/83), truncated walk: every step gathers
+    obs.trace.clear()
+    before = steps()
+    run = Run(config(250, 4, 0, resident=resident), SPREAD, 21)
+    assert len(run.bound(run.cycle(150, max_pods=150))) == 150
+    assert moved(before) == {"gather": 150}
+    spans = rotation_spans()
+    assert len(spans) == 1
+    # three zones: the axis order and the three rotated ones, in a bucket of 4
+    assert spans[0]["args"]["orders"] == 4
+    assert spans[0]["args"]["cycles"] == 256
+    names = [e["name"] for e in obs.trace.events()]
+    # inside the encode phase, before the pod rows are stacked
+    assert names.index("burst.rotation") < names.index("burst.stack") \
+        < names.index("burst.dispatch")
+
+    # the same cluster with every node scored: the position sort, no gather
+    obs.trace.clear()
+    before = steps()
+    run = Run(config(250, 4, 100, resident=resident), SPREAD, 22)
+    assert len(run.bound(run.cycle(150, max_pods=150))) == 150
+    assert moved(before) == {"position": 150}
+    assert len(rotation_spans()) == 1
+
+    # even zones (80/80/80): the tree never rotates, nothing is shipped
+    obs.trace.clear()
+    before = steps()
+    run = Run(config(240, 4, 0, resident=resident), SPREAD, 23)
+    assert len(run.bound(run.cycle(150, max_pods=150))) == 150
+    assert moved(before) == {"axis": 150}
+    assert rotation_spans() == []

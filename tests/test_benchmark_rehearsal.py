@@ -5,6 +5,8 @@ is found here and not on the chip. `correct` is the benchmark's own verdict
 (the client's watch replayed through `benchmark/reference/`); nothing here is
 a speed. `benchmark/tests/` holds the benchmark's own, fuller tests (by hand).
 """
+import importlib
+import json
 import os
 import sys
 
@@ -17,10 +19,13 @@ BACKLOG = "headline-15000n.backlog-10k"
 ROLLOUT = "density-5000n-150k.rollout-1k"
 ARRIVALS = "headline-15000n.arrivals-steady"
 ADAPTIVE = "headline-15000n-adaptive.backlog-10k"
+DENSITY_ADAPTIVE = "density-5000n-150k-adaptive.rollout-1k"
 
 # (config overlay, traffic overlay): the sizes benchmark/tests rehearses at.
 # The adaptive cell needs more than 100 nodes for the walk to be cut short
-# (num_to_find = 117 of 240).
+# (num_to_find = 117 of 240), and so does the density cell at the default
+# percentage, on zones of 84/83/83 so that the NodeTree's order rotates
+# (num_to_find = 120 of 250).
 AT_50 = {"nodes": {"count": 50},
          "check": {"first_binds": 200, "sampled_binds": 60}}
 SMALL = {
@@ -32,7 +37,14 @@ SMALL = {
     ADAPTIVE: ({"nodes": {"count": 240},
                 "check": {"first_binds": 200, "sampled_binds": 200}},
                {"warm_binds": 0, "backlog": 150}),
+    DENSITY_ADAPTIVE: ({"nodes": {"count": 250},
+                        "resident": {"pods_per_node": 6, "services": 5},
+                        "check": {"first_binds": 200, "sampled_binds": 100}},
+                       {"warm_binds": 0, "backlog": 150}),
 }
+# the control of an adaptive cell: the program scores every node while the
+# reference judges at the file's default percentage
+EVERY_NODE = {"scheduler": {"percentage_of_nodes_to_score": 100}}
 
 
 @pytest.fixture(scope="module")
@@ -47,10 +59,11 @@ def execute():
         sys.path.remove(BENCH_DIR)
 
 
-def rehearse(execute, cell, seed, hook=None):
+def rehearse(execute, cell, seed, hook=None, program=None):
     config, traffic = SMALL[cell]
     return execute(cell, seed, 1.5, False, rehearse=True, hook=hook,
-                   overrides={"config": config, "traffic": traffic})
+                   overrides={"config": config, "traffic": traffic,
+                              "program": program})
 
 
 def altered_binding(sched, store):
@@ -69,18 +82,22 @@ def altered_binding(sched, store):
     store.commit_wave = altered
 
 
-@pytest.mark.parametrize("cell,seed,hook", [
-    (BACKLOG, 1, None),               # K-batch kernel
-    (ROLLOUT, 2**31 + 5, None),       # generic scan + spread
-    (ARRIVALS, 3, None),              # serve loop
-    (ADAPTIVE, 2**31 + 17, None),     # truncated walk
-    (BACKLOG, 11, altered_binding),   # the guard can fail
-], ids=["backlog", "rollout", "arrivals", "adaptive", "altered-binding"])
-def test_rehearsed_cell(execute, cell, seed, hook):
-    out = rehearse(execute, cell, seed, hook)
+@pytest.mark.parametrize("cell,seed,hook,program", [
+    (BACKLOG, 1, None, None),               # K-batch kernel
+    (ROLLOUT, 2**31 + 5, None, None),       # generic scan + spread
+    (ARRIVALS, 3, None, None),              # serve loop
+    (ADAPTIVE, 2**31 + 17, None, None),     # truncated walk
+    (BACKLOG, 11, altered_binding, None),   # the guard can fail
+    # truncated walk + rotation by gather + carried spread counts
+    (DENSITY_ADAPTIVE, 2**31 + 23, None, None),
+    (DENSITY_ADAPTIVE, 2**31 + 23, None, EVERY_NODE),   # its control
+], ids=["backlog", "rollout", "arrivals", "adaptive", "altered-binding",
+        "density-adaptive", "density-adaptive-control"])
+def test_rehearsed_cell(execute, cell, seed, hook, program):
+    out = rehearse(execute, cell, seed, hook, program)
     res, rep = out["result"], out["report"]
     assert rep["compared"] > 0
-    if hook is not None:
+    if hook is not None or program is not None:
         assert res["correct"] is False
         return
     assert res["correct"] is True and res["failed"] == 0
@@ -92,3 +109,46 @@ def test_rehearsed_cell(execute, cell, seed, hook):
         assert "burst_uniform" not in moved["tpu_device_dispatch_total"]
         # the pod count is the scan's trip count: one step per pod given
         assert moved["tpu_scan_steps_total"]["real"] == res["attempted"]
+        # even zones: no order is shipped
+        assert moved["tpu_scan_order_steps_total"] == \
+            {"axis": res["attempted"]}
+    if cell == DENSITY_ADAPTIVE:
+        moved = rep["counters"]
+        assert "burst_uniform" not in moved["tpu_device_dispatch_total"]
+        # every step of every launch permutes its masks (the gather program)
+        assert moved["tpu_scan_order_steps_total"] == \
+            {"gather": res["attempted"]}
+        assert moved["tpu_scan_steps_total"]["real"] == res["attempted"]
+        # a walk stops at its quota: 120 of 250 nodes, none of them full
+        assert moved["tpu_walk_nodes_evaluated_total"] == \
+            {"truncated": 120 * res["attempted"]}
+
+
+with open(os.path.join(os.path.dirname(BENCH_DIR), "BENCHMARK.json")) as _f:
+    CELLS = [w["name"] for w in json.load(_f)["workloads"]]
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_cell_files_load(execute, cell):
+    """What a traced run of the cell opens before it touches the device: its
+    configuration, its traffic mix, and for every per-layer metric the file
+    and the reader it names (and, for a roofline, the byte model)."""
+    from lib import spec
+    bench = spec.load_benchmark()
+    entry = spec.find_cell(bench, cell)
+    cfg = spec.load_config(bench, entry["config"])
+    importlib.import_module(f"reference.{cfg['reference']}")
+    spec.load_traffic(entry["traffic"])
+    e2e = [m["name"] for m in spec.metrics_for(bench, entry, "end_to_end")]
+    assert "setup_s" in e2e and len(e2e) >= 2
+    layer = spec.metrics_for(bench, entry, "per_layer")
+    assert layer
+    for m in layer:
+        mf = spec.load_metric(m["name"])
+        reader = importlib.import_module(f"readers.{mf['reader']}")
+        assert callable(reader.read)
+        if mf["reader"] == "trace_program_roofline":
+            model = importlib.import_module(
+                f"roofline.{mf['args'].get('module', 'bytes')}")
+            assert getattr(model, mf["args"]["model"])(
+                rows=8192, pods=1000, nodes=5000) > 0
