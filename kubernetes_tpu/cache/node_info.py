@@ -100,6 +100,13 @@ class NodeInfo:
     def __init__(self, node: Optional[Node] = None):
         self.node: Optional[Node] = None
         self.pods: list[Pod] = []
+        # join stamps, parallel to `pods`: the generation add_pod issued
+        # when it appended the pod. A generation is issued once in the
+        # process, so a stamp names one add of one Pod object, in every
+        # clone; a pod re-added (how the cache delivers an update, even of
+        # the same object) gets a new one. ops.node_state's pod table keys
+        # its cached rows on it.
+        self.pod_gens: list[int] = []
         self.pods_with_affinity: list[Pod] = []
         self.used_ports = HostPortInfo()
         self.requested = ResourceAgg()
@@ -155,11 +162,13 @@ class NodeInfo:
         for p in get_container_ports(pod):
             self.used_ports.add(p.host_ip, p.protocol, p.host_port)
         self.generation = next_generation()
+        self.pod_gens.append(self.generation)
 
     def remove_pod(self, pod: Pod) -> bool:
         for i, p in enumerate(self.pods):
             if p.uid == pod.uid:
                 del self.pods[i]
+                del self.pod_gens[i]
                 break
         else:
             return False
@@ -185,6 +194,7 @@ class NodeInfo:
         out = NodeInfo()
         out.node = self.node
         out.pods = list(self.pods)
+        out.pod_gens = list(self.pod_gens)
         out.pods_with_affinity = list(self.pods_with_affinity)
         out.used_ports = self.used_ports.clone()
         out.requested = self.requested.clone()

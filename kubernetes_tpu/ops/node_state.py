@@ -47,6 +47,12 @@ MIRROR_PERMUTES = obs.counter(
 MIRROR_REBUILDS = obs.counter(
     "tpu_encoder_mirror_rebuilds_total",
     "Full mirror rebuilds (capacity, vocab, or node-membership change).")
+POD_TABLE_ROWS = obs.counter(
+    "tpu_pod_table_rows_total",
+    "Pod-table rows of nodes whose NodeInfo generation moved, by how "
+    "pod_table got them: extracted (derived from the Pod in Python), "
+    "reused (taken from the node's previous block). Booked once per "
+    "pod_table call that found a moved generation.", ("result",))
 VICTIM_ROW_RESORTS = obs.counter(
     "tpu_victim_table_row_resorts_total",
     "Victim-table node rows re-sorted (generation moved or the PDB set "
@@ -108,19 +114,18 @@ class NodeStateEncoder:
         self._generations: dict[str, int] = {}
         self._scalar_vocab: list[str] = []
         self._zone_vocab: list[str] = [""]
-        # columnar pod-table cache (pod_table): per-node blocks keyed by
-        # NodeInfo generation; vocabs grow monotonically so ids are stable
+        # columnar pod-table cache (pod_table): the table of the call
+        # before with the batch it was cut against (victim_table and the
+        # per-burst PodEncoder both ask, often in the same cycle), and per
+        # node (generation, first row, join stamps) of its block there;
+        # vocabs grow monotonically so ids are stable
+        self._pt_built: Optional["PodTable"] = None
+        self._pt_batch: Optional[NodeBatch] = None
         self._pt_blocks: dict[str, tuple] = {}
         self._pt_ns_vocab: dict[str, int] = {}
         self._pt_key_vocab: dict[str, int] = {}
         self._pt_val_vocab: dict[str, int] = {}
         self._pt_val_ints: list[float] = []
-        # assembled-table memo: when no block re-extracted and the batch is
-        # the same object, the concatenated arrays are bit-identical — skip
-        # the O(total pods) reassembly (victim_table + the per-burst
-        # PodEncoder both read the table, often in the same cycle)
-        self._pt_built: Optional["PodTable"] = None
-        self._pt_built_key: Optional[tuple] = None
         # calculate_resource memo keyed by the containers tuple: victim
         # columns and uniform waves re-read the same specs constantly
         self._cr_memo: dict = {}
@@ -378,24 +383,62 @@ class NodeStateEncoder:
                 self._pt_val_ints.append(float("nan"))
         return vid
 
-    def _pt_block(self, ni: NodeInfo):
-        """One node's pods as dictionary-encoded rows. Vocab ids are
-        monotonic (never reassigned) so cached blocks stay valid across
-        encodes. Alongside the label rows, each pod's VICTIM columns are
-        extracted here — priority, start time, calculate_resource sums
-        (memoized by the containers tuple), and the inertness-class flags
-        (affinity terms / container ports / scalar resources) — so the
-        preemption path reads cached per-generation facts instead of
-        re-deriving them per scan."""
-        pods = list(ni.pods)
-        p = len(pods)
-        aff_ids = set(map(id, ni.pods_with_affinity))
-        lmax = max((len(pd.labels) for pd in pods), default=0)
-        kid = np.full((p, max(lmax, 1)), -1, np.int32)
-        vid = np.full((p, max(lmax, 1)), -1, np.int32)
+    def _pt_block(self, name: str, ni: NodeInfo, cached, rows: list,
+                  fresh: list) -> None:
+        """One node whose block is not cached at its generation: append to
+        `rows`, per pod of ni.pods in order, the row of the previous table
+        that describes it, or -(k+1) where k is its place in `fresh`, the
+        (pod, has_affinity, named_holder) triples _pt_extract will derive
+        in Python.
+
+        A previous row is reused when the node's previous block has a row
+        cut for the same JOIN STAMP (NodeInfo.pod_gens). A stamp is a
+        generation number, issued once in the process, by the one add_pod
+        that put the pod into `pods`; clones copy it with the pod. So an
+        equal stamp means the same Pod object, held without a break since
+        that add, whichever snapshot, clone or ghost-carrying copy of the
+        node the block was cut from. That is enough because a held pod
+        does not change in place: the store never mutates a stored object
+        and hands it out read-only (store/store.py, "writes"), the
+        scheduler sets node_name on its own clone before it assumes, and
+        the cache delivers an update as remove_pod + add_pod, which issues
+        a new stamp even when old and new are one object. A mutation that
+        reaches no NodeInfo moves no generation either, and nothing that
+        reads the cache sees it. has_affinity is covered too: add_pod
+        fixes it when the pod joins (NodeInfo.pods_with_affinity). What a
+        row holds that is NOT the pod's own (the holder's batch row, the
+        row its node_name resolves to) is never cached: pod_table derives
+        it on every call. Every other pod extracts: a stale row is a wrong
+        binding."""
+        start, stamps = cached[1:] if cached is not None else (0, [])
+        held = dict(zip(stamps, range(start, start + len(stamps))))
+        got = list(map(held.get, ni.pod_gens))
+        if None in got:
+            aff_ids = set(map(id, ni.pods_with_affinity))
+            for j, pd in enumerate(ni.pods):
+                if got[j] is None:
+                    fresh.append(
+                        (pd, id(pd) in aff_ids, pd.node_name == name))
+                    got[j] = -len(fresh)
+        rows.extend(got)
+
+    def _pt_extract(self, fresh: list, width: int) -> dict:
+        """Derive the cached columns of `fresh` pods, dictionary-encoded,
+        the label columns at least `width` wide. Vocab ids are monotonic
+        (never reassigned) so rows stay valid across calls. Alongside the
+        label rows, each pod's VICTIM columns are extracted here —
+        priority, start time, calculate_resource sums (memoized by the
+        containers tuple), and the inertness-class flags (affinity terms /
+        container ports / scalar resources) — so the preemption path reads
+        cached facts instead of re-deriving them per scan."""
+        p = len(fresh)
+        width = max([width] + [len(pd.labels) for pd, _, _ in fresh])
+        kid = np.full((p, width), -1, np.int32)
+        vid = np.full((p, width), -1, np.int32)
         ns = np.empty(p, np.int32)
         deleted = np.empty(p, bool)
         has_aff = np.empty(p, bool)
+        named = np.empty(p, bool)
         prio = np.empty(p, np.int64)
         start = np.empty(p, np.float64)
         rcpu = np.empty(p, np.int64)
@@ -404,16 +447,13 @@ class NodeStateEncoder:
         rscalar = np.empty(p, bool)
         aterms = np.empty(p, bool)
         ports = np.empty(p, bool)
-        names = []
         nsv, kvoc = self._pt_ns_vocab, self._pt_key_vocab
         cr_memo = self._cr_memo
-        for j, pd in enumerate(pods):
-            nid = nsv.get(pd.namespace)
-            if nid is None:
-                nid = nsv[pd.namespace] = len(nsv)
-            ns[j] = nid
+        for j, (pd, aff, nm) in enumerate(fresh):
+            ns[j] = nsv.setdefault(pd.namespace, len(nsv))
             deleted[j] = pd.deleted
-            has_aff[j] = id(pd) in aff_ids
+            has_aff[j] = aff
+            named[j] = nm
             prio[j] = pd.priority
             start[j] = pd.start_time if pd.start_time is not None else np.inf
             key = pd.containers
@@ -426,102 +466,129 @@ class NodeStateEncoder:
                                       bool(get_container_ports(pd)))
             rcpu[j], rmem[j], reph[j], rscalar[j], ports[j] = got
             aterms[j] = has_pod_affinity_terms(pd)
-            names.append(pd.node_name)
             for l, (k, v) in enumerate(pd.labels.items()):
-                kk = kvoc.get(k)
-                if kk is None:
-                    kk = kvoc[k] = len(kvoc)
-                kid[j, l] = kk
+                kid[j, l] = kvoc.setdefault(k, len(kvoc))
                 vid[j, l] = self._pt_val_id(v)
-        return (pods, ns, kid, vid, deleted, has_aff, names,
-                (prio, start, rcpu, rmem, reph, rscalar, aterms, ports))
+        return dict(zip(_PT_CACHED, (
+            ns, deleted, has_aff, named, kid, vid, prio, start, rcpu, rmem,
+            reph, rscalar, aterms, ports)))
 
     def pod_table(self, node_infos: dict[str, NodeInfo],
                   b: NodeBatch) -> "PodTable":
-        """Columnar table of every snapshot pod, cached per node by the
-        NodeInfo generation exactly like the dirty-row encode: only nodes
-        whose generation moved re-extract their pods' label rows; assembly
-        of the cached blocks is pure numpy. Callers that feed the table to
-        the vectorized matchers assume the batch axis covers the snapshot
-        (node_infos keys ⊆ batch names), which is how every encoder
-        consumer builds it."""
-        blocks = []
-        new_cache = {}
-        all_hit = True
+        """Columnar table of every snapshot pod, rows in node_infos order
+        and ni.pods order within a node, kept by DELTA from the table of
+        the call before: a call costs what changed, not what exists.
+
+        The cache is that previous table plus, per node, the generation its
+        rows were cut at, where they lie and their join stamps. A node at
+        its cached generation hands over its row range; a node whose
+        generation moved goes through _pt_block, which reuses the rows of
+        pods it still holds and sends only the pods that joined to
+        _pt_extract. Every
+        cached column of the new table is then ONE numpy gather from
+        (previous rows ++ extracted rows): no Python loop touches a row
+        that did not change, and the from-scratch build is just the first
+        call, when every row is extracted. The three columns that depend on
+        the batch and the snapshot, not on the pod (holder_row,
+        holder_has_obj, name_row), are derived on every call. The result
+        equals, field for field and row for row, what build_pod_table makes
+        from the same snapshot. When nothing moved against the same batch
+        the previous table itself is returned. Callers that feed the table
+        to the vectorized matchers assume the batch axis covers the
+        snapshot (node_infos keys ⊆ batch names), which is how every
+        encoder consumer builds it."""
+        prev = self._pt_built
+        prev_pods = prev.pods if prev is not None else []
+        cache = self._pt_blocks
+        blocks = {}
+        starts, counts = [], []      # per node: first source row, rows
+        rows: list = []              # source row per pod of a moved node
+        moved: list = []             # which nodes (positions) those are
+        fresh: list = []
+        total = 0
         for name, ni in node_infos.items():
-            cached = self._pt_blocks.get(name)
+            cached = cache.get(name)
             if cached is not None and cached[0] == ni.generation:
-                blk = cached[1]
+                start, stamps = cached[1:]
             else:
-                blk = self._pt_block(ni)
-                all_hit = False
-            new_cache[name] = (ni.generation, blk)
-            blocks.append((name, blk))
-        if len(new_cache) != len(self._pt_blocks):
-            all_hit = False              # a node left or joined the snapshot
-        self._pt_blocks = new_cache   # prunes nodes that left the snapshot
-        key = (id(b), len(blocks))
-        if all_hit and self._pt_built is not None \
-                and self._pt_built_key == key:
-            # no block re-extracted against the same batch: the assembled
-            # arrays are bit-identical — reuse them
-            return self._pt_built
-        total = sum(len(blk[0]) for _, blk in blocks)
-        lmax = max((blk[2].shape[1] for _, blk in blocks if len(blk[0])),
-                   default=1)
-        pods: list = []
-        holder_row = np.full(total, -1, np.int32)
-        holder_has_obj = np.zeros(total, bool)
-        name_row = np.full(total, -1, np.int32)
-        ns_id = np.empty(total, np.int32)
-        deleted = np.empty(total, bool)
-        has_aff = np.empty(total, bool)
-        key_ids = np.full((total, lmax), -1, np.int32)
-        val_ids = np.full((total, lmax), -1, np.int32)
-        prio = np.empty(total, np.int64)
-        start = np.empty(total, np.float64)
-        res_cpu = np.empty(total, np.int64)
-        res_mem = np.empty(total, np.int64)
-        res_eph = np.empty(total, np.int64)
-        has_scalar = np.empty(total, bool)
-        has_aff_terms = np.empty(total, bool)
-        has_ports = np.empty(total, bool)
-        off = 0
-        for name, blk in blocks:
-            bpods, ns, kid, vid, dele, haff, names, vcols = blk
-            p = len(bpods)
-            if not p:
-                continue
-            pods.extend(bpods)
-            sl = slice(off, off + p)
-            hrow = b.index.get(name, -1)
-            holder_row[sl] = hrow
-            holder_has_obj[sl] = node_infos[name].node is not None
-            ns_id[sl] = ns
-            deleted[sl] = dele
-            has_aff[sl] = haff
-            key_ids[sl, : kid.shape[1]] = kid
-            val_ids[sl, : vid.shape[1]] = vid
-            (prio[sl], start[sl], res_cpu[sl], res_mem[sl], res_eph[sl],
-             has_scalar[sl], has_aff_terms[sl], has_ports[sl]) = vcols
-            for j, nm in enumerate(names):
-                if nm == name:
-                    name_row[off + j] = hrow
-                elif nm in node_infos:
-                    name_row[off + j] = b.index.get(nm, -1)
-            off += p
+                moved.append(len(counts))
+                start, stamps = 0, list(ni.pod_gens)
+                self._pt_block(name, ni, cached, rows, fresh)
+            blocks[name] = (ni.generation, total, stamps)
+            starts.append(start)
+            counts.append(len(stamps))
+            total += len(stamps)
+        self._pt_blocks = blocks      # prunes nodes that left the snapshot
+        if moved:
+            POD_TABLE_ROWS.labels("extracted").inc(len(fresh))
+            POD_TABLE_ROWS.labels("reused").inc(len(rows) - len(fresh))
+        counts = np.asarray(counts, np.int64)
+        offs = np.cumsum(counts) - counts
+        # source row of every new row: a node's cached range, then the
+        # moved nodes' per-pod rows scattered over theirs (extracted rows
+        # lie behind the previous table's)
+        src = np.repeat(np.asarray(starts, np.int64) - offs, counts) \
+            + np.arange(total)
+        if rows:
+            m = np.asarray(moved, np.int64)
+            mc = counts[m]
+            r = np.asarray(rows, np.int64)
+            src[np.repeat(offs[m] - (np.cumsum(mc) - mc), mc)
+                + np.arange(r.size)] = np.where(
+                    r >= 0, r, len(prev_pods) - 1 - r)
+        if prev is not None and total == len(prev_pods) \
+                and np.array_equal(src, np.arange(total)):
+            # every row is where it was and describes the pod it did: the
+            # cached columns ARE the previous table's (shared: no consumer
+            # writes a table), and so is the rest when no generation moved
+            # and no node left or joined against the same batch
+            if not moved and len(blocks) == len(cache) \
+                    and self._pt_batch is b:
+                return prev
+            pods = prev_pods
+            cols = {f: getattr(prev, f) for f in _PT_CACHED}
+        else:
+            pods = [pd for ni in node_infos.values() for pd in ni.pods]
+            got = self._pt_extract(
+                fresh, prev.key_ids.shape[1] if prev is not None else 1)
+            cols = {}
+            for f in _PT_CACHED:
+                col = getattr(prev, f) if prev is not None else got[f][:0]
+                if fresh:
+                    if col.ndim == 2 and col.shape[1] < got[f].shape[1]:
+                        col = np.pad(col, ((0, 0), (
+                            0, got[f].shape[1] - col.shape[1])),
+                            constant_values=-1)
+                    col = np.concatenate((col, got[f]))
+                cols[f] = col[src]
+            # label columns as wide as the widest row left, as a fresh
+            # build's are
+            used = np.flatnonzero((cols["key_ids"] >= 0).any(axis=0))
+            w = int(used[-1]) + 1 if used.size else 1
+            if w < cols["key_ids"].shape[1]:
+                for f in ("key_ids", "val_ids"):
+                    cols[f] = np.ascontiguousarray(cols[f][:, :w])
+        index = b.index
+        holder_row = np.repeat(
+            np.fromiter((index.get(name, -1) for name in node_infos),
+                        np.int32, len(counts)), counts)
+        holder_has_obj = np.repeat(
+            np.fromiter((ni.node is not None for ni in node_infos.values()),
+                        bool, len(counts)), counts)
+        # a pod names its holder (every bound pod the cache holds) or,
+        # rarely, another node: node_name is read anew for those
+        name_row = np.where(cols["named_holder"], holder_row, np.int32(-1))
+        for j in np.flatnonzero(~cols["named_holder"]).tolist():
+            nm = pods[j].node_name
+            if nm in node_infos:
+                name_row[j] = index.get(nm, -1)
         out = PodTable(
             pods=pods, holder_row=holder_row, holder_has_obj=holder_has_obj,
-            name_row=name_row, has_affinity=has_aff, deleted=deleted,
-            ns_id=ns_id, key_ids=key_ids, val_ids=val_ids,
-            ns_vocab=self._pt_ns_vocab, key_vocab=self._pt_key_vocab,
-            val_vocab=self._pt_val_vocab,
-            val_ints=np.asarray(self._pt_val_ints, dtype=np.float64),
-            prio=prio, start=start, res_cpu=res_cpu, res_mem=res_mem,
-            res_eph=res_eph, has_scalar=has_scalar,
-            has_aff_terms=has_aff_terms, has_ports=has_ports)
+            name_row=name_row, ns_vocab=self._pt_ns_vocab,
+            key_vocab=self._pt_key_vocab, val_vocab=self._pt_val_vocab,
+            val_ints=np.asarray(self._pt_val_ints, dtype=np.float64), **cols)
         self._pt_built = out
-        self._pt_built_key = key
+        self._pt_batch = b
         return out
 
     # -- persistent victim table --------------------------------------------
@@ -760,6 +827,7 @@ class PodTable:
     holder_row: np.ndarray      # [P] i32 batch row of the holding NodeInfo (-1 off-axis)
     holder_has_obj: np.ndarray  # [P] bool: holder NodeInfo.node is not None
     name_row: np.ndarray        # [P] i32 batch row of the node named pod.node_name (-1 unknown)
+    named_holder: np.ndarray    # [P] bool: pod.node_name is its holder's name
     has_affinity: np.ndarray    # [P] bool (mirrors NodeInfo.pods_with_affinity)
     deleted: np.ndarray         # [P] bool
     ns_id: np.ndarray           # [P] i32
@@ -769,9 +837,9 @@ class PodTable:
     key_vocab: dict
     val_vocab: dict
     val_ints: np.ndarray        # [V] f64 parsed-integer value (NaN unparseable)
-    # victim columns (cached per node generation in the same blocks): the
-    # facts preemption reads about every snapshot pod, so a victim scan
-    # never re-derives them per pod
+    # victim columns (cached with the label rows): the facts preemption
+    # reads about every snapshot pod, so a victim scan never re-derives
+    # them per pod
     prio: np.ndarray = None          # [P] i64 pod priority
     start: np.ndarray = None         # [P] f64 start time (+inf when None)
     res_cpu: np.ndarray = None       # [P] i64 calculate_resource milli-CPU
@@ -780,6 +848,13 @@ class PodTable:
     has_scalar: np.ndarray = None    # [P] bool — extended resources requested
     has_aff_terms: np.ndarray = None  # [P] bool — any pod (anti-)affinity term
     has_ports: np.ndarray = None     # [P] bool — declares container ports
+
+
+# PodTable's per-pod columns, which NodeStateEncoder.pod_table carries from
+# one table to the next; the rest depend on the batch and the snapshot
+_PT_CACHED = ("ns_id", "deleted", "has_affinity", "named_holder", "key_ids",
+              "val_ids", "prio", "start", "res_cpu", "res_mem", "res_eph",
+              "has_scalar", "has_aff_terms", "has_ports")
 
 
 @dataclass

@@ -312,6 +312,74 @@ class TestEncoderVectorParity:
             assert got.tolist() == want.tolist(), pod.affinity
 
 
+def table_rows(t):
+    """A PodTable decoded through its vocabularies, one tuple per row."""
+    ns = {v: k for k, v in t.ns_vocab.items()}
+    keys = {v: k for k, v in t.key_vocab.items()}
+    vals = {v: k for k, v in t.val_vocab.items()}
+    rows = []
+    for i, pd in enumerate(t.pods):
+        labels = {keys[k]: vals[v]
+                  for k, v in zip(t.key_ids[i].tolist(),
+                                  t.val_ids[i].tolist()) if k >= 0}
+        rows.append((id(pd), int(t.holder_row[i]), bool(t.holder_has_obj[i]),
+                     int(t.name_row[i]), ns[int(t.ns_id[i])],
+                     bool(t.deleted[i]), bool(t.has_affinity[i]), labels,
+                     int(t.prio[i]), float(t.start[i]), int(t.res_cpu[i]),
+                     int(t.res_mem[i]), int(t.res_eph[i]),
+                     bool(t.has_scalar[i]), bool(t.has_aff_terms[i]),
+                     bool(t.has_ports[i])))
+    return rows
+
+
+def plain_rows(infos, b):
+    """The same tuples, row by row from the snapshot: the loop the
+    columnar build replaced, kept as its reference."""
+    from kubernetes_tpu.api.types import (
+        get_container_ports, has_pod_affinity_terms)
+    from kubernetes_tpu.cache.node_info import calculate_resource
+    rows = []
+    for name, ni in infos.items():
+        aff = set(map(id, ni.pods_with_affinity))
+        for pd in ni.pods:
+            r = calculate_resource(pd)
+            rows.append((
+                id(pd), b.index.get(name, -1), ni.node is not None,
+                b.index.get(pd.node_name, -1)
+                if pd.node_name in infos else -1,
+                pd.namespace, pd.deleted, id(pd) in aff, dict(pd.labels),
+                pd.priority,
+                pd.start_time if pd.start_time is not None else np.inf,
+                r.milli_cpu, r.memory, r.ephemeral_storage, bool(r.scalar),
+                has_pod_affinity_terms(pd), bool(get_container_ports(pd))))
+    return rows
+
+
+def rand_held_pod(rng, j, names):
+    """A resident pod that exercises every cached column."""
+    from kubernetes_tpu.api.types import (
+        Affinity, ContainerPort, PodAntiAffinity)
+    req = {"cpu": rng.choice([50, 100, 250]),
+           "memory": rng.choice([0, GI, 2 * GI])}
+    if rng.random() < 0.2:
+        req["example.com/gpu"] = 1
+    ports = (ContainerPort(host_port=8000 + j % 50),) \
+        if rng.random() < 0.2 else ()
+    p = rand_pod(rng, j)
+    p.containers = (Container.make(name="c", requests=req, ports=ports),)
+    p.priority = rng.choice([0, 0, 10, 1000])
+    p.start_time = rng.choice([None, 1.0, 2.5, float(j)])
+    p.deleted = rng.random() < 0.1
+    if rng.random() < 0.2:
+        p.affinity = Affinity(pod_anti_affinity=PodAntiAffinity(required=(
+            PodAffinityTerm(LabelSelector(match_labels=(("app", "web"),)),
+                            LABEL_HOSTNAME),)))
+    # mostly the holder's name; now and then another node's, or none
+    p.node_name = rng.choice(names + ["gone", ""]) \
+        if rng.random() < 0.15 else None
+    return p
+
+
 class TestPodTableCache:
     def test_generation_cache_reuses_blocks_and_tracks_changes(self):
         rng = random.Random(7)
@@ -347,6 +415,166 @@ class TestPodTableCache:
         for sel in ({"app": "web"}, {"tier": "db"}, {}):
             assert selector_match_mask(sel, ta).tolist() == \
                 selector_match_mask(sel, tb).tolist()
+
+    @pytest.mark.parametrize("seed", range(8))
+    def test_delta_table_equals_fresh_build(self, seed):
+        """Random adds, removes, replacements with changed labels,
+        deletion marks and nodes leaving and joining: after every step the
+        delta-kept table equals a from-scratch build_pod_table, and both
+        equal the plain row-by-row loop, field for field in row order."""
+        rng = random.Random(seed)
+        infos, names = {}, []
+        spare = [f"n{i}" for i in range(9)]
+
+        def join():
+            name = spare.pop(0)
+            node = Node(name=name, labels={LABEL_HOSTNAME: name},
+                        allocatable={"cpu": 64000, "memory": 64 * GI,
+                                     "pods": 110})
+            infos[name] = NodeInfo(None if rng.random() < 0.1 else node)
+            names.append(name)
+
+        def add(host, p):
+            if p.node_name is None:
+                p.node_name = host
+            infos[host].add_pod(p)
+
+        for _ in range(6):
+            join()
+        serial = iter(range(10 ** 6))
+        for _ in range(40):
+            add(rng.choice(names), rand_held_pod(rng, next(serial), names))
+        enc = NodeStateEncoder()
+        for step in range(40):
+            held = [(h, p) for h in names for p in infos[h].pods]
+            op = rng.choice(["add", "add", "remove", "relabel", "inplace",
+                             "delete", "leave", "join", "resnap", "none"])
+            if op == "add":
+                add(rng.choice(names),
+                    rand_held_pod(rng, next(serial), names))
+            elif op == "join" and spare:
+                join()
+                for _ in range(rng.randint(0, 3)):
+                    add(names[-1], rand_held_pod(rng, next(serial), names))
+            elif op == "leave" and len(names) > 2:
+                gone = names.pop(rng.randrange(len(names)))
+                del infos[gone]
+                spare.append(gone)
+            elif op == "resnap":
+                # what update_snapshot does to a changed node
+                h = rng.choice(names)
+                infos[h] = infos[h].clone()
+            elif held and op != "none":
+                h, p = rng.choice(held)
+                infos[h].remove_pod(p)
+                if op == "relabel":      # the store's way: a new object
+                    p = p.clone()
+                    p.labels = rand_labels(rng)
+                    p.labels["step"] = str(step)
+                elif op == "inplace":    # same object, then the re-add
+                    p.labels["step"] = str(step)
+                    p.priority += 1
+                elif op == "delete":
+                    p = p.clone()
+                    p.deleted = True
+                if op != "remove":
+                    infos[h].add_pod(p)
+            b = enc.encode(infos, names)
+            t = enc.pod_table(infos, b)
+            fresh = build_pod_table(infos, b)
+            want = plain_rows(infos, b)
+            assert table_rows(t) == want, (seed, step, op)
+            assert table_rows(fresh) == want, (seed, step, op)
+            assert t.key_ids.shape == fresh.key_ids.shape
+            assert enc.pod_table(infos, b) is t
+
+    def test_cost_follows_change(self):
+        """One pod added to one node of a snapshot: one row is extracted,
+        that node's other rows are reused, no other node is looked at; a
+        held pod mutated in place is seen once the cache re-adds it."""
+        from kubernetes_tpu.cache.cache import SchedulerCache, Snapshot
+        from kubernetes_tpu.ops.node_state import POD_TABLE_ROWS
+        rng = random.Random(11)
+        cache = SchedulerCache()
+        names = [f"n{i}" for i in range(12)]
+        for name in names:
+            cache.add_node(Node(name=name, labels={LABEL_HOSTNAME: name},
+                                allocatable={"cpu": 64000, "memory": 64 * GI,
+                                             "pods": 110}))
+        for j in range(60):
+            p = rand_pod(rng, j)
+            p.node_name = names[j % len(names)]
+            cache.add_pod(p)
+        snap = Snapshot()
+        enc = NodeStateEncoder()
+
+        def table():
+            cache.update_snapshot(snap)
+            b = enc.encode(snap.node_infos, names)
+            before = {r: POD_TABLE_ROWS.labels(r).value
+                      for r in ("extracted", "reused")}
+            t = enc.pod_table(snap.node_infos, b)
+            assert table_rows(t) == plain_rows(snap.node_infos, b)
+            return t, {r: POD_TABLE_ROWS.labels(r).value - v
+                       for r, v in before.items()}
+
+        _, moved = table()
+        assert moved == {"extracted": 60, "reused": 0}
+        extra = rand_pod(rng, 99)
+        extra.node_name = "n3"
+        cache.add_pod(extra)
+        others = len(snap.node_infos["n3"].pods)
+        _, moved = table()
+        assert moved == {"extracted": 1, "reused": others}
+        # bound and gone again, as a rollout's pods are between two tables:
+        # the node's generation moved twice and nothing is derived
+        cache.remove_pod(extra)
+        _, moved = table()
+        assert moved == {"extracted": 0, "reused": others}
+        victim = snap.node_infos["n5"].pods[0]
+        victim.labels["mutated"] = "in-place"
+        cache.update_pod(victim, victim)
+        t, moved = table()
+        assert moved == {"extracted": 1,
+                         "reused": len(snap.node_infos["n5"].pods) - 1}
+        m = selector_match_mask({"mutated": "in-place"}, t)
+        assert [t.pods[i] for i in np.flatnonzero(m)] == [victim]
+        _, moved = table()
+        assert moved == {"extracted": 0, "reused": 0}
+
+    def test_victim_table_after_delta_equals_fresh(self):
+        from kubernetes_tpu.api.types import PodDisruptionBudget
+        rng = random.Random(12)
+        infos, names = rand_snapshot(rng, n_nodes=6, n_pods=0)
+        for j in range(50):
+            host = rng.choice(names)
+            p = rand_held_pod(rng, j, names)
+            p.node_name = host
+            infos[host].add_pod(p)
+        pdbs = [PodDisruptionBudget(
+            name="b", namespace="default", disruptions_allowed=0,
+            selector=LabelSelector(match_labels=(("app", "web"),)))]
+        enc = NodeStateEncoder()
+        enc.victim_table(infos, enc.encode(infos, names), pdbs)
+        for j in range(50, 70):
+            host = rng.choice(names)
+            if infos[host].pods and rng.random() < 0.5:
+                infos[host].remove_pod(rng.choice(infos[host].pods))
+            p = rand_held_pod(rng, j, names)
+            p.node_name = host
+            infos[host].add_pod(p)
+        b = enc.encode(infos, names)
+        got = enc.victim_table(infos, b, pdbs)
+        fresh_enc = NodeStateEncoder()
+        want = fresh_enc.victim_table(
+            infos, fresh_enc.encode(infos, names), pdbs)
+        assert got.P == want.P
+        for f in ("cpu", "mem", "eph", "prio", "start", "valid", "viol",
+                  "aff", "ports", "scalar", "count", "overflow"):
+            assert np.array_equal(getattr(got, f), getattr(want, f)), f
+        for name in names:
+            assert [id(p) for p in got.slots[name]] == \
+                [id(p) for p in want.slots[name]], name
 
 
 class TestPermutedReencode:
