@@ -116,6 +116,24 @@ SCAN_ORDER_STEPS = obs.counter(
     "step) or 'gather' (a rotating tree under a truncated walk: the step "
     "permutes its masks through perms/inv_perms). Booked once a launch, "
     "beside tpu_scan_steps_total.", ("order",))
+SCAN_POD_ROWS = obs.counter(
+    "tpu_scan_pod_rows_total",
+    "Pods of schedule_burst's generic scan launches, by how a launch's "
+    "[B] pod operand was made: 'stacked' (the launch held pods of more "
+    "than one signature, so _stack_pods stacked rows taken from "
+    "per-signature arrays) or 'shared' (every pod of the launch was one "
+    "object, broadcast). Booked once a launch, beside "
+    "tpu_scan_steps_total.", ("rows",))
+PICK_TIED_NODES = obs.counter(
+    "tpu_pick_tied_nodes_total",
+    "Nodes that tied for the best score (selectHost's round-robin set), "
+    "summed over every decision of schedule_burst's generic scan launches; "
+    "read from the packed block the launch fetches anyway.")
+FILTER_REJECTED_NODES = obs.counter(
+    "tpu_filter_rejected_nodes_total",
+    "Nodes the filter's walk tested that did not fit (upstream's evaluated "
+    "minus found), summed over every decision of schedule_burst's generic "
+    "scan launches; read from the packed block the launch fetches anyway.")
 DISCARDED_FOLDS = obs.counter(
     "tpu_burst_folds_discarded_total",
     "Device-resident burst folds dropped after a mid-burst failure.")
@@ -1682,9 +1700,10 @@ class TPUScheduler:
         shape (so the warmup burst compiles the same program) and whose
         trip count is `len(pods)`, a dynamic operand: the pad rows give
         the operands their shape and are never stepped over. The host
-        fetches ONE packed [3B] block — selections plus the per-pod walk
-        counters, rows from `len(pods)` on a fixed fill. Commit then
-        consumes the block wave-by-wave.
+        fetches ONE packed [5B] block — selections, the per-pod walk
+        counters and two words a pod for the tie and rejection counters,
+        rows from `len(pods)` on a fixed fill. Commit then consumes the
+        block wave-by-wave.
 
         Rewind contract, re-derived from slices of the single block: the
         scan keeps deciding after a failed pod, so everything from the
@@ -1697,7 +1716,8 @@ class TPUScheduler:
         B = bucket
         n_pods = len(pods)
         W = max(1, min(int(self.wave_size), B))
-        with obs_trace.span("burst.stack"):
+        signatures = len({id(pp) for pp in per_pod})
+        with obs_trace.span("burst.stack", signatures=signatures):
             wave = list(per_pod)
             if len(wave) < B:
                 pad = dict(wave[-1])
@@ -1719,6 +1739,8 @@ class TPUScheduler:
                 n_pods=n_pods)
             DEVICE_DISPATCH.labels("burst_scan").inc()
             SCAN_STEPS.labels("real").inc(n_pods)
+            SCAN_POD_ROWS.labels(
+                "stacked" if signatures > 1 else "shared").inc(n_pods)
             SCAN_ORDER_STEPS.labels(
                 "gather" if rotation is not None else
                 "position" if rotation_pos is not None else "axis"
@@ -1760,6 +1782,9 @@ class TPUScheduler:
                 int(np.where(moved == 0, n, moved).sum()))
         else:
             WALK_NODES.labels("full").inc(n_pods * n)
+        PICK_TIED_NODES.inc(int(h[3 * B:3 * B + n_pods].sum(dtype=np.int64)))
+        FILTER_REJECTED_NODES.inc(
+            int(h[4 * B:4 * B + n_pods].sum(dtype=np.int64)))
         neg = sel_arr < 0
         bad = int(np.argmax(neg)) if neg.any() else n_pods
         committed = bad

@@ -582,6 +582,8 @@ def _cycle_core(nodes, pod, last_index, last_node_index, num_to_find, n_real,
         "found": found.astype(jnp.int64),
         "evaluated": evaluated,
         "max_score": jnp.where(found > 0, max_score, 0),
+        # how wide the top band was: the nodes selectHost chose among
+        "num_ties": jnp.where(found > 0, num_ties, 0),
         "total": total,
         "kept": kept,
         "feasible": feasible,
@@ -732,23 +734,27 @@ def _batch_core(nodes, mut0, pods, n_pods, last_index, last_node_index,
         li, lni = out["next_last_index"], out["next_last_node_index"]
         packed = packed.at[:, i].set(jnp.stack([
             sel.astype(i32), li.astype(i32),
-            (lni - last_node_index).astype(i32)]))
+            (lni - last_node_index).astype(i32),
+            out["num_ties"].astype(i32),
+            (out["evaluated"] - out["found"]).astype(i32)]))
         aux = aux.at[:, i].set(jnp.stack([
             out["found"], out["evaluated"], out["max_score"], lni]))
         return new_state, li, lni, spread, packed, aux
 
     init = (constrain(mut0), last_index, last_node_index, constrain(spread0),
-            jnp.full((3, B), -1, i32), jnp.zeros((4, B), jnp.int64))
+            jnp.full((5, B), -1, i32), jnp.zeros((4, B), jnp.int64))
     state, li, lni, spread, packed, aux = jax.lax.fori_loop(
         jnp.int32(0), jnp.asarray(n_pods, i32), body, init)
-    # ONE packed fetch block [3B] i32: selections, then the walk counters
+    # ONE packed fetch block [5B] i32: selections, then the walk counters
     # AFTER each pod (li absolute — it is < n; lni as a delta from the
     # launch's start so it fits i32) — a mid-burst failure's prefix rewind
     # reads the counters straight out of the single fetched block instead
-    # of paying a second round trip for the evaluated/found vectors
+    # of paying a second round trip for the evaluated/found vectors — then
+    # two words a pod for the host's counters: the nodes that tied for the
+    # best score, and the nodes the walk tested that did not fit
     outs = {"selected": packed[0].astype(jnp.int64), "li_after": packed[1],
             "found": aux[0], "evaluated": aux[1], "max_score": aux[2],
-            "lni_after": aux[3], "packed": packed.reshape(3 * B)}
+            "lni_after": aux[3], "packed": packed.reshape(5 * B)}
     return state, li, lni, spread, outs
 
 
@@ -806,7 +812,8 @@ def schedule_batch(nodes, pods, last_index, last_node_index, num_to_find, n_real
     spread its carried count vector. `last_index`/`last_node_index` may
     likewise be the prior launch's device scalars. Returns
     (state, li, lni, spread, outs); outs["packed"] is the ONE-fetch block
-    [3B] i32 — selected | li-after-each-pod | lni-delta-after-each-pod —
+    [5B] i32 — selected | li-after-each-pod | lni-delta-after-each-pod |
+    tied-nodes | tested-nodes-that-did-not-fit —
     so a caller fetches a single array per launch and re-derives any
     failure-prefix rewind from slices of it.
 

@@ -20,12 +20,15 @@ ROLLOUT = "density-5000n-150k.rollout-1k"
 ARRIVALS = "headline-15000n.arrivals-steady"
 ADAPTIVE = "headline-15000n-adaptive.backlog-10k"
 DENSITY_ADAPTIVE = "density-5000n-150k-adaptive.rollout-1k"
+MIXED = "inuse-15000n-135k.backlog-10k-mixed"
 
 # (config overlay, traffic overlay): the sizes benchmark/tests rehearses at.
 # The adaptive cell needs more than 100 nodes for the walk to be cut short
 # (num_to_find = 117 of 240), and so does the density cell at the default
 # percentage, on zones of 84/83/83 so that the NodeTree's order rotates
-# (num_to_find = 120 of 250).
+# (num_to_find = 120 of 250). The mixed cell keeps its even zones (240 = 3 x
+# 80: the scan walks the device axis, as at 15,000) and a backlog whose dirty
+# rows stay in one scatter bucket (at most 120, never 64 or fewer).
 AT_50 = {"nodes": {"count": 50},
          "check": {"first_binds": 200, "sampled_binds": 60}}
 SMALL = {
@@ -41,6 +44,10 @@ SMALL = {
                         "resident": {"pods_per_node": 6, "services": 5},
                         "check": {"first_binds": 200, "sampled_binds": 100}},
                        {"warm_binds": 0, "backlog": 150}),
+    MIXED: ({"nodes": {"count": 240},
+             "resident": {"pods_per_node": 3, "services": 5},
+             "check": {"first_binds": 200, "sampled_binds": 100}},
+            {"warm_binds": 0, "backlog": 120}),
 }
 # the control of an adaptive cell: the program scores every node while the
 # reference judges at the file's default percentage
@@ -91,8 +98,10 @@ def altered_binding(sched, store):
     # truncated walk + rotation by gather + carried spread counts
     (DENSITY_ADAPTIVE, 2**31 + 23, None, None),
     (DENSITY_ADAPTIVE, 2**31 + 23, None, EVERY_NODE),   # its control
+    # eight pod sizes onto nodes that hold pods: stacked rows, uneven board
+    (MIXED, 2**31 + 41, None, None),
 ], ids=["backlog", "rollout", "arrivals", "adaptive", "altered-binding",
-        "density-adaptive", "density-adaptive-control"])
+        "density-adaptive", "density-adaptive-control", "mixed"])
 def test_rehearsed_cell(execute, cell, seed, hook, program):
     out = rehearse(execute, cell, seed, hook, program)
     res, rep = out["result"], out["report"]
@@ -112,6 +121,11 @@ def test_rehearsed_cell(execute, cell, seed, hook, program):
         # even zones: no order is shipped
         assert moved["tpu_scan_order_steps_total"] == \
             {"axis": res["attempted"]}
+        # one pod copied: its row is one object, broadcast; every node fits
+        assert moved["tpu_scan_pod_rows_total"] == \
+            {"shared": res["attempted"]}
+        assert "tpu_filter_rejected_nodes_total" not in moved
+        assert moved["tpu_pick_tied_nodes_total"][""] >= res["attempted"]
     if cell == DENSITY_ADAPTIVE:
         moved = rep["counters"]
         assert "burst_uniform" not in moved["tpu_device_dispatch_total"]
@@ -122,6 +136,19 @@ def test_rehearsed_cell(execute, cell, seed, hook, program):
         # a walk stops at its quota: 120 of 250 nodes, none of them full
         assert moved["tpu_walk_nodes_evaluated_total"] == \
             {"truncated": 120 * res["attempted"]}
+    if cell == MIXED:
+        moved = rep["counters"]
+        # unlike plain pods share one segment, and it goes to the scan
+        assert "burst_uniform" not in moved["tpu_device_dispatch_total"]
+        assert moved["tpu_scan_order_steps_total"] == \
+            {"axis": res["attempted"]}
+        # every launch held more than one signature: its rows were stacked
+        assert moved["tpu_scan_pod_rows_total"] == \
+            {"stacked": res["attempted"]}
+        # the filter said no, and the board is not one tie over every node
+        assert moved["tpu_filter_rejected_nodes_total"][""] > 0
+        tied = moved["tpu_pick_tied_nodes_total"][""] / res["attempted"]
+        assert 1 <= tied < 0.9 * 240
 
 
 with open(os.path.join(os.path.dirname(BENCH_DIR), "BENCHMARK.json")) as _f:

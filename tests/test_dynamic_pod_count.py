@@ -83,7 +83,8 @@ def _rotated_cycle(nodes, pod, li, lni, ntf, n_real, perm, inv_perm, gather):
     out = K._cycle_core(nodes, pod, li, lni, ntf, n_real,
                         dict(K.DEFAULT_WEIGHTS), Z_PAD, **kw)
     return {k: out[k] for k in ("selected", "next_last_index",
-                                "next_last_node_index")}
+                                "next_last_node_index", "num_ties",
+                                "found", "evaluated")}
 
 
 def _serial(node_arrays, per_pod, batch, kw, ntf, li, lni):
@@ -97,6 +98,7 @@ def _serial(node_arrays, per_pod, batch, kw, ntf, li, lni):
         inv_perms, seq = kw["rotation_pos"]
         perms = inv_perms                    # not read in position mode
     lni0, sel, li_after, lni_delta = lni, [], [], []
+    tied, rejected = [], []
     i64 = partial(np.asarray, dtype=np.int64)
     for t, pod in enumerate(per_pod):
         if spread is not None:
@@ -113,6 +115,8 @@ def _serial(node_arrays, per_pod, batch, kw, ntf, li, lni):
         sel.append(s)
         li_after.append(li)
         lni_delta.append(lni - lni0)
+        tied.append(int(out["num_ties"]))
+        rejected.append(int(out["evaluated"]) - int(out["found"]))
         if s < 0:
             continue
         for key, upd in (("req_cpu", "upd_cpu"), ("req_mem", "upd_mem"),
@@ -122,7 +126,7 @@ def _serial(node_arrays, per_pod, batch, kw, ntf, li, lni):
         nodes["pod_count"][s] += 1
         if spread is not None:
             spread[s] += 1
-    return sel, li_after, lni_delta, nodes, li, lni, spread
+    return (sel, li_after, lni_delta, tied, rejected), nodes, li, lni, spread
 
 
 def _carry(ret):
@@ -148,11 +152,11 @@ def test_a_launch_runs_its_pods_not_its_bucket(world, mesh, mode, n_pods,
                 if k in ("rotation", "rotation_pos")}
     exact = launch(pods=_stack(per_pod[:n_pods]), **exact_kw)
 
-    block = np.asarray(dyn[4]["packed"]).reshape(3, bucket)
+    block = np.asarray(dyn[4]["packed"]).reshape(5, bucket)
     assert (block[:, n_pods:] == -1).all()            # the fixed fill
     np.testing.assert_array_equal(
         block[:, :n_pods],
-        np.asarray(exact[4]["packed"]).reshape(3, n_pods))
+        np.asarray(exact[4]["packed"]).reshape(5, n_pods))
     for key in ("selected", "found", "evaluated", "max_score", "lni_after"):
         np.testing.assert_array_equal(
             np.asarray(dyn[4][key])[:n_pods], np.asarray(exact[4][key]),
@@ -164,10 +168,14 @@ def test_a_launch_runs_its_pods_not_its_bucket(world, mesh, mode, n_pods,
     for key in K._MUTABLE:
         np.testing.assert_array_equal(state[key], state_x[key], err_msg=key)
 
-    sel, li_after, lni_delta, nodes, li_s, lni_s, spread_s = _serial(
+    rows, nodes, li_s, lni_s, spread_s = _serial(
         node_arrays, per_pod[:n_pods], batch, kw, ntf, li0, lni0)
-    np.testing.assert_array_equal(block[:, :n_pods],
-                                  [sel, li_after, lni_delta])
+    sel, li_after, _lni_delta, tied, rejected = rows
+    # selected | li after | lni delta | tied nodes | tested and unfit
+    np.testing.assert_array_equal(block[:, :n_pods], rows)
+    assert all(t >= 1 for s, t in zip(sel, tied) if s >= 0)
+    assert all(t == 0 and r > 0 for s, t, r in zip(sel, tied, rejected)
+               if s < 0)
     assert (li, lni) == (li_s, lni_s)
     for key in K._MUTABLE:
         np.testing.assert_array_equal(state[key], nodes[key], err_msg=key)
@@ -189,7 +197,7 @@ def test_two_pod_counts_in_one_bucket_cost_one_compile(world):
     def launch(n_pods):
         out = K.schedule_batch(node_arrays, pods, 0, 0, 10, batch.n_real,
                                Z_PAD, n_pods=n_pods)
-        return np.asarray(out[4]["packed"]).reshape(3, 64)
+        return np.asarray(out[4]["packed"]).reshape(5, 64)
 
     before = compiles()
     first = launch(40)
