@@ -116,6 +116,16 @@ SCAN_ORDER_STEPS = obs.counter(
     "step) or 'gather' (a rotating tree under a truncated walk: the step "
     "permutes its masks through perms/inv_perms). Booked once a launch, "
     "beside tpu_scan_steps_total.", ("order",))
+SCAN_SCORE_STEPS = obs.counter(
+    "tpu_scan_score_steps_total",
+    "Steps of schedule_burst's generic scan launches, by how the row-local "
+    "resource scores (least/most requested, RTCR, balanced) reached a "
+    "step: 'carried' (the launch carries a score board of its pods' "
+    "classes and a step rescores the one row it bound) or 'full' (every "
+    "row rescored every step: a per-pod weight row, more classes than "
+    "kernels.SCORE_CLASS_CAP, or fewer node rows a device than "
+    "kernels.SCORE_BOARD_MIN_ROWS). Booked once a launch, beside "
+    "tpu_scan_steps_total.", ("scores",))
 SCAN_POD_ROWS = obs.counter(
     "tpu_scan_pod_rows_total",
     "Pods of schedule_burst's generic scan launches, by how a launch's "
@@ -1724,11 +1734,21 @@ class TPUScheduler:
                 pad["skip"] = self._true
                 wave.extend([pad] * (B - len(wave)))
             stacked = self._stack_pods(wave)
+            # a per-pod weight row (tensor mode) makes the row-local scores
+            # per profile, and over few node rows a device the score board
+            # costs more than it saves: those launches rescore every row
+            # each step
+            tensor = self._ptab is not None
+            shards = 1 if self.mesh is None else self.mesh.size
+            carried = not tensor and \
+                b.n_pad // shards >= K.SCORE_BOARD_MIN_ROWS
+            classes = K.score_classes(
+                stacked["nz_cpu"], stacked["nz_mem"], n_pods) \
+                if carried else None
         ph.open("kernel")
         t_d = obs_trace.now()
         try:
             chaos.check("device.dispatch")
-            tensor = self._ptab is not None
             state, _li_out, _lni_out, _spread, outs = K.schedule_batch(
                 self._dev_nodes, stacked, self.last_index,
                 self.last_node_index, num_to_find, n, z_pad,
@@ -1736,9 +1756,11 @@ class TPUScheduler:
                 rotation=rotation, spread0=spread0,
                 rotation_pos=rotation_pos,
                 mesh=self.mesh, wtab=self._wtab() if tensor else None,
-                n_pods=n_pods)
+                n_pods=n_pods, classes=classes)
             DEVICE_DISPATCH.labels("burst_scan").inc()
             SCAN_STEPS.labels("real").inc(n_pods)
+            SCAN_SCORE_STEPS.labels(
+                "full" if classes is None else "carried").inc(n_pods)
             SCAN_POD_ROWS.labels(
                 "stacked" if signatures > 1 else "shared").inc(n_pods)
             SCAN_ORDER_STEPS.labels(

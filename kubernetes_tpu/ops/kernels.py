@@ -26,7 +26,16 @@ one snapshot, folding each decision's resource deltas into the node state on
 device — serially-equivalent decisions at one kernel launch for the burst.
 The burst's operands are padded to a bucket for one compile per bucket; the
 pod count is a dynamic trip count, so the launch runs as many steps as it was
-given pods.
+given pods. Its carry holds, beside the mutable node rows, the walk counters
+and the spread counts, the SCORE BOARD: the four row-local resource
+priorities (`_local_total`) of each class of pod in the launch against every
+node, [S_pad, n_pad]. A step moves one node row, so it reads its pod's row of
+the board and rescores one column, where it used to divide over every node.
+The rule that chooses is in the operands: the board when the launch has no
+per-pod weight row, at most SCORE_CLASS_CAP classes (`score_classes`) and at
+least SCORE_BOARD_MIN_ROWS node rows a device (below that, dividing over every
+row is the cheaper step), every row every step otherwise; both are
+`_schedule_batch_jit`, one name in a trace and in the compile counters.
 
 Every core names its stages with `jax.named_scope`, in upstream's words:
 `filter` (feasibility and the adaptive walk; for preemption, the victim
@@ -241,7 +250,8 @@ def _local_total(weights, req_cpu, req_mem, alloc_cpu, alloc_mem,
 
 
 @jax.named_scope("score")
-def _fit_scores(nodes, pod, kept, weights, z_pad, wrow=None, gang=None):
+def _fit_scores(nodes, pod, kept, weights, z_pad, wrow=None, gang=None,
+                local=None):
     """Enabled priorities, masked-normalized over `kept`. Returns total[N] i64.
 
     Zero-weight priorities and inert (default-valued, shape-[1]) pod fields
@@ -255,14 +265,19 @@ def _fit_scores(nodes, pod, kept, weights, z_pad, wrow=None, gang=None):
     gang set-scoring input: gz counts THIS segment's already-placed
     members per zone, and nodes score min(count, 10) * gang weight — the
     group objective that prefers packing a gang into few zones, via the
-    same one-hot zone reduction the spread family uses."""
-    alloc_cpu, alloc_mem = nodes["alloc_cpu"], nodes["alloc_mem"]
-    req_cpu = pod["nz_cpu"] + nodes["nz_cpu"]
-    req_mem = pod["nz_mem"] + nodes["nz_mem"]
+    same one-hot zone reduction the spread family uses.
+
+    `local` [N] i64 (optional) is `_local_total` of this pod against these
+    rows, already computed: the generic scan carries it (`_batch_core`'s
+    score board) and every other caller passes none and computes it here."""
+    if local is None:
+        local = _local_total(
+            weights, pod["nz_cpu"] + nodes["nz_cpu"],
+            pod["nz_mem"] + nodes["nz_mem"], nodes["alloc_cpu"],
+            nodes["alloc_mem"], wrow=wrow)
 
     const = 0   # python-int accumulator for provably-constant scores
-    total = jnp.zeros(nodes["valid"].shape, dtype=jnp.int64) + _local_total(
-        weights, req_cpu, req_mem, alloc_cpu, alloc_mem, wrow=wrow)
+    total = jnp.zeros(nodes["valid"].shape, dtype=jnp.int64) + local
 
     if gang is not None and weights.get("gang_locality"):
         # gang-locality (rank-aware set-scoring): zone member counts of the
@@ -457,7 +472,7 @@ def _reorder(x, order):
 
 def _cycle_core(nodes, pod, last_index, last_node_index, num_to_find, n_real,
                 weights, z_pad, perm=None, inv_perm=None, pos=None,
-                ghost=None, wtab=None, gang=None):
+                ghost=None, wtab=None, gang=None, local=None):
     """One fused cycle. The reference's sequential walk from last_index
     (generic_scheduler.go:486,519) is emulated WITHOUT materializing the
     rotation permutation: for natural index j, its 1-based rank in rotation
@@ -486,7 +501,8 @@ def _cycle_core(nodes, pod, last_index, last_node_index, num_to_find, n_real,
     table; this pod's row is gathered by `pod["profile_id"]` and every
     score family scales by its lane (the static `weights` dict gates
     which families compile in — the cross-profile union). `gang` threads
-    the rank-aware gang set-scoring input into _fit_scores."""
+    the rank-aware gang set-scoring input into _fit_scores, `local` the
+    generic scan's carried row-local scores."""
     n_pad = nodes["valid"].shape[0]
     i32 = jnp.int32
     i = jnp.arange(n_pad, dtype=i32)
@@ -545,7 +561,7 @@ def _cycle_core(nodes, pod, last_index, last_node_index, num_to_find, n_real,
 
     wrow = None if wtab is None else wtab[pod["profile_id"]]
     total = _fit_scores(nodes, pod, kept, weights, z_pad, wrow=wrow,
-                        gang=gang)
+                        gang=gang, local=local)
 
     with jax.named_scope("pick"):
         tmask = jnp.where(kept, total, jnp.iinfo(jnp.int64).min)
@@ -673,10 +689,56 @@ def _fold_state(state, pod, sel, hit):
     }
 
 
+# The generic scan carries the row-local scores of at most this many pod
+# classes, a class being a distinct (nz_cpu, nz_mem) pair among a launch's
+# pods. The board is [S_pad, n_pad] int64 in the loop's carry (16 classes at
+# 16,384 rows: 2 MB). On a TPU v5e at 16,384 rows (PERF.md, PR 35) a step's
+# one-column rescore costs about 40 us whether it holds 1 class or 8 (a chain
+# of some 300 small dependent operations: latency, not width), and reading a
+# pod's row costs about 1 us a class (8 us at 8); `_local_total` over every
+# row costs 68. So the board wins by 27 us a step at one class and would stop
+# winning near 28 classes; 16 keeps a margin, bounds the board's build at a
+# launch's head (one `_local_total` over [S_pad, n_pad]) and the programs a
+# process can compile (one per power of two: 1, 2, 4, 8, 16). A launch of
+# more classes runs `_local_total` over every row, every step.
+SCORE_CLASS_CAP = 16
+
+# ... and only over at least this many node rows a device. The one-column
+# rescore's 40 us do not shrink with the cluster; `_local_total` over every
+# row does: 68 us at 16,384 rows, 37 at 8192. Measured on a TPU v5e (PERF.md,
+# PR 35): at 16,384 rows the board takes 28-30 us off a step, at 8192 it adds
+# 4 (125.9 against 121.9 us, and 1% of `pods_per_s` lost). Nothing between
+# the two was measured; the bound is the size that was seen to win.
+SCORE_BOARD_MIN_ROWS = 16384
+
+
+def score_classes(nz_cpu, nz_mem, n_pods):
+    """The score classes of a launch, made on the host from its stacked
+    [B] `nz_cpu` / `nz_mem` pod columns: `(cls[B] int32, tab[S_pad, 2]
+    int64)` with `tab[cls[i]] == (nz_cpu[i], nz_mem[i])` for every real pod
+    (rows from `n_pods` on take class 0: they are never stepped over), or
+    None when the launch holds more than SCORE_CLASS_CAP classes and has to
+    rescore every row each step. S_pad is the class count rounded up to a
+    power of two (the spare rows repeat class 0), so a stream of launches
+    of about as many classes compiles one program."""
+    cls = np.zeros(len(nz_cpu), np.int32)
+    pairs = np.stack([np.asarray(nz_cpu, np.int64)[:n_pods],
+                      np.asarray(nz_mem, np.int64)[:n_pods]], axis=1)
+    if not len(pairs):
+        return cls, np.zeros((1, 2), np.int64)
+    tab, inverse = np.unique(pairs, axis=0, return_inverse=True)
+    if len(tab) > SCORE_CLASS_CAP:
+        return None
+    cls[:n_pods] = inverse.reshape(-1)
+    spare = (1 << (len(tab) - 1).bit_length()) - len(tab)
+    return cls, np.concatenate([tab, np.repeat(tab[:1], spare, axis=0)])
+
+
 def _batch_core(nodes, mut0, pods, n_pods, last_index, last_node_index,
                 num_to_find, n_real, perms, inv_perms, oid_seq,
                 spread0, z_pad, weights, rotate, carry_spread,
-                rotate_pos=False, constrain=None, wtab=None):
+                rotate_pos=False, constrain=None, wtab=None,
+                score_tab=None):
     """Body of the generic burst kernel: one serial cycle per pod, each
     folding its decision into the carried node state.
 
@@ -695,9 +757,29 @@ def _batch_core(nodes, mut0, pods, n_pods, last_index, last_node_index,
     below compiles). `wtab` (tensor mode) makes the loop profile-aware:
     `pods["profile_id"]` [B] rides the operands, and each step's cycle
     gathers that pod's weight row — a window MIXING tenants scores in the
-    one launch."""
+    one launch.
+
+    `score_tab` [S_pad, 2] int64 (optional; `score_classes` makes it, with
+    `pods["score_class"]` [B]) puts the SCORE BOARD into the carry:
+    board[s, j] = `_local_total` of a pod of class s (its nz_cpu, nz_mem)
+    against node row j. Those four priorities are row-local and a step
+    moves one row (`_fold_state` adds the bound pod to row `sel`), so the
+    board is built once at the launch's head, a step reads its pod's row
+    of it in place of `_local_total` over every node, and after the fold
+    rescores column `sel` alone for all S_pad classes — with the same
+    function, so board and full recompute are the same int64 by
+    construction; a step that bound nothing rescores an unmoved row and
+    writes back what was there. None = `_local_total` over [N] every step,
+    the program of every launch before the board. The caller chooses from
+    the launch's operands: the board when there is no `wtab` (a per-pod
+    weight row would make the board per profile), the launch holds at most
+    SCORE_CLASS_CAP classes and a device holds at least
+    SCORE_BOARD_MIN_ROWS node rows. Both are the one jitted function
+    `_schedule_batch_jit` (None is an empty pytree), so a device trace and
+    `tpu_compiles_total` keep finding the scan under the name they know."""
     if constrain is None:
         constrain = lambda v: v
+    assert score_tab is None or wtab is None
     i32 = jnp.int32
     static = {k: v for k, v in nodes.items() if k not in _MUTABLE}
     # selector-spread counts evolve with in-burst placements: the caller
@@ -708,10 +790,41 @@ def _batch_core(nodes, mut0, pods, n_pods, last_index, last_node_index,
         pods = {k: v for k, v in pods.items() if k != "spread_counts"}
     B = pods["skip"].shape[0]
 
+    @jax.named_scope("score")
+    def class_scores(rows, j=None):
+        """`_local_total` of every class against the node rows `rows`: the
+        whole board [S_pad, N], or column `j` of it [S_pad]. One function
+        for the build and for a step's rescore."""
+        cls_cpu, cls_mem = score_tab[:, 0], score_tab[:, 1]
+        if j is None:
+            cls_cpu, cls_mem = cls_cpu[:, None], cls_mem[:, None]
+        at = (lambda v: v) if j is None else (lambda v: v[j])
+        return _local_total(
+            weights, cls_cpu + at(rows["nz_cpu"]), cls_mem + at(rows["nz_mem"]),
+            at(static["alloc_cpu"]), at(static["alloc_mem"]))
+
+    def constrain_board(board):
+        # the node axis is the board's LAST (a step reads a contiguous
+        # row); `constrain` pins node-axis-first trees
+        return constrain(board.T).T
+
     def body(i, carry):
-        state, li, lni, spread, packed, aux = carry
+        state, li, lni, spread, board, packed, aux = carry
         pod = {k: jax.lax.dynamic_index_in_dim(v, i, keepdims=False)
                for k, v in pods.items()}
+        local = None
+        if score_tab is not None:
+            with jax.named_scope("score"):
+                # the pod's row of the board, read as a masked sum over the
+                # class axis: a dynamic slice along it costs a TPU v5e 16 us
+                # at 8 classes x 16,384 rows, this 8, and with one class the
+                # mask alone 2 (PERF.md, PR 35)
+                if len(score_tab) == 1:
+                    local = board[0]
+                else:
+                    mine = jnp.arange(len(score_tab)) == pod["score_class"]
+                    local = jnp.sum(jnp.where(mine[:, None], board, 0),
+                                    axis=0)
         perm = inv_perm = pos = None
         if rotate_pos:
             # gather-free rotation: perms holds per-order POSITION vectors
@@ -724,13 +837,17 @@ def _batch_core(nodes, mut0, pods, n_pods, last_index, last_node_index,
         full = {**static, **state}
         out = _cycle_core(full, pod, li, lni, num_to_find, n_real, weights,
                           z_pad, perm=perm, inv_perm=inv_perm, pos=pos,
-                          wtab=wtab)
+                          wtab=wtab, local=local)
         sel = out["selected"]
         hit = out["found"] > 0
         new_state = constrain(_fold_state(state, pod, sel, hit))
         if carry_spread:
             spread = constrain(spread.at[jnp.maximum(sel, 0)].add(
                 jnp.where(hit & ~pod["skip"], 1, 0)))
+        if score_tab is not None:
+            idx = jnp.maximum(sel, 0)
+            board = constrain_board(
+                board.at[:, idx].set(class_scores(new_state, idx)))
         li, lni = out["next_last_index"], out["next_last_node_index"]
         packed = packed.at[:, i].set(jnp.stack([
             sel.astype(i32), li.astype(i32),
@@ -739,11 +856,13 @@ def _batch_core(nodes, mut0, pods, n_pods, last_index, last_node_index,
             (out["evaluated"] - out["found"]).astype(i32)]))
         aux = aux.at[:, i].set(jnp.stack([
             out["found"], out["evaluated"], out["max_score"], lni]))
-        return new_state, li, lni, spread, packed, aux
+        return new_state, li, lni, spread, board, packed, aux
 
+    board0 = None if score_tab is None \
+        else constrain_board(class_scores(mut0))
     init = (constrain(mut0), last_index, last_node_index, constrain(spread0),
-            jnp.full((5, B), -1, i32), jnp.zeros((4, B), jnp.int64))
-    state, li, lni, spread, packed, aux = jax.lax.fori_loop(
+            board0, jnp.full((5, B), -1, i32), jnp.zeros((4, B), jnp.int64))
+    state, li, lni, spread, _board, packed, aux = jax.lax.fori_loop(
         jnp.int32(0), jnp.asarray(n_pods, i32), body, init)
     # ONE packed fetch block [5B] i32: selections, then the walk counters
     # AFTER each pod (li absolute — it is < n; lni as a delta from the
@@ -762,13 +881,14 @@ def _batch_core(nodes, mut0, pods, n_pods, last_index, last_node_index,
                                    "carry_spread", "rotate_pos"))
 def _schedule_batch_jit(nodes, mut0, pods, n_pods, last_index,
                         last_node_index, num_to_find, n_real, perms,
-                        inv_perms, oid_seq, spread0, z_pad, weights_tuple,
-                        rotate, carry_spread, rotate_pos=False):
+                        inv_perms, oid_seq, spread0, score_tab, z_pad,
+                        weights_tuple, rotate, carry_spread,
+                        rotate_pos=False):
     return _batch_core(nodes, mut0, pods, n_pods, last_index,
                        last_node_index, num_to_find, n_real, perms,
                        inv_perms, oid_seq, spread0, z_pad,
                        dict(weights_tuple), rotate, carry_spread,
-                       rotate_pos=rotate_pos)
+                       rotate_pos=rotate_pos, score_tab=score_tab)
 
 
 @partial(jax.jit, static_argnames=("z_pad", "weights_tuple", "rotate",
@@ -788,7 +908,7 @@ def _schedule_batch_wtab_jit(nodes, mut0, pods, n_pods, wtab, last_index,
 def schedule_batch(nodes, pods, last_index, last_node_index, num_to_find, n_real,
                    z_pad, weights=None, rotation=None, spread0=None,
                    rotation_pos=None, carry_in=None, mesh=None, wtab=None,
-                   n_pods=None):
+                   n_pods=None, classes=None):
     """Schedule a burst of pods against one snapshot, decisions serially
     equivalent to per-pod cycles. `pods` is a dict of [B, ...] arrays
     padded to the caller's bucket (one compile per bucket); `n_pods` is the
@@ -827,9 +947,19 @@ def schedule_batch(nodes, pods, last_index, last_node_index, num_to_find, n_real
 
     `wtab` (tensor mode) is the [P, K] profile weight table (PRIORITY_AXIS
     columns); `pods` must then carry a `profile_id` [B] column and
-    `weights` the static cross-profile union gate dict."""
+    `weights` the static cross-profile union gate dict.
+
+    `classes` = `score_classes(pods["nz_cpu"], pods["nz_mem"], n_pods)`,
+    or None: with it (never beside a `wtab`) the loop carries the score
+    board and a step rescores the one row it bound (_batch_core); without
+    it every step scores every row. The same decisions either way."""
     weights_tuple = tuple(sorted((weights or DEFAULT_WEIGHTS).items()))
     z = jnp.zeros((1, 1), jnp.int32)
+    score_tab = None
+    if classes is not None:
+        assert wtab is None, "a per-pod weight row makes the board per profile"
+        pods = {**pods, "score_class": classes[0]}
+        score_tab = jnp.asarray(classes[1], jnp.int64)
     if rotation_pos is not None:
         assert rotation is None
         perms = jnp.asarray(rotation_pos[0], jnp.int32)
@@ -866,7 +996,7 @@ def schedule_batch(nodes, pods, last_index, last_node_index, num_to_find, n_real
                       _i64(n_real), perms, inv_perms, oid_seq, s0)
         return fn(nodes, mut0, pods, n_pods, _i64(last_index),
                   _i64(last_node_index), _i64(num_to_find), _i64(n_real),
-                  perms, inv_perms, oid_seq, s0)
+                  perms, inv_perms, oid_seq, s0, score_tab)
     if wtab is not None:
         return _schedule_batch_wtab_jit(
             nodes, mut0, pods, n_pods, wtab, _i64(last_index),
@@ -877,7 +1007,7 @@ def schedule_batch(nodes, pods, last_index, last_node_index, num_to_find, n_real
     return _schedule_batch_jit(
         nodes, mut0, pods, n_pods, _i64(last_index), _i64(last_node_index),
         _i64(num_to_find), _i64(n_real), perms, inv_perms, oid_seq, s0,
-        z_pad, weights_tuple, rotation is not None, carry_spread,
+        score_tab, z_pad, weights_tuple, rotation is not None, carry_spread,
         rotate_pos=rotation_pos is not None)
 
 

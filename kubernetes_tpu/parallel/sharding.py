@@ -241,9 +241,11 @@ def sharded_scan_fn(mesh: Mesh, z_pad: int, weights_tuple, rotate: bool,
     replicate (they are tiny [L, N] index tables), the pod count (the
     loop's dynamic trip count) is a replicated scalar, and the per-node
     feasibility/score vectors ride XLA collectives (all-gather over ICI)
-    into the replicated select epilogue. Decisions are bit-identical to
-    the single-device scan (tests/test_sharding.py + the sharded fuzz
-    variants). Compiled once per (mesh, statics) and cached."""
+    into the replicated select epilogue. The carried score board
+    (`score_tab` given) is pinned on its node axis like the state rows.
+    Decisions are bit-identical to the single-device scan
+    (tests/test_sharding.py + the sharded fuzz variants). Compiled once
+    per (mesh, statics) and cached."""
     key = (mesh, z_pad, weights_tuple, rotate, carry_spread, rotate_pos,
            use_wtab)
     fn = _SCAN_CACHE.get(key)
@@ -265,13 +267,15 @@ def sharded_scan_fn(mesh: Mesh, z_pad: int, weights_tuple, rotate: bool,
                                  wtab=wtab)
     else:
         def f(nodes, mut0, pods, n_pods, last_index, last_node_index,
-              num_to_find, n_real, perms, inv_perms, oid_seq, spread0):
+              num_to_find, n_real, perms, inv_perms, oid_seq, spread0,
+              score_tab=None):
             nodes = _constrain_nodes(mesh, nodes)
             return K._batch_core(nodes, mut0, pods, n_pods, last_index,
                                  last_node_index, num_to_find, n_real,
                                  perms, inv_perms, oid_seq, spread0, z_pad,
                                  dict(weights_tuple), rotate, carry_spread,
-                                 rotate_pos=rotate_pos, constrain=c)
+                                 rotate_pos=rotate_pos, constrain=c,
+                                 score_tab=score_tab)
 
     fn = _SCAN_CACHE[key] = jax.jit(f)
     return fn

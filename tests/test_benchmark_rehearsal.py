@@ -21,6 +21,8 @@ ARRIVALS = "headline-15000n.arrivals-steady"
 ADAPTIVE = "headline-15000n-adaptive.backlog-10k"
 DENSITY_ADAPTIVE = "density-5000n-150k-adaptive.rollout-1k"
 MIXED = "inuse-15000n-135k.backlog-10k-mixed"
+# the scan cells whose 15,000 nodes reach kernels.SCORE_BOARD_MIN_ROWS
+BOARD = (ADAPTIVE, MIXED)
 
 # (config overlay, traffic overlay): the sizes benchmark/tests rehearses at.
 # The adaptive cell needs more than 100 nodes for the walk to be cut short
@@ -89,6 +91,18 @@ def altered_binding(sched, store):
     store.commit_wave = altered
 
 
+def counter_metric(name, res, rep):
+    """A `program_counter` metric of `benchmark/metrics/`, read by its own
+    reader from the run's moved counters."""
+    from lib import spec
+    mf = spec.load_metric(name)
+    reader = importlib.import_module(f"readers.{mf['reader']}")
+    moved = {fam: {tuple(lab.split("/")): v for lab, v in ch.items()}
+             for fam, ch in rep["counters"].items()}
+    return reader.read({"pods_bound": res["attempted"], "counters": moved},
+                       **mf["args"])
+
+
 @pytest.mark.parametrize("cell,seed,hook,program", [
     (BACKLOG, 1, None, None),               # K-batch kernel
     (ROLLOUT, 2**31 + 5, None, None),       # generic scan + spread
@@ -102,7 +116,13 @@ def altered_binding(sched, store):
     (MIXED, 2**31 + 41, None, None),
 ], ids=["backlog", "rollout", "arrivals", "adaptive", "altered-binding",
         "density-adaptive", "density-adaptive-control", "mixed"])
-def test_rehearsed_cell(execute, cell, seed, hook, program):
+def test_rehearsed_cell(monkeypatch, execute, cell, seed, hook, program):
+    if cell in BOARD:
+        # these cells hold 16,384 node rows, enough for the scan to carry
+        # its score board; the rehearsal's 240 nodes, which `mesh="auto"`
+        # may spread over the CPU's devices, stand in for them
+        from kubernetes_tpu.ops import kernels
+        monkeypatch.setattr(kernels, "SCORE_BOARD_MIN_ROWS", 1)
     out = rehearse(execute, cell, seed, hook, program)
     res, rep = out["result"], out["report"]
     assert rep["compared"] > 0
@@ -112,6 +132,17 @@ def test_rehearsed_cell(execute, cell, seed, hook, program):
     assert res["correct"] is True and res["failed"] == 0
     assert res["attempted"] > 0
     assert rep["compiles_in_window"] == 0
+    if cell in (ROLLOUT, ADAPTIVE, DENSITY_ADAPTIVE, MIXED):
+        # the generic scan's cells: at 16,384 rows every launch carries the
+        # score board (one pod class, or up to eight in the mixed cell), at
+        # the density cells' 8192 every step rescores every row
+        how = "carried" if cell in BOARD else "full"
+        assert rep["counters"]["tpu_scan_score_steps_total"] == \
+            {how: res["attempted"]}
+        assert counter_metric("score_carried_steps_per_pod.backlog",
+                              res, rep) == (1.0 if cell in BOARD else 0.0)
+    else:
+        assert "tpu_scan_score_steps_total" not in rep["counters"]
     if cell == ADAPTIVE:
         moved = rep["counters"]
         # the truncated regime on the generic scan, never the K-batch kernel
