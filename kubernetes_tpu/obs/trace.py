@@ -16,8 +16,8 @@ of the chip can be named by the program span that covers it. With no
 session running the annotation is one `is_enabled()` check. Spans of one
 launch window share its sequence number (`next_window()`): `args.window`
 in the ring, `window` metadata on the annotation. The budget on the bind
-path is a span per pump, per window and per commit wave — never per pod,
-per node or per event.
+path is a span per pump, per window and per commit wave, and per poll
+batch and handler run inside a pump — never per pod, per node or per event.
 
 Device-cost accounting: dispatch is asynchronous, so a span around the
 launch measures the enqueue only. The TPU pipeline records cat="device"

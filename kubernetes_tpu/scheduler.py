@@ -506,7 +506,7 @@ class Scheduler:
             on_update=self._update_pod_in_cache,
             on_delete=self._delete_pod_from_cache,
             on_delete_many=self._delete_pods_from_cache,
-            filter_fn=lambda p: bool(p.node_name))
+            filter_fn=lambda p: bool(p.node_name), name="cache")
         # unassigned pods owned by this scheduler -> queue (adds, updates,
         # and deletes all arrive in informer run batches: one queue lock +
         # one native heap push / row-cache pass per batch, and the pod-row
@@ -519,17 +519,19 @@ class Scheduler:
             on_update_many=self._update_pods_in_queue,
             on_delete=self._delete_pod_from_queue,
             on_delete_many=self._delete_pods_from_queue,
-            filter_fn=lambda p: not p.node_name and self._responsible_for(p))
+            filter_fn=lambda p: not p.node_name and self._responsible_for(p),
+            name="queue")
         nodes = self.informers.informer(NODES)
         nodes.add_event_handler(
             on_add=self._add_node, on_update=self._update_node,
-            on_delete=self._delete_node)
+            on_delete=self._delete_node, name="nodes")
         # service/RS/PDB events wake the queue (eventhandlers.go:32-86)
         for kind in (SERVICES, REPLICASETS, PDBS):
             self.informers.informer(kind).add_event_handler(
                 on_add=lambda _o: self.queue.move_all_to_active(),
                 on_update=lambda _o, _n: self.queue.move_all_to_active(),
-                on_delete=lambda _o: self.queue.move_all_to_active())
+                on_delete=lambda _o: self.queue.move_all_to_active(),
+                name="wake")
 
     def _add_pod_to_cache(self, pod: Pod) -> None:
         self.cache.add_pod(pod)

@@ -40,6 +40,15 @@ RELIST_BACKOFF = obs.histogram(
     "exponential ladder instead of hot-looping list+watch.", ("kind",),
     buckets=(0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5))
 
+DELIVERED = obs.counter(
+    "informer_delivered_events_total",
+    "Events that reached one of a registered handler's callbacks, by how: "
+    "`batched` when an on_*_many callback took a run of same-type events "
+    "whole, `single` when they went through on_add / on_update / on_delete "
+    "one at a time (a run the handler could not take whole, a run of one, "
+    "a re-list's replay, the background thread). A pump books once a run "
+    "and handler, not once an event.", ("path", "kind", "handler"))
+
 Handler = Callable[[Any], None]
 UpdateHandler = Callable[[Any, Any], None]
 BatchHandler = Callable[[list], None]
@@ -61,7 +70,15 @@ class ResourceEventHandler:
     call. A MODIFIED run batches ONLY when every pair is a plain update
     under the filter (both sides pass) — mixed filter categories
     (update-as-add / update-as-delete) fall back to the per-event loop so
-    their interleaved order is bit-identical to the unbatched path."""
+    their interleaved order is bit-identical to the unbatched path.
+
+    `name` is what the handler is called in the pump's spans and counter
+    (client-go's per-handler work-duration metrics): a pump wraps this
+    handler's whole treatment of a run, filter included, in one span
+    `pump.<name>.<type>`, and every `handle*` method returns what it
+    delivered as `(single, batched)` — events that reached `on_add` /
+    `on_update` / `on_delete` one at a time, and events an `on_*_many`
+    callback took whole — for `informer_delivered_events_total`."""
 
     def __init__(self,
                  on_add: Optional[Handler] = None,
@@ -70,7 +87,8 @@ class ResourceEventHandler:
                  filter_fn: Optional[Callable[[Any], bool]] = None,
                  on_add_many: Optional[BatchHandler] = None,
                  on_update_many: Optional[BatchHandler] = None,
-                 on_delete_many: Optional[BatchHandler] = None):
+                 on_delete_many: Optional[BatchHandler] = None,
+                 name: str = "handler"):
         self.on_add = on_add
         self.on_add_many = on_add_many
         self.on_update = on_update
@@ -78,29 +96,38 @@ class ResourceEventHandler:
         self.on_delete = on_delete
         self.on_delete_many = on_delete_many
         self.filter_fn = filter_fn
+        self.name = name
 
     def _passes(self, obj: Any) -> bool:
         return self.filter_fn is None or self.filter_fn(obj)
 
-    def handle_added_run(self, objs: list) -> None:
-        """A run of consecutive ADDED objects, in delivery order: one
-        `on_add_many` call for the filtered batch when registered, else
-        the per-object `on_add` loop."""
-        if self.on_add is None and self.on_add_many is None:
-            return
+    def handle_run(self, ev_type: str, run: list) -> tuple[int, int]:
+        """A run of consecutive same-type events, in delivery order (objects
+        for ADDED and DELETED, (old, new) pairs for MODIFIED)."""
+        if ev_type == MODIFIED:
+            return self.handle_updated_run(run)
+        if ev_type == ADDED:
+            return self._handle_objects(run, self.on_add, self.on_add_many)
+        return self._handle_objects(run, self.on_delete, self.on_delete_many)
+
+    def _handle_objects(self, objs: list, one: Optional[Handler],
+                        many: Optional[BatchHandler]) -> tuple[int, int]:
+        """A run of ADDED (or DELETED) objects: one `many` call for the
+        filtered batch when registered, else the per-object `one` loop."""
+        if one is None and many is None:
+            return 0, 0
         passing = objs if self.filter_fn is None \
             else [o for o in objs if self.filter_fn(o)]
         if not passing:
-            return
-        if self.on_add_many is not None and len(passing) > 1:
-            self.on_add_many(passing)
-        elif self.on_add is not None:
-            for o in passing:
-                self.on_add(o)
-        else:
-            self.on_add_many(passing)
+            return 0, 0
+        if one is None or (many is not None and len(passing) > 1):
+            many(passing)
+            return 0, len(passing)
+        for o in passing:
+            one(o)
+        return len(passing), 0
 
-    def handle_updated_run(self, pairs: list) -> None:
+    def handle_updated_run(self, pairs: list) -> tuple[int, int]:
         """A run of consecutive MODIFIED (old, new) pairs, in delivery
         order: one `on_update_many` call when registered and EVERY pair
         is a plain update under the filter — anything else (an
@@ -110,32 +137,19 @@ class ResourceEventHandler:
                 old is not None and self._passes(old) and self._passes(new)
                 for old, new in pairs):
             self.on_update_many(pairs)
-            return
+            return 0, len(pairs)
+        single = 0
         for old, new in pairs:
-            self.handle(MODIFIED, old, new)
+            single += self.handle(MODIFIED, old, new)
+        return single, 0
 
-    def handle_deleted_run(self, objs: list) -> None:
-        """A run of consecutive DELETED objects, in delivery order: one
-        `on_delete_many` call for the filtered batch when registered,
-        else the per-object `on_delete` loop."""
-        if self.on_delete is None and self.on_delete_many is None:
-            return
-        passing = objs if self.filter_fn is None \
-            else [o for o in objs if self.filter_fn(o)]
-        if not passing:
-            return
-        if self.on_delete_many is not None and len(passing) > 1:
-            self.on_delete_many(passing)
-        elif self.on_delete is not None:
-            for o in passing:
-                self.on_delete(o)
-        else:
-            self.on_delete_many(passing)
-
-    def handle(self, ev_type: str, old: Any, new: Any) -> None:
+    def handle(self, ev_type: str, old: Any, new: Any) -> int:
+        """One event through the per-event callbacks; 1 when one of them
+        took it, 0 when the filter or a missing callback dropped it."""
         if ev_type == ADDED:
             if self._passes(new) and self.on_add:
                 self.on_add(new)
+                return 1
         elif ev_type == MODIFIED:
             old_ok = old is not None and self._passes(old)
             new_ok = self._passes(new)
@@ -143,15 +157,20 @@ class ResourceEventHandler:
             if old_ok and new_ok:
                 if self.on_update:
                     self.on_update(old, new)
+                    return 1
             elif new_ok:
                 if self.on_add:
                     self.on_add(new)
+                    return 1
             elif old_ok:
                 if self.on_delete:
                     self.on_delete(old)
+                    return 1
         elif ev_type == DELETED:
             if self._passes(new) and self.on_delete:
                 self.on_delete(new)
+                return 1
+        return 0
 
 
 class SharedInformer:
@@ -191,11 +210,14 @@ class SharedInformer:
                           on_add_many: Optional[BatchHandler] = None,
                           on_update_many: Optional[BatchHandler] = None,
                           on_delete_many: Optional[BatchHandler] = None,
-                          ) -> None:
+                          name: str = "handler") -> None:
+        """`name` tells this handler apart in the pump's spans
+        (`pump.<name>.<type>`) and in `informer_delivered_events_total`;
+        handlers registered without one share `handler`."""
         self._handlers.append(ResourceEventHandler(
             on_add, on_update, on_delete, filter_fn,
             on_add_many=on_add_many, on_update_many=on_update_many,
-            on_delete_many=on_delete_many))
+            on_delete_many=on_delete_many, name=name))
 
     # -- lister (reference: informer.Lister()) ------------------------------
     def list(self) -> list[Any]:
@@ -296,15 +318,22 @@ class SharedInformer:
         """Synchronously apply pending watch events, copied out in
         batches (one core poll per `pump_batch` events; consecutive adds
         dispatch as one batch to handlers that registered on_add_many).
-        Returns count applied. A pump that delivers anything is one span,
-        `pump.<kind>`, opened at its first event (an idle pump records
-        nothing) and closed with the events it delivered, by type: the
-        creates, the binds and the deletes of a window are told apart."""
+        Returns count applied.
+
+        A pump that delivers anything is one span, `pump.<kind>`, closed
+        with the events it delivered, by type: the creates, the binds and
+        the deletes of a window are told apart. Its children, none of them
+        per event: `pump.poll` for each poll that returned events (args
+        `events`), `pump.index` for the locked pass that folds a batch into
+        the informer's cache (`events`), and `pump.<handler>.<type>` for
+        each handler's treatment of each run of same-type events (`events`,
+        the run's length; `<type>` is `added`, `modified` or `deleted`).
+        An idle pump records nothing."""
         if self._watch is None:
             self.sync()
         n = 0
-        span = None
         tally: dict = {}
+        span = obs.trace.begin("pump." + self.kind)
         try:
             while max_events is None or n < max_events:
                 limit = self.pump_batch if max_events is None \
@@ -321,43 +350,55 @@ class SharedInformer:
                     continue
                 if not evs:
                     break
-                if span is None:
-                    span = obs.trace.begin("pump." + self.kind)
-                self._apply_batch(evs, tally)
+                with obs.trace.span("pump.index", events=len(evs)):
+                    prepared = self._index(evs)
+                self._deliver_runs(prepared, tally)
                 n += len(evs)
         finally:
-            if span is not None:
+            if n:
                 span.end(events=n,
                          **{t.lower(): c for t, c in tally.items()})
+            else:
+                span.cancel()
         return n
 
     def _poll_batch(self, timeout: float, limit: int) -> list:
         """Copy out up to `limit` pending events: one cursor poll on the
         embedded store's Watch (the core call is GIL-released on the
         native commit core); transports without the batch poll
-        (RemoteWatch's reader queue) drain per event."""
-        w = self._watch
-        poll = getattr(w, "_poll", None)
-        if poll is not None:
-            return poll(timeout if timeout else 0, limit)
-        evs = []
-        ev = w.next(timeout=timeout) if timeout else w.try_next()
-        while ev is not None:
-            evs.append(ev)
-            if len(evs) >= limit:
-                break
-            ev = w.try_next()
-        return evs
+        (RemoteWatch's reader queue) drain per event. One span,
+        `pump.poll`, unless nothing came back."""
+        span = obs.trace.begin("pump.poll")
+        evs: list = []
+        try:
+            w = self._watch
+            poll = getattr(w, "_poll", None)
+            if poll is not None:
+                evs = poll(timeout if timeout else 0, limit)
+            else:
+                ev = w.next(timeout=timeout) if timeout else w.try_next()
+                while ev is not None:
+                    evs.append(ev)
+                    if len(evs) >= limit:
+                        break
+                    ev = w.try_next()
+            return evs
+        finally:
+            if evs:
+                span.end(events=len(evs))
+            else:
+                span.cancel()
 
     def _apply(self, ev: Event) -> None:
-        self._apply_batch([ev])
+        """One event from the background thread."""
+        self._dispatch(*self._index([ev])[0])
 
-    def _apply_batch(self, evs: list, tally: Optional[dict] = None) -> None:
-        """`tally` (optional) collects the events delivered by effective
-        type, one addition per run of the batch."""
+    def _index(self, evs: list) -> list:
+        """Fold a batch into the informer's own cache under its lock;
+        returns (effective type, old, new) per event in delivery order."""
         # a delivered event ends any consecutive-ExpiredError streak
         self._expired_streak = 0
-        prepared = []   # (effective etype, old, new) in delivery order
+        prepared = []
         with self._lock:
             cache = self._cache
             for ev in evs:
@@ -373,38 +414,48 @@ class SharedInformer:
                 if etype == ADDED and old is not None:
                     etype = MODIFIED
                 prepared.append((etype, old, ev.obj))
+        return prepared
+
+    def _deliver_runs(self, prepared: list, tally: dict) -> None:
+        """Hand an indexed batch to the handlers, one run of consecutive
+        same-type events at a time (per-handler order identical to the
+        per-event loop). `tally` collects the events delivered by effective
+        type, one addition per run. Per run and handler: one span around
+        the handler's whole treatment of the run and one booking of what
+        reached its callbacks."""
         i = 0
         n = len(prepared)
         while i < n:
-            # run of consecutive same-type events: one batched dispatch
-            # per handler (per-handler order identical to the per-event
-            # loop; singletons take the plain _dispatch path)
-            etype, old, new = prepared[i]
+            etype = prepared[i][0]
             j = i + 1
             while j < n and prepared[j][0] == etype:
                 j += 1
-            if tally is not None:
-                tally[etype] = tally.get(etype, 0) + (j - i)
-            if j - i == 1:
-                self._dispatch(etype, old, new)
-            elif etype == ADDED:
+            tally[etype] = tally.get(etype, 0) + (j - i)
+            if etype == MODIFIED:
+                run = [(prepared[k][1], prepared[k][2]) for k in range(i, j)]
+            else:
                 run = [prepared[k][2] for k in range(i, j)]
-                for h in self._handlers:
-                    h.handle_added_run(run)
-            elif etype == MODIFIED:
-                pairs = [(prepared[k][1], prepared[k][2])
-                         for k in range(i, j)]
-                for h in self._handlers:
-                    h.handle_updated_run(pairs)
-            else:   # DELETED
-                run = [prepared[k][2] for k in range(i, j)]
-                for h in self._handlers:
-                    h.handle_deleted_run(run)
+            suffix = "." + etype.lower()
+            for h in self._handlers:
+                with obs.trace.span("pump." + h.name + suffix,
+                                    events=j - i):
+                    single, batched = h.handle_run(etype, run)
+                self._book(h, single, batched)
             i = j
 
     def _dispatch(self, ev_type: str, old: Any, new: Any) -> None:
+        """One event to every handler's per-event callbacks, outside any
+        run (a re-list's replay, the background thread): no span, and the
+        delivery is booked as it goes."""
         for h in self._handlers:
-            h.handle(ev_type, old, new)
+            self._book(h, h.handle(ev_type, old, new), 0)
+
+    def _book(self, h: ResourceEventHandler, single: int,
+              batched: int) -> None:
+        if single:
+            DELIVERED.labels("single", self.kind, h.name).inc(single)
+        if batched:
+            DELIVERED.labels("batched", self.kind, h.name).inc(batched)
 
     # -- background mode ----------------------------------------------------
     def start(self) -> None:

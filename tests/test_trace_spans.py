@@ -1,9 +1,10 @@
 """The one span API inside the bind path (`obs.trace.span` / `begin`): a span
 is recorded once and lands in the ring and, while a `jax.profiler` session
 runs, in the profiler's trace under the same name, nesting and window
-number; spans are per pump, per window and per commit wave, never per pod;
-the kernels' stages carry `jax.named_scope` names; compiles are counted by
-program."""
+number; spans are per pump, per window and per commit wave (and, inside a
+pump, per poll batch, index pass and handler run), never per pod; a pump's
+deliveries are counted by whether a handler took the run whole; the kernels'
+stages carry `jax.named_scope` names; compiles are counted by program."""
 import glob
 import os
 import re
@@ -13,6 +14,7 @@ import pytest
 from kubernetes_tpu import obs
 from kubernetes_tpu.api.types import Container, Node, Pod
 from kubernetes_tpu.scheduler import Scheduler
+from kubernetes_tpu.store.informer import DELIVERED, SharedInformer
 from kubernetes_tpu.store.store import NODES, PODS, Store
 
 GI = 1024 ** 3
@@ -24,6 +26,11 @@ BIND_PATH_SPANS = {
     "burst.encode.nodes", "burst.encode.pods", "burst.dispatch",
     "burst.fetch", "burst.wave.commit", "burst.commit.cache",
     "burst.commit.store", "burst.commit.fanout", "burst.commit.finish"}
+# what a pump opens inside `pump.pods`: a poll and an index pass a batch, and
+# one span per run of same-type events and handler the scheduler registered
+PUMP_CHILDREN = {
+    "pump.poll", "pump.index", "pump.cache.added", "pump.queue.added",
+    "pump.cache.modified", "pump.queue.modified"}
 
 
 def mknode(name: str) -> Node:
@@ -56,6 +63,16 @@ def drain(sched, max_pods: int = 128) -> int:
         bound += n
     sched.pump()
     return bound
+
+
+def delivered() -> dict:
+    """{(path, kind, handler): events} of the delivery counter."""
+    return {k: c.value for k, c in DELIVERED._children.items()}
+
+
+def moved(before: dict) -> dict:
+    return {k: v - before.get(k, 0) for k, v in delivered().items()
+            if v - before.get(k, 0)}
 
 
 def burst(store, sched, tag: str, n: int) -> list[dict]:
@@ -171,6 +188,108 @@ class TestBindPathSpans:
                    if e["name"] == "pump.pods"]
         assert gone["events"] == 5 and gone["deleted"] == 5
 
+    def test_pump_children_nest_under_the_pump_and_leave_it_its_self_time(
+            self):
+        """Every child lies inside its `pump.pods`, beside and not over its
+        siblings, under the pump's window: the children's durations plus
+        the pump's self time are the pump's duration."""
+        store, sched = make_sched()
+        burst(store, sched, "c", 6)
+        store.delete_many(PODS, [f"default/c-{j}" for j in range(6)])
+        sched.pump()
+        evs = [e for e in obs.trace.events() if e["name"].startswith("pump.")]
+        names = {e["name"] for e in evs}
+        assert PUMP_CHILDREN | {"pump.cache.deleted",
+                                "pump.queue.deleted"} <= names
+        pumps = [e for e in evs if e["name"] == "pump.pods"]
+        assert len(pumps) == 3      # the creates, the binds, the deletes
+        kids = [e for e in evs if e["name"] != "pump.pods"]
+        for k in kids:
+            assert k["args"]["parent"] == "pump.pods"
+        for p in pumps:
+            mine = sorted((k for k in kids
+                           if p["ts"] <= k["ts"] < p["ts"] + p["dur"]),
+                          key=lambda k: k["ts"])
+            assert [k["name"] for k in mine][:2] == ["pump.poll",
+                                                     "pump.index"]
+            edge = p["ts"]
+            for k in mine:
+                # (microseconds as floats: a nanosecond of rounding)
+                assert k["ts"] >= edge - 1e-3, (k["name"], "over a sibling")
+                edge = k["ts"] + k["dur"]
+                assert k["args"].get("window", 0) == p["args"].get(
+                    "window", 0)
+                assert k["args"]["events"] == 6
+            assert edge <= p["ts"] + p["dur"] + 1e-3
+            self_us = p["dur"] - sum(k["dur"] for k in mine)
+            assert -1e-3 <= self_us <= p["dur"]
+        assert sum(len([k for k in kids
+                        if p["ts"] <= k["ts"] < p["ts"] + p["dur"]])
+                   for p in pumps) == len(kids)
+
+    def test_deliveries_are_counted_by_whether_the_handler_took_the_run(
+            self):
+        """5 pods created, bound and deleted: what each registered handler
+        can take whole follows from its registration (its filter and its
+        `on_*_many` callbacks), and the counter reads that."""
+        store, sched = make_sched()
+        handlers = sched.informers.informer(PODS)._handlers
+        assert [h.name for h in handlers] == ["cache", "queue"]
+        pending, bound = mkpod("x"), mkpod("x", node_name="n0")
+        want: dict = {}
+
+        def book(path, h, n=5):
+            key = (path, PODS, h.name)
+            want[key] = want.get(key, 0) + n
+
+        for h in handlers:
+            if h.filter_fn(pending):                    # the creates
+                book("batched" if h.on_add_many else "single", h)
+            if h.filter_fn(pending) and h.filter_fn(bound):   # the binds
+                book("batched" if h.on_update_many else "single", h)
+            elif h.filter_fn(pending) or h.filter_fn(bound):
+                book("single", h)    # update-as-delete / update-as-add
+            if h.filter_fn(bound):                      # the deletes
+                book("batched" if h.on_delete_many else "single", h)
+        before = delivered()
+        burst(store, sched, "d", 5)
+        store.delete_many(PODS, [f"default/d-{j}" for j in range(5)])
+        sched.pump()
+        assert moved(before) == want
+        # today: the creates reach the queue whole, the deletes the cache
+        # whole, and each bind goes one by one through BOTH handlers
+        assert want == {("batched", PODS, "queue"): 5,
+                        ("single", PODS, "cache"): 5,
+                        ("single", PODS, "queue"): 5,
+                        ("batched", PODS, "cache"): 5}
+
+    def test_unnamed_handler_reads_handler_and_only_a_pump_opens_spans(self):
+        store = Store()
+        store.create(PODS, mkpod("listed"))
+        inf = SharedInformer(store, PODS)
+        got = []
+        inf.add_event_handler(on_add=lambda p: got.append(p.name))
+        before = delivered()
+        obs.trace.clear()
+        inf.sync()                  # the list's replay: object by object
+        assert obs.trace.events() == []
+        assert moved(before) == {("single", PODS, "handler"): 1}
+        for j in range(2):
+            store.create(PODS, mkpod(f"u-{j}"))
+        assert inf.pump() == 2
+        assert got == ["listed", "u-0", "u-1"]
+        assert [(e["name"], e["args"]["events"])
+                for e in obs.trace.events()] == [
+            ("pump.poll", 2), ("pump.index", 2), ("pump.handler.added", 2),
+            ("pump.pods", 2)]
+        assert moved(before) == {("single", PODS, "handler"): 3}
+        # the background thread's path: event by event, so no span either
+        store.create(PODS, mkpod("bg"))
+        obs.trace.clear()
+        inf._apply(inf._watch.try_next())
+        assert got[-1] == "bg" and obs.trace.events() == []
+        assert moved(before) == {("single", PODS, "handler"): 4}
+
     def test_scatter_span_and_rows_counter_count_real_rows(self):
         from kubernetes_tpu.core import tpu_scheduler as T
         store, sched = make_sched(n_nodes=40)
@@ -210,7 +329,10 @@ class TestBindPathSpans:
         for e in evs:
             count[e["name"]] = count.get(e["name"], 0) + 1
         assert count == {
-            "pump.pods": 2, "burst.plan": 1, "burst.snapshot": 1,
+            "pump.pods": 2, "pump.poll": 2, "pump.index": 2,
+            "pump.cache.added": 1, "pump.queue.added": 1,
+            "pump.cache.modified": 1, "pump.queue.modified": 1,
+            "burst.plan": 1, "burst.snapshot": 1,
             "burst.encode": 1, "burst.encode.nodes": 1,
             "burst.encode.pods": 1, "burst.dispatch": 1, "burst.fetch": 1,
             "burst.wave.device": 1, "burst.wave.commit": 1,
@@ -305,6 +427,12 @@ class TestProfilerSeesTheSameSpans:
                     for line in plane.lines for e in line.events
                     if e.name == "pump.pods")
         assert pump["events"] == 6
+        child = next(dict(e.stats) for plane in
+                     ProfileData.from_file(path).planes
+                     if plane.name.startswith("/host:")
+                     for line in plane.lines for e in line.events
+                     if e.name == "pump.queue.added")
+        assert child["events"] == 6 and child["window"] == pump["window"]
 
     def test_no_session_no_annotation(self):
         sp = obs.trace.begin("quiet")
