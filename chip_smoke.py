@@ -22,6 +22,10 @@ Stages, all in this one process (a chip belongs to one process):
           replayed through the oracle.
 - serial  single-pod cycles with serial_path="device"; prints the warm
           round trip of the cycle program and of a trivial program.
+- walk    1000 nodes in three uneven zones at upstream's default
+          percentageOfNodesToScore: the scan's truncated walk on shipped
+          positions (a sort of feasible positions a step, a sort of tie
+          positions); the launches are replayed through the serial oracle.
 - serve   perf.harness.run_serve_cell: arrivals -> admission gate ->
           ServeLoop windows -> commit -> watch, with its two audits.
 - mesh    only with more than one device: the drain again with the node
@@ -57,6 +61,7 @@ REAL = {
     "parity_pods": 32, "gang_size": 64,
     "preempt_victims": 10000, "preemptors": 128,
     "serial_nodes": 1000, "serial_cycles": 12,
+    "walk_nodes": 1000, "walk_pods": 600,
     "serve_nodes": 1000, "serve_rate": 2000.0, "serve_seconds": 5.0,
     "serve_window": 2048, "serve_parity_pods": 256,
 }
@@ -66,6 +71,7 @@ REHEARSAL = {
     "parity_pods": 12, "gang_size": 8,
     "preempt_victims": 320, "preemptors": 8,
     "serial_nodes": 60, "serial_cycles": 6,
+    "walk_nodes": 250, "walk_pods": 40,
     "serve_nodes": 90, "serve_rate": 300.0, "serve_seconds": 2.0,
     "serve_window": 128, "serve_parity_pods": 48,
 }
@@ -502,6 +508,55 @@ def stage_serial(smoke: Smoke):
     return out
 
 
+def stage_walk(smoke: Smoke):
+    """A rotating NodeTree under a truncated walk: the scan program that
+    takes the walk's stopping point as an order statistic of positions."""
+    from kubernetes_tpu.core import tpu_scheduler as T
+    from kubernetes_tpu.oracle.generic_scheduler import \
+        num_feasible_nodes_to_find
+    from kubernetes_tpu.scheduler import Scheduler
+    from kubernetes_tpu.store.store import PODS, Store
+    s = smoke.sizes
+    n = s["walk_nodes"]
+    assert n % 3, "the zones have to be uneven for the order to rotate"
+    quota = num_feasible_nodes_to_find(n, 0)
+    store = Store(watch_log_size=1 << 16)
+    build_cluster(store, n)
+    sched = Scheduler(store, use_tpu=True, percentage_of_nodes_to_score=0)
+    sched.sync()
+    make_pods(store, s["walk_pods"])
+    sched.pump()
+    d0 = dispatch_counts()
+    order0, walked0 = family(T.SCAN_ORDER_STEPS), family(T.WALK_NODES)
+
+    def run():
+        # launches of at most 256 pods: last_index, the tie counter and the
+        # tree's zone cursor carry from launch to launch
+        while sched.schedule_burst(max_pods=256):
+            pass
+    launches, mism = replayed(run)
+    sched.pump()
+    ops = dispatch_delta(d0)
+    order = delta(family(T.SCAN_ORDER_STEPS), order0)
+    walked = delta(family(T.WALK_NODES), walked0)
+    smoke.check("walk.all_bound",
+                all(p.node_name for p in store.list(PODS)[0]))
+    smoke.check("walk.op_burst_scan",
+                ops.get("burst_scan", 0) > 0 and "burst_uniform" not in ops,
+                ops)
+    smoke.check("walk.every_step_on_positions",
+                order == {"position": s["walk_pods"]}, order)
+    # every walk stopped at its quota: no node is full, so it tested no more
+    smoke.check("walk.truncated", quota < n
+                and walked == {"truncated": quota * s["walk_pods"]},
+                f"quota {quota} of {n}: {walked}")
+    smoke.check("walk.replay_parity", launches > 0 and not mism,
+                f"{launches} launches replayed"
+                + (f", {mism[:2]}" if mism else ""))
+    return {"nodes": n, "pods": s["walk_pods"], "num_to_find": quota,
+            "device_ops": ops, "launches_replayed": launches}
+
+
 def stage_serve(smoke: Smoke):
     from kubernetes_tpu.perf.harness import run_serve_cell
     s = smoke.sizes
@@ -623,7 +678,8 @@ def main(argv=None) -> int:
     stages += [(f"lanes.{lane}", lane_stage(lane)) for lane in LANES]
     stages += [("lanes.gang", stage_gang), ("lanes.preempt", stage_preempt),
                ("lanes.preempt_scan", stage_preempt_scan),
-               ("serial", stage_serial), ("serve", stage_serve)]
+               ("serial", stage_serial), ("walk", stage_walk),
+               ("serve", stage_serve)]
     if len(dev) > 1:
         stages.append(("mesh", stage_mesh))
     for name, fn in stages:

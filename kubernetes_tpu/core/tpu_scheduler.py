@@ -111,11 +111,14 @@ SCAN_ORDER_STEPS = obs.counter(
     "tpu_scan_order_steps_total",
     "Steps of schedule_burst's generic scan launches, by how a step finds "
     "its NodeTree enumeration order: 'axis' (no order shipped: the tree "
-    "never rotates, so every step walks the device axis), 'position' (a "
-    "rotating tree with every node scored: one [N] sort of tie positions a "
-    "step) or 'gather' (a rotating tree under a truncated walk: the step "
-    "permutes its masks through perms/inv_perms). Booked once a launch, "
-    "beside tpu_scan_steps_total.", ("order",))
+    "never rotates, so every step walks the device axis) or 'position' (a "
+    "rotating tree: each node's position in the cycle's order is shipped, "
+    "and a step sorts tie positions, under a truncated walk feasible "
+    "positions too). 'gather' (a step that permuted its masks through "
+    "perms/inv_perms) reads 0 since the truncated walk runs on positions "
+    "as well: no launch ships a permutation. Booked once a launch, beside "
+    "tpu_scan_steps_total.", ("order",))
+SCAN_ORDER_STEPS.labels("gather")
 SCAN_SCORE_STEPS = obs.counter(
     "tpu_scan_score_steps_total",
     "Steps of schedule_burst's generic scan launches, by how the row-local "
@@ -1168,11 +1171,11 @@ class TPUScheduler:
 
     def _generic_rotation(self, b: NodeBatch, bucket: int,
                           start0: Optional[int] = None):
-        """(perms[L, n_pad], inv_perms, oid_seq[bucket]) for the generic
-        scan: each in-burst cycle's enumeration order as axis indices
-        (invalid rows tail every permutation so position-space feasibility
-        masks them out). oid_seq[0] is the axis itself (the enumeration the
-        shell just consumed for pod 0)."""
+        """(positions[L, n_pad], oid_seq[bucket]) for the generic scan:
+        positions[l][j] is axis row j's place in enumeration order l (rows
+        past n_real keep their own index, behind every valid node), and
+        oid_seq[t] the order of in-burst cycle t. oid_seq[0] is the axis
+        itself (the enumeration the shell just consumed for pod 0)."""
         tree = self.node_tree
         if tree is None:
             return None
@@ -1207,39 +1210,35 @@ class TPUScheduler:
         while len(perm_rows) < l_pad:
             perm_rows.append(perm_rows[0])
         skey = ("stack-g", tuple(map(id, perm_rows)))
-        got = self._rot_rows.get(skey)
-        if got is None:
-            perms = np.stack(perm_rows)
-            inv = np.empty_like(perms)
-            for l in range(perms.shape[0]):
-                inv[l, perms[l]] = np.arange(n_pad, dtype=np.int32)
-            got = self._rot_rows[skey] = (perms, inv)
-        perms, inv = got
-        return perms, inv, seq
+        positions = self._rot_rows.get(skey)
+        if positions is None:
+            positions = np.empty((l_pad, n_pad), np.int32)
+            for l, perm in enumerate(perm_rows):
+                positions[l, perm] = np.arange(n_pad, dtype=np.int32)
+            self._rot_rows[skey] = positions
+        return positions, seq
 
     def _scan_rotation(self, b: NodeBatch, bucket: int,
-                       start0: Optional[int], full_scan: bool):
-        """(rotation, rotation_pos) for a generic scan launch, already on
-        the device; (None, None) when the tree never rotates. The rotation
-        program is selected from CLUSTER shape (uneven zones), not from
-        whether THIS burst's walk happens to be the identity: the identity
-        is just data (order id 0), while flip-flopping the jit signature
-        between bursts costs a fresh 10s+ XLA compile mid-workload each
-        time the zone cursor lands on a fixed point. With every node scored
-        (`full_scan`: num_to_find >= n) the gather-free position mode
-        applies, one [N] sort a cycle; a truncated walk ships the <= L
-        distinct permutations, their inverses and each cycle's order id, and
-        a step pays three [N] gathers for them: 200 of its 321 us at 5000
-        nodes on a TPU v5e (PERF.md section 5)."""
+                       start0: Optional[int]):
+        """`rotation` for a generic scan launch, already on the device;
+        None when the tree never rotates. The rotation program is selected
+        from CLUSTER shape (uneven zones), not from whether THIS burst's
+        walk happens to be the identity: the identity is just data (order
+        id 0), while flip-flopping the jit signature between bursts costs a
+        fresh 10s+ XLA compile mid-workload each time the zone cursor lands
+        on a fixed point. What is shipped is the <= L distinct orders as
+        POSITIONS and each cycle's order id, whatever num_to_find is: a
+        step finds the k-th tie, and under a truncated walk the node the
+        walk stops at, by sorting positions (kernels._cycle_core), and
+        which of the two programs a launch runs is read off its own
+        num_to_find and n (kernels.schedule_batch)."""
         if not self._tree_rotates():
-            return None, None
+            return None
         sp = obs_trace.begin("burst.rotation", cycles=bucket)
-        perms, inv, seq = self._generic_rotation(b, bucket, start0)
-        up = [jnp.asarray(a, jnp.int32)
-              for a in ((inv, seq) if full_scan else (perms, inv, seq))]
-        sp.end(orders=int(perms.shape[0]))
-        # with every node scored, inv_perms ARE the positions
-        return (None, tuple(up)) if full_scan else (tuple(up), None)
+        positions, seq = self._generic_rotation(b, bucket, start0)
+        up = (jnp.asarray(positions, jnp.int32), jnp.asarray(seq, jnp.int32))
+        sp.end(orders=int(positions.shape[0]))
+        return up
 
     # -- fused bursts, wave-windowed commit ----------------------------------
     # Round 10 moved the wave chain INTO the kernel: a burst is ONE
@@ -1451,8 +1450,7 @@ class TPUScheduler:
             ORACLE_FALLBACKS.labels("burst-spread-mixed").inc()
             return None
         # per-cycle rotated enumeration orders (uneven zones)
-        rotation, rotation_pos = self._scan_rotation(
-            b, bucket, start0, num_to_find >= n)
+        rotation = self._scan_rotation(b, bucket, start0)
         spread0 = None
         if carry_spread:
             # the scan carries ONE [N] count vector; the stacked per-pod
@@ -1499,7 +1497,7 @@ class TPUScheduler:
                                        all_node_names, node_infos)
         ph.close()
         return self._scan_waves(pods, b, per_pod, spread0, rotation,
-                                rotation_pos, num_to_find, n, z_pad, bucket,
+                                num_to_find, n, z_pad, bucket,
                                 commit, ph, fl=fl)
 
     def _uniform_waves(self, pods: list[Pod], b: NodeBatch, cls, extra_ok,
@@ -1702,7 +1700,7 @@ class TPUScheduler:
         return sel
 
     def _scan_waves(self, pods: list[Pod], b: NodeBatch, per_pod: list,
-                    spread0, rotation, rotation_pos, num_to_find: int,
+                    spread0, rotation, num_to_find: int,
                     n: int, z_pad: int, bucket: int, commit,
                     ph: _BurstPhases, fl=None) -> list[Optional[str]]:
         """Single-launch driver for the generic scan burst: the whole
@@ -1754,7 +1752,6 @@ class TPUScheduler:
                 self.last_node_index, num_to_find, n, z_pad,
                 weights=self._union_weights if tensor else self.weights,
                 rotation=rotation, spread0=spread0,
-                rotation_pos=rotation_pos,
                 mesh=self.mesh, wtab=self._wtab() if tensor else None,
                 n_pods=n_pods, classes=classes)
             DEVICE_DISPATCH.labels("burst_scan").inc()
@@ -1764,9 +1761,7 @@ class TPUScheduler:
             SCAN_POD_ROWS.labels(
                 "stacked" if signatures > 1 else "shared").inc(n_pods)
             SCAN_ORDER_STEPS.labels(
-                "gather" if rotation is not None else
-                "position" if rotation_pos is not None else "axis"
-            ).inc(n_pods)
+                "axis" if rotation is None else "position").inc(n_pods)
             ph.close()
             ph.open("fetch")
             chaos.node_dead_point("dispatch-fetch")
@@ -1996,8 +1991,7 @@ class TPUScheduler:
         # one burst-wide walk, indexed by enumerations CONSUMED inside
         # the kernel (the carried t) — a rejected gang rewinds the
         # cursor, so the walk must NOT be pre-sliced by pod position
-        rotation, rotation_pos = self._scan_rotation(
-            b, B, start0, num_to_find >= n)
+        rotation = self._scan_rotation(b, B, start0)
         seg_start = np.zeros(B, dtype=bool)
         gang = np.zeros(B, dtype=bool)
         idx = 0
@@ -2028,7 +2022,7 @@ class TPUScheduler:
                 nodes, stacked, seg_start, gang, n_total, self.last_index,
                 self.last_node_index, num_to_find, n, z_pad,
                 weights=self._union_weights if tensor else self.weights,
-                rotation=rotation, rotation_pos=rotation_pos,
+                rotation=rotation,
                 mesh=self.mesh, wtab=self._wtab() if tensor else None,
                 gang_score=self._gang_score)
             DEVICE_DISPATCH.labels("burst_fused").inc()

@@ -13,8 +13,11 @@ reference's *sequential* semantics are reproduced exactly:
   (750 found per decision, 10,000-step scan launches in a 16,384 bucket,
   last_index going round the cluster) against the benchmark's independent
   reference by the cell `headline-15000n-adaptive.backlog-10k`; walks over
-  full nodes and the perm/inv_perm gather of uneven zones by
-  tests/test_adaptive_walk.py on the CPU.
+  full nodes by tests/test_adaptive_walk.py on the CPU. Where uneven zones
+  rotate the enumeration between cycles the walk runs on each node's
+  POSITION in the cycle's order, and its stopping point is an order
+  statistic of the feasible positions (`_cycle_core`, `pos`): on the chip
+  in the cell `density-5000n-150k-adaptive.rollout-1k`.
 - integer 0-10 scores with the reference's exact int64/float64 formulas
   (the float64 ones as correctly rounded integer arithmetic, ops/exactf64.py;
   no f64 and no vector integer division reaches the device),
@@ -43,9 +46,10 @@ selection), `score`, `pick` (selectHost; pickOneNodeForPreemption) and `fold`
 (the decision's delta into the carried node state). A scope is op metadata,
 set while tracing and free at run time; a device trace is read by these names
 (`SCOPES`), which survive a refactor that renumbers `fusion.9`. Inside
-`filter` and `pick`, `rotate` names the permutation gathers of a cycle whose
-NodeTree order is shipped as perm/inv_perm (`_cycle_core`): nested, so the
-four stages still add up to what they did.
+`filter` and `pick`, `rotate` names what a cycle does only because its
+NodeTree order is not the device axis (`_cycle_core`, `pos` given): each
+node's offset from the walk's origin and the sorts that take order statistics
+of it. Nested, so the four stages still add up to what they did.
 """
 from __future__ import annotations
 
@@ -464,15 +468,17 @@ def _feasibility(nodes, pod):
 
 
 @jax.named_scope("rotate")
-def _reorder(x, order):
-    """`x` taken in another order: the gathers between the device axis and
-    a cycle's NodeTree enumeration, under a scope of their own."""
-    return x[order]
+def _kth_smallest(mask, rel, k):
+    """The k-th (0-based) smallest of the walk offsets `rel` [N] i32 under
+    `mask`, by one sort; a fill above every offset when the mask holds no
+    more than k of them (`dynamic_slice` clamps k into the axis)."""
+    ranked = jnp.sort(jnp.where(mask, rel, jnp.int32(2 ** 30)))
+    return jax.lax.dynamic_slice(ranked, (k,), (1,))[0]
 
 
 def _cycle_core(nodes, pod, last_index, last_node_index, num_to_find, n_real,
-                weights, z_pad, perm=None, inv_perm=None, pos=None,
-                ghost=None, wtab=None, gang=None, local=None):
+                weights, z_pad, pos=None, full_scan=False, ghost=None,
+                wtab=None, gang=None, local=None):
     """One fused cycle. The reference's sequential walk from last_index
     (generic_scheduler.go:486,519) is emulated WITHOUT materializing the
     rotation permutation: for natural index j, its 1-based rank in rotation
@@ -482,20 +488,30 @@ def _cycle_core(nodes, pod, last_index, last_node_index, num_to_find, n_real,
 
     When the per-cycle NodeTree enumeration differs from the device axis
     (uneven zones rotate the zone-interleaved order between cycles —
-    node_tree.py rotation_map), `perm`/`inv_perm` supply THIS cycle's order:
-    perm[p] = natural row at enumeration position p, inv_perm its inverse.
-    The walk/tie math then runs in position space (the cumsums act on
-    permuted masks, one gather each way) and last_index keeps its positional
-    meaning; perm=None is the identity fast path.
+    node_tree.py rotation_map), `pos` supplies THIS cycle's order as
+    positions: pos[j] = node j's place in the enumeration (the inverse of
+    the permutation; rows past n_real keep their own index). No mask is
+    ever moved into position space or back: with rel[j] = node j's offset
+    from the walk's origin, everything the walk decides is an order
+    statistic of rel, taken by a sort (scope `rotate`), and last_index keeps
+    its positional meaning. pos=None is the identity fast path above.
 
-    `pos` is the GATHER-FREE rotation mode for the full-scan regime (the
-    caller guarantees num_to_find >= n_real): pos[j] = node j's position in
-    this cycle's enumeration (the inverse permutation). With a full scan
-    kept == feasible and evaluated == n, so the only order-dependent step
-    is selectHost's k-th-tie pick — resolved by one [N] sort of tie
-    positions instead of the three [N] gathers of the perm path
-    (`_reorder`, scope `rotate`: 200 of a step's 321 us at 5000 nodes on a
-    TPU v5e against the sort's 14, PERF.md section 5).
+    - The walk stops at the `num_to_find`-th smallest rel among feasible
+      nodes, `kth`: kept = feasible & (rel <= kth), evaluated = kth + 1.
+      With fewer feasible nodes than that the slot holds the mask's fill,
+      which every rel is below: kept = feasible, evaluated = n. One [N]
+      sort in `filter`; right for any num_to_find >= 1.
+    - selectHost's k-th tie in walk order is the k-th smallest rel among
+      the ties: one [N] sort in `pick`.
+
+    `full_scan` (STATIC; with `pos` only) is the caller's word that
+    num_to_find >= n_real, which it knows on the host before the launch:
+    every feasible node is kept and the walk tests all n, so `filter` needs
+    no order statistic and its sort is not compiled in. The two regimes are
+    two programs because they want different work, chosen from the launch's
+    own operands (schedule_batch), never by a switch. On a TPU v5e the
+    permutation gathers these sorts replaced cost 67 us each at 8192 rows,
+    three a step; what the sorts cost is in PERF.md section 5.
 
     `wtab` (tensor mode) is the resident [profiles x priorities] weight
     table; this pod's row is gathered by `pod["profile_id"]` and every
@@ -515,6 +531,11 @@ def _cycle_core(nodes, pod, last_index, last_node_index, num_to_find, n_real,
     ntf = jnp.asarray(num_to_find, i32)
     in_range = i < nr
 
+    @jax.named_scope("rotate")
+    def walk_offsets():
+        # positions of valid nodes are distinct in [0, n), so are these
+        return jnp.where(pos >= li, pos - li, nr - li + pos)
+
     # Nominated-ghost two-pass (podFitsOnNode :598,627) for resource-only
     # ghosts: pass 1 filters against ghost-augmented usage; pass 2 (without
     # ghosts) is implied, since removing pods only frees resources. Scores
@@ -530,34 +551,35 @@ def _cycle_core(nodes, pod, last_index, last_node_index, num_to_find, n_real,
     feasible, fail_first, general_bits = _feasibility(fnodes, pod)
     feas = feasible & in_range
 
+    rel = None
     with jax.named_scope("filter"):
         if pos is not None:
-            # full-scan regime (num_to_find >= n by caller contract): every
-            # feasible node is kept and the walk always evaluates all n, so no
-            # position-space cumsum machinery is needed at all
             F = jnp.sum(feas.astype(i32))
-            kept = feas
             found = jnp.minimum(F, ntf)
-            evaluated = jnp.where(pod["skip"], 0, nr).astype(jnp.int64)
+            if full_scan:
+                # every feasible node is kept and the walk tests all n
+                kept = feas
+                evaluated = nr
+            else:
+                rel = walk_offsets()
+                kth = _kth_smallest(feas, rel, ntf - 1)
+                kept = feas & (rel <= kth)
+                evaluated = jnp.where(F >= ntf, kth + 1, nr)
         else:
-            feas_p = feas if perm is None else _reorder(feas, perm)
-            S = jnp.cumsum(feas_p.astype(i32))
+            S = jnp.cumsum(feas.astype(i32))
             F = S[-1]                                   # total feasible
             pre = jnp.where(li > 0, S[jnp.maximum(li - 1, 0)], 0)
-            after = i >= li                              # position space
-            # rank at position p
-            rank_p = jnp.where(after, S - pre, F - pre + S)
-            kept_p = feas_p & (rank_p <= ntf)
-            kept = kept_p if perm is None else _reorder(kept_p, inv_perm)
+            after = i >= li
+            rank = jnp.where(after, S - pre, F - pre + S)
+            kept = feas & (rank <= ntf)
             found = jnp.minimum(F, ntf)
-            reached = F >= ntf
-            # the position where the sequential walk stops: unique feasible p
+            # the node where the sequential walk stops: the one feasible j
             # with rank == num_to_find; evaluated = its rotation offset + 1
-            pstar = jnp.argmax(kept_p & (rank_p == ntf)).astype(i32)
-            stop_pos = jnp.where(pstar >= li, pstar - li, nr - li + pstar)
-            evaluated = jnp.where(reached, stop_pos + 1, nr)
-            # a skip (bucket-padding) pod consumes no rotation state
-            evaluated = jnp.where(pod["skip"], 0, evaluated).astype(jnp.int64)
+            jstar = jnp.argmax(kept & (rank == ntf)).astype(i32)
+            stop = jnp.where(jstar >= li, jstar - li, nr - li + jstar)
+            evaluated = jnp.where(F >= ntf, stop + 1, nr)
+        # a skip (bucket-padding) pod consumes no rotation state
+        evaluated = jnp.where(pod["skip"], 0, evaluated).astype(jnp.int64)
 
     wrow = None if wtab is None else wtab[pod["profile_id"]]
     total = _fit_scores(nodes, pod, kept, weights, z_pad, wrow=wrow,
@@ -571,26 +593,17 @@ def _cycle_core(nodes, pod, last_index, last_node_index, num_to_find, n_real,
         # round-robin k-th tie in rotation order (selectHost :286-295)
         k = (last_node_index % num_ties.astype(jnp.int64)).astype(i32)
         if pos is not None:
-            # k-th tie by enumeration position relative to the walk origin:
-            # one sort replaces the permuted cumsum + two gathers. Positions of
-            # valid nodes are distinct in [0, n); ties exclude invalid rows.
-            rel = jnp.where(pos >= li, pos - li, nr - li + pos)
-            t_pos = jnp.where(is_tie, rel, jnp.int32(2 ** 30))
-            kth = jax.lax.dynamic_slice(jnp.sort(t_pos), (k,), (1,))[0]
-            sel = jnp.argmax(is_tie & (rel == kth)).astype(jnp.int64)
-        elif perm is None:
-            tie_p = is_tie
-            T = jnp.cumsum(tie_p.astype(i32))
-            preT = jnp.where(li > 0, T[jnp.maximum(li - 1, 0)], 0)
-            trank = jnp.where(after, T - preT, T[-1] - preT + T)
-            sel = jnp.argmax(tie_p & (trank == k + 1)).astype(jnp.int64)
+            # k-th tie by enumeration position relative to the walk origin;
+            # ties exclude invalid rows
+            if rel is None:
+                rel = walk_offsets()
+            kth_tie = _kth_smallest(is_tie, rel, k)
+            sel = jnp.argmax(is_tie & (rel == kth_tie)).astype(jnp.int64)
         else:
-            tie_p = _reorder(is_tie, perm)
-            T = jnp.cumsum(tie_p.astype(i32))
+            T = jnp.cumsum(is_tie.astype(i32))
             preT = jnp.where(li > 0, T[jnp.maximum(li - 1, 0)], 0)
             trank = jnp.where(after, T - preT, T[-1] - preT + T)
-            sel_p = jnp.argmax(tie_p & (trank == k + 1)).astype(jnp.int64)
-            sel = _reorder(perm, sel_p).astype(jnp.int64)
+            sel = jnp.argmax(is_tie & (trank == k + 1)).astype(jnp.int64)
     selected = jnp.where(found > 0, sel, -1)
 
     return {
@@ -735,9 +748,9 @@ def score_classes(nz_cpu, nz_mem, n_pods):
 
 
 def _batch_core(nodes, mut0, pods, n_pods, last_index, last_node_index,
-                num_to_find, n_real, perms, inv_perms, oid_seq,
+                num_to_find, n_real, positions, oid_seq,
                 spread0, z_pad, weights, rotate, carry_spread,
-                rotate_pos=False, constrain=None, wtab=None,
+                full_scan=False, constrain=None, wtab=None,
                 score_tab=None):
     """Body of the generic burst kernel: one serial cycle per pod, each
     folding its decision into the carried node state.
@@ -748,6 +761,12 @@ def _batch_core(nodes, mut0, pods, n_pods, last_index, last_node_index,
     runs exactly `n_pods` iterations — 10,000 pods in a 16,384 bucket pay
     for 10,000 cycles. Rows from `n_pods` on are never read; their output
     rows keep a fixed fill (-1 in the packed block, 0 elsewhere).
+
+    `rotate` (STATIC) says the NodeTree's order rotates between cycles:
+    step i then walks `positions[oid_seq[i]]`, each node's place in that
+    cycle's enumeration, and `full_scan` (STATIC) says every node is scored
+    (`_cycle_core`). Without `rotate` every step walks the device axis and
+    neither operand is read.
 
     `constrain` (optional) pins the node-axis carry — the mutable state
     rows and the carried spread vector — to a mesh sharding every
@@ -825,19 +844,13 @@ def _batch_core(nodes, mut0, pods, n_pods, last_index, last_node_index,
                     mine = jnp.arange(len(score_tab)) == pod["score_class"]
                     local = jnp.sum(jnp.where(mine[:, None], board, 0),
                                     axis=0)
-        perm = inv_perm = pos = None
-        if rotate_pos:
-            # gather-free rotation: perms holds per-order POSITION vectors
-            pos = perms[oid_seq[i]]
-        elif rotate:
-            oid = oid_seq[i]
-            perm, inv_perm = perms[oid], inv_perms[oid]
+        pos = positions[oid_seq[i]] if rotate else None
         if carry_spread:
             pod = {**pod, "spread_counts": spread}
         full = {**static, **state}
         out = _cycle_core(full, pod, li, lni, num_to_find, n_real, weights,
-                          z_pad, perm=perm, inv_perm=inv_perm, pos=pos,
-                          wtab=wtab, local=local)
+                          z_pad, pos=pos, full_scan=full_scan, wtab=wtab,
+                          local=local)
         sel = out["selected"]
         hit = out["found"] > 0
         new_state = constrain(_fold_state(state, pod, sel, hit))
@@ -878,36 +891,48 @@ def _batch_core(nodes, mut0, pods, n_pods, last_index, last_node_index,
 
 
 @partial(jax.jit, static_argnames=("z_pad", "weights_tuple", "rotate",
-                                   "carry_spread", "rotate_pos"))
+                                   "carry_spread", "full_scan"))
 def _schedule_batch_jit(nodes, mut0, pods, n_pods, last_index,
-                        last_node_index, num_to_find, n_real, perms,
-                        inv_perms, oid_seq, spread0, score_tab, z_pad,
+                        last_node_index, num_to_find, n_real, positions,
+                        oid_seq, spread0, score_tab, z_pad,
                         weights_tuple, rotate, carry_spread,
-                        rotate_pos=False):
+                        full_scan=False):
     return _batch_core(nodes, mut0, pods, n_pods, last_index,
-                       last_node_index, num_to_find, n_real, perms,
-                       inv_perms, oid_seq, spread0, z_pad,
+                       last_node_index, num_to_find, n_real, positions,
+                       oid_seq, spread0, z_pad,
                        dict(weights_tuple), rotate, carry_spread,
-                       rotate_pos=rotate_pos, score_tab=score_tab)
+                       full_scan=full_scan, score_tab=score_tab)
 
 
 @partial(jax.jit, static_argnames=("z_pad", "weights_tuple", "rotate",
-                                   "carry_spread", "rotate_pos"))
+                                   "carry_spread", "full_scan"))
 def _schedule_batch_wtab_jit(nodes, mut0, pods, n_pods, wtab, last_index,
-                             last_node_index, num_to_find, n_real, perms,
-                             inv_perms, oid_seq, spread0, z_pad,
+                             last_node_index, num_to_find, n_real,
+                             positions, oid_seq, spread0, z_pad,
                              weights_tuple, rotate, carry_spread,
-                             rotate_pos=False):
+                             full_scan=False):
     return _batch_core(nodes, mut0, pods, n_pods, last_index,
-                       last_node_index, num_to_find, n_real, perms,
-                       inv_perms, oid_seq, spread0, z_pad,
+                       last_node_index, num_to_find, n_real, positions,
+                       oid_seq, spread0, z_pad,
                        dict(weights_tuple), rotate, carry_spread,
-                       rotate_pos=rotate_pos, wtab=wtab)
+                       full_scan=full_scan, wtab=wtab)
+
+
+def _rotation_operands(rotation, num_to_find, n_real):
+    """(rotate, full_scan, positions, oid_seq) of a launch: the two statics
+    that choose its program and the order operands (placeholders when the
+    tree never rotates). `full_scan` is read off the launch's own host
+    integers; the axis program is one program at any quota."""
+    if rotation is None:
+        return (False, False, jnp.zeros((1, 1), jnp.int32),
+                jnp.zeros(1, jnp.int32))
+    positions, oid_seq = (jnp.asarray(a, jnp.int32) for a in rotation)
+    return True, int(num_to_find) >= int(n_real), positions, oid_seq
 
 
 def schedule_batch(nodes, pods, last_index, last_node_index, num_to_find, n_real,
                    z_pad, weights=None, rotation=None, spread0=None,
-                   rotation_pos=None, carry_in=None, mesh=None, wtab=None,
+                   carry_in=None, mesh=None, wtab=None,
                    n_pods=None, classes=None):
     """Schedule a burst of pods against one snapshot, decisions serially
     equivalent to per-pod cycles. `pods` is a dict of [B, ...] arrays
@@ -916,14 +941,16 @@ def schedule_batch(nodes, pods, last_index, last_node_index, num_to_find, n_real
     `n_pods` on are never read, and their rows of the outputs keep a fixed
     fill (-1 in `packed`). None = all B rows.
 
-    `rotation` = (perms[L, n_pad], inv_perms[L, n_pad], oid_seq[B]) supplies
-    each in-burst cycle's NodeTree enumeration order when it differs from
-    the device axis (uneven zones); None = the axis order every cycle.
-    `rotation_pos` = (pos_arr[L, n_pad], oid_seq[B]) is the gather-free
-    variant for the full-scan regime (caller guarantees
-    num_to_find >= n_real): pos_arr[l][j] = node j's enumeration position
-    under order l (the inverse permutation). Mutually exclusive with
-    `rotation`. `spread0` [n_pad] carries selector-spread counts across the
+    `rotation` = (positions[L, n_pad], oid_seq[B]) supplies each in-burst
+    cycle's NodeTree enumeration order when it differs from the device axis
+    (uneven zones): positions[l][j] = node j's place in the enumeration
+    under order l (the inverse of its permutation; rows past n_real keep
+    their own index), oid_seq[i] the order of cycle i. None = the axis
+    order every cycle. Whether the walk is truncated is read off the
+    launch's own `num_to_find` and `n_real` (host integers): with every
+    node scored (num_to_find >= n_real) the program without a sort in
+    `filter` runs, the one with it otherwise (`_cycle_core`). `spread0`
+    [n_pad] carries selector-spread counts across the
     burst (requires spec-identical pods — one shared selector set).
 
     `carry_in` = (mut_state, spread) chains a pipelined wave straight off
@@ -954,23 +981,13 @@ def schedule_batch(nodes, pods, last_index, last_node_index, num_to_find, n_real
     board and a step rescores the one row it bound (_batch_core); without
     it every step scores every row. The same decisions either way."""
     weights_tuple = tuple(sorted((weights or DEFAULT_WEIGHTS).items()))
-    z = jnp.zeros((1, 1), jnp.int32)
     score_tab = None
     if classes is not None:
         assert wtab is None, "a per-pod weight row makes the board per profile"
         pods = {**pods, "score_class": classes[0]}
         score_tab = jnp.asarray(classes[1], jnp.int64)
-    if rotation_pos is not None:
-        assert rotation is None
-        perms = jnp.asarray(rotation_pos[0], jnp.int32)
-        inv_perms = z
-        oid_seq = jnp.asarray(rotation_pos[1], jnp.int32)
-    elif rotation is None:
-        perms = inv_perms = z
-        oid_seq = jnp.zeros(1, jnp.int32)
-    else:
-        perms, inv_perms, oid_seq = (jnp.asarray(a, jnp.int32)
-                                     for a in rotation)
+    rotate, full_scan, positions, oid_seq = _rotation_operands(
+        rotation, num_to_find, n_real)
     carry_spread = spread0 is not None or (
         carry_in is not None and carry_in[1] is not None)
     if carry_in is not None:
@@ -986,29 +1003,27 @@ def schedule_batch(nodes, pods, last_index, last_node_index, num_to_find, n_real
     n_pods = _i64(pods["skip"].shape[0] if n_pods is None else n_pods)
     if mesh is not None:
         from kubernetes_tpu.parallel import sharding as S
-        fn = S.sharded_scan_fn(mesh, z_pad, weights_tuple,
-                               rotation is not None, carry_spread,
-                               rotation_pos is not None,
+        fn = S.sharded_scan_fn(mesh, z_pad, weights_tuple, rotate,
+                               carry_spread, full_scan,
                                use_wtab=wtab is not None)
         if wtab is not None:
             return fn(nodes, mut0, pods, n_pods, wtab, _i64(last_index),
                       _i64(last_node_index), _i64(num_to_find),
-                      _i64(n_real), perms, inv_perms, oid_seq, s0)
+                      _i64(n_real), positions, oid_seq, s0)
         return fn(nodes, mut0, pods, n_pods, _i64(last_index),
                   _i64(last_node_index), _i64(num_to_find), _i64(n_real),
-                  perms, inv_perms, oid_seq, s0, score_tab)
+                  positions, oid_seq, s0, score_tab)
     if wtab is not None:
         return _schedule_batch_wtab_jit(
             nodes, mut0, pods, n_pods, wtab, _i64(last_index),
-            _i64(last_node_index), _i64(num_to_find), _i64(n_real), perms,
-            inv_perms, oid_seq, s0, z_pad, weights_tuple,
-            rotation is not None, carry_spread,
-            rotate_pos=rotation_pos is not None)
+            _i64(last_node_index), _i64(num_to_find), _i64(n_real),
+            positions, oid_seq, s0, z_pad, weights_tuple, rotate,
+            carry_spread, full_scan=full_scan)
     return _schedule_batch_jit(
         nodes, mut0, pods, n_pods, _i64(last_index), _i64(last_node_index),
-        _i64(num_to_find), _i64(n_real), perms, inv_perms, oid_seq, s0,
-        score_tab, z_pad, weights_tuple, rotation is not None, carry_spread,
-        rotate_pos=rotation_pos is not None)
+        _i64(num_to_find), _i64(n_real), positions, oid_seq, s0,
+        score_tab, z_pad, weights_tuple, rotate, carry_spread,
+        full_scan=full_scan)
 
 
 # ---------------------------------------------------------------------------
@@ -1041,11 +1056,12 @@ def schedule_batch(nodes, pods, last_index, last_node_index, num_to_find, n_real
 
 def _segments_core(nodes, mut0, pods, seg_start, gang, n_pods,
                    last_index, last_node_index, num_to_find, n_real,
-                   perms, inv_perms, oid_seq, spread0, z_pad,
-                   weights, rot_mode, carry_spread, constrain=None,
-                   wtab=None, gang_score=False):
-    """rot_mode: 0 = stable axis order, 1 = perm/inv-perm gathers,
-    2 = gather-free positions (full-scan regime).
+                   positions, oid_seq, spread0, z_pad,
+                   weights, rotate, carry_spread, full_scan=False,
+                   constrain=None, wtab=None, gang_score=False):
+    """`rotate` / `full_scan` (STATIC) and `positions` / `oid_seq` as in
+    `_batch_core`, but the order id is looked up by enumerations CONSUMED
+    (`oid_seq[t]`, see the block comment above).
 
     The pod count is a DYNAMIC operand of a single lax.while_loop (the
     uniform kernel's trick): the [B, ...] operands are padded to the
@@ -1108,18 +1124,13 @@ def _segments_core(nodes, mut0, pods, seg_start, gang, n_pods,
         # the serial trial's post-failure decisions are discarded anyway
         eskip = pod["skip"] | (gflag & failed)
         pod = {**pod, "skip": eskip}
-        perm = inv_perm = pos = None
-        if rot_mode == 2:
-            pos = perms[oid_seq[t]]
-        elif rot_mode == 1:
-            oid = oid_seq[t]
-            perm, inv_perm = perms[oid], inv_perms[oid]
+        pos = positions[oid_seq[t]] if rotate else None
         if carry_spread:
             pod = {**pod, "spread_counts": spread}
         full = {**static, **state}
         out_c = _cycle_core(full, pod, li, lni, num_to_find, n_real,
-                            weights, z_pad, perm=perm, inv_perm=inv_perm,
-                            pos=pos, wtab=wtab,
+                            weights, z_pad, pos=pos, full_scan=full_scan,
+                            wtab=wtab,
                             gang=(gz, gflag) if gang_score else None)
         sel = out_c["selected"]
         hit = out_c["found"] > 0
@@ -1176,29 +1187,33 @@ def _segments_core(nodes, mut0, pods, seg_start, gang, n_pods,
     return state, li, lni, spread, out.reshape(4 * B)
 
 
-@partial(jax.jit, static_argnames=("z_pad", "weights_tuple", "rot_mode",
-                                   "carry_spread"))
+@partial(jax.jit, static_argnames=("z_pad", "weights_tuple", "rotate",
+                                   "carry_spread", "full_scan"))
 def _schedule_batch_seg_jit(nodes, mut0, pods, seg_start, gang, n_pods,
                             last_index, last_node_index, num_to_find, n_real,
-                            perms, inv_perms, oid_seq, spread0, z_pad,
-                            weights_tuple, rot_mode, carry_spread):
+                            positions, oid_seq, spread0, z_pad,
+                            weights_tuple, rotate, carry_spread, full_scan):
     return _segments_core(nodes, mut0, pods, seg_start, gang, n_pods,
                           last_index, last_node_index, num_to_find, n_real,
-                          perms, inv_perms, oid_seq, spread0, z_pad,
-                          dict(weights_tuple), rot_mode, carry_spread)
+                          positions, oid_seq, spread0, z_pad,
+                          dict(weights_tuple), rotate, carry_spread,
+                          full_scan=full_scan)
 
 
-@partial(jax.jit, static_argnames=("z_pad", "weights_tuple", "rot_mode",
-                                   "carry_spread", "gang_score", "use_wtab"))
+@partial(jax.jit, static_argnames=("z_pad", "weights_tuple", "rotate",
+                                   "carry_spread", "full_scan", "gang_score",
+                                   "use_wtab"))
 def _schedule_batch_seg_prof_jit(nodes, mut0, pods, seg_start, gang, n_pods,
                                  last_index, last_node_index, num_to_find,
-                                 n_real, perms, inv_perms, oid_seq, spread0,
-                                 wtab, z_pad, weights_tuple, rot_mode,
-                                 carry_spread, gang_score, use_wtab):
+                                 n_real, positions, oid_seq, spread0,
+                                 wtab, z_pad, weights_tuple, rotate,
+                                 carry_spread, full_scan, gang_score,
+                                 use_wtab):
     return _segments_core(nodes, mut0, pods, seg_start, gang, n_pods,
                           last_index, last_node_index, num_to_find, n_real,
-                          perms, inv_perms, oid_seq, spread0, z_pad,
-                          dict(weights_tuple), rot_mode, carry_spread,
+                          positions, oid_seq, spread0, z_pad,
+                          dict(weights_tuple), rotate, carry_spread,
+                          full_scan=full_scan,
                           wtab=wtab if use_wtab else None,
                           gang_score=gang_score)
 
@@ -1206,7 +1221,7 @@ def _schedule_batch_seg_prof_jit(nodes, mut0, pods, seg_start, gang, n_pods,
 def schedule_batch_segments(nodes, pods, seg_start, gang, n_pods,
                             last_index, last_node_index, num_to_find,
                             n_real, z_pad, weights=None, rotation=None,
-                            rotation_pos=None, spread0=None, mesh=None,
+                            spread0=None, mesh=None,
                             wtab=None, gang_score=False):
     """Schedule a segmented drain window — singleton runs and all-or-nothing
     gang segments — in ONE launch with ONE packed fetch (see block comment).
@@ -1216,7 +1231,7 @@ def schedule_batch_segments(nodes, pods, seg_start, gang, n_pods,
     the while_loop runs exactly that many cycles, so bucket padding costs
     nothing at run time. `seg_start[B]` marks each segment's first pod;
     `gang[B]` marks members of all-or-nothing segments.
-    `rotation`/`rotation_pos` follow schedule_batch's contract except the
+    `rotation` follows schedule_batch's contract except the
     per-cycle order id sequence is indexed by enumerations CONSUMED (gang
     rewinds restore the cursor), so it must be the plain burst-wide walk,
     unsliced. Returns (state, li, lni, spread, packed[4B] i32) with
@@ -1234,21 +1249,8 @@ def schedule_batch_segments(nodes, pods, seg_start, gang, n_pods,
     set-scoring carry in (see _segments_core) — both off reproduce the
     pre-profile program exactly."""
     weights_tuple = tuple(sorted((weights or DEFAULT_WEIGHTS).items()))
-    z = jnp.zeros((1, 1), jnp.int32)
-    if rotation_pos is not None:
-        assert rotation is None
-        rot_mode = 2
-        perms = jnp.asarray(rotation_pos[0], jnp.int32)
-        inv_perms = z
-        oid_seq = jnp.asarray(rotation_pos[1], jnp.int32)
-    elif rotation is not None:
-        rot_mode = 1
-        perms, inv_perms, oid_seq = (jnp.asarray(a, jnp.int32)
-                                     for a in rotation)
-    else:
-        rot_mode = 0
-        perms = inv_perms = z
-        oid_seq = jnp.zeros(1, jnp.int32)
+    rotate, full_scan, positions, oid_seq = _rotation_operands(
+        rotation, num_to_find, n_real)
     mut0 = {k: nodes[k] for k in _MUTABLE}
     carry_spread = spread0 is not None
     s0 = jnp.asarray(spread0, jnp.int64) if carry_spread \
@@ -1258,8 +1260,8 @@ def schedule_batch_segments(nodes, pods, seg_start, gang, n_pods,
         wtab = jnp.asarray(wtab, jnp.int64)
     if mesh is not None:
         from kubernetes_tpu.parallel import sharding as S
-        fn = S.sharded_segments_fn(mesh, z_pad, weights_tuple, rot_mode,
-                                   carry_spread,
+        fn = S.sharded_segments_fn(mesh, z_pad, weights_tuple, rotate,
+                                   carry_spread, full_scan,
                                    use_wtab=wtab is not None,
                                    gang_score=bool(gang_score))
         if profile_mode:
@@ -1268,27 +1270,27 @@ def schedule_batch_segments(nodes, pods, seg_start, gang, n_pods,
             return fn(nodes, mut0, pods, jnp.asarray(seg_start, bool),
                       jnp.asarray(gang, bool), _i64(n_pods),
                       _i64(last_index), _i64(last_node_index),
-                      _i64(num_to_find), _i64(n_real), perms, inv_perms,
+                      _i64(num_to_find), _i64(n_real), positions,
                       oid_seq, s0, w)
         return fn(nodes, mut0, pods, jnp.asarray(seg_start, bool),
                   jnp.asarray(gang, bool), _i64(n_pods), _i64(last_index),
                   _i64(last_node_index), _i64(num_to_find), _i64(n_real),
-                  perms, inv_perms, oid_seq, s0)
+                  positions, oid_seq, s0)
     if profile_mode:
         w = wtab if wtab is not None else jnp.zeros(
             (1, len(PRIORITY_AXIS)), jnp.int64)
         return _schedule_batch_seg_prof_jit(
             nodes, mut0, pods, jnp.asarray(seg_start, bool),
             jnp.asarray(gang, bool), _i64(n_pods), _i64(last_index),
-            _i64(last_node_index), _i64(num_to_find), _i64(n_real), perms,
-            inv_perms, oid_seq, s0, w, z_pad, weights_tuple, rot_mode,
-            carry_spread, bool(gang_score), wtab is not None)
+            _i64(last_node_index), _i64(num_to_find), _i64(n_real),
+            positions, oid_seq, s0, w, z_pad, weights_tuple, rotate,
+            carry_spread, full_scan, bool(gang_score), wtab is not None)
     return _schedule_batch_seg_jit(
         nodes, mut0, pods, jnp.asarray(seg_start, bool),
         jnp.asarray(gang, bool), _i64(n_pods), _i64(last_index),
-        _i64(last_node_index), _i64(num_to_find), _i64(n_real), perms,
-        inv_perms, oid_seq, s0, z_pad, weights_tuple, rot_mode,
-        carry_spread)
+        _i64(last_node_index), _i64(num_to_find), _i64(n_real),
+        positions, oid_seq, s0, z_pad, weights_tuple, rotate,
+        carry_spread, full_scan)
 
 
 # ---------------------------------------------------------------------------

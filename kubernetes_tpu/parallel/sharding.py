@@ -231,13 +231,13 @@ _PREEMPT_CACHE: dict = {}
 
 
 def sharded_scan_fn(mesh: Mesh, z_pad: int, weights_tuple, rotate: bool,
-                    carry_spread: bool, rotate_pos: bool,
+                    carry_spread: bool, full_scan: bool,
                     use_wtab: bool = False):
     """The generic burst kernel (kernels._batch_core) with the
     node axis sharded over the mesh — the SAME program single-device runs,
     parameterized by the sharding spec: each chip folds the selected pod's
     deltas into its node rows every step (the carried _MUTABLE state and
-    spread vector are pinned to the node sharding), rotation perm rows
+    spread vector are pinned to the node sharding), rotation position rows
     replicate (they are tiny [L, N] index tables), the pod count (the
     loop's dynamic trip count) is a replicated scalar, and the per-node
     feasibility/score vectors ride XLA collectives (all-gather over ICI)
@@ -246,7 +246,7 @@ def sharded_scan_fn(mesh: Mesh, z_pad: int, weights_tuple, rotate: bool,
     Decisions are bit-identical to the single-device scan
     (tests/test_sharding.py + the sharded fuzz variants). Compiled once
     per (mesh, statics) and cached."""
-    key = (mesh, z_pad, weights_tuple, rotate, carry_spread, rotate_pos,
+    key = (mesh, z_pad, weights_tuple, rotate, carry_spread, full_scan,
            use_wtab)
     fn = _SCAN_CACHE.get(key)
     if fn is not None:
@@ -257,24 +257,24 @@ def sharded_scan_fn(mesh: Mesh, z_pad: int, weights_tuple, rotate: bool,
         # profile tensor mode: the replicated [P, K] weight table rides the
         # operands and each step gathers its pod's row (profile_id in pods)
         def f(nodes, mut0, pods, n_pods, wtab, last_index, last_node_index,
-              num_to_find, n_real, perms, inv_perms, oid_seq, spread0):
+              num_to_find, n_real, positions, oid_seq, spread0):
             nodes = _constrain_nodes(mesh, nodes)
             return K._batch_core(nodes, mut0, pods, n_pods, last_index,
                                  last_node_index, num_to_find, n_real,
-                                 perms, inv_perms, oid_seq, spread0, z_pad,
+                                 positions, oid_seq, spread0, z_pad,
                                  dict(weights_tuple), rotate, carry_spread,
-                                 rotate_pos=rotate_pos, constrain=c,
+                                 full_scan=full_scan, constrain=c,
                                  wtab=wtab)
     else:
         def f(nodes, mut0, pods, n_pods, last_index, last_node_index,
-              num_to_find, n_real, perms, inv_perms, oid_seq, spread0,
+              num_to_find, n_real, positions, oid_seq, spread0,
               score_tab=None):
             nodes = _constrain_nodes(mesh, nodes)
             return K._batch_core(nodes, mut0, pods, n_pods, last_index,
                                  last_node_index, num_to_find, n_real,
-                                 perms, inv_perms, oid_seq, spread0, z_pad,
+                                 positions, oid_seq, spread0, z_pad,
                                  dict(weights_tuple), rotate, carry_spread,
-                                 rotate_pos=rotate_pos, constrain=c,
+                                 full_scan=full_scan, constrain=c,
                                  score_tab=score_tab)
 
     fn = _SCAN_CACHE[key] = jax.jit(f)
@@ -282,18 +282,18 @@ def sharded_scan_fn(mesh: Mesh, z_pad: int, weights_tuple, rotate: bool,
 
 
 def sharded_segments_fn(mesh: Mesh, z_pad: int, weights_tuple,
-                        rot_mode: int, carry_spread: bool,
+                        rotate: bool, carry_spread: bool, full_scan: bool,
                         use_wtab: bool = False, gang_score: bool = False):
     """The fused segmented drain-window kernel (kernels._segments_core)
     sharded over the mesh: the whole while_loop carry — live mutable rows,
     spread, AND the in-scan gang checkpoint — stays under
     NamedSharding(mesh, P("nodes")); a gang rewind is a shard-local
     element-wise select between two identically-sharded carries, rotation
-    stays indexed by the consumed-count t with the perm tables replicated,
+    stays indexed by the consumed-count t with the position table replicated,
     and the single [4B] packed output replicates (per-pod, tiny).
     Decisions bit-identical to the single-device fused kernel."""
-    key = (mesh, z_pad, weights_tuple, rot_mode, carry_spread, use_wtab,
-           gang_score)
+    key = (mesh, z_pad, weights_tuple, rotate, carry_spread, full_scan,
+           use_wtab, gang_score)
     fn = _SEG_CACHE.get(key)
     if fn is not None:
         return fn
@@ -305,28 +305,30 @@ def sharded_segments_fn(mesh: Mesh, z_pad: int, weights_tuple,
         # the tiny [z_pad] gang zone-count carry replicates with the
         # scalar walk counters
         def f(nodes, mut0, pods, seg_start, gang, n_pods, last_index,
-              last_node_index, num_to_find, n_real, perms, inv_perms,
+              last_node_index, num_to_find, n_real, positions,
               oid_seq, spread0, wtab):
             nodes = _constrain_nodes(mesh, nodes)
             return K._segments_core(nodes, mut0, pods, seg_start, gang,
                                     n_pods, last_index, last_node_index,
-                                    num_to_find, n_real, perms, inv_perms,
+                                    num_to_find, n_real, positions,
                                     oid_seq, spread0, z_pad,
-                                    dict(weights_tuple), rot_mode,
-                                    carry_spread, constrain=c,
+                                    dict(weights_tuple), rotate,
+                                    carry_spread, full_scan=full_scan,
+                                    constrain=c,
                                     wtab=wtab if use_wtab else None,
                                     gang_score=gang_score)
     else:
         def f(nodes, mut0, pods, seg_start, gang, n_pods, last_index,
-              last_node_index, num_to_find, n_real, perms, inv_perms,
+              last_node_index, num_to_find, n_real, positions,
               oid_seq, spread0):
             nodes = _constrain_nodes(mesh, nodes)
             return K._segments_core(nodes, mut0, pods, seg_start, gang,
                                     n_pods, last_index, last_node_index,
-                                    num_to_find, n_real, perms, inv_perms,
+                                    num_to_find, n_real, positions,
                                     oid_seq, spread0, z_pad,
-                                    dict(weights_tuple), rot_mode,
-                                    carry_spread, constrain=c)
+                                    dict(weights_tuple), rotate,
+                                    carry_spread, full_scan=full_scan,
+                                    constrain=c)
 
     fn = _SEG_CACHE[key] = jax.jit(f)
     return fn
@@ -406,7 +408,7 @@ def sharded_batch_fn(mesh: Mesh, z_pad: int, weights=None):
     the single-device scan (see tests/test_sharding.py)."""
     weights_tuple = tuple(sorted((weights or K.DEFAULT_WEIGHTS).items()))
     inner = sharded_scan_fn(mesh, z_pad, weights_tuple, rotate=False,
-                            carry_spread=False, rotate_pos=False)
+                            carry_spread=False, full_scan=False)
 
     def fn(nodes, pods, last_index, last_node_index, num_to_find, n_real,
            n_pods=None):
@@ -416,7 +418,7 @@ def sharded_batch_fn(mesh: Mesh, z_pad: int, weights=None):
             n_pods = pods["skip"].shape[0]
         state, li, lni, _spread, outs = inner(
             nodes, mut0, pods, jnp.asarray(n_pods, jnp.int64), last_index,
-            last_node_index, num_to_find, n_real, z, z,
+            last_node_index, num_to_find, n_real, z,
             jnp.zeros(1, jnp.int32), jnp.zeros((), jnp.int64))
         return state, li, lni, outs
 

@@ -6,7 +6,7 @@ informer pump, Scheduler.schedule_burst, the client's watch) with the
 benchmark's own client, cluster builder and replay (`benchmark/lib/`), at
 sizes where walks meet nodes that do not fit, last_index wraps and a tail of
 pods is unschedulable; on uneven zones with Services the launch has to take
-the rotation (gather) program. The reference is checked too: against
+the rotation program (the truncated walk on positions). The reference is checked too: against
 `default_provider` at 100%, against the serial oracle, and for the deferred
 walk of `skip_decision`. CPU backend; decisions and counts only.
 """
@@ -176,16 +176,15 @@ def test_program_equals_reference_at_a_set_percentage(percentage):
     assert run.sched.algorithm.last_index == ref.last_index
 
 
-# -- (c) uneven zones, Services: the rotation (gather) program ------------------
+# -- (c) uneven zones, Services: the rotation program, truncated ----------------
 def test_uneven_zones_with_services_take_the_rotation_program(monkeypatch):
     from kubernetes_tpu.core import tpu_scheduler as T
     seen = []
     orig = T.K.schedule_batch
 
     def spy(*a, **kw):
-        seen.append((kw.get("rotation") is not None,
-                     kw.get("rotation_pos") is not None,
-                     kw.get("spread0") is not None))
+        seen.append((kw.get("rotation") is not None, len(kw["rotation"]),
+                     a[4] < a[5], kw.get("spread0") is not None))
         return orig(*a, **kw)
     monkeypatch.setattr(T.K, "schedule_batch", spy)
     fallbacks0 = sum(c.value for c in T.ORACLE_FALLBACKS._children.values())
@@ -207,8 +206,9 @@ def test_uneven_zones_with_services_take_the_rotation_program(monkeypatch):
     assert run.sched.algorithm.last_index == ref.last_index
     assert run.sched.algorithm.last_node_index == ref.last_node_index
     assert len(seen) >= 5
-    # every launch: rotation by gather, no position mode, carried spread
-    assert set(seen) == {(True, False, True)}
+    # every launch: an order shipped as (positions, order ids), under a
+    # truncated walk (num_to_find < n), carried spread
+    assert set(seen) == {(True, 2, True, True)}
     assert sum(c.value for c in T.ORACLE_FALLBACKS._children.values()) \
         == fallbacks0
 
@@ -241,20 +241,24 @@ def rollouts(run):
 def test_program_reference_and_oracle_agree_on_uneven_zones(
         n, cap, percentage, per_node, services):
     """Uneven zones x Services x truncated walk over consecutive launches:
-    the program (the gather rotation program with carried spread counts),
+    the program (the truncated position program with carried spread counts),
     the benchmark's plain reference and the program's serial oracle."""
     from kubernetes_tpu.core.tpu_scheduler import (ORACLE_FALLBACKS,
                                                    SCAN_ORDER_STEPS,
                                                    WALK_NODES)
     cfg = config(n, cap, percentage, resident=resident_of(per_node, services))
     seed = 2 ** 31 + n
-    gather0 = SCAN_ORDER_STEPS.labels("gather").value
+    order0 = {o: SCAN_ORDER_STEPS.labels(o).value
+              for o in ("position", "gather", "axis")}
     tested0 = WALK_NODES.labels("truncated").value
     fallbacks0 = sum(c.value for c in ORACLE_FALLBACKS._children.values())
     run = Run(cfg, SPREAD, seed)
     on_device = rollouts(run)
-    # every one of the 750 decisions was a step of the gather program
-    assert SCAN_ORDER_STEPS.labels("gather").value - gather0 == 750
+    # every one of the 750 decisions was a step on shipped positions; the
+    # permutation gathers are gone, their label stays declared at 0
+    assert {o: SCAN_ORDER_STEPS.labels(o).value - v
+            for o, v in order0.items()} == \
+        {"position": 750, "gather": 0, "axis": 0}
     assert sum(c.value for c in ORACLE_FALLBACKS._children.values()) \
         == fallbacks0
     rep, ref = run.replay()
@@ -495,12 +499,12 @@ def test_order_counter_and_rotation_span(monkeypatch):
         return [e for e in obs.trace.events() if e["name"] == "burst.rotation"]
 
     resident = resident_of(2, 5)
-    # uneven zones (84/83/83), truncated walk: every step gathers
+    # uneven zones (84/83/83), truncated walk: every step sorts positions
     obs.trace.clear()
     before = steps()
     run = Run(config(250, 4, 0, resident=resident), SPREAD, 21)
     assert len(run.bound(run.cycle(150, max_pods=150))) == 150
-    assert moved(before) == {"gather": 150}
+    assert moved(before) == {"position": 150}
     spans = rotation_spans()
     assert len(spans) == 1
     # three zones: the axis order and the three rotated ones, in a bucket of 4
@@ -511,7 +515,8 @@ def test_order_counter_and_rotation_span(monkeypatch):
     assert names.index("burst.rotation") < names.index("burst.stack") \
         < names.index("burst.dispatch")
 
-    # the same cluster with every node scored: the position sort, no gather
+    # the same cluster with every node scored: positions again, no sort in
+    # `filter` (the program differs, not the way the order is shipped)
     obs.trace.clear()
     before = steps()
     run = Run(config(250, 4, 100, resident=resident), SPREAD, 22)

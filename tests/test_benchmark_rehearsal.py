@@ -109,7 +109,7 @@ def counter_metric(name, res, rep):
     (ARRIVALS, 3, None, None),              # serve loop
     (ADAPTIVE, 2**31 + 17, None, None),     # truncated walk
     (BACKLOG, 11, altered_binding, None),   # the guard can fail
-    # truncated walk + rotation by gather + carried spread counts
+    # truncated walk on shipped positions + carried spread counts
     (DENSITY_ADAPTIVE, 2**31 + 23, None, None),
     (DENSITY_ADAPTIVE, 2**31 + 23, None, EVERY_NODE),   # its control
     # eight pod sizes onto nodes that hold pods: stacked rows, uneven board
@@ -160,9 +160,13 @@ def test_rehearsed_cell(monkeypatch, execute, cell, seed, hook, program):
     if cell == DENSITY_ADAPTIVE:
         moved = rep["counters"]
         assert "burst_uniform" not in moved["tpu_device_dispatch_total"]
-        # every step of every launch permutes its masks (the gather program)
+        # every step of every launch walks shipped positions, as cell 2's do
         assert moved["tpu_scan_order_steps_total"] == \
-            {"gather": res["attempted"]}
+            {"position": res["attempted"]}
+        assert counter_metric("rotation_position_steps_per_pod.backlog",
+                              res, rep) == 1.0
+        assert counter_metric("rotation_gather_steps_per_pod.backlog",
+                              res, rep) == 0.0
         assert moved["tpu_scan_steps_total"]["real"] == res["attempted"]
         # a walk stops at its quota: 120 of 250 nodes, none of them full
         assert moved["tpu_walk_nodes_evaluated_total"] == \

@@ -5,8 +5,9 @@ A launch of `n_pods` pods in a power-of-two bucket has to return what a
 launch whose bucket is exactly `n_pods` returns, and what serial
 `schedule_cycle` calls with a host-side fold return: selections, the
 `li_after` / `lni_delta` prefix of the packed block, and the carry (`state`,
-`li`, `lni`, `spread`) — with the truncated walk, both rotation modes,
-carried spread, and on the 8-device CPU mesh. The rows from `n_pods` on hold
+`li`, `lni`, `spread`) — with the truncated walk, a rotating order under
+it and with every node scored (the two position programs), carried spread,
+and on the 8-device CPU mesh. The rows from `n_pods` on hold
 pods that would fit (not skip pods), so a step that read one would show.
 CPU backend; decisions and counts only.
 """
@@ -27,7 +28,7 @@ from test_sharding import _cluster, _encode, _mk_pods
 N_NODES = 40
 Z_PAD = 4
 COUNTS = [(1, 16), (15, 16), (16, 16), (17, 32), (150, 256), (300, 512)]
-MODES = ["truncated", "rotation", "rotation_pos", "spread", "sharded"]
+MODES = ["truncated", "rotation", "rotation_full", "spread", "sharded"]
 
 
 @pytest.fixture(scope="module")
@@ -56,20 +57,15 @@ def _setup(mode, batch, bucket):
     n, n_pad = batch.n_real, batch.n_pad
     rng = np.random.RandomState(len(mode))
     kw, ntf, li0 = {}, 10, n - 5            # the walk wraps at once
-    if mode in ("rotation", "rotation_pos"):
-        # 4 enumeration orders; invalid rows tail every permutation
-        perms = np.stack([np.concatenate([rng.permutation(n),
-                                          np.arange(n, n_pad)])
-                          for _ in range(4)]).astype(np.int32)
-        inv = np.empty_like(perms)
-        for l in range(4):
-            inv[l, perms[l]] = np.arange(n_pad, dtype=np.int32)
+    if mode in ("rotation", "rotation_full"):
+        # 4 enumeration orders as positions; invalid rows keep their index
+        positions = np.stack([np.concatenate([rng.permutation(n),
+                                              np.arange(n, n_pad)])
+                              for _ in range(4)]).astype(np.int32)
         seq = rng.randint(0, 4, size=bucket).astype(np.int32)
-        if mode == "rotation":
-            kw["rotation"] = (perms, inv, seq)
-        else:
-            kw["rotation_pos"] = (inv, seq)
-            ntf, li0 = n, 7                 # the full-scan regime
+        kw["rotation"] = (positions, seq)
+        if mode == "rotation_full":
+            ntf, li0 = n, 7                 # every node scored
     elif mode == "spread":
         spread0 = np.zeros(n_pad, np.int64)
         spread0[:n] = rng.randint(0, 4, size=n)
@@ -77,14 +73,27 @@ def _setup(mode, batch, bucket):
     return kw, ntf, li0, 3
 
 
-@partial(jax.jit, static_argnames=("gather",))
-def _rotated_cycle(nodes, pod, li, lni, ntf, n_real, perm, inv_perm, gather):
-    kw = {"perm": perm, "inv_perm": inv_perm} if gather else {"pos": inv_perm}
+@partial(jax.jit, static_argnames=("full_scan",))
+def _rotated_cycle(nodes, pod, li, lni, ntf, n_real, pos, full_scan):
     out = K._cycle_core(nodes, pod, li, lni, ntf, n_real,
-                        dict(K.DEFAULT_WEIGHTS), Z_PAD, **kw)
+                        dict(K.DEFAULT_WEIGHTS), Z_PAD, pos=pos,
+                        full_scan=full_scan)
     return {k: out[k] for k in ("selected", "next_last_index",
                                 "next_last_node_index", "num_ties",
                                 "found", "evaluated")}
+
+
+def _fold(nodes, pod, s, spread):
+    """A decision folded on the host: NodeInfo.AddPod's aggregates."""
+    if s < 0:
+        return
+    for key, upd in (("req_cpu", "upd_cpu"), ("req_mem", "upd_mem"),
+                     ("req_eph", "upd_eph"), ("req_scalar", "upd_scalar"),
+                     ("nz_cpu", "nz_cpu"), ("nz_mem", "nz_mem")):
+        nodes[key][s] += pod[upd]
+    nodes["pod_count"][s] += 1
+    if spread is not None:
+        spread[s] += 1
 
 
 def _serial(node_arrays, per_pod, batch, kw, ntf, li, lni):
@@ -93,20 +102,17 @@ def _serial(node_arrays, per_pod, batch, kw, ntf, li, lni):
     nodes = {k: np.array(v) for k, v in node_arrays.items()}
     spread = None if "spread0" not in kw else kw["spread0"].copy()
     if "rotation" in kw:
-        perms, inv_perms, seq = kw["rotation"]
-    elif "rotation_pos" in kw:
-        inv_perms, seq = kw["rotation_pos"]
-        perms = inv_perms                    # not read in position mode
+        positions, seq = kw["rotation"]
     lni0, sel, li_after, lni_delta = lni, [], [], []
     tied, rejected = [], []
     i64 = partial(np.asarray, dtype=np.int64)
     for t, pod in enumerate(per_pod):
         if spread is not None:
             pod = {**pod, "spread_counts": spread}
-        if "rotation" in kw or "rotation_pos" in kw:
+        if "rotation" in kw:
             out = _rotated_cycle(nodes, pod, i64(li), i64(lni), i64(ntf),
-                                 i64(batch.n_real), perms[seq[t]],
-                                 inv_perms[seq[t]], gather="rotation" in kw)
+                                 i64(batch.n_real), positions[seq[t]],
+                                 full_scan=ntf >= batch.n_real)
         else:
             out = K.schedule_cycle(nodes, pod, li, lni, ntf, batch.n_real,
                                    Z_PAD)
@@ -117,15 +123,7 @@ def _serial(node_arrays, per_pod, batch, kw, ntf, li, lni):
         lni_delta.append(lni - lni0)
         tied.append(int(out["num_ties"]))
         rejected.append(int(out["evaluated"]) - int(out["found"]))
-        if s < 0:
-            continue
-        for key, upd in (("req_cpu", "upd_cpu"), ("req_mem", "upd_mem"),
-                         ("req_eph", "upd_eph"), ("req_scalar", "upd_scalar"),
-                         ("nz_cpu", "nz_cpu"), ("nz_mem", "nz_mem")):
-            nodes[key][s] += pod[upd]
-        nodes["pod_count"][s] += 1
-        if spread is not None:
-            spread[s] += 1
+        _fold(nodes, pod, s, spread)
     return (sel, li_after, lni_delta, tied, rejected), nodes, li, lni, spread
 
 
@@ -149,7 +147,7 @@ def test_a_launch_runs_its_pods_not_its_bucket(world, mesh, mode, n_pods,
     # rows from n_pods on are pods like any other, never skip pods
     dyn = launch(pods=_stack(per_pod[:bucket]), n_pods=n_pods)
     exact_kw = {k: v[:-1] + (v[-1][:n_pods],) for k, v in kw.items()
-                if k in ("rotation", "rotation_pos")}
+                if k == "rotation"}
     exact = launch(pods=_stack(per_pod[:n_pods]), **exact_kw)
 
     block = np.asarray(dyn[4]["packed"]).reshape(5, bucket)
@@ -183,7 +181,7 @@ def test_a_launch_runs_its_pods_not_its_bucket(world, mesh, mode, n_pods,
         np.testing.assert_array_equal(spread, spread_s)
     if n_pods == 300:
         assert -1 in sel and sel[-1] >= 0     # the loop goes on after a miss
-    if mode != "rotation_pos" and n_pods > 16:
+    if mode != "rotation_full" and n_pods > 16:
         assert min(np.diff(li_after)) < 0     # last_index went round
 
 

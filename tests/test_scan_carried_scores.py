@@ -126,7 +126,7 @@ CASES = [
     ("nodes-fill-every-node-scored", "tight", "full", 64, 64, 4, ()),
     ("skip-rows", "mixed", "truncated", 96, 96, None, (0, 7, 8, 40, 95)),
     ("rotate", "mixed", "rotation", 128, 128, None, ()),
-    ("rotate-pos", "mixed", "rotation_pos", 128, 128, None, ()),
+    ("rotate-pos", "mixed", "rotation_full", 128, 128, None, ()),
     ("carry-spread", "one", "spread", 128, 128, 1, ()),
     ("below-the-bucket", "mixed", "truncated", 150, 256, 8, ()),
     ("one-pod", "mixed", "truncated", 1, 16, 1, ()),
@@ -240,7 +240,7 @@ def test_batch_core_both_ways_under_one_jit():
         i64 = partial(jnp.asarray, dtype=jnp.int64)
         return K._batch_core(
             nodes, mut0, pods, i64(64), i64(0), i64(0), i64(batch.n_real),
-            i64(batch.n_real), z, z, jnp.zeros(1, jnp.int32),
+            i64(batch.n_real), z, jnp.zeros(1, jnp.int32),
             jnp.zeros((), jnp.int64), Z_PAD, dict(K.DEFAULT_WEIGHTS), False,
             False, score_tab=tab)
     _equal(run(node_arrays, mut0, {**pods, "score_class": cls}, tab),
@@ -288,7 +288,7 @@ def test_callers_that_pass_no_board_trace_no_board():
     z = np.zeros((1, 1), np.int32)
     mut0 = {k: node_arrays[k] for k in K._MUTABLE}
     args = (node_arrays, mut0, pods, i64(16), i64(0), i64(0), i64(10),
-            i64(batch.n_real), z, z, np.zeros(1, np.int32), i64(0))
+            i64(batch.n_real), z, np.zeros(1, np.int32), i64(0))
     statics = (Z_PAD, tuple(sorted(K.DEFAULT_WEIGHTS.items())), False, False)
     n_pad = batch.n_pad
     cls, tab = K.score_classes(pods["nz_cpu"], pods["nz_mem"], 16)
