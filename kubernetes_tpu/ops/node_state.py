@@ -53,6 +53,11 @@ POD_TABLE_ROWS = obs.counter(
     "pod_table got them: extracted (derived from the Pod in Python), "
     "reused (taken from the node's previous block). Booked once per "
     "pod_table call that found a moved generation.", ("result",))
+SPREAD_COUNT_ENCODES = obs.counter(
+    "tpu_spread_count_encodes_total",
+    "Selector-spread count passes: one PodEncoder.encode of a pod that a "
+    "Service or ReplicaSet selects, which matches its selectors over the "
+    "whole columnar pod table and sums the matches by holder node.")
 VICTIM_ROW_RESORTS = obs.counter(
     "tpu_victim_table_row_resorts_total",
     "Victim-table node rows re-sorted (generation moved or the PDB set "
@@ -1215,6 +1220,7 @@ class PodEncoder:
             # vectorized selector-match over the columnar pod table plus a
             # segment-sum by holder node, replacing the per-existing-pod
             # Python that made the spread lane the encode-side cliff
+            SPREAD_COUNT_ENCODES.inc()
             t = self._table()
             nsid = t.ns_vocab.get(pod.namespace)
             if nsid is None:

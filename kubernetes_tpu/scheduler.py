@@ -78,6 +78,13 @@ BURST_CLASS = obs.counter(
     "pass), shared (the pod took the class of an earlier pod of its "
     "signature in the same pass). Booked once per pass with the two "
     "counts.", ("result",))
+SEGMENT_CUTS = obs.counter(
+    "scheduler_burst_segment_cuts_total",
+    "Burst segments _schedule_singletons_burst closed, by what ended the "
+    "run of pods: class (the next pod's burst class differs), nominated "
+    "(a nomination became active), unburstable (the next pod carries "
+    "volumes), end (the run was out of pods). One count a segment.",
+    ("cause",))
 GANG_WAIT = obs.histogram(
     "gang_wait_duration_seconds",
     "Seconds from PodGroup creation (or first scheduler sighting) to the "
@@ -1342,10 +1349,19 @@ class Scheduler:
                 continue
             seg_class = classes[i]
             j = i
-            while j < len(pods) and not self.queue.nominated.has_any() \
-                    and self._pod_is_burstable(pods[j]) \
-                    and classes[j] is seg_class:
+            cut = "end"
+            while j < len(pods):
+                if self.queue.nominated.has_any():
+                    cut = "nominated"
+                    break
+                if not self._pod_is_burstable(pods[j]):
+                    cut = "unburstable"
+                    break
+                if classes[j] is not seg_class:
+                    cut = "class"
+                    break
                 j += 1
+            SEGMENT_CUTS.labels(cut).inc()
             bound += self._burst_segment(pods[i:j], cycles[i:j], bucket)
             i = j
         return bound

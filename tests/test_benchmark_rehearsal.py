@@ -21,6 +21,7 @@ ARRIVALS = "headline-15000n.arrivals-steady"
 ADAPTIVE = "headline-15000n-adaptive.backlog-10k"
 DENSITY_ADAPTIVE = "density-5000n-150k-adaptive.rollout-1k"
 MIXED = "inuse-15000n-135k.backlog-10k-mixed"
+LOAD = "load-5000n-150k.rollouts-1k-8svc"
 # the scan cells whose 15,000 nodes reach kernels.SCORE_BOARD_MIN_ROWS
 BOARD = (ADAPTIVE, MIXED)
 
@@ -30,7 +31,9 @@ BOARD = (ADAPTIVE, MIXED)
 # percentage, on zones of 84/83/83 so that the NodeTree's order rotates
 # (num_to_find = 120 of 250). The mixed cell keeps its even zones (240 = 3 x
 # 80: the scan walks the device axis, as at 15,000) and a backlog whose dirty
-# rows stay in one scatter bucket (at most 120, never 64 or fewer).
+# rows stay in one scatter bucket (at most 120, never 64 or fewer). The load
+# cell takes the density cell's 250 nodes (a truncated walk on a rotating
+# order, as at 5000) and ten Services, eight of which its mix names.
 AT_50 = {"nodes": {"count": 50},
          "check": {"first_binds": 200, "sampled_binds": 60}}
 SMALL = {
@@ -50,6 +53,10 @@ SMALL = {
              "resident": {"pods_per_node": 3, "services": 5},
              "check": {"first_binds": 200, "sampled_binds": 100}},
             {"warm_binds": 0, "backlog": 120}),
+    LOAD: ({"nodes": {"count": 250},
+            "resident": {"pods_per_node": 6, "services": 10},
+            "check": {"first_binds": 200, "sampled_binds": 100}},
+           {"warm_binds": 0, "backlog": 150}),
 }
 # the control of an adaptive cell: the program scores every node while the
 # reference judges at the file's default percentage
@@ -75,32 +82,44 @@ def rehearse(execute, cell, seed, hook=None, program=None):
                               "program": program})
 
 
-def altered_binding(sched, store):
-    """Break the timed path where an answer is produced: the first window
-    pod's binding is committed to the node of the second."""
-    commit_wave = store.commit_wave
-    done = False
+def altering_a_binding_of(cycle):
+    """A hook that breaks the timed path where an answer is produced: the
+    first pod of a commit wave of window cycle `cycle` is committed to the
+    node of the wave's second pod (the first such wave that holds two pods
+    on unlike nodes)."""
+    def hook(sched, store):
+        commit_wave = store.commit_wave
+        done = False
 
-    def altered(bindings, *a, **kw):
-        nonlocal done
-        if not done and len(bindings) > 1 and "/bl-1-" in bindings[0][0] \
-                and bindings[0][1] != bindings[1][1]:
-            bindings = [(bindings[0][0], bindings[1][1]), *bindings[1:]]
-            done = True
-        return commit_wave(bindings, *a, **kw)
-    store.commit_wave = altered
+        def altered(bindings, *a, **kw):
+            nonlocal done
+            if not done and len(bindings) > 1 and cycle in bindings[0][0] \
+                    and bindings[0][1] != bindings[1][1]:
+                bindings = [(bindings[0][0], bindings[1][1]), *bindings[1:]]
+                done = True
+            return commit_wave(bindings, *a, **kw)
+        store.commit_wave = altered
+    return hook
 
 
-def counter_metric(name, res, rep):
+altered_binding = altering_a_binding_of("/bl-1-")
+# the load cell's window may hold one cycle only, and a wave of two pods is
+# one segment in eight there
+altered_load_binding = altering_a_binding_of("/bl-0-")
+
+
+def counter_metric(name, res, rep, pods=None, moved=None):
     """A `program_counter` metric of `benchmark/metrics/`, read by its own
-    reader from the run's moved counters."""
+    reader from the run's moved counters: the window's, as the report has
+    them, or `moved` over `pods` pods where the caller took its own."""
     from lib import spec
     mf = spec.load_metric(name)
     reader = importlib.import_module(f"readers.{mf['reader']}")
-    moved = {fam: {tuple(lab.split("/")): v for lab, v in ch.items()}
-             for fam, ch in rep["counters"].items()}
-    return reader.read({"pods_bound": res["attempted"], "counters": moved},
-                       **mf["args"])
+    if moved is None:
+        pods = res["attempted"]
+        moved = {fam: {tuple(lab.split("/")): v for lab, v in ch.items()}
+                 for fam, ch in rep["counters"].items()}
+    return reader.read({"pods_bound": pods, "counters": moved}, **mf["args"])
 
 
 @pytest.mark.parametrize("cell,seed,hook,program", [
@@ -114,8 +133,13 @@ def counter_metric(name, res, rep):
     (DENSITY_ADAPTIVE, 2**31 + 23, None, EVERY_NODE),   # its control
     # eight pod sizes onto nodes that hold pods: stacked rows, uneven board
     (MIXED, 2**31 + 41, None, None),
+    # eight Services' pods interleaved: a burst segment a change of Service
+    (LOAD, 2**31 + 77, None, None),
+    (LOAD, 2**31 + 77, altered_load_binding, None),
+    (LOAD, 2**31 + 77, None, EVERY_NODE),               # its control
 ], ids=["backlog", "rollout", "arrivals", "adaptive", "altered-binding",
-        "density-adaptive", "density-adaptive-control", "mixed"])
+        "density-adaptive", "density-adaptive-control", "mixed",
+        "load", "load-altered-binding", "load-control"])
 def test_rehearsed_cell(monkeypatch, execute, cell, seed, hook, program):
     if cell in BOARD:
         # these cells hold 16,384 node rows, enough for the scan to carry
@@ -123,16 +147,22 @@ def test_rehearsed_cell(monkeypatch, execute, cell, seed, hook, program):
         # may spread over the CPU's devices, stand in for them
         from kubernetes_tpu.ops import kernels
         monkeypatch.setattr(kernels, "SCORE_BOARD_MIN_ROWS", 1)
+    broken = hook is not None or program is not None
+    before = {}
+    if cell == LOAD and not broken:
+        # the shell's counters are not in the report: take the whole run's
+        from lib import counters
+        hook = lambda sched, store: before.update(counters.snapshot())
     out = rehearse(execute, cell, seed, hook, program)
     res, rep = out["result"], out["report"]
     assert rep["compared"] > 0
-    if hook is not None or program is not None:
+    if broken:
         assert res["correct"] is False
         return
     assert res["correct"] is True and res["failed"] == 0
     assert res["attempted"] > 0
     assert rep["compiles_in_window"] == 0
-    if cell in (ROLLOUT, ADAPTIVE, DENSITY_ADAPTIVE, MIXED):
+    if cell in (ROLLOUT, ADAPTIVE, DENSITY_ADAPTIVE, MIXED, LOAD):
         # the generic scan's cells: at 16,384 rows every launch carries the
         # score board (one pod class, or up to eight in the mixed cell), at
         # the density cells' 8192 every step rescores every row
@@ -171,6 +201,36 @@ def test_rehearsed_cell(monkeypatch, execute, cell, seed, hook, program):
         # a walk stops at its quota: 120 of 250 nodes, none of them full
         assert moved["tpu_walk_nodes_evaluated_total"] == \
             {"truncated": 120 * res["attempted"]}
+    if cell == LOAD:
+        moved = rep["counters"]
+        # no window is refused: every segment is one Service's, on the scan
+        assert "tpu_oracle_fallback_total" not in moved
+        assert "burst_uniform" not in moved["tpu_device_dispatch_total"]
+        assert moved["tpu_scan_order_steps_total"] == \
+            {"position": res["attempted"]}
+        assert moved["tpu_walk_nodes_evaluated_total"] == \
+            {"truncated": 120 * res["attempted"]}
+        # one launch and one spread count pass a segment (a truncated walk
+        # never tries the K-batch class first), a segment a change of
+        # Service: 1 - 1/8 of the pods begin one, by the draw
+        launches = moved["tpu_device_dispatch_total"]["burst_scan"]
+        assert moved["tpu_spread_count_encodes_total"] == {"": launches}
+        encodes = counter_metric("spread_encodes_per_pod.backlog", res, rep)
+        assert encodes == launches / res["attempted"]
+        assert 0.8 < encodes < 0.95
+        # the shell's side, over warm-up (two cycles) and window: what cut
+        # each of those segments
+        whole = counters.delta(counters.snapshot(), before)
+        backlog = SMALL[LOAD][1]["backlog"]
+        pods = 2 * backlog + res["attempted"]
+        cuts = whole["scheduler_burst_segment_cuts_total"]
+        assert set(cuts) == {("class",), ("end",)}
+        assert cuts[("end",)] == pods / backlog      # one a drain pass
+        assert cuts[("class",)] + cuts[("end",)] == \
+            counters.total(whole, "tpu_spread_count_encodes_total")
+        per_pod = counter_metric("segment_class_cuts_per_pod.backlog",
+                                 res, rep, pods, whole)
+        assert per_pod == cuts[("class",)] / pods and 0.8 < per_pod < 0.95
     if cell == MIXED:
         moved = rep["counters"]
         # unlike plain pods share one segment, and it goes to the scan
