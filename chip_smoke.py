@@ -26,6 +26,10 @@ Stages, all in this one process (a chip belongs to one process):
           percentageOfNodesToScore: the scan's truncated walk on shipped
           positions (a sort of feasible positions a step, a sort of tie
           positions); the launches are replayed through the serial oracle.
+- groups  the walk stage's cluster holding eight Services' pods, and a few
+          hundred pending pods of the eight interleaved pod by pod: one
+          burst segment, one launch whose scan carries a count row a
+          Service, replayed through the serial oracle.
 - serve   perf.harness.run_serve_cell: arrivals -> admission gate ->
           ServeLoop windows -> commit -> watch, with its two audits.
 - mesh    only with more than one device: the drain again with the node
@@ -61,7 +65,7 @@ REAL = {
     "parity_pods": 32, "gang_size": 64,
     "preempt_victims": 10000, "preemptors": 128,
     "serial_nodes": 1000, "serial_cycles": 12,
-    "walk_nodes": 1000, "walk_pods": 600,
+    "walk_nodes": 1000, "walk_pods": 600, "groups_pods": 400,
     "serve_nodes": 1000, "serve_rate": 2000.0, "serve_seconds": 5.0,
     "serve_window": 2048, "serve_parity_pods": 256,
 }
@@ -71,7 +75,7 @@ REHEARSAL = {
     "parity_pods": 12, "gang_size": 8,
     "preempt_victims": 320, "preemptors": 8,
     "serial_nodes": 60, "serial_cycles": 6,
-    "walk_nodes": 250, "walk_pods": 40,
+    "walk_nodes": 250, "walk_pods": 40, "groups_pods": 40,
     "serve_nodes": 90, "serve_rate": 300.0, "serve_seconds": 2.0,
     "serve_window": 128, "serve_parity_pods": 48,
 }
@@ -557,6 +561,64 @@ def stage_walk(smoke: Smoke):
             "device_ops": ops, "launches_replayed": launches}
 
 
+def stage_groups(smoke: Smoke):
+    """Unlike Services' pods in one launch: the scan carries one
+    selector-spread count row a Service, on uneven zones under a truncated
+    walk."""
+    import random
+    from kubernetes_tpu.api.types import Service
+    from kubernetes_tpu.core import tpu_scheduler as T
+    from kubernetes_tpu.models.hollow import PodStrategy, make_pods as _pods
+    from kubernetes_tpu.scheduler import SEGMENT_CUTS, Scheduler
+    from kubernetes_tpu.store.store import PODS, SERVICES, Store
+    s = smoke.sizes
+    n, n_pods, k = s["walk_nodes"], s["groups_pods"], 8
+    assert n % 3, "the zones have to be uneven for the order to rotate"
+    rng = random.Random(42)
+    store = Store(watch_log_size=1 << 16)
+    build_cluster(store, n)
+    for j in range(k):
+        store.create(SERVICES, Service(name=f"svc-{j}",
+                                       selector={"app": f"svc-{j}"}))
+    # residents the Services already select, so the count rows differ
+    for pod in _pods(PodStrategy(count=n, name_prefix="res")):
+        pod.node_name = f"node-{rng.randrange(n)}"
+        pod.labels = {"app": f"svc-{rng.randrange(k)}"}
+        store.create(PODS, pod)
+    sched = Scheduler(store, use_tpu=True, percentage_of_nodes_to_score=0)
+    sched.sync()
+    for pod in _pods(PodStrategy(count=n_pods)):
+        pod.labels = {"app": f"svc-{rng.randrange(k)}"}
+        store.create(PODS, pod)
+    sched.pump()
+    d0 = dispatch_counts()
+    steps0, cuts0 = family(T.SCAN_SPREAD_STEPS), family(SEGMENT_CUTS)
+    f0 = fallback_counts()
+
+    def run():
+        while sched.schedule_burst(max_pods=512):
+            pass
+    launches, mism = replayed(run)
+    sched.pump()
+    ops = dispatch_delta(d0)
+    steps = delta(family(T.SCAN_SPREAD_STEPS), steps0)
+    cuts = delta(family(SEGMENT_CUTS), cuts0)
+    smoke.check("groups.all_bound",
+                all(p.node_name for p in store.list(PODS)[0]))
+    smoke.check("groups.one_segment_one_launch",
+                cuts == {"end": 1} and ops.get("burst_scan", 0) == 1
+                and "burst_uniform" not in ops, f"{cuts} {ops}")
+    smoke.check("groups.every_step_grouped",
+                steps == {"grouped": n_pods}, steps)
+    smoke.check("groups.no_refusal",
+                not delta(fallback_counts(), f0), delta(fallback_counts(), f0))
+    smoke.check("groups.replay_parity", launches == 1 and not mism,
+                f"{launches} launches replayed"
+                + (f", {mism[:2]}" if mism else ""))
+    return {"nodes": n, "pods": n_pods, "services": k, "device_ops": ops,
+            "launches_replayed": launches}
+
+
 def stage_serve(smoke: Smoke):
     from kubernetes_tpu.perf.harness import run_serve_cell
     s = smoke.sizes
@@ -679,7 +741,7 @@ def main(argv=None) -> int:
     stages += [("lanes.gang", stage_gang), ("lanes.preempt", stage_preempt),
                ("lanes.preempt_scan", stage_preempt_scan),
                ("serial", stage_serial), ("walk", stage_walk),
-               ("serve", stage_serve)]
+               ("groups", stage_groups), ("serve", stage_serve)]
     if len(dev) > 1:
         stages.append(("mesh", stage_mesh))
     for name, fn in stages:

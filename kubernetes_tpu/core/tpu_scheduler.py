@@ -24,6 +24,7 @@ import numpy as np
 from kubernetes_tpu.api.types import Pod
 from kubernetes_tpu.cache.node_info import NodeInfo
 from kubernetes_tpu.oracle import predicates as P
+from kubernetes_tpu.oracle.priorities import counts_toward
 from kubernetes_tpu.oracle.generic_scheduler import (
     ScheduleResult, FitError, num_feasible_nodes_to_find,
     DEFAULT_PERCENTAGE_OF_NODES_TO_SCORE,
@@ -137,6 +138,15 @@ SCAN_POD_ROWS = obs.counter(
     "per-signature arrays) or 'shared' (every pod of the launch was one "
     "object, broadcast). Booked once a launch, beside "
     "tpu_scan_steps_total.", ("rows",))
+SCAN_SPREAD_STEPS = obs.counter(
+    "tpu_scan_spread_steps_total",
+    "Steps of schedule_burst's generic scan launches, by how the launch "
+    "carries selector-spread counts: 'none' (no Service or ReplicaSet "
+    "selects its pods), 'single' (one set of them selects every pod: one "
+    "[N] count vector in the loop's carry) or 'grouped' (pods of 2 to "
+    "kernels.SPREAD_GROUP_CAP selector groups: one count row a group, a "
+    "step reads its pod's row and adds a column). Booked once a launch, "
+    "beside tpu_scan_steps_total.", ("carry",))
 PICK_TIED_NODES = obs.counter(
     "tpu_pick_tied_nodes_total",
     "Nodes that tied for the best score (selectHost's round-robin set), "
@@ -1255,6 +1265,9 @@ class TPUScheduler:
     # the shell passes a per-wave commit callback when the algorithm
     # advertises this (Scheduler._burst_segment)
     supports_wave_commit = True
+    # ... and lets a segment hold pods of this many selector groups
+    # (Scheduler._schedule_singletons_burst; _spread_carry)
+    spread_group_cap = K.SPREAD_GROUP_CAP
     # -- N-deep launch queue (round 16) --------------------------------------
     # The round-7 pipeline kept ONE chunk in flight ahead of the chunk
     # being committed (2-deep). Serving at arrival rate needs the
@@ -1443,43 +1456,34 @@ class TPUScheduler:
                     if f is None:
                         f = feat_by_sig[sig] = enc.encode(p)
                     feats.append(f)
-        # selector-spread counts change with every in-burst placement; the
-        # scan carries them only for spec-identical pods (one selector set)
+        # selector-spread counts change with every in-burst placement: the
+        # scan carries them, one [N] vector where one set of Services /
+        # ReplicaSets selects every pod, else one row a selector group
+        spread0 = spread_groups = None
         carry_spread = any(f.spread_counts is not None for f in feats)
-        if carry_spread and not uniform_spec:
-            ORACLE_FALLBACKS.labels("burst-spread-mixed").inc()
-            return None
+        if carry_spread:
+            carried = self._spread_carry(feats, b.n_pad)
+            if carried is None:
+                ORACLE_FALLBACKS.labels("burst-spread-mixed").inc()
+                return None
+            spread0, spread_groups = carried
         # per-cycle rotated enumeration orders (uneven zones)
         rotation = self._scan_rotation(b, bucket, start0)
-        spread0 = None
-        if carry_spread:
-            # the scan carries ONE [N] count vector; the stacked per-pod
-            # field stays inert so no [B, N] upload happens
-            spread0 = feats[0].spread_counts
-        if uniform_spec:
-            base = self._pod_arrays(feats[0], b.n_pad, upd_fields=True,
-                                    pod=pods[0])
-            if carry_spread:
-                base["spread_counts"] = self._defaults["zeros_i64"]
-            per_pod = [base] * len(pods)   # _stack_pods broadcasts by identity
-        else:
-            # one device-array dict per SIGNATURE (equal sigs -> identical
-            # _pod_arrays output by construction), so _stack_pods
-            # broadcasts repeated specs by identity instead of stacking B
-            # copies — the mixed-window twin of the uniform fast path
-            arr_by_sig: dict = {}
-            per_pod = []
-            for p, f, sig in zip(pods, feats, sigs):
-                pp = arr_by_sig.get(sig)
-                if pp is None:
-                    pp = arr_by_sig[sig] = self._pod_arrays(
-                        f, b.n_pad, upd_fields=True, pod=p)
-                per_pod.append(pp)
-        if carry_spread and (spread0 is None
-                             or spread0.shape[-1] != b.n_pad):
-            # inert/dense mix — shouldn't happen, stay exact
-            ORACLE_FALLBACKS.labels("burst-spread-shape").inc()
-            return None
+        # one device-array dict per SIGNATURE (equal sigs -> identical
+        # _pod_arrays output by construction), so _stack_pods broadcasts
+        # repeated specs by identity instead of stacking B copies
+        arr_by_feat: dict = {}
+        for p, f in zip(pods, feats):
+            if id(f) not in arr_by_feat:
+                pp = arr_by_feat[id(f)] = self._pod_arrays(
+                    f, b.n_pad, upd_fields=True, pod=p)
+                if carry_spread:
+                    # the counts ride the carry; the stacked per-pod field
+                    # stays inert so no [B, N] upload happens
+                    pp["spread_counts"] = self._defaults["zeros_i64"]
+                if uniform_spec:
+                    break
+        per_pod = [arr_by_feat[id(f)] for f in feats]
         z_pad = _pad_pow2(len(b.zone_names), 4)
         # mesh mode rides the SAME _scan_waves driver below: since round 15
         # the generic scan kernel is one code path parameterized by the
@@ -1498,7 +1502,56 @@ class TPUScheduler:
         ph.close()
         return self._scan_waves(pods, b, per_pod, spread0, rotation,
                                 num_to_find, n, z_pad, bucket,
-                                commit, ph, fl=fl)
+                                commit, ph, fl=fl,
+                                spread_groups=spread_groups)
+
+    @staticmethod
+    def _spread_carry(feats: list, n_pad: int) -> Optional[tuple]:
+        """(spread0, spread_groups) for a generic scan launch whose pods
+        carry selector-spread counts; `feats` are the pods' features, one
+        object a signature. A pod's group is what SelectorSpread can tell
+        of it (`PodFeatures.spread_group`: its namespace and the set of
+        selectors that select it), so signatures that differ in requests
+        alone share a group, and the group's counts are the vector the
+        encoder made for any pod of it.
+
+        One group: (its [n_pad] vector, None), the launch every burst of
+        one Service's pods has always been. Up to K.SPREAD_GROUP_CAP:
+        ([G_pad, n_pad] rows, (group of each pod [len(feats)] int32,
+        counts_for [G_pad, G_pad] bool)), G_pad a power of two so that a
+        stream of launches of about as many groups runs one program; the
+        spare rows are zero and no pod reads or moves them. None where the
+        carry cannot be made exact: a pod that nothing selects among pods
+        that something does (its constant score is not a row of zeros'),
+        more groups than the cap, or counts off the node axis."""
+        keys: dict = {}
+        rows = []
+        group_of_feat: dict = {}
+        for f in feats:
+            if id(f) in group_of_feat:
+                continue
+            if f.spread_counts is None \
+                    or f.spread_counts.shape[-1] != n_pad:
+                return None
+            g = keys.get(f.spread_group)
+            if g is None:
+                g = keys[f.spread_group] = len(rows)
+                rows.append(f.spread_counts)
+            group_of_feat[id(f)] = g
+        if len(rows) == 1:
+            return rows[0], None
+        if len(rows) > K.SPREAD_GROUP_CAP:
+            return None
+        g_pad = _pad_pow2(len(rows), 2)
+        spread0 = np.zeros((g_pad, n_pad), np.int64)
+        spread0[:len(rows)] = rows
+        counts_for = np.zeros((g_pad, g_pad), bool)
+        for h, h_key in enumerate(keys):
+            for g, g_key in enumerate(keys):
+                counts_for[h, g] = counts_toward(h_key, g_key)
+        group = np.fromiter((group_of_feat[id(f)] for f in feats), np.int32,
+                            len(feats))
+        return spread0, (group, counts_for)
 
     def _uniform_waves(self, pods: list[Pod], b: NodeBatch, cls, extra_ok,
                        ban: bool, rotation, n: int, commit,
@@ -1702,7 +1755,8 @@ class TPUScheduler:
     def _scan_waves(self, pods: list[Pod], b: NodeBatch, per_pod: list,
                     spread0, rotation, num_to_find: int,
                     n: int, z_pad: int, bucket: int, commit,
-                    ph: _BurstPhases, fl=None) -> list[Optional[str]]:
+                    ph: _BurstPhases, fl=None,
+                    spread_groups=None) -> list[Optional[str]]:
         """Single-launch driver for the generic scan burst: the whole
         burst runs as ONE launch whose operands have the caller's bucket
         shape (so the warmup burst compiles the same program) and whose
@@ -1743,6 +1797,11 @@ class TPUScheduler:
             classes = K.score_classes(
                 stacked["nz_cpu"], stacked["nz_mem"], n_pods) \
                 if carried else None
+            if spread_groups is not None:
+                # the pad rows take group 0: they are never stepped over
+                group = np.zeros(B, np.int32)
+                group[:n_pods] = spread_groups[0]
+                spread_groups = (group, spread_groups[1])
         ph.open("kernel")
         t_d = obs_trace.now()
         try:
@@ -1753,9 +1812,12 @@ class TPUScheduler:
                 weights=self._union_weights if tensor else self.weights,
                 rotation=rotation, spread0=spread0,
                 mesh=self.mesh, wtab=self._wtab() if tensor else None,
-                n_pods=n_pods, classes=classes)
+                n_pods=n_pods, classes=classes, spread_groups=spread_groups)
             DEVICE_DISPATCH.labels("burst_scan").inc()
             SCAN_STEPS.labels("real").inc(n_pods)
+            SCAN_SPREAD_STEPS.labels(
+                "none" if spread0 is None else
+                "single" if spread_groups is None else "grouped").inc(n_pods)
             SCAN_SCORE_STEPS.labels(
                 "full" if classes is None else "carried").inc(n_pods)
             SCAN_POD_ROWS.labels(

@@ -724,6 +724,15 @@ SCORE_CLASS_CAP = 16
 # the two was measured; the bound is the size that was seen to win.
 SCORE_BOARD_MIN_ROWS = 16384
 
+# The generic scan carries the selector-spread counts of at most this many
+# selector groups a launch (a group: a namespace and the set of Services /
+# ReplicaSets that select a pod), one [n_pad] int64 row each. A step reads
+# its pod's row as the board's is read and adds one column; the cap is the
+# board's for the board's reasons: the carry's size and the programs a
+# process can compile (one per power of two: 2, 4, 8, 16; a launch of one
+# group carries one vector, the program of every launch before the rows).
+SPREAD_GROUP_CAP = SCORE_CLASS_CAP
+
 
 def score_classes(nz_cpu, nz_mem, n_pods):
     """The score classes of a launch, made on the host from its stacked
@@ -751,7 +760,7 @@ def _batch_core(nodes, mut0, pods, n_pods, last_index, last_node_index,
                 num_to_find, n_real, positions, oid_seq,
                 spread0, z_pad, weights, rotate, carry_spread,
                 full_scan=False, constrain=None, wtab=None,
-                score_tab=None):
+                score_tab=None, counts_for=None):
     """Body of the generic burst kernel: one serial cycle per pod, each
     folding its decision into the carried node state.
 
@@ -795,18 +804,32 @@ def _batch_core(nodes, mut0, pods, n_pods, last_index, last_node_index,
     SCORE_CLASS_CAP classes and a device holds at least
     SCORE_BOARD_MIN_ROWS node rows. Both are the one jitted function
     `_schedule_batch_jit` (None is an empty pytree), so a device trace and
-    `tpu_compiles_total` keep finding the scan under the name they know."""
+    `tpu_compiles_total` keep finding the scan under the name they know.
+
+    `spread0` (with `carry_spread`) is the selector-spread counts the loop
+    carries, and its RANK says how. [n_pad]: every pod of the launch is
+    selected by the same Services / ReplicaSets, so one count vector is
+    carried and each placement folds +1 on its node
+    (selector_spreading.go:66 counting semantics). [G_pad, n_pad], with
+    `pods["spread_group"]` [B] and `counts_for` [G_pad, G_pad] bool: the
+    launch holds pods of G_pad selector groups at most, row g the counts a
+    pod of group g scores against, and `counts_for[h, g]` says a bound pod
+    of group h counts toward row g (g's selectors all match it). A step
+    reads its pod's row as the board's row is read, hands it to
+    `_cycle_core` as the one vector it has always scored, and after the
+    fold adds `counts_for[h]` to column `sel`. The rank is a static shape:
+    a launch of one group is the rank-1 program, operand for operand."""
     if constrain is None:
         constrain = lambda v: v
     assert score_tab is None or wtab is None
     i32 = jnp.int32
     static = {k: v for k, v in nodes.items() if k not in _MUTABLE}
-    # selector-spread counts evolve with in-burst placements: the caller
-    # guarantees every pod shares one selector set (spec-identical), so the
-    # shared dense base counts (spread0 [N]) are carried and each placement
-    # folds +1 on its node (selector_spreading.go:66 counting semantics)
+    # the carried counts stand in for the stacked per-pod field, which the
+    # caller leaves inert so that no [B, N] upload happens
     if carry_spread:
         pods = {k: v for k, v in pods.items() if k != "spread_counts"}
+    grouped = carry_spread and spread0.ndim == 2
+    assert grouped == (counts_for is not None)
     B = pods["skip"].shape[0]
 
     @jax.named_scope("score")
@@ -823,9 +846,12 @@ def _batch_core(nodes, mut0, pods, n_pods, last_index, last_node_index,
             at(static["alloc_cpu"]), at(static["alloc_mem"]))
 
     def constrain_board(board):
-        # the node axis is the board's LAST (a step reads a contiguous
-        # row); `constrain` pins node-axis-first trees
+        # the node axis is the board's LAST, and the grouped spread rows'
+        # (a step reads a contiguous row); `constrain` pins node-axis-first
+        # trees
         return constrain(board.T).T
+
+    constrain_spread = constrain_board if grouped else constrain
 
     def body(i, carry):
         state, li, lni, spread, board, packed, aux = carry
@@ -845,7 +871,14 @@ def _batch_core(nodes, mut0, pods, n_pods, last_index, last_node_index,
                     local = jnp.sum(jnp.where(mine[:, None], board, 0),
                                     axis=0)
         pos = positions[oid_seq[i]] if rotate else None
-        if carry_spread:
+        if grouped:
+            with jax.named_scope("spread"):
+                # the pod's row, a masked sum over the group axis like the
+                # board's above
+                mine = jnp.arange(len(counts_for)) == pod["spread_group"]
+                pod = {**pod, "spread_counts": jnp.sum(
+                    jnp.where(mine[:, None], spread, 0), axis=0)}
+        elif carry_spread:
             pod = {**pod, "spread_counts": spread}
         full = {**static, **state}
         out = _cycle_core(full, pod, li, lni, num_to_find, n_real, weights,
@@ -854,7 +887,17 @@ def _batch_core(nodes, mut0, pods, n_pods, last_index, last_node_index,
         sel = out["selected"]
         hit = out["found"] > 0
         new_state = constrain(_fold_state(state, pod, sel, hit))
-        if carry_spread:
+        if grouped:
+            with jax.named_scope("spread"):
+                # the bound pod counts toward every row whose selectors
+                # all match it: row `mine` of counts_for, added to column
+                # sel
+                toward = jnp.any(mine[:, None] & counts_for, axis=0)
+                spread = constrain_spread(
+                    spread.at[:, jnp.maximum(sel, 0)].add(jnp.where(
+                        hit & ~pod["skip"], toward, False).astype(
+                            spread.dtype)))
+        elif carry_spread:
             spread = constrain(spread.at[jnp.maximum(sel, 0)].add(
                 jnp.where(hit & ~pod["skip"], 1, 0)))
         if score_tab is not None:
@@ -873,7 +916,8 @@ def _batch_core(nodes, mut0, pods, n_pods, last_index, last_node_index,
 
     board0 = None if score_tab is None \
         else constrain_board(class_scores(mut0))
-    init = (constrain(mut0), last_index, last_node_index, constrain(spread0),
+    init = (constrain(mut0), last_index, last_node_index,
+            constrain_spread(spread0),
             board0, jnp.full((5, B), -1, i32), jnp.zeros((4, B), jnp.int64))
     state, li, lni, spread, _board, packed, aux = jax.lax.fori_loop(
         jnp.int32(0), jnp.asarray(n_pods, i32), body, init)
@@ -896,12 +940,13 @@ def _schedule_batch_jit(nodes, mut0, pods, n_pods, last_index,
                         last_node_index, num_to_find, n_real, positions,
                         oid_seq, spread0, score_tab, z_pad,
                         weights_tuple, rotate, carry_spread,
-                        full_scan=False):
+                        full_scan=False, counts_for=None):
     return _batch_core(nodes, mut0, pods, n_pods, last_index,
                        last_node_index, num_to_find, n_real, positions,
                        oid_seq, spread0, z_pad,
                        dict(weights_tuple), rotate, carry_spread,
-                       full_scan=full_scan, score_tab=score_tab)
+                       full_scan=full_scan, score_tab=score_tab,
+                       counts_for=counts_for)
 
 
 @partial(jax.jit, static_argnames=("z_pad", "weights_tuple", "rotate",
@@ -910,12 +955,13 @@ def _schedule_batch_wtab_jit(nodes, mut0, pods, n_pods, wtab, last_index,
                              last_node_index, num_to_find, n_real,
                              positions, oid_seq, spread0, z_pad,
                              weights_tuple, rotate, carry_spread,
-                             full_scan=False):
+                             full_scan=False, counts_for=None):
     return _batch_core(nodes, mut0, pods, n_pods, last_index,
                        last_node_index, num_to_find, n_real, positions,
                        oid_seq, spread0, z_pad,
                        dict(weights_tuple), rotate, carry_spread,
-                       full_scan=full_scan, wtab=wtab)
+                       full_scan=full_scan, wtab=wtab,
+                       counts_for=counts_for)
 
 
 def _rotation_operands(rotation, num_to_find, n_real):
@@ -933,7 +979,7 @@ def _rotation_operands(rotation, num_to_find, n_real):
 def schedule_batch(nodes, pods, last_index, last_node_index, num_to_find, n_real,
                    z_pad, weights=None, rotation=None, spread0=None,
                    carry_in=None, mesh=None, wtab=None,
-                   n_pods=None, classes=None):
+                   n_pods=None, classes=None, spread_groups=None):
     """Schedule a burst of pods against one snapshot, decisions serially
     equivalent to per-pod cycles. `pods` is a dict of [B, ...] arrays
     padded to the caller's bucket (one compile per bucket); `n_pods` is the
@@ -950,8 +996,11 @@ def schedule_batch(nodes, pods, last_index, last_node_index, num_to_find, n_real
     launch's own `num_to_find` and `n_real` (host integers): with every
     node scored (num_to_find >= n_real) the program without a sort in
     `filter` runs, the one with it otherwise (`_cycle_core`). `spread0`
-    [n_pad] carries selector-spread counts across the
-    burst (requires spec-identical pods — one shared selector set).
+    carries selector-spread counts across the burst: [n_pad] where one set
+    of Services / ReplicaSets selects every pod, or [G_pad, n_pad] with
+    `spread_groups` = (group[B] int32, counts_for[G_pad, G_pad] bool), one
+    row a selector group (`_batch_core`; G_pad a power of two from 2 to
+    SPREAD_GROUP_CAP).
 
     `carry_in` = (mut_state, spread) chains a pipelined wave straight off
     the previous wave's device-resident carry (no host round trip):
@@ -986,6 +1035,11 @@ def schedule_batch(nodes, pods, last_index, last_node_index, num_to_find, n_real
         assert wtab is None, "a per-pod weight row makes the board per profile"
         pods = {**pods, "score_class": classes[0]}
         score_tab = jnp.asarray(classes[1], jnp.int64)
+    counts_for = None
+    if spread_groups is not None:
+        assert np.ndim(spread0) == 2 and carry_in is None
+        pods = {**pods, "spread_group": spread_groups[0]}
+        counts_for = jnp.asarray(spread_groups[1], bool)
     rotate, full_scan, positions, oid_seq = _rotation_operands(
         rotation, num_to_find, n_real)
     carry_spread = spread0 is not None or (
@@ -1009,21 +1063,22 @@ def schedule_batch(nodes, pods, last_index, last_node_index, num_to_find, n_real
         if wtab is not None:
             return fn(nodes, mut0, pods, n_pods, wtab, _i64(last_index),
                       _i64(last_node_index), _i64(num_to_find),
-                      _i64(n_real), positions, oid_seq, s0)
+                      _i64(n_real), positions, oid_seq, s0,
+                      counts_for=counts_for)
         return fn(nodes, mut0, pods, n_pods, _i64(last_index),
                   _i64(last_node_index), _i64(num_to_find), _i64(n_real),
-                  positions, oid_seq, s0, score_tab)
+                  positions, oid_seq, s0, score_tab, counts_for=counts_for)
     if wtab is not None:
         return _schedule_batch_wtab_jit(
             nodes, mut0, pods, n_pods, wtab, _i64(last_index),
             _i64(last_node_index), _i64(num_to_find), _i64(n_real),
             positions, oid_seq, s0, z_pad, weights_tuple, rotate,
-            carry_spread, full_scan=full_scan)
+            carry_spread, full_scan=full_scan, counts_for=counts_for)
     return _schedule_batch_jit(
         nodes, mut0, pods, n_pods, _i64(last_index), _i64(last_node_index),
         _i64(num_to_find), _i64(n_real), positions, oid_seq, s0,
         score_tab, z_pad, weights_tuple, rotate, carry_spread,
-        full_scan=full_scan)
+        full_scan=full_scan, counts_for=counts_for)
 
 
 # ---------------------------------------------------------------------------

@@ -30,7 +30,7 @@ from kubernetes_tpu.oracle.predicates import (
     pod_matches_term_props_mask, selector_match_mask,
     InterPodAffinityChecker,
 )
-from kubernetes_tpu.oracle.priorities import get_selectors
+from kubernetes_tpu.oracle.priorities import get_selectors, spread_group_key
 from kubernetes_tpu import obs
 
 # mirror-maintenance counters: how often the host mirror pays a per-row
@@ -949,6 +949,8 @@ class PodFeatures:
     node_aff_counts: Optional[np.ndarray] = None   # [N] i64
     taint_counts: Optional[np.ndarray] = None      # [N] i64
     spread_counts: Optional[np.ndarray] = None     # [N] i64
+    # with spread_counts: whose counts they are (priorities.spread_group_key)
+    spread_group: Optional[tuple] = None
     interpod_counts: Optional[np.ndarray] = None   # [N] i64
     interpod_tracked: Optional[np.ndarray] = None  # [N] bool
     image_sums: Optional[np.ndarray] = None        # [N] i64
@@ -1237,6 +1239,7 @@ class PodEncoder:
             if rows.size:
                 counts += np.bincount(rows, minlength=b.n_pad)
             f.spread_counts = counts
+            f.spread_group = spread_group_key(pod.namespace, selectors)
         has_pref_terms = a is not None and (
             (a.pod_affinity is not None and a.pod_affinity.preferred)
             or (a.pod_anti_affinity is not None and a.pod_anti_affinity.preferred))

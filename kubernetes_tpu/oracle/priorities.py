@@ -244,6 +244,26 @@ def get_selectors(pod: Pod, services: list[Service],
     return selectors
 
 
+def spread_group_key(namespace: str, selectors: list) -> tuple:
+    """What `get_selectors`' answer for a pod makes of it as far as
+    SelectorSpread can tell: (namespace, the set of its selectors). Pods of
+    one key read the same counts and count toward the same pods' counts,
+    whatever else their specs hold. A bound pod of key h counts toward the
+    pods of key g exactly when `counts_toward(h, g)`."""
+    return (namespace, frozenset(
+        tuple(sorted(s.items())) if isinstance(s, dict) else s
+        for s in selectors))
+
+
+def counts_toward(h: tuple, g: tuple) -> bool:
+    """True when a pod of spread group `h` is counted by a pod of group `g`
+    (`selector_spread_map`: same namespace, and every selector of g matches
+    it). `get_selectors` gives a pod EVERY selector of its namespace that
+    matches its labels, so g's all match a pod of h exactly when they are
+    among h's."""
+    return h[0] == g[0] and g[1] <= h[1]
+
+
 def _selector_matches(selector, labels: dict[str, str]) -> bool:
     if isinstance(selector, dict):
         return all(labels.get(k) == v for k, v in selector.items())

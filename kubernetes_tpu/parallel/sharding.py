@@ -242,7 +242,9 @@ def sharded_scan_fn(mesh: Mesh, z_pad: int, weights_tuple, rotate: bool,
     loop's dynamic trip count) is a replicated scalar, and the per-node
     feasibility/score vectors ride XLA collectives (all-gather over ICI)
     into the replicated select epilogue. The carried score board
-    (`score_tab` given) is pinned on its node axis like the state rows.
+    (`score_tab` given) and the grouped spread rows (`counts_for` given:
+    spread0 [G_pad, n_pad], one row a selector group) have the node axis
+    LAST and are pinned on it like the state rows.
     Decisions are bit-identical to the single-device scan
     (tests/test_sharding.py + the sharded fuzz variants). Compiled once
     per (mesh, statics) and cached."""
@@ -257,25 +259,26 @@ def sharded_scan_fn(mesh: Mesh, z_pad: int, weights_tuple, rotate: bool,
         # profile tensor mode: the replicated [P, K] weight table rides the
         # operands and each step gathers its pod's row (profile_id in pods)
         def f(nodes, mut0, pods, n_pods, wtab, last_index, last_node_index,
-              num_to_find, n_real, positions, oid_seq, spread0):
+              num_to_find, n_real, positions, oid_seq, spread0,
+              counts_for=None):
             nodes = _constrain_nodes(mesh, nodes)
             return K._batch_core(nodes, mut0, pods, n_pods, last_index,
                                  last_node_index, num_to_find, n_real,
                                  positions, oid_seq, spread0, z_pad,
                                  dict(weights_tuple), rotate, carry_spread,
                                  full_scan=full_scan, constrain=c,
-                                 wtab=wtab)
+                                 wtab=wtab, counts_for=counts_for)
     else:
         def f(nodes, mut0, pods, n_pods, last_index, last_node_index,
               num_to_find, n_real, positions, oid_seq, spread0,
-              score_tab=None):
+              score_tab=None, counts_for=None):
             nodes = _constrain_nodes(mesh, nodes)
             return K._batch_core(nodes, mut0, pods, n_pods, last_index,
                                  last_node_index, num_to_find, n_real,
                                  positions, oid_seq, spread0, z_pad,
                                  dict(weights_tuple), rotate, carry_spread,
                                  full_scan=full_scan, constrain=c,
-                                 score_tab=score_tab)
+                                 score_tab=score_tab, counts_for=counts_for)
 
     fn = _SCAN_CACHE[key] = jax.jit(f)
     return fn

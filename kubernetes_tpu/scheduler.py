@@ -34,7 +34,8 @@ from kubernetes_tpu.oracle.gang import GangTrial
 from kubernetes_tpu.oracle.generic_scheduler import (
     GenericScheduler, FitError, ScheduleResult, default_priority_configs,
 )
-from kubernetes_tpu.oracle.priorities import get_selectors
+from kubernetes_tpu.oracle.priorities import (
+    get_selectors, spread_group_key)
 from kubernetes_tpu.queue.scheduling_queue import PriorityQueue
 from kubernetes_tpu.store.store import (
     Store, PODS, NODES, PODGROUPS, SERVICES, REPLICASETS, PDBS, PVS, PVCS,
@@ -53,6 +54,10 @@ DEFAULT_SCHEDULER_NAME = "default-scheduler"
 #: the burst class of a pod with no in-burst-dynamic feature; a module
 #: constant because segmentation compares classes by identity
 _PLAIN = "plain"
+#: ... and of a pod whose one such feature is selector spread: the scan
+#: carries the counts of several selector groups, so unlike Services' pods
+#: share a segment
+_SPREAD = "spread"
 
 #: per-process scheduler instance sequence: wave dedupe tokens must be
 #: unique PER INSTANCE, not per scheduler name — an active-active fleet
@@ -81,9 +86,11 @@ BURST_CLASS = obs.counter(
 SEGMENT_CUTS = obs.counter(
     "scheduler_burst_segment_cuts_total",
     "Burst segments _schedule_singletons_burst closed, by what ended the "
-    "run of pods: class (the next pod's burst class differs), nominated "
-    "(a nomination became active), unburstable (the next pod carries "
-    "volumes), end (the run was out of pods). One count a segment.",
+    "run of pods: class (the next pod's burst class differs), groups (the "
+    "next pod's selector group would be one more than the algorithm's "
+    "spread_group_cap carries in a launch), nominated (a nomination became "
+    "active), unburstable (the next pod carries volumes), end (the run was "
+    "out of pods). One count a segment.",
     ("cause",))
 GANG_WAIT = obs.histogram(
     "gang_wait_duration_seconds",
@@ -1115,27 +1122,36 @@ class Scheduler:
                 and not self.framework.permit
                 and not self.framework.prebind)
 
-    def _burst_class(self, pod: Pod, sig: tuple, services, replicasets):
-        """Segmentation key: pods with in-burst-dynamic features (affinity /
-        host ports / selector-spread) burst only with spec-identical peers
-        (the kernels' eligibility contract), so their class is their class
-        signature; plain pods share one generic segment even when
+    def _burst_class(self, pod: Pod, sig: tuple, services,
+                     replicasets) -> tuple:
+        """Segmentation key, as (class, spread group). Pods whose per-node
+        masks depend on in-burst placements (affinity terms, host ports)
+        burst only with spec-identical peers (the kernels' eligibility
+        contract), so their class is their class signature. Pods that a
+        Service or ReplicaSet selects, and nothing more, share `_SPREAD`
+        whichever selects them: the scan carries one count row a selector
+        group, and `group` (`spread_group_key`; None for every other class)
+        is what `_schedule_singletons_burst` counts against the
+        algorithm's cap. Plain pods share one generic segment even when
         heterogeneous. Every input it reads (namespace, labels, affinity,
         containers) is in `sig`, so `_burst_classes`, its one caller, asks
         once per distinct signature and pass."""
-        if has_pod_affinity_terms(pod) or get_container_ports(pod) \
-                or get_selectors(pod, services, replicasets):
-            return sig
-        return _PLAIN
+        if has_pod_affinity_terms(pod) or get_container_ports(pod):
+            return sig, None
+        selectors = get_selectors(pod, services, replicasets)
+        if selectors:
+            return _SPREAD, spread_group_key(pod.namespace, selectors)
+        return _PLAIN, None
 
     def _burst_classes(self, pods: list) -> list:
         """THE place a pod's burst class is decided: one `_burst_class`
         evaluation on the first pod of each distinct class signature in
         `pods`, against one snapshot of the Service / ReplicaSet lists;
-        every other pod takes the class of its signature. The cost follows
-        the number of distinct signatures, not pods x Services. Equal
-        classes are the SAME object (the interned signature, or `_PLAIN`),
-        so segmentation compares by identity. The decision lives for the
+        every other pod takes the (class, spread group) of its signature.
+        The cost follows the number of distinct signatures, not pods x
+        Services. Equal classes are the SAME object (the interned
+        signature, `_SPREAD` or `_PLAIN`), so segmentation compares by
+        identity. The decision lives for the
         call: a Service created between two drain passes reclassifies the
         next pass's pods, and nothing has to be invalidated."""
         if self.pod_rows is not None:
@@ -1262,7 +1278,7 @@ class Scheduler:
 
         def plain_burstable(pod: Pod) -> bool:
             return self._pod_is_burstable(pod) \
-                and class_of[id(pod)] is _PLAIN
+                and class_of[id(pod)][0] is _PLAIN
 
         def singletons(pairs: list) -> int:
             return self._schedule_singletons_burst(
@@ -1328,14 +1344,18 @@ class Scheduler:
                                    classes: Optional[list] = None) -> int:
         """Schedule a run of non-gang pods: device burst segments where
         safe, serial cycles otherwise (the pre-gang schedule_burst body).
-        `classes` are the pods' burst classes from the drain pass's
-        decision; the degraded gang paths and the unfused leftovers, which
-        have none to hand, get them from `_burst_classes` here."""
+        `classes` are the pods' (burst class, spread group) pairs from the
+        drain pass's decision; the degraded gang paths and the unfused
+        leftovers, which have none to hand, get them from `_burst_classes`
+        here."""
         pods = [p for p, _ in pairs]
         cycles = [c for _, c in pairs]
         can_burst = self._can_burst()
         if can_burst and classes is None:
             classes = self._burst_classes(pods)
+        # selector groups the algorithm carries spread counts for in one
+        # launch; one that does not say carries one
+        group_cap = getattr(self.algorithm, "spread_group_cap", 1)
         bound = 0
         i = 0
         while i < len(pods):
@@ -1347,7 +1367,8 @@ class Scheduler:
                     bound += 1
                 i += 1
                 continue
-            seg_class = classes[i]
+            seg_class = classes[i][0]
+            groups: set = set()
             j = i
             cut = "end"
             while j < len(pods):
@@ -1357,9 +1378,15 @@ class Scheduler:
                 if not self._pod_is_burstable(pods[j]):
                     cut = "unburstable"
                     break
-                if classes[j] is not seg_class:
+                cls, group = classes[j]
+                if cls is not seg_class:
                     cut = "class"
                     break
+                if group is not None and group not in groups:
+                    if len(groups) == group_cap:
+                        cut = "groups"
+                        break
+                    groups.add(group)
                 j += 1
             SEGMENT_CUTS.labels(cut).inc()
             bound += self._burst_segment(pods[i:j], cycles[i:j], bucket)

@@ -103,8 +103,7 @@ def altering_a_binding_of(cycle):
 
 
 altered_binding = altering_a_binding_of("/bl-1-")
-# the load cell's window may hold one cycle only, and a wave of two pods is
-# one segment in eight there
+# the load cell's window may hold one cycle only
 altered_load_binding = altering_a_binding_of("/bl-0-")
 
 
@@ -203,34 +202,36 @@ def test_rehearsed_cell(monkeypatch, execute, cell, seed, hook, program):
             {"truncated": 120 * res["attempted"]}
     if cell == LOAD:
         moved = rep["counters"]
-        # no window is refused: every segment is one Service's, on the scan
+        # no window is refused: a pass of eight Services' pods is one
+        # segment and one launch of the scan, a count row a Service
         assert "tpu_oracle_fallback_total" not in moved
         assert "burst_uniform" not in moved["tpu_device_dispatch_total"]
         assert moved["tpu_scan_order_steps_total"] == \
             {"position": res["attempted"]}
         assert moved["tpu_walk_nodes_evaluated_total"] == \
             {"truncated": 120 * res["attempted"]}
-        # one launch and one spread count pass a segment (a truncated walk
-        # never tries the K-batch class first), a segment a change of
-        # Service: 1 - 1/8 of the pods begin one, by the draw
-        launches = moved["tpu_device_dispatch_total"]["burst_scan"]
-        assert moved["tpu_spread_count_encodes_total"] == {"": launches}
-        encodes = counter_metric("spread_encodes_per_pod.backlog", res, rep)
-        assert encodes == launches / res["attempted"]
-        assert 0.8 < encodes < 0.95
-        # the shell's side, over warm-up (two cycles) and window: what cut
-        # each of those segments
-        whole = counters.delta(counters.snapshot(), before)
         backlog = SMALL[LOAD][1]["backlog"]
+        launches = moved["tpu_device_dispatch_total"]["burst_scan"]
+        assert launches == res["attempted"] / backlog
+        assert moved["tpu_scan_spread_steps_total"] == \
+            {"grouped": res["attempted"]}
+        assert counter_metric("spread_grouped_steps_per_pod.backlog",
+                              res, rep) == 1.0
+        assert moved["tpu_scan_pod_rows_total"] == \
+            {"stacked": res["attempted"]}
+        # one spread count pass a Service and launch (a truncated walk
+        # never tries the K-batch class first)
+        assert moved["tpu_spread_count_encodes_total"] == {"": 8 * launches}
+        encodes = counter_metric("spread_encodes_per_pod.backlog", res, rep)
+        assert encodes == 8 / backlog
+        # the shell's side, over warm-up (two cycles) and window: a pass
+        # ends where it is out of pods and nowhere else
+        whole = counters.delta(counters.snapshot(), before)
         pods = 2 * backlog + res["attempted"]
         cuts = whole["scheduler_burst_segment_cuts_total"]
-        assert set(cuts) == {("class",), ("end",)}
-        assert cuts[("end",)] == pods / backlog      # one a drain pass
-        assert cuts[("class",)] + cuts[("end",)] == \
-            counters.total(whole, "tpu_spread_count_encodes_total")
-        per_pod = counter_metric("segment_class_cuts_per_pod.backlog",
-                                 res, rep, pods, whole)
-        assert per_pod == cuts[("class",)] / pods and 0.8 < per_pod < 0.95
+        assert cuts == {("end",): pods / backlog}
+        assert counter_metric("segment_class_cuts_per_pod.backlog",
+                              res, rep, pods, whole) == 0.0
     if cell == MIXED:
         moved = rep["counters"]
         # unlike plain pods share one segment, and it goes to the scan

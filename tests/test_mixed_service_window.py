@@ -2,13 +2,13 @@
 
 Such a pass is what the scheduler's queue holds while more than one
 Deployment behind a Service scales at the same moment (the benchmark's cell
-`load-5000n-150k.rollouts-1k-8svc`). The shell cuts a burst segment wherever
-the burst class changes (`Scheduler._burst_class`: a pod that a Service
-selects bursts only with pods of its own class signature), so every change of
-Service in the pass is a segment of its own: a snapshot, an encode with its
-selector-spread count pass over the pod table, a launch, a fetch, a commit.
-Held here: every binding is the serial oracle's, and the two counters that
-name the mechanism count what the pass implies.
+`load-5000n-150k.rollouts-1k-8svc`). Pods that a Service selects, and
+nothing more, share one burst class (`Scheduler._burst_class`: `_SPREAD`), so
+the pass is ONE burst segment: one snapshot, one selector-spread count pass a
+distinct Service, one launch whose scan carries a count row a Service
+(`TPUScheduler._spread_carry`), one fetch, one commit. Held here: every
+binding is the serial oracle's, and the counters that name the mechanism
+count what the pass implies.
 """
 import random
 
@@ -16,6 +16,7 @@ import pytest
 
 from kubernetes_tpu.api.types import (
     Container, LABEL_HOSTNAME, Node, Pod, Service)
+from kubernetes_tpu.core.tpu_scheduler import SCAN_SPREAD_STEPS
 from kubernetes_tpu.ops.node_state import SPREAD_COUNT_ENCODES
 from kubernetes_tpu.oracle.generic_scheduler import num_feasible_nodes_to_find
 from kubernetes_tpu.scheduler import SEGMENT_CUTS, Scheduler
@@ -27,7 +28,8 @@ REGION = "failure-domain.beta.kubernetes.io/region"
 N_NODES = 130          # zones of 44/43/43: the NodeTree's order rotates
 N_PODS = 60
 MAX_PODS = 32          # so 60 pods are two drain passes
-CAUSES = ("class", "nominated", "unburstable", "end")
+CAUSES = ("class", "groups", "nominated", "unburstable", "end")
+CARRIES = ("none", "single", "grouped")
 
 
 def build(seed: int) -> Store:
@@ -107,6 +109,7 @@ def test_interleaved_services_bind_as_the_serial_oracle(services, percentage,
     sched._schedule_singletons_burst = watched_singletons
     sched._burst_segment = watched_segment
     cuts0 = {c: SEGMENT_CUTS.labels(c).value for c in CAUSES}
+    steps0 = {c: SCAN_SPREAD_STEPS.labels(c).value for c in CARRIES}
     encodes0 = SPREAD_COUNT_ENCODES.value
     while sched.schedule_burst(max_pods=MAX_PODS):
         pass
@@ -117,20 +120,28 @@ def test_interleaved_services_bind_as_the_serial_oracle(services, percentage,
     assert [len(p) for p in passes] == [MAX_PODS, N_PODS - MAX_PODS]
     changes = sum(a != b for p in passes for a, b in zip(p, p[1:]))
     assert (changes == 0) == (services == 1)
-    assert all(len(set(seg)) == 1 for seg in segments)
-    assert [app for seg in segments for app in seg] == \
-        [app for p in passes for app in p]
+    # a pass is one segment, however many Services' pods it holds
+    assert segments == passes
     cuts = {c: SEGMENT_CUTS.labels(c).value - cuts0[c] for c in CAUSES}
-    assert cuts == {"class": changes, "nominated": 0, "unburstable": 0,
-                    "end": len(passes)}
-    assert len(segments) == changes + len(passes)
-    # the rule found: every segment of pods that a Service selects makes one
-    # selector-spread count pass over the pod table, and a second where the
-    # walk is whole (every node scored: `num_to_find >= n and last_index ==
-    # 0`), because `schedule_burst` then tries the segment for the K-batch
-    # class first, which refuses carried spread counts, and encodes its
-    # first pod again for the generic scan. No segment shares an encode.
+    assert cuts == {"class": 0, "groups": 0, "nominated": 0,
+                    "unburstable": 0, "end": len(passes)}
+    # ... and one launch: a count row a Service where it holds several, the
+    # one vector the scan has always carried where it holds one
+    held = [len(set(p)) for p in passes]
+    assert all(k > 1 for k in held) == (services > 1)
+    steps = {c: SCAN_SPREAD_STEPS.labels(c).value - steps0[c]
+             for c in CARRIES}
+    assert steps == {
+        "none": 0,
+        "single": sum(len(p) for p, k in zip(passes, held) if k == 1),
+        "grouped": sum(len(p) for p, k in zip(passes, held) if k > 1)}
+    # one selector-spread count pass over the pod table a distinct Service
+    # and segment. A segment of ONE Service makes a second where the walk
+    # is whole (every node scored: `num_to_find >= n and last_index == 0`),
+    # because `schedule_burst` then tries the spec-identical segment for
+    # the K-batch class first, which refuses carried spread counts, and
+    # encodes its first pod again for the generic scan (PERF.md 7 (l)).
     whole = num_feasible_nodes_to_find(N_NODES, percentage) >= N_NODES
     assert whole == (percentage == 100)     # at 0 a walk stops at 63 of 130
-    per_segment = 2 if whole else 1
-    assert SPREAD_COUNT_ENCODES.value - encodes0 == per_segment * len(segments)
+    assert SPREAD_COUNT_ENCODES.value - encodes0 == \
+        sum(k + (whole and k == 1) for k in held)

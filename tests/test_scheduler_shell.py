@@ -15,7 +15,8 @@ from kubernetes_tpu.api.types import (
 from kubernetes_tpu.api.quantity import requests
 from kubernetes_tpu.coscheduling.types import LABEL_POD_GROUP
 from kubernetes_tpu.ops.pod_rows import pod_class_signature
-from kubernetes_tpu.oracle.priorities import get_selectors
+from kubernetes_tpu.oracle.priorities import (
+    get_selectors, spread_group_key)
 from kubernetes_tpu.scheduler import BURST_CLASS, Scheduler
 from kubernetes_tpu.store.store import (
     Store, PODS, NODES, REPLICASETS, SERVICES,
@@ -467,11 +468,16 @@ class TestBurstClassDecision:
 
     @staticmethod
     def _reference_class(pod, services, replicasets):
-        """`Scheduler._burst_class` as it stood when it was asked per pod."""
-        if has_pod_affinity_terms(pod) or get_container_ports(pod) \
-                or get_selectors(pod, services, replicasets):
-            return pod_class_signature(pod)
-        return "plain"
+        """`Scheduler._burst_class`, asked per pod: the signature for what
+        bursts with spec-identical peers alone, one class for every pod
+        whose only in-burst-dynamic feature is selector spread, one for
+        the rest."""
+        if has_pod_affinity_terms(pod) or get_container_ports(pod):
+            return pod_class_signature(pod), None
+        selectors = get_selectors(pod, services, replicasets)
+        if selectors:
+            return "spread", spread_group_key(pod.namespace, selectors)
+        return "plain", None
 
     @staticmethod
     def _reference_cuts(pods, services, replicasets):
@@ -479,7 +485,7 @@ class TestBurstClassDecision:
         the class function on every pod, twice, compared by value."""
         def burst_class(pod):
             return TestBurstClassDecision._reference_class(
-                pod, services, replicasets)
+                pod, services, replicasets)[0]
 
         cuts, i = [], 0
         while i < len(pods):
@@ -528,15 +534,17 @@ class TestBurstClassDecision:
         for how, seg in cuts:
             i, j = names.index(seg[0]), names.index(seg[-1]) + 1
             assert seg == names[i:j]
-            got.append((i, j, "serial" if how == "serial" else classes[i]))
+            got.append((i, j,
+                        "serial" if how == "serial" else classes[i][0]))
         assert got == ref
         # classes are identical objects exactly where the per-pod
-        # function's values are equal
+        # function's values are equal, and the groups are its groups
         old = [self._reference_class(p, services, replicasets) for p in pods]
         assert classes == old
         for a in range(len(pods)):
             for b in range(a + 1, len(pods)):
-                assert (classes[a] is classes[b]) == (old[a] == old[b])
+                assert (classes[a][0] is classes[b][0]) == \
+                    (old[a][0] == old[b][0])
 
     @pytest.mark.parametrize("rows", [True, False],
                              ids=["row-cache", "no-row-cache"])
