@@ -30,6 +30,10 @@ Stages, all in this one process (a chip belongs to one process):
           hundred pending pods of the eight interleaved pod by pod: one
           burst segment, one launch whose scan carries a count row a
           Service, replayed through the serial oracle.
+- serve-groups  that cluster holding 24 Services' pods behind a ServeLoop:
+          windows of 3, 20 and 200 pods drawn Zipf over the 24, each window
+          on the scan, the large one cut where a 17th Service comes, every
+          launch replayed through the serial oracle.
 - serve   perf.harness.run_serve_cell: arrivals -> admission gate ->
           ServeLoop windows -> commit -> watch, with its two audits.
 - mesh    only with more than one device: the drain again with the node
@@ -66,6 +70,7 @@ REAL = {
     "preempt_victims": 10000, "preemptors": 128,
     "serial_nodes": 1000, "serial_cycles": 12,
     "walk_nodes": 1000, "walk_pods": 600, "groups_pods": 400,
+    "serve_groups_windows": (3, 20, 200),
     "serve_nodes": 1000, "serve_rate": 2000.0, "serve_seconds": 5.0,
     "serve_window": 2048, "serve_parity_pods": 256,
 }
@@ -76,6 +81,7 @@ REHEARSAL = {
     "preempt_victims": 320, "preemptors": 8,
     "serial_nodes": 60, "serial_cycles": 6,
     "walk_nodes": 250, "walk_pods": 40, "groups_pods": 40,
+    "serve_groups_windows": (3, 20, 80),
     "serve_nodes": 90, "serve_rate": 300.0, "serve_seconds": 2.0,
     "serve_window": 128, "serve_parity_pods": 48,
 }
@@ -243,6 +249,23 @@ def make_pods(store, n_pods: int) -> None:
 
 
 # -- stages ------------------------------------------------------------------
+def build_services_cluster(store, n_nodes: int, k: int, rng) -> None:
+    """`build_cluster`'s nodes, `k` Services (`app=svc-j`) and one resident
+    pod a node on average, each on a node and behind a Service drawn from
+    `rng`, so the Services' count rows differ."""
+    from kubernetes_tpu.api.types import Service
+    from kubernetes_tpu.models.hollow import PodStrategy, make_pods as _pods
+    from kubernetes_tpu.store.store import PODS, SERVICES
+    build_cluster(store, n_nodes)
+    for j in range(k):
+        store.create(SERVICES, Service(name=f"svc-{j}",
+                                       selector={"app": f"svc-{j}"}))
+    for pod in _pods(PodStrategy(count=n_nodes, name_prefix="res")):
+        pod.node_name = f"node-{rng.randrange(n_nodes)}"
+        pod.labels = {"app": f"svc-{rng.randrange(k)}"}
+        store.create(PODS, pod)
+
+
 def drain(smoke: Smoke, tag: str, device: bool, n_pods: int, **sched_kw):
     """Headline-shaped cluster through the CLI's path, drained; checks
     every pod bound exactly once and returns (scheduler, store,
@@ -566,25 +589,16 @@ def stage_groups(smoke: Smoke):
     selector-spread count row a Service, on uneven zones under a truncated
     walk."""
     import random
-    from kubernetes_tpu.api.types import Service
     from kubernetes_tpu.core import tpu_scheduler as T
     from kubernetes_tpu.models.hollow import PodStrategy, make_pods as _pods
     from kubernetes_tpu.scheduler import SEGMENT_CUTS, Scheduler
-    from kubernetes_tpu.store.store import PODS, SERVICES, Store
+    from kubernetes_tpu.store.store import PODS, Store
     s = smoke.sizes
     n, n_pods, k = s["walk_nodes"], s["groups_pods"], 8
     assert n % 3, "the zones have to be uneven for the order to rotate"
     rng = random.Random(42)
     store = Store(watch_log_size=1 << 16)
-    build_cluster(store, n)
-    for j in range(k):
-        store.create(SERVICES, Service(name=f"svc-{j}",
-                                       selector={"app": f"svc-{j}"}))
-    # residents the Services already select, so the count rows differ
-    for pod in _pods(PodStrategy(count=n, name_prefix="res")):
-        pod.node_name = f"node-{rng.randrange(n)}"
-        pod.labels = {"app": f"svc-{rng.randrange(k)}"}
-        store.create(PODS, pod)
+    build_services_cluster(store, n, k, rng)
     sched = Scheduler(store, use_tpu=True, percentage_of_nodes_to_score=0)
     sched.sync()
     for pod in _pods(PodStrategy(count=n_pods)):
@@ -616,6 +630,85 @@ def stage_groups(smoke: Smoke):
                 f"{launches} launches replayed"
                 + (f", {mism[:2]}" if mism else ""))
     return {"nodes": n, "pods": n_pods, "services": k, "device_ops": ops,
+            "launches_replayed": launches}
+
+
+def stage_serve_groups(smoke: Smoke):
+    """A serve loop whose windows hold many Services' pods: every window on
+    the scan, a window of more Services than one launch carries cut by the
+    shell, pods of earlier windows still bound when the next is planned."""
+    import random
+    from kubernetes_tpu.core import tpu_scheduler as T
+    from kubernetes_tpu.models.hollow import PodStrategy, make_pods as _pods
+    from kubernetes_tpu.ops.kernels import SPREAD_GROUP_CAP
+    from kubernetes_tpu.scheduler import SEGMENT_CUTS, Scheduler
+    from kubernetes_tpu.serve import ServeLoop
+    from kubernetes_tpu.store.store import PODS, Store
+    s = smoke.sizes
+    n, windows, k = s["walk_nodes"], s["serve_groups_windows"], 24
+    assert n % 3, "the zones have to be uneven for the order to rotate"
+    rng = random.Random(43)
+    weights = [(j + 1) ** -1.1 for j in range(k)]
+    store = Store(watch_log_size=1 << 16)
+    build_services_cluster(store, n, k, rng)
+    sched = Scheduler(store, use_tpu=True, percentage_of_nodes_to_score=0)
+    sched.sync()
+    loop = ServeLoop(sched, window_size=max(windows), depth=3)
+    d0 = dispatch_counts()
+    steps0, cuts0 = family(T.SCAN_SPREAD_STEPS), family(SEGMENT_CUTS)
+    groups0 = T.SCAN_SPREAD_GROUPS.value
+    f0 = fallback_counts()
+    launches, mism, bound, want_cuts, want_groups = 0, [], 0, 0, 0
+    for w, size in enumerate(windows):
+        drawn = rng.choices(range(k), weights, k=size)
+        # what the shell makes of the window: a segment ends before the pod
+        # whose Service would be one more than a launch carries
+        seen: set = set()
+        for j in drawn:
+            if j not in seen and len(seen) == SPREAD_GROUP_CAP:
+                want_cuts += 1
+                want_groups += len(seen)
+                seen = set()
+            seen.add(j)
+        want_groups += len(seen)
+        for pod, j in zip(_pods(PodStrategy(count=size,
+                                            name_prefix=f"w{w}")), drawn):
+            pod.labels = {"app": f"svc-{j}"}
+            store.create(PODS, pod)
+
+        def run():
+            nonlocal bound
+            bound += loop.step()
+        got, bad = replayed(run)
+        launches += got
+        mism += bad
+    sched.pump()
+    ops = dispatch_delta(d0)
+    steps = delta(family(T.SCAN_SPREAD_STEPS), steps0)
+    cuts = delta(family(SEGMENT_CUTS), cuts0)
+    groups = int(T.SCAN_SPREAD_GROUPS.value - groups0)
+    smoke.check("serve_groups.all_bound", bound == sum(windows)
+                and all(p.node_name for p in store.list(PODS)[0]), bound)
+    smoke.check("serve_groups.every_window_on_the_scan",
+                ops.get("burst_scan", 0) == len(windows) + want_cuts
+                and "burst_uniform" not in ops, ops)
+    smoke.check("serve_groups.cut_at_the_group_cap",
+                want_cuts > 0 and cuts == {"groups": want_cuts,
+                                           "end": len(windows)},
+                f"{cuts}, {want_cuts} expected")
+    smoke.check("serve_groups.a_count_row_a_service",
+                set(steps) <= {"grouped", "single"}
+                and sum(steps.values()) == sum(windows)
+                and steps.get("grouped", 0) > 0 and groups == want_groups,
+                f"{steps}, {groups} groups carried, {want_groups} expected")
+    smoke.check("serve_groups.no_refusal",
+                not delta(fallback_counts(), f0), delta(fallback_counts(), f0))
+    smoke.check("serve_groups.replay_parity",
+                launches == len(windows) + want_cuts and not mism,
+                f"{launches} launches replayed"
+                + (f", {mism[:2]}" if mism else ""))
+    return {"nodes": n, "windows": list(windows), "services": k,
+            "group_cuts": want_cuts, "device_ops": ops,
             "launches_replayed": launches}
 
 
@@ -741,7 +834,8 @@ def main(argv=None) -> int:
     stages += [("lanes.gang", stage_gang), ("lanes.preempt", stage_preempt),
                ("lanes.preempt_scan", stage_preempt_scan),
                ("serial", stage_serial), ("walk", stage_walk),
-               ("groups", stage_groups), ("serve", stage_serve)]
+               ("groups", stage_groups),
+               ("serve-groups", stage_serve_groups), ("serve", stage_serve)]
     if len(dev) > 1:
         stages.append(("mesh", stage_mesh))
     for name, fn in stages:

@@ -147,6 +147,14 @@ SCAN_SPREAD_STEPS = obs.counter(
     "kernels.SPREAD_GROUP_CAP selector groups: one count row a group, a "
     "step reads its pod's row and adds a column). Booked once a launch, "
     "beside tpu_scan_steps_total.", ("carry",))
+SCAN_SPREAD_GROUPS = obs.counter(
+    "tpu_scan_spread_groups_total",
+    "Selector groups whose spread counts schedule_burst's generic scan "
+    "launches carried: 1 for a 'single' launch, the 2 to "
+    "kernels.SPREAD_GROUP_CAP count rows its pods read for a 'grouped' "
+    "one (the spare rows of the padded carry are not counted), nothing "
+    "for 'none'. Booked once a launch, beside "
+    "tpu_scan_spread_steps_total.")
 PICK_TIED_NODES = obs.counter(
     "tpu_pick_tied_nodes_total",
     "Nodes that tied for the best score (selectHost's round-robin set), "
@@ -1505,8 +1513,7 @@ class TPUScheduler:
                                 commit, ph, fl=fl,
                                 spread_groups=spread_groups)
 
-    @staticmethod
-    def _spread_carry(feats: list, n_pad: int) -> Optional[tuple]:
+    def _spread_carry(self, feats: list, n_pad: int) -> Optional[tuple]:
         """(spread0, spread_groups) for a generic scan launch whose pods
         carry selector-spread counts; `feats` are the pods' features, one
         object a signature. A pod's group is what SelectorSpread can tell
@@ -1520,10 +1527,15 @@ class TPUScheduler:
         ([G_pad, n_pad] rows, (group of each pod [len(feats)] int32,
         counts_for [G_pad, G_pad] bool)), G_pad a power of two so that a
         stream of launches of about as many groups runs one program; the
-        spare rows are zero and no pod reads or moves them. None where the
-        carry cannot be made exact: a pod that nothing selects among pods
-        that something does (its constant score is not a row of zeros'),
-        more groups than the cap, or counts off the node axis."""
+        spare rows are zero and no pod reads or moves them. Behind a serve
+        loop (`launch_cap` pinned) the group count changes from window to
+        window, and a program first met there is a compile inside a window
+        that pods wait on: G_pad is then the cap itself, so the loop runs
+        two scan programs (one vector, the cap's rows) and its first large
+        window has met both. None where the carry cannot be made exact: a
+        pod that nothing selects among pods that something does (its
+        constant score is not a row of zeros'), more groups than the cap,
+        or counts off the node axis."""
         keys: dict = {}
         rows = []
         group_of_feat: dict = {}
@@ -1542,7 +1554,8 @@ class TPUScheduler:
             return rows[0], None
         if len(rows) > K.SPREAD_GROUP_CAP:
             return None
-        g_pad = _pad_pow2(len(rows), 2)
+        g_pad = K.SPREAD_GROUP_CAP if self.launch_cap \
+            else _pad_pow2(len(rows), 2)
         spread0 = np.zeros((g_pad, n_pad), np.int64)
         spread0[:len(rows)] = rows
         counts_for = np.zeros((g_pad, g_pad), bool)
@@ -1818,6 +1831,10 @@ class TPUScheduler:
             SCAN_SPREAD_STEPS.labels(
                 "none" if spread0 is None else
                 "single" if spread_groups is None else "grouped").inc(n_pods)
+            if spread0 is not None:
+                SCAN_SPREAD_GROUPS.inc(
+                    1 if spread_groups is None
+                    else int(spread_groups[0][:n_pods].max()) + 1)
             SCAN_SCORE_STEPS.labels(
                 "full" if classes is None else "carried").inc(n_pods)
             SCAN_POD_ROWS.labels(

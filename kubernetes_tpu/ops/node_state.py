@@ -58,6 +58,12 @@ SPREAD_COUNT_ENCODES = obs.counter(
     "Selector-spread count passes: one PodEncoder.encode of a pod that a "
     "Service or ReplicaSet selects, which matches its selectors over the "
     "whole columnar pod table and sums the matches by holder node.")
+SELECTOR_WALK_SERVICES = obs.counter(
+    "tpu_selector_walk_services_total",
+    "Services and ReplicaSets get_selectors tested against a pod for an "
+    "encode (PodEncoder._encode_scores: a linear walk over every one of "
+    "the cluster, as upstream's GetPodServices is). One increment a walk, "
+    "by the number tested.")
 VICTIM_ROW_RESORTS = obs.counter(
     "tpu_victim_table_row_resorts_total",
     "Victim-table node rows re-sorted (generation moved or the PDB set "
@@ -1217,6 +1223,7 @@ class PodEncoder:
                     np.add.at(counts, rows, 1)
             f.taint_counts = counts
         selectors = get_selectors(pod, self.services, self.replicasets)
+        SELECTOR_WALK_SERVICES.inc(len(self.services) + len(self.replicasets))
         if selectors:
             # selector-spread counting (selector_spreading.go:66): one
             # vectorized selector-match over the columnar pod table plus a

@@ -22,6 +22,7 @@ ADAPTIVE = "headline-15000n-adaptive.backlog-10k"
 DENSITY_ADAPTIVE = "density-5000n-150k-adaptive.rollout-1k"
 MIXED = "inuse-15000n-135k.backlog-10k-mixed"
 LOAD = "load-5000n-150k.rollouts-1k-8svc"
+SERVICES = "services-5000n-150k.arrivals-zipf-64svc"
 # the scan cells whose 15,000 nodes reach kernels.SCORE_BOARD_MIN_ROWS
 BOARD = (ADAPTIVE, MIXED)
 
@@ -33,7 +34,10 @@ BOARD = (ADAPTIVE, MIXED)
 # 80: the scan walks the device axis, as at 15,000) and a backlog whose dirty
 # rows stay in one scatter bucket (at most 120, never 64 or fewer). The load
 # cell takes the density cell's 250 nodes (a truncated walk on a rotating
-# order, as at 5000) and ten Services, eight of which its mix names.
+# order, as at 5000) and ten Services, eight of which its mix names. The
+# services cell takes the same 250 nodes and 80 Services, 64 of which its mix
+# names, at a rate whose windows hold one pod to a few (the warm-up's first
+# pass holds 232 pods: more than sixteen Services, so the shell cuts it).
 AT_50 = {"nodes": {"count": 50},
          "check": {"first_binds": 200, "sampled_binds": 60}}
 SMALL = {
@@ -57,6 +61,11 @@ SMALL = {
             "resident": {"pods_per_node": 6, "services": 10},
             "check": {"first_binds": 200, "sampled_binds": 100}},
            {"warm_binds": 0, "backlog": 150}),
+    SERVICES: ({"nodes": {"count": 250},
+                "resident": {"pods_per_node": 6, "services": 80},
+                "check": {"first_binds": 300, "sampled_binds": 200}},
+               {"arrival": {"rate_per_s": 200.0}, "lifetime_s": 0.4,
+                "serve": {"window_size": 64}}),
 }
 # the control of an adaptive cell: the program scores every node while the
 # reference judges at the file's default percentage
@@ -136,9 +145,13 @@ def counter_metric(name, res, rep, pods=None, moved=None):
     (LOAD, 2**31 + 77, None, None),
     (LOAD, 2**31 + 77, altered_load_binding, None),
     (LOAD, 2**31 + 77, None, EVERY_NODE),               # its control
+    # 64 Services' replicas through the serve loop: windows on the scan
+    (SERVICES, 2**31 + 91, None, None),
+    (SERVICES, 2**31 + 91, None, EVERY_NODE),           # its control
 ], ids=["backlog", "rollout", "arrivals", "adaptive", "altered-binding",
         "density-adaptive", "density-adaptive-control", "mixed",
-        "load", "load-altered-binding", "load-control"])
+        "load", "load-altered-binding", "load-control",
+        "services", "services-control"])
 def test_rehearsed_cell(monkeypatch, execute, cell, seed, hook, program):
     if cell in BOARD:
         # these cells hold 16,384 node rows, enough for the scan to carry
@@ -148,7 +161,7 @@ def test_rehearsed_cell(monkeypatch, execute, cell, seed, hook, program):
         monkeypatch.setattr(kernels, "SCORE_BOARD_MIN_ROWS", 1)
     broken = hook is not None or program is not None
     before = {}
-    if cell == LOAD and not broken:
+    if cell in (LOAD, SERVICES) and not broken:
         # the shell's counters are not in the report: take the whole run's
         from lib import counters
         hook = lambda sched, store: before.update(counters.snapshot())
@@ -161,7 +174,7 @@ def test_rehearsed_cell(monkeypatch, execute, cell, seed, hook, program):
     assert res["correct"] is True and res["failed"] == 0
     assert res["attempted"] > 0
     assert rep["compiles_in_window"] == 0
-    if cell in (ROLLOUT, ADAPTIVE, DENSITY_ADAPTIVE, MIXED, LOAD):
+    if cell in (ROLLOUT, ADAPTIVE, DENSITY_ADAPTIVE, MIXED, LOAD, SERVICES):
         # the generic scan's cells: at 16,384 rows every launch carries the
         # score board (one pod class, or up to eight in the mixed cell), at
         # the density cells' 8192 every step rescores every row
@@ -232,6 +245,45 @@ def test_rehearsed_cell(monkeypatch, execute, cell, seed, hook, program):
         assert cuts == {("end",): pods / backlog}
         assert counter_metric("segment_class_cuts_per_pod.backlog",
                               res, rep, pods, whole) == 0.0
+    if cell == SERVICES:
+        moved = rep["counters"]
+        pods = res["attempted"]
+        # every serve window goes to the scan, on a truncated rotating walk
+        assert "tpu_oracle_fallback_total" not in moved
+        assert set(moved["tpu_device_dispatch_total"]) == \
+            {"burst_scan", "scatter"}
+        assert counter_metric("scan_steps_per_pod.arrivals", res, rep) == 1.0
+        assert counter_metric("walk_nodes_per_pod.arrivals", res, rep) == 120
+        # one count row a Service where a segment holds several, one vector
+        # where it holds one: no pod goes uncounted
+        steps = moved["tpu_scan_spread_steps_total"]
+        assert set(steps) <= {"grouped", "single"} and steps["grouped"] > 0
+        assert sum(steps.values()) == pods
+        assert counter_metric("spread_grouped_steps_per_pod.arrivals",
+                              res, rep) == steps["grouped"] / pods
+        # the deployment's two counters: one count pass and one walk over
+        # all 80 Services a group a segment
+        groups = moved["tpu_scan_spread_groups_total"][""]
+        assert groups == moved["tpu_spread_count_encodes_total"][""] > 0
+        assert counter_metric("spread_groups_per_pod.arrivals",
+                              res, rep) == groups / pods
+        assert counter_metric("selector_services_tested_per_pod.arrivals",
+                              res, rep) == 80 * groups / pods
+        # pods of earlier windows are still bound when a table is made
+        assert counter_metric("pod_table_rows_extracted_per_pod.arrivals",
+                              res, rep) > 0
+        assert counter_metric("pod_table_rows_reused_per_pod.arrivals",
+                              res, rep) > 0
+        # the shell's side, over warm-up and window: the warm-up's 232-pod
+        # pass holds more than sixteen Services and is cut; no class cut
+        whole = counters.delta(counters.snapshot(), before)
+        cuts = whole["scheduler_burst_segment_cuts_total"]
+        assert set(cuts) == {("groups",), ("end",)}
+        bound = whole["serve_pods_scheduled_total"][()]
+        assert counter_metric("segment_group_cuts_per_pod.arrivals", res, rep,
+                              bound, whole) == cuts[("groups",)] / bound > 0
+        assert counter_metric("segment_class_cuts_per_pod.arrivals", res, rep,
+                              bound, whole) == 0.0
     if cell == MIXED:
         moved = rep["counters"]
         # unlike plain pods share one segment, and it goes to the scan

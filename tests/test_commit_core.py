@@ -618,7 +618,14 @@ class TestSharedSubscriptionClasses:
         for i in range(200):
             store.create(PODS, mkpod(f"p{i}"))
             if i % 16 == 15:
-                time.sleep(0.002)   # let the fast classmate catch up
+                # let the fast classmate catch up: a pause, and on a
+                # loaded machine as many more as its thread needs to come
+                # within a batch of the writer (bounded; the slow one
+                # never drains and is dropped all the same)
+                caught_up = time.monotonic() + 5
+                time.sleep(0.002)
+                while len(got) < i - 15 and time.monotonic() < caught_up:
+                    time.sleep(0.002)
         t.join(timeout=12)
         assert not t.is_alive()
         assert len(got) == 200
