@@ -32,8 +32,9 @@ Stages, all in this one process (a chip belongs to one process):
           Service, replayed through the serial oracle.
 - serve-groups  that cluster holding 24 Services' pods behind a ServeLoop:
           windows of 3, 20 and 200 pods drawn Zipf over the 24, each window
-          on the scan, the large one cut where a 17th Service comes, every
-          launch replayed through the serial oracle.
+          on the scan, the large one cut where a 17th Service comes, each
+          launch's pod operand built from a row a signature, every launch
+          replayed through the serial oracle.
 - serve   perf.harness.run_serve_cell: arrivals -> admission gate ->
           ServeLoop windows -> commit -> watch, with its two audits.
 - mesh    only with more than one device: the drain again with the node
@@ -654,6 +655,18 @@ def stage_serve_groups(smoke: Smoke):
     sched = Scheduler(store, use_tpu=True, percentage_of_nodes_to_score=0)
     sched.sync()
     loop = ServeLoop(sched, window_size=max(windows), depth=3)
+    # each launch's pod operand: (rows built from Python objects, the
+    # launch's signatures, rows the gather filled)
+    stacked = []
+    stack = sched.algorithm._stack_pods
+
+    def watched(*a):
+        rows0 = family(T.SCAN_STACK_ROWS)
+        out = stack(*a)
+        rows = delta(family(T.SCAN_STACK_ROWS), rows0)
+        stacked.append((rows.get("built"), out[1], rows.get("taken")))
+        return out
+    sched.algorithm._stack_pods = watched
     d0 = dispatch_counts()
     steps0, cuts0 = family(T.SCAN_SPREAD_STEPS), family(SEGMENT_CUTS)
     groups0 = T.SCAN_SPREAD_GROUPS.value
@@ -701,6 +714,14 @@ def stage_serve_groups(smoke: Smoke):
                 and sum(steps.values()) == sum(windows)
                 and steps.get("grouped", 0) > 0 and groups == want_groups,
                 f"{steps}, {groups} groups carried, {want_groups} expected")
+    # the pods differ in their Service alone, so a launch's signatures are
+    # its Services; the bucket is the loop's drain pass, whatever it holds
+    smoke.check("serve_groups.a_row_built_a_signature",
+                len(stacked) == len(windows) + want_cuts
+                and all(built <= sigs + 1 and taken >= 3 * max(windows)
+                        for built, sigs, taken in stacked)
+                and sum(sigs for _b, sigs, _t in stacked) == want_groups,
+                stacked)
     smoke.check("serve_groups.no_refusal",
                 not delta(fallback_counts(), f0), delta(fallback_counts(), f0))
     smoke.check("serve_groups.replay_parity",

@@ -138,6 +138,15 @@ SCAN_POD_ROWS = obs.counter(
     "per-signature arrays) or 'shared' (every pod of the launch was one "
     "object, broadcast). Booked once a launch, beside "
     "tpu_scan_steps_total.", ("rows",))
+SCAN_STACK_ROWS = obs.counter(
+    "tpu_scan_stack_rows_total",
+    "Rows of the [B] pod operands that _stack_pods made for the scan "
+    "launches (schedule_burst's generic scan, the fused window, the "
+    "pressure scan): 'built' (rows assembled from Python objects: one a "
+    "distinct per-signature dict of the launch, and the pad row where the "
+    "pods do not fill the bucket) and 'taken' (rows of the operand the "
+    "gather filled: the bucket B). Booked once a launch, where the rows "
+    "are stacked.", ("rows",))
 SCAN_SPREAD_STEPS = obs.counter(
     "tpu_scan_spread_steps_total",
     "Steps of schedule_burst's generic scan launches, by how the launch "
@@ -371,7 +380,7 @@ class TPUScheduler:
             "tens_i64": np.full(1, 10, dtype=np.int64),
         }
         # shared scalar singletons: identical-by-identity inputs let
-        # _stack_pods broadcast instead of stacking B python objects
+        # _stack_pods broadcast a field instead of gathering it
         self._true = np.bool_(True)
         self._false = np.bool_(False)
         self._zero_i64 = np.int64(0)
@@ -564,19 +573,39 @@ class TPUScheduler:
             })
         return out
 
-    @staticmethod
-    def _stack_pods(per_pod: list[dict]) -> dict:
-        """Stack per-pod dicts to [B, ...] arrays. A field that is inert
-        ([1]-shaped) for every pod stays [B, 1] — the scan broadcasts it —
-        so plain pods upload O(B) data, not O(B*N). Fields holding the SAME
-        object for every pod (the shared inert defaults / scalar singletons)
-        are broadcast views, not B-element stacks."""
+    def _stack_pods(self, per_pod: list[dict], bucket: int,
+                    profile_ids=None) -> tuple[dict, int]:
+        """(operands, signatures): the [bucket, ...] pod operands of a scan
+        launch from its pods' dicts, and how many DISTINCT dicts those are
+        (pods of one signature share one dict object). One row a distinct
+        dict is stacked, plus the pad row where the pods do not fill the
+        bucket (the last pod's row marked `skip`: it gives the operand its
+        shape and is never stepped over), and one gather over a [bucket]
+        index brings each field to its length: the Python-object work is
+        O(fields x signatures), whatever the bucket.
+
+        A field that is inert ([1]-shaped) for every pod stays [B, 1] — the
+        scan broadcasts it — so plain pods upload O(B) data, not O(B*N).
+        Fields holding the SAME object in every row (the shared inert
+        defaults / scalar singletons) are broadcast views, not gathers.
+        `profile_ids` (tensor mode) is a per-pod scalar and rides as the
+        [bucket] vector it is, the pad rows taking the last pod's."""
+        n = len(per_pod)
+        ids = np.fromiter(map(id, per_pod), np.int64, n)
+        _ids, first, index = np.unique(ids, return_index=True,
+                                       return_inverse=True)
+        rows = [per_pod[i] for i in first]
+        signatures = len(rows)
+        if n < bucket:
+            rows.append(dict(per_pod[-1], skip=self._true))
+            index = np.concatenate(
+                [index, np.full(bucket - n, signatures, index.dtype)])
         out = {}
-        for k in per_pod[0]:
-            vals = [pp[k] for pp in per_pod]
+        for k in rows[0]:
+            vals = [pp[k] for pp in rows]
             v0 = vals[0]
             if all(v is v0 for v in vals):
-                out[k] = np.broadcast_to(v0, (len(vals),) + np.shape(v0))
+                out[k] = np.broadcast_to(v0, (bucket,) + np.shape(v0))
                 continue
             shapes = {np.shape(v) for v in vals}
             if len(shapes) > 1:
@@ -584,8 +613,13 @@ class TPUScheduler:
                 target = max(shapes, key=len) if len({len(s) for s in shapes}) > 1 \
                     else max(shapes)
                 vals = [np.broadcast_to(v, target) for v in vals]
-            out[k] = np.stack(vals)
-        return out
+            out[k] = np.take(np.stack(vals), index, axis=0)
+        if profile_ids is not None:
+            out["profile_id"] = np.concatenate(
+                [profile_ids, np.full(bucket - n, profile_ids[-1], np.int64)])
+        SCAN_STACK_ROWS.labels("built").inc(len(rows))
+        SCAN_STACK_ROWS.labels("taken").inc(bucket)
+        return out, signatures
 
     # -- reason decoding -----------------------------------------------------
     def _decode_reasons(self, b: NodeBatch, f: PodFeatures, idx: int,
@@ -1478,8 +1512,8 @@ class TPUScheduler:
         # per-cycle rotated enumeration orders (uneven zones)
         rotation = self._scan_rotation(b, bucket, start0)
         # one device-array dict per SIGNATURE (equal sigs -> identical
-        # _pod_arrays output by construction), so _stack_pods broadcasts
-        # repeated specs by identity instead of stacking B copies
+        # _pod_arrays output by construction), so _stack_pods builds one
+        # row a signature and gathers it to the pods that share it
         arr_by_feat: dict = {}
         for p, f in zip(pods, feats):
             if id(f) not in arr_by_feat:
@@ -1499,19 +1533,14 @@ class TPUScheduler:
         # spread counts, and the single-dispatch/single-fetch contract all
         # run sharded — the old burst-sharded-rotation / burst-sharded-
         # spread oracle fallbacks are deleted, not dodged.
-        if pids is not None:
-            # per-pod weight-row selection: shallow per-pod dicts so the
-            # varying profile_id stacks while every other field keeps its
-            # identity-broadcast (equal sigs still share field objects)
-            per_pod = [dict(pp, profile_id=np.int64(pids[i]))
-                       for i, pp in enumerate(per_pod)]
         fl = obs_flight.RECORDER.begin("scan", self, [(pods, False)],
                                        all_node_names, node_infos)
         ph.close()
         return self._scan_waves(pods, b, per_pod, spread0, rotation,
                                 num_to_find, n, z_pad, bucket,
                                 commit, ph, fl=fl,
-                                spread_groups=spread_groups)
+                                spread_groups=spread_groups,
+                                profile_ids=pids)
 
     def _spread_carry(self, feats: list, n_pad: int) -> Optional[tuple]:
         """(spread0, spread_groups) for a generic scan launch whose pods
@@ -1769,7 +1798,8 @@ class TPUScheduler:
                     spread0, rotation, num_to_find: int,
                     n: int, z_pad: int, bucket: int, commit,
                     ph: _BurstPhases, fl=None,
-                    spread_groups=None) -> list[Optional[str]]:
+                    spread_groups=None,
+                    profile_ids=None) -> list[Optional[str]]:
         """Single-launch driver for the generic scan burst: the whole
         burst runs as ONE launch whose operands have the caller's bucket
         shape (so the warmup burst compiles the same program) and whose
@@ -1791,30 +1821,24 @@ class TPUScheduler:
         B = bucket
         n_pods = len(pods)
         W = max(1, min(int(self.wave_size), B))
-        signatures = len({id(pp) for pp in per_pod})
-        with obs_trace.span("burst.stack", signatures=signatures):
-            wave = list(per_pod)
-            if len(wave) < B:
-                pad = dict(wave[-1])
-                pad["skip"] = self._true
-                wave.extend([pad] * (B - len(wave)))
-            stacked = self._stack_pods(wave)
-            # a per-pod weight row (tensor mode) makes the row-local scores
-            # per profile, and over few node rows a device the score board
-            # costs more than it saves: those launches rescore every row
-            # each step
-            tensor = self._ptab is not None
-            shards = 1 if self.mesh is None else self.mesh.size
-            carried = not tensor and \
-                b.n_pad // shards >= K.SCORE_BOARD_MIN_ROWS
-            classes = K.score_classes(
-                stacked["nz_cpu"], stacked["nz_mem"], n_pods) \
-                if carried else None
-            if spread_groups is not None:
-                # the pad rows take group 0: they are never stepped over
-                group = np.zeros(B, np.int32)
-                group[:n_pods] = spread_groups[0]
-                spread_groups = (group, spread_groups[1])
+        sp = obs_trace.begin("burst.stack")
+        stacked, signatures = self._stack_pods(per_pod, B, profile_ids)
+        # a per-pod weight row (tensor mode) makes the row-local scores per
+        # profile, and over few node rows a device the score board costs
+        # more than it saves: those launches rescore every row each step
+        tensor = self._ptab is not None
+        shards = 1 if self.mesh is None else self.mesh.size
+        carried = not tensor and \
+            b.n_pad // shards >= K.SCORE_BOARD_MIN_ROWS
+        classes = K.score_classes(
+            stacked["nz_cpu"], stacked["nz_mem"], n_pods) \
+            if carried else None
+        if spread_groups is not None:
+            # the pad rows take group 0: they are never stepped over
+            group = np.zeros(B, np.int32)
+            group[:n_pods] = spread_groups[0]
+            spread_groups = (group, spread_groups[1])
+        sp.end(signatures=signatures)
         ph.open("kernel")
         t_d = obs_trace.now()
         try:
@@ -2050,19 +2074,12 @@ class TPUScheduler:
                     return None
                 pp = arr_by_sig.get(sig)
                 if pp is None:
-                    # one array dict per signature: repeated specs
-                    # broadcast by identity through _stack_pods (same
-                    # values — equal sigs imply identical _pod_arrays
-                    # output)
+                    # one array dict per signature: _stack_pods builds
+                    # its row once (same values — equal sigs imply
+                    # identical _pod_arrays output)
                     pp = arr_by_sig[sig] = self._pod_arrays(
                         f, b.n_pad, upd_fields=True, pod=p)
                 per_pod.append(pp)
-        pids = self._profile_ids(flat)
-        if pids is not None:
-            # tensor mode: each pod selects its weight row in-kernel; the
-            # shallow dict keeps every other field identity-broadcastable
-            per_pod = [dict(pp, profile_id=np.int64(pids[i]))
-                       for i, pp in enumerate(per_pod)]
         n = b.n_real
         num_to_find = num_feasible_nodes_to_find(
             n, self.percentage_of_nodes_to_score)
@@ -2082,10 +2099,9 @@ class TPUScheduler:
             idx += len(seg_pods)
         if idx < B:
             seg_start[idx] = True   # padding: its own inert segment
-            pad = dict(per_pod[-1])
-            pad["skip"] = self._true
-            per_pod.extend([pad] * (B - idx))
-        stacked = self._stack_pods(per_pod)
+        # tensor mode: each pod selects its weight row in-kernel
+        stacked, _signatures = self._stack_pods(
+            per_pod, B, self._profile_ids(flat))
         z_pad = _pad_pow2(len(b.zone_names), 4)
         # flight recorder: the fused window is THE canonical record — gang
         # boundaries, rewinds and rotation state all ride one launch
@@ -2596,12 +2612,8 @@ class TPUScheduler:
             for lo in range(0, len(per_pod), self.PRESSURE_B_CAP):
                 chaos.check("device.dispatch")
                 chunk = per_pod[lo: lo + self.PRESSURE_B_CAP]
-                bucket = _pad_pow2(len(chunk), 8)
-                if len(chunk) < bucket:
-                    pad = dict(chunk[-1])
-                    pad["skip"] = self._true
-                    chunk = chunk + [pad] * (bucket - len(chunk))
-                stacked = self._stack_pods(chunk)
+                stacked, _signatures = self._stack_pods(
+                    chunk, _pad_pow2(len(chunk), 8))
                 mut0, ghost0, li, lni, outs = K.pressure_batch(
                     nodes, mut0, ghost0, stacked, vic, li, lni, num_to_find,
                     n, z_pad, weights=press_weights, mesh=self.mesh)

@@ -232,6 +232,11 @@ def test_rehearsed_cell(monkeypatch, execute, cell, seed, hook, program):
                               res, rep) == 1.0
         assert moved["tpu_scan_pod_rows_total"] == \
             {"stacked": res["attempted"]}
+        # a row built a signature (eight) and the pad's: 150 pods in 256
+        assert moved["tpu_scan_stack_rows_total"] == \
+            {"built": 9 * launches, "taken": 256 * launches}
+        assert counter_metric("stack_rows_built_per_pod.backlog",
+                              res, rep) == 9 / backlog
         # one spread count pass a Service and launch (a truncated walk
         # never tries the K-batch class first)
         assert moved["tpu_spread_count_encodes_total"] == {"": 8 * launches}
@@ -269,6 +274,13 @@ def test_rehearsed_cell(monkeypatch, execute, cell, seed, hook, program):
                               res, rep) == groups / pods
         assert counter_metric("selector_services_tested_per_pod.arrivals",
                               res, rep) == 80 * groups / pods
+        # the pods differ in their Service alone: a launch builds a row a
+        # group and the pad's, and gathers them to the drain's bucket
+        launches = moved["tpu_device_dispatch_total"]["burst_scan"]
+        rows = moved["tpu_scan_stack_rows_total"]
+        assert rows == {"built": groups + launches, "taken": 256 * launches}
+        assert counter_metric("stack_rows_built_per_pod.arrivals",
+                              res, rep) == rows["built"] / pods
         # pods of earlier windows are still bound when a table is made
         assert counter_metric("pod_table_rows_extracted_per_pod.arrivals",
                               res, rep) > 0

@@ -31,7 +31,6 @@ from reference.default_provider import Reference  # noqa: E402
 
 from kubernetes_tpu.api.types import Container, Node, Pod  # noqa: E402
 from kubernetes_tpu.cache.node_info import NodeInfo  # noqa: E402
-from kubernetes_tpu.core.tpu_scheduler import TPUScheduler  # noqa: E402
 from kubernetes_tpu.ops import kernels as K  # noqa: E402
 
 from test_adaptive_walk import Run, config  # noqa: E402
@@ -152,11 +151,11 @@ def test_mixed_burst_against_the_reference(monkeypatch):
     run.client.create(pods)
     run.sched.pump()
     stacked_rows = []
-    stack = TPUScheduler._stack_pods
+    stack = run.sched.algorithm._stack_pods
 
-    def spy(per_pod):
-        out = stack(per_pod)
-        stacked_rows.append((per_pod, out))
+    def spy(per_pod, bucket, profile_ids=None):
+        out = stack(per_pod, bucket, profile_ids)
+        stacked_rows.append((per_pod, bucket, out[0]))
         return out
     monkeypatch.setattr(run.sched.algorithm, "_stack_pods", spy)
     before = counters.snapshot()
@@ -180,8 +179,9 @@ def test_mixed_burst_against_the_reference(monkeypatch):
 
     # what the launch was handed: rows that differ in value, and an inert
     # [1] field beside the same field dense [n_pad], broadcast up row by row
-    (per_pod, out), = stacked_rows
-    B, n_pad = len(per_pod), 128
+    (per_pod, B, out), = stacked_rows
+    n_pad = 128
+    assert len(per_pod) == 300        # the pad rows are the stacking's own
     assert B == 512 and out["req_cpu"].shape == (B,)
     assert [int(v) for v in out["req_cpu"][:300]] == \
         [d["cpu"] for _p, d in made]
