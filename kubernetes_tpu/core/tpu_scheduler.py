@@ -277,6 +277,7 @@ class TPUScheduler:
                  hard_pod_affinity_weight: int = 1,
                  services_fn=lambda: [],
                  replicasets_fn=lambda: [],
+                 selector_index_fn=None,
                  collect_host_priority: bool = True,
                  nominated=None,
                  volume_listers=None, volume_binder=None,
@@ -287,6 +288,10 @@ class TPUScheduler:
         self.hard_pod_affinity_weight = hard_pod_affinity_weight
         self.services_fn = services_fn
         self.replicasets_fn = replicasets_fn
+        # the owner of the two lists may keep a `SelectorIndex` over them
+        # (the shell's `LiveSelectorIndex`); without one, each PodEncoder
+        # builds its own from the lists it is given
+        self.selector_index_fn = selector_index_fn
         self.collect_host_priority = collect_host_priority
         self.check_resources = True   # PodFitsResources enabled (provider/policy)
         self.weights = None           # None -> kernels.DEFAULT_WEIGHTS
@@ -812,16 +817,25 @@ class TPUScheduler:
                 self._lat_dev = dt if self._lat_dev is None \
                     else 0.7 * self._lat_dev + 0.3 * dt
 
+    def _pod_encoder(self, node_infos: dict[str, NodeInfo],
+                     b: NodeBatch) -> PodEncoder:
+        """A PodEncoder over one encoded snapshot, with this algorithm's
+        listers, settings and selector index."""
+        index_fn = self.selector_index_fn
+        return PodEncoder(
+            node_infos, b, self.services_fn(), self.replicasets_fn(),
+            hard_pod_affinity_weight=self.hard_pod_affinity_weight,
+            enabled=self.enabled_predicates,
+            volume_listers=self.volume_listers,
+            volume_binder=self.volume_binder,
+            state_encoder=self.encoder,
+            selector_index=index_fn() if index_fn is not None else None)
+
     def _schedule_device(self, pod: Pod, node_infos: dict[str, NodeInfo],
                          all_node_names: list[str]) -> ScheduleResult:
         b = self.encoder.encode(node_infos, all_node_names)
         nodes = self._node_arrays(b)
-        enc = PodEncoder(node_infos, b, self.services_fn(), self.replicasets_fn(),
-                         hard_pod_affinity_weight=self.hard_pod_affinity_weight,
-                         enabled=self.enabled_predicates,
-                         volume_listers=self.volume_listers,
-                         volume_binder=self.volume_binder,
-                         state_encoder=self.encoder)
+        enc = self._pod_encoder(node_infos, b)
         feats = enc.encode(pod)
         pod_in = self._pod_arrays(feats, b.n_pad)
         wtab = None
@@ -1421,12 +1435,7 @@ class TPUScheduler:
         with obs_trace.span("burst.encode.nodes"):
             b = self.encoder.encode(node_infos, axis_order)
         self._node_arrays(b)
-        enc = PodEncoder(node_infos, b, self.services_fn(), self.replicasets_fn(),
-                         hard_pod_affinity_weight=self.hard_pod_affinity_weight,
-                         enabled=self.enabled_predicates,
-                         volume_listers=self.volume_listers,
-                         volume_binder=self.volume_binder,
-                         state_encoder=self.encoder)
+        enc = self._pod_encoder(node_infos, b)
         n = b.n_real
         num_to_find = num_feasible_nodes_to_find(n, self.percentage_of_nodes_to_score)
         bucket = _pad_pow2(bucket if bucket else len(pods), 16)
@@ -2050,13 +2059,7 @@ class TPUScheduler:
         with obs_trace.span("burst.encode.nodes"):
             b = self.encoder.encode(node_infos, axis_order)
         nodes = self._node_arrays(b)
-        enc = PodEncoder(node_infos, b, self.services_fn(),
-                         self.replicasets_fn(),
-                         hard_pod_affinity_weight=self.hard_pod_affinity_weight,
-                         enabled=self.enabled_predicates,
-                         volume_listers=self.volume_listers,
-                         volume_binder=self.volume_binder,
-                         state_encoder=self.encoder)
+        enc = self._pod_encoder(node_infos, b)
         feat_by_sig: dict = {}
         arr_by_sig: dict = {}
         per_pod = []
@@ -2298,13 +2301,7 @@ class TPUScheduler:
         if vic is None:
             ORACLE_FALLBACKS.labels(f"preempt-victims-{gate}").inc()
             return None
-        enc = PodEncoder(node_infos, b, self.services_fn(),
-                         self.replicasets_fn(),
-                         hard_pod_affinity_weight=self.hard_pod_affinity_weight,
-                         enabled=self.enabled_predicates,
-                         volume_listers=self.volume_listers,
-                         volume_binder=self.volume_binder,
-                         state_encoder=self.encoder)
+        enc = self._pod_encoder(node_infos, b)
         f = enc.encode(pod)
         if f.unknown_scalars:
             ORACLE_FALLBACKS.labels("preempt-unknown-scalars").inc()
@@ -2533,13 +2530,7 @@ class TPUScheduler:
         axis_order, start0 = self._axis_order(all_node_names)
         b = self.encoder.encode(node_infos, axis_order)
         nodes = self._node_arrays(b)
-        enc = PodEncoder(node_infos, b, self.services_fn(),
-                         self.replicasets_fn(),
-                         hard_pod_affinity_weight=self.hard_pod_affinity_weight,
-                         enabled=self.enabled_predicates,
-                         volume_listers=self.volume_listers,
-                         volume_binder=self.volume_binder,
-                         state_encoder=self.encoder)
+        enc = self._pod_encoder(node_infos, b)
         feat_by_sig: dict = {}
         feats = []
         for p in pods:

@@ -30,7 +30,8 @@ from kubernetes_tpu.oracle.predicates import (
     pod_matches_term_props_mask, selector_match_mask,
     InterPodAffinityChecker,
 )
-from kubernetes_tpu.oracle.priorities import get_selectors, spread_group_key
+from kubernetes_tpu.oracle.priorities import spread_group_key
+from kubernetes_tpu.oracle.selector_index import SelectorIndex
 from kubernetes_tpu import obs
 
 # mirror-maintenance counters: how often the host mirror pays a per-row
@@ -60,10 +61,11 @@ SPREAD_COUNT_ENCODES = obs.counter(
     "whole columnar pod table and sums the matches by holder node.")
 SELECTOR_WALK_SERVICES = obs.counter(
     "tpu_selector_walk_services_total",
-    "Services and ReplicaSets get_selectors tested against a pod for an "
-    "encode (PodEncoder._encode_scores: a linear walk over every one of "
-    "the cluster, as upstream's GetPodServices is). One increment a walk, "
-    "by the number tested.")
+    "Services and ReplicaSets tested against a pod for an encode "
+    "(PodEncoder._encode_scores): the candidates the selector index found "
+    "under the pod's own labels, each tested in full, where a walk tested "
+    "every one of the cluster. One increment a lookup, by the number "
+    "tested.")
 VICTIM_ROW_RESORTS = obs.counter(
     "tpu_victim_table_row_resorts_total",
     "Victim-table node rows re-sorted (generation moved or the PDB set "
@@ -976,7 +978,8 @@ class PodEncoder:
                  hard_pod_affinity_weight: int = 1,
                  enabled: Optional[set] = None,
                  volume_listers=None, volume_binder=None,
-                 state_encoder: Optional[NodeStateEncoder] = None):
+                 state_encoder: Optional[NodeStateEncoder] = None,
+                 selector_index: Optional[SelectorIndex] = None):
         self.node_infos = node_infos
         self.batch = batch
         # predicate names enabled by the provider/policy; None = all
@@ -985,6 +988,10 @@ class PodEncoder:
         self.volume_binder = volume_binder
         self.services = services or []
         self.replicasets = replicasets or []
+        # `get_selectors` by lookup: the index the lists' owner keeps, or
+        # one built from the two lists here
+        self.selector_index = selector_index if selector_index is not None \
+            else SelectorIndex(self.services, self.replicasets)
         self.total_num_nodes = total_num_nodes or max(1, batch.n_real)
         self.hard_weight = hard_pod_affinity_weight
         # columnar pod table: generation-cached when the scheduler's
@@ -1222,8 +1229,8 @@ class PodEncoder:
                 if not tolerations_tolerate_taint(tols, taint):
                     np.add.at(counts, rows, 1)
             f.taint_counts = counts
-        selectors = get_selectors(pod, self.services, self.replicasets)
-        SELECTOR_WALK_SERVICES.inc(len(self.services) + len(self.replicasets))
+        selectors, tested = self.selector_index.select(pod)
+        SELECTOR_WALK_SERVICES.inc(tested)
         if selectors:
             # selector-spread counting (selector_spreading.go:66): one
             # vectorized selector-match over the columnar pod table plus a

@@ -229,7 +229,12 @@ ZONE_WEIGHTING = 2.0 / 3.0
 def get_selectors(pod: Pod, services: list[Service],
                   replicasets: list[ReplicaSet]) -> list:
     """Selectors of services / RC / RS / STS that select this pod
-    (reference: selector_spreading.go getSelectors)."""
+    (reference: selector_spreading.go getSelectors): a walk over both
+    lists, every member tested. This is the definition. The serial oracle's
+    SelectorSpread calls it (`generic_scheduler.default_priority_configs`,
+    `factory`); the burst path gets the same list, member for member and
+    in this order, from `selector_index.SelectorIndex`, which
+    `tests/test_selector_index.py` holds to this function."""
     selectors = []
     for svc in services:
         if svc.namespace != pod.namespace or not svc.selector:

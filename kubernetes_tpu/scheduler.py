@@ -34,8 +34,8 @@ from kubernetes_tpu.oracle.gang import GangTrial
 from kubernetes_tpu.oracle.generic_scheduler import (
     GenericScheduler, FitError, ScheduleResult, default_priority_configs,
 )
-from kubernetes_tpu.oracle.priorities import (
-    get_selectors, spread_group_key)
+from kubernetes_tpu.oracle.priorities import spread_group_key
+from kubernetes_tpu.oracle.selector_index import LiveSelectorIndex
 from kubernetes_tpu.queue.scheduling_queue import PriorityQueue
 from kubernetes_tpu.store.store import (
     Store, PODS, NODES, PODGROUPS, SERVICES, REPLICASETS, PDBS, PVS, PVCS,
@@ -287,6 +287,10 @@ class Scheduler:
         replicasets = self.informers.informer(REPLICASETS)
         self._services_fn = services.list
         self._replicasets_fn = replicasets.list
+        # the burst path's way to `get_selectors`' answer: an index over
+        # the two caches, rebuilt when either informer's change count has
+        # moved; `_burst_class` and the algorithm's encodes both ask it
+        self._selector_index_fn = LiveSelectorIndex(services, replicasets)
         # volume-aware scheduling (volumebinder bridge)
         self.volume_listers = VolumeListers(
             pvcs_fn=self.informers.informer(PVCS).list,
@@ -333,6 +337,7 @@ class Scheduler:
                 hard_pod_affinity_weight=hard_pod_affinity_weight,
                 services_fn=self._services_fn,
                 replicasets_fn=self._replicasets_fn,
+                selector_index_fn=self._selector_index_fn,
                 nominated=self.queue.nominated,
                 volume_listers=self.volume_listers,
                 volume_binder=self.volume_binder,
@@ -1122,8 +1127,7 @@ class Scheduler:
                 and not self.framework.permit
                 and not self.framework.prebind)
 
-    def _burst_class(self, pod: Pod, sig: tuple, services,
-                     replicasets) -> tuple:
+    def _burst_class(self, pod: Pod, sig: tuple, index) -> tuple:
         """Segmentation key, as (class, spread group). Pods whose per-node
         masks depend on in-burst placements (affinity terms, host ports)
         burst only with spec-identical peers (the kernels' eligibility
@@ -1133,12 +1137,14 @@ class Scheduler:
         group, and `group` (`spread_group_key`; None for every other class)
         is what `_schedule_singletons_burst` counts against the
         algorithm's cap. Plain pods share one generic segment even when
-        heterogeneous. Every input it reads (namespace, labels, affinity,
-        containers) is in `sig`, so `_burst_classes`, its one caller, asks
-        once per distinct signature and pass."""
+        heterogeneous. Which selectors select the pod is asked of `index`
+        (`get_selectors`' answer by lookup). Every input it reads
+        (namespace, labels, affinity, containers) is in `sig`, so
+        `_burst_classes`, its one caller, asks once per distinct signature
+        and pass."""
         if has_pod_affinity_terms(pod) or get_container_ports(pod):
             return sig, None
-        selectors = get_selectors(pod, services, replicasets)
+        selectors, _tested = index.select(pod)
         if selectors:
             return _SPREAD, spread_group_key(pod.namespace, selectors)
         return _PLAIN, None
@@ -1146,14 +1152,16 @@ class Scheduler:
     def _burst_classes(self, pods: list) -> list:
         """THE place a pod's burst class is decided: one `_burst_class`
         evaluation on the first pod of each distinct class signature in
-        `pods`, against one snapshot of the Service / ReplicaSet lists;
-        every other pod takes the (class, spread group) of its signature.
-        The cost follows the number of distinct signatures, not pods x
-        Services. Equal classes are the SAME object (the interned
-        signature, `_SPREAD` or `_PLAIN`), so segmentation compares by
-        identity. The decision lives for the
-        call: a Service created between two drain passes reclassifies the
-        next pass's pods, and nothing has to be invalidated."""
+        `pods`, against the selector index of the moment; every other pod
+        takes the (class, spread group) of its signature. The cost follows
+        the number of distinct signatures, not pods x Services. Equal
+        classes are the SAME object (the interned signature, `_SPREAD` or
+        `_PLAIN`), so segmentation compares by identity. The decision
+        lives for the call, and the index for as long as the Service and
+        ReplicaSet informers' change counts stand still
+        (`LiveSelectorIndex`): a Service created between two drain passes
+        moves the count, the next pass asks a rebuilt index, and its pods
+        are reclassified."""
         if self.pod_rows is not None:
             sigs = self.pod_rows.signatures(pods)
         else:
@@ -1161,8 +1169,7 @@ class Scheduler:
             # algorithm), and must not pull jax in through this module
             from kubernetes_tpu.core.tpu_scheduler import TPUScheduler
             sigs = TPUScheduler.class_signatures(pods)
-        services = self._services_fn()
-        replicasets = self._replicasets_fn()
+        index = self._selector_index_fn()
         by_sig: dict = {}
         classes = []
         last_sig = last_cls = None
@@ -1173,8 +1180,7 @@ class Scheduler:
             if sig is not last_sig:
                 cls = by_sig.get(sig)
                 if cls is None:
-                    cls = by_sig[sig] = self._burst_class(
-                        pod, sig, services, replicasets)
+                    cls = by_sig[sig] = self._burst_class(pod, sig, index)
                 last_sig, last_cls = sig, cls
             classes.append(last_cls)
         BURST_CLASS.labels("decided").inc(len(by_sig))

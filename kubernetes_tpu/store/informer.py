@@ -181,6 +181,10 @@ class SharedInformer:
         self.kind = kind
         self._handlers: list[ResourceEventHandler] = []
         self._cache: dict[str, Any] = {}
+        # how often the cache has changed (a batch of events folded in, a
+        # relist's replacement), counted under the lock: what is derived
+        # from `list()` is good for as long as this has not moved
+        self.changes = 0
         self._watch: Optional[Watch] = None
         self._synced = False
         self._thread: Optional[threading.Thread] = None
@@ -223,6 +227,11 @@ class SharedInformer:
     def list(self) -> list[Any]:
         with self._lock:
             return list(self._cache.values())
+
+    def versioned_list(self) -> tuple[int, list[Any]]:
+        """`list()` and the change count it was read at, as one read."""
+        with self._lock:
+            return self.changes, list(self._cache.values())
 
     def get(self, key: str) -> Optional[Any]:
         with self._lock:
@@ -298,6 +307,7 @@ class SharedInformer:
         with self._lock:
             old_cache = self._cache
             self._cache = new
+            self.changes += 1
         for key, obj in new.items():
             old = old_cache.get(key)
             if old is None:
@@ -401,6 +411,7 @@ class SharedInformer:
         prepared = []
         with self._lock:
             cache = self._cache
+            self.changes += 1
             for ev in evs:
                 old = None
                 if ev.type in (ADDED, MODIFIED):

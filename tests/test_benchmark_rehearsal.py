@@ -266,14 +266,18 @@ def test_rehearsed_cell(monkeypatch, execute, cell, seed, hook, program):
         assert sum(steps.values()) == pods
         assert counter_metric("spread_grouped_steps_per_pod.arrivals",
                               res, rep) == steps["grouped"] / pods
-        # the deployment's two counters: one count pass and one walk over
-        # all 80 Services a group a segment
+        # the deployment's two counters: one count pass and one lookup in
+        # the selector index a group a segment; a lookup tests the one
+        # Service filed under the pod's label, not all 80
         groups = moved["tpu_scan_spread_groups_total"][""]
         assert groups == moved["tpu_spread_count_encodes_total"][""] > 0
         assert counter_metric("spread_groups_per_pod.arrivals",
                               res, rep) == groups / pods
         assert counter_metric("selector_services_tested_per_pod.arrivals",
-                              res, rep) == 80 * groups / pods
+                              res, rep) == groups / pods
+        # no Service moves in the window: the warm-up's index serves it
+        assert "tpu_selector_index_builds_total" not in moved
+        assert counter_metric("selector_index_builds.arrivals", res, rep) == 0
         # the pods differ in their Service alone: a launch builds a row a
         # group and the pad's, and gathers them to the drain's bucket
         launches = moved["tpu_device_dispatch_total"]["burst_scan"]

@@ -6,7 +6,6 @@ import random
 
 import pytest
 
-from kubernetes_tpu import scheduler as scheduler_mod
 from kubernetes_tpu.api.types import (
     Affinity, Container, ContainerPort, LabelSelector, Node, Pod,
     PodAffinityTerm, PodAntiAffinity, ReplicaSet, Service, VolumeSource,
@@ -17,6 +16,7 @@ from kubernetes_tpu.coscheduling.types import LABEL_POD_GROUP
 from kubernetes_tpu.ops.pod_rows import pod_class_signature
 from kubernetes_tpu.oracle.priorities import (
     get_selectors, spread_group_key)
+from kubernetes_tpu.oracle.selector_index import SelectorIndex
 from kubernetes_tpu.scheduler import BURST_CLASS, Scheduler
 from kubernetes_tpu.store.store import (
     Store, PODS, NODES, REPLICASETS, SERVICES,
@@ -549,19 +549,22 @@ class TestBurstClassDecision:
     @pytest.mark.parametrize("rows", [True, False],
                              ids=["row-cache", "no-row-cache"])
     def test_selectors_walked_once_per_signature(self, monkeypatch, rows):
-        """50 Services, 64 spec-identical pods of one of them: the Service
-        list is walked once in the pass, not 129 times; and the counter
-        books that one decision and the 63 pods that shared it."""
+        """50 Services, 64 spec-identical pods of one of them: the selector
+        index is asked once in the pass, not 129 times, and tests the one
+        Service filed under the pod's label, not 50; and the counter books
+        that one decision and the 63 pods that shared it."""
         store, sched = self._cluster(
             services=[f"s{k}" for k in range(50)], replicaset=False,
             rows=rows)
         calls = []
+        select = SelectorIndex.select
 
-        def counting(pod, services, replicasets):
-            calls.append(pod.name)
-            return get_selectors(pod, services, replicasets)
+        def counting(index, pod):
+            selectors, tested = select(index, pod)
+            calls.append((pod.name, selectors, tested))
+            return selectors, tested
 
-        monkeypatch.setattr(scheduler_mod, "get_selectors", counting)
+        monkeypatch.setattr(SelectorIndex, "select", counting)
         for j in range(64):
             store.create(PODS, mkpod(f"p{j:02d}", labels={"app": "s7"}))
         sched.pump()
@@ -569,7 +572,7 @@ class TestBurstClassDecision:
         shared0 = BURST_CLASS.labels("shared").value
         cuts = self._record_cuts(sched)
         sched._burst_pass_planned(64)
-        assert len(calls) == 1
+        assert calls == [("p00", [{"app": "s7"}], 1)]
         assert [len(seg) for _how, seg in cuts] == [64]
         assert BURST_CLASS.labels("decided").value - decided0 == 1
         assert BURST_CLASS.labels("shared").value - shared0 == 63

@@ -34,6 +34,7 @@ from kubernetes_tpu.core.tpu_scheduler import (
 from kubernetes_tpu.ops import kernels as K
 from kubernetes_tpu.ops.node_state import (
     POD_TABLE_ROWS, SELECTOR_WALK_SERVICES, SPREAD_COUNT_ENCODES)
+from kubernetes_tpu.oracle.selector_index import SELECTOR_INDEX_BUILDS
 from kubernetes_tpu.scheduler import SEGMENT_CUTS, Scheduler
 from kubernetes_tpu.serve import ServeLoop
 from kubernetes_tpu.store.store import PODS
@@ -104,6 +105,7 @@ def counters() -> dict:
     out["groups"] = SCAN_SPREAD_GROUPS.value
     out["encodes"] = SPREAD_COUNT_ENCODES.value
     out["tested"] = SELECTOR_WALK_SERVICES.value
+    out["builds"] = SELECTOR_INDEX_BUILDS.value
     out["fallbacks"] = sum(c.value
                            for c in ORACLE_FALLBACKS._children.values())
     return out
@@ -201,10 +203,15 @@ def test_serve_windows_bind_as_the_serial_oracle(seed, case):
     assert moved[("steps", "grouped")] == pods - single > 0
     # (a window's last segment may hold one group in any case)
     assert single > 0 or case != "few"
-    # one count pass and one walk over every Service a group a segment
-    # (a truncated walk never tries the K-batch class first)
+    # one count pass and one lookup a group a segment (a truncated walk
+    # never tries the K-batch class first); a lookup tests the candidates
+    # filed under the pod's label, which is the one Service that selects
+    # it, not the world's 48
     assert moved["groups"] == groups == moved["encodes"]
-    assert moved["tested"] == 48 * moved["encodes"]
+    assert moved["tested"] == moved["encodes"]
+    # no Service moves over the script: the shell's index is built once,
+    # at the first window, and both its callers ask that one
+    assert moved["builds"] == 1 and want["moved"]["builds"] == 0
     # behind a serve loop a grouped launch carries the cap's rows whatever
     # it holds, so the loop runs two scan programs and no third
     assert len(got["shapes"]) == segs
