@@ -67,10 +67,6 @@ DEVICE_FETCHES = obs.counter(
     "Device-to-host fetch synchronizations, by op — each one is a full "
     "dispatch+readback round trip, so per-launch fetch counts are "
     "load-bearing (one per wave/launch, never per pod).", ("op",))
-PIPELINE_OVERLAP = obs.counter(
-    "tpu_pipeline_overlap_seconds_total",
-    "Seconds of host commit work performed while a later burst wave was "
-    "in flight on the device (the pipelined-wave overlap win).")
 BURST_WAVES = obs.counter(
     "tpu_burst_waves_total",
     "Burst commit waves, by path — since round 10 a wave is a commit "
@@ -390,9 +386,6 @@ class TPUScheduler:
         self._false = np.bool_(False)
         self._zero_i64 = np.int64(0)
         self._zero_scalars: dict[int, np.ndarray] = {}
-        # single-worker readback executor for the pipelined burst waves
-        # (lazy: serial-only configurations never start the thread)
-        self._fetch_pool = None
         # zero ghost-load vectors by n_pad (device arrays are immutable, so
         # every pressure launch can share one set instead of re-creating
         # four jnp.zeros per wave)
@@ -407,11 +400,12 @@ class TPUScheduler:
         # (None = no exact per-window counters on this path)
         self.commit_marker: Optional[dict] = None
         # rotation-row cache (round 17): order_for_start(rr) -> axis-index
-        # row, keyed per NodeBatch OBJECT (a rebuild/permute makes a fresh
-        # batch, invalidating by identity). A serving loop cuts hundreds
-        # of small windows per second against a stable tree; without this
-        # every window re-extracts each distinct enumeration order as an
-        # O(N) python walk — the encode prologue's top cost at 1k nodes.
+        # row, keyed on the NodeBatch's serial (a rebuild/permute makes a
+        # batch with the next serial, which drops the rows). A serving
+        # loop cuts hundreds of small windows per second against a stable
+        # tree; without this every window re-extracts each distinct
+        # enumeration order as an O(N) python walk — the encode prologue's
+        # top cost at 1k nodes.
         self._rot_rows: dict[int, np.ndarray] = {}
         self._rot_rows_b: Optional[int] = None
 
@@ -487,7 +481,7 @@ class TPUScheduler:
         """Device node matrix, kept resident across cycles; only rows the
         encoder marked generation-dirty are re-uploaded. In mesh mode the
         node axis is split across the chips at upload time."""
-        key = (b.n_pad, len(b.scalar_names), id(b))
+        key = (b.n_pad, len(b.scalar_names), b.serial)
         if self._dev_nodes is None or self._dev_key != key or b.dirty_rows is None:
             with obs_trace.span("burst.upload", cat="device", rows=b.n_pad):
                 host = {k: np.asarray(getattr(b, k))
@@ -1115,14 +1109,14 @@ class TPUScheduler:
                     kind: str):
         """Padded axis-index row for the enumeration starting at zone
         index `rr`, or None when it equals the identity (axis) order —
-        cached per NodeBatch object (`kind` keys the two pad layouts:
+        cached per NodeBatch serial (`kind` keys the two pad layouts:
         "u" pads with the n_pad scratch row, "g" with the invalid-row
         tail). The tree's orders are a function of its membership, and
-        membership changes always rebuild/permute the batch (a fresh
-        object), so identity-keyed invalidation is exact."""
-        if self._rot_rows_b != id(b):
+        membership changes always rebuild/permute the batch (the next
+        serial), so serial-keyed invalidation is exact."""
+        if self._rot_rows_b != b.serial:
             self._rot_rows = {}
-            self._rot_rows_b = id(b)
+            self._rot_rows_b = b.serial
         key = (kind, rr)
         got = self._rot_rows.get(key, _ROT_MISS)
         if got is not _ROT_MISS:
@@ -1145,9 +1139,9 @@ class TPUScheduler:
     def _rot_identity(self, b: NodeBatch, kind: str) -> np.ndarray:
         """The axis-order (identity) permutation row, cached with the
         per-order rows."""
-        if self._rot_rows_b != id(b):
+        if self._rot_rows_b != b.serial:
             self._rot_rows = {}
-            self._rot_rows_b = id(b)
+            self._rot_rows_b = b.serial
         key = ("id", kind)
         row = self._rot_rows.get(key)
         if row is None:
@@ -1306,70 +1300,68 @@ class TPUScheduler:
         sp.end(orders=int(positions.shape[0]))
         return up
 
-    # -- fused bursts, wave-windowed commit ----------------------------------
-    # Round 10 moved the wave chain INTO the kernel: a burst is ONE
-    # dispatch and ONE packed fetch (the round-7 pipeline paid one
-    # dispatch+fetch round trip per wave), and `wave_size` now sizes the
-    # COMMIT windows the host consumes out of the single fetched block
-    # (bounded store/event batches, same failure granularity as the
-    # pipelined rounds). Bursts above B_CAP chunk at the kernel cap; chunk
-    # k+1's device execution still overlaps chunk k's fetch+commit (the
-    # old pipeline, one level up). The value was sized against a round
-    # trip much longer than the kernel; that premise is unmeasured on the
-    # local chip.
+    # -- bursts: one launch, wave-windowed commit -----------------------------
+    # A launch is ONE dispatch and ONE packed fetch (`_launch`), and the
+    # host consumes the fetched block in `wave_size` COMMIT windows
+    # (bounded store/event batches; a short commit stops consumption at
+    # that window). A uniform burst above its cap runs as chunks, one
+    # launch after the other: a chunk's decisions land before the next
+    # chunk is dispatched.
     wave_size = 4096
-    # the shell passes a per-wave commit callback when the algorithm
-    # advertises this (Scheduler._burst_segment)
-    supports_wave_commit = True
-    # ... and lets a segment hold pods of this many selector groups
+    # ... and a segment may hold pods of this many selector groups
     # (Scheduler._schedule_singletons_burst; _spread_carry)
     spread_group_cap = K.SPREAD_GROUP_CAP
-    # -- N-deep launch queue (round 16) --------------------------------------
-    # The round-7 pipeline kept ONE chunk in flight ahead of the chunk
-    # being committed (2-deep). Serving at arrival rate needs the
-    # dispatch+fetch round trip hidden ACROSS windows, not just inside one
-    # burst (a premise unmeasured on the local chip): launch_depth
-    # is the number of launch windows planned+encoded+dispatched at once
-    # (2 = the historical behavior), and launch_cap (None = B_CAP) caps
-    # the chunk size so a serve window IS a launch chunk — while window k
-    # commits, windows k+1..k+depth-1 are already on the device. Each
-    # window stays ONE dispatch + ONE packed fetch (TestDeviceFetchContract
-    # pins it at depth >= 3), and the rewind contract extends unchanged: a
-    # refused/failed/aborted window cancels its in-flight successors
-    # UNFETCHED and replans from the packed-block boundaries.
-    launch_depth = 2
+    # cap on a uniform launch's chunk (None = B_CAP): the serve loop pins
+    # it to its window size, so a window is one launch of one compiled
+    # shape
     launch_cap: Optional[int] = None
-    # live launch-queue occupancy (windows dispatched, not yet consumed) —
-    # the serving backpressure gate's inflight_fn reads it lock-free
-    inflight_launches = 0
     # encode-at-admission pod-row cache (ops.pod_rows.PodRowCache),
     # attached by the scheduler shell: window planning gathers prebuilt
     # per-pod rows/signatures instead of re-encoding at line rate. None =
     # the pre-round-17 per-window encode (identical decisions either way)
     pod_rows = None
 
-    def _fetch_pool_get(self):
-        pool = self._fetch_pool
-        if pool is None:
-            from concurrent.futures import ThreadPoolExecutor
-            # two workers = the pipeline's in-flight window: wave k+1's
-            # readback can start while wave k's is still in progress
-            # (per-wave results are consumed strictly in wave order via
-            # their own futures, so completion order doesn't matter). The
-            # pool was built to overlap long round trips; whether a local
-            # chip needs it is unmeasured.
-            pool = self._fetch_pool = ThreadPoolExecutor(
-                max_workers=2, thread_name_prefix="tpu-fetch")
-        return pool
+    def _launch(self, op: str, ph: _BurstPhases, fl, dispatch,
+                **span_args) -> tuple:
+        """One launch of a burst driver: ONE dispatch and ONE packed fetch.
+        `dispatch()` calls the kernel, books what only its driver counts and
+        returns (what the driver keeps of the call, the packed block on the
+        device). Returns (that, the block on the host). Nothing of the
+        launch has reached the walk counters or a commit when a chaos seam
+        raises here: the caller decides what stands (`_refuse_launch`)."""
+        ph.open("kernel")
+        t_d = obs_trace.now()
+        chaos.check("device.dispatch")
+        kept, packed = dispatch()
+        DEVICE_DISPATCH.labels(op).inc()
+        ph.close()   # the dispatch is async; the fetch waits
+        ph.open("fetch")
+        chaos.node_dead_point("dispatch-fetch")
+        chaos.check("device.fetch")
+        h = np.asarray(jax.device_get(packed))
+        chaos.node_dead_point("fetch-commit")
+        t_done = obs_trace.now()
+        DEVICE_FETCHES.labels(op).inc()
+        DEVICE_FETCHED_BYTES.labels(op).inc(h.nbytes)
+        # the launch from its dispatch to its block on the host, in the
+        # ring alone (the profiler's trace has the device's own lines)
+        obs_trace.add_span("burst.wave.device", t_d, t_done, cat="device",
+                           args=span_args or None)
+        obs_flight.RECORDER.note_block(fl, h)
+        ph.close()
+        return kept, h
 
-    def _submit_fetch(self, tree):
-        """Start the device->host readback of `tree` in the background:
-        kick the async copy, then hand the blocking sync to a fetch worker
-        so the main thread stays free to commit the previous wave. An
-        error from the copy is a device error and propagates."""
-        for leaf in jax.tree_util.tree_leaves(tree):
-            leaf.copy_to_host_async()
-        return self._fetch_pool_get().submit(jax.device_get, tree)
+    def _refuse_launch(self, exc: BaseException, fl, outcome: dict) -> None:
+        """A launch lost to an injected device fault decides nothing: book
+        the fault, drop the resident folds (the host mirror is
+        authoritative again) and close the flight record with `outcome`,
+        an aborted one. What the launch's pods fall back to is the
+        caller's: a None return sends them to the shell's serial path,
+        decisions identical."""
+        self._device_fault(exc)
+        self.discard_burst_folds()
+        ORACLE_FALLBACKS.labels("device-fault").inc()
+        obs_flight.RECORDER.note_outcome(fl, outcome)
 
     def schedule_burst(self, pods: list[Pod], node_infos: dict[str, NodeInfo],
                        all_node_names: list[str],
@@ -1391,11 +1383,11 @@ class TPUScheduler:
         over the bucket.
 
         `commit(lo, hosts) -> bool` (optional) is the wave-window sink:
-        since round 10 the whole burst is ONE dispatch and ONE packed
-        fetch, and `commit` is called with consecutive `wave_size` windows
-        of DECIDED hosts (never None) consumed out of that single fetched
-        block (bursts above B_CAP chunk, and a later chunk's device time
-        still overlaps the earlier chunk's commit). Returning False
+        the whole burst is ONE dispatch and ONE packed fetch, and `commit`
+        is called with consecutive `wave_size` windows of DECIDED hosts
+        (never None) consumed out of that single fetched block (a uniform
+        burst above its cap is several such launches, one after the
+        other). Returning False
         signals a commit failure — the algorithm stops consuming the
         block, discards the undelivered decisions and the device folds
         (the host mirror is authoritative again), rewinds the walk
@@ -1608,22 +1600,22 @@ class TPUScheduler:
                        ban: bool, rotation, n: int, commit,
                        ph: _BurstPhases, bucket: int,
                        fl=None, pid: int = 0) -> Optional[list]:
-        """Single-launch driver for the uniform kernel: the ENTIRE burst
-        (up to B_CAP; larger bursts chunk, with chunk k's fetch+commit
-        overlapping chunk k+1's device execution) is ONE dispatch and ONE
-        packed [cap+1] fetch, which the commit then consumes wave-by-wave
-        (`wave_size` windows — the same bounded store/event batches the
-        pipelined rounds used). Returns the decided selection prefix
-        (device axis indices, all >= 0); the caller pads the undecided
-        tail with None.
+        """Driver for the uniform kernel: the burst is ONE launch (one
+        dispatch, one packed [cap+1] fetch) up to its cap, and above it a
+        chunk a launch, one after the other; the commit consumes each
+        fetched block wave-by-wave (`wave_size` windows: bounded
+        store/event batches). Returns the decided selection prefix (device
+        axis indices, all >= 0); the caller pads the undecided tail with
+        None.
 
-        Rewind contract, re-derived from the single fetched block: the
-        uniform kernel's failures are a frozen-state suffix (F==0
-        persists for identical pods), so the decided prefix is exactly
-        the block's leading non-negative run. A commit failure (callback
-        returned False) stops consumption — the rest of the block is
-        discarded along with the resident folds, and the returned prefix
-        ends at the last window handed to the callback."""
+        Rewind contract, read off a launch's one block: the uniform
+        kernel's failures are a frozen-state suffix (F==0 persists for
+        identical pods), so the decided prefix is exactly the block's
+        leading non-negative run. A commit failure (callback returned
+        False) stops consumption — the rest of the block is discarded
+        along with the resident folds, and the returned prefix ends at the
+        last window handed to the callback. Chunks that landed before a
+        failure, an abort or a fault stand."""
         # the launch cap IS the caller's burst bucket (clamped to B_CAP,
         # and to launch_cap when the serve loop pinned window-sized
         # chunks): the warmup burst rides the same bucket, so the one
@@ -1634,173 +1626,111 @@ class TPUScheduler:
         cap = _pad_pow2(max(1, min(bucket, hard)), 16)
         W = max(1, min(int(self.wave_size), cap))
         n_pods = len(pods)
-        chunks = [(lo, min(cap, n_pods - lo))
-                  for lo in range(0, n_pods, cap)]
-        lni_dev = self.last_node_index   # device scalar after chunk 0
         li_entry, lni_entry = self.last_index, self.last_node_index
+        tensor = self._ptab is not None
         sel: list[int] = []
-        inflight: list[tuple] = []
 
-        def dispatch(ci: int) -> None:
-            nonlocal lni_dev
-            ph.open("kernel")
-            lo, chunk = chunks[ci]
-            rot = rotation
-            if rotation is not None:
-                win = np.empty(cap + K.K_BATCH, dtype=np.int32)
-                piece = rotation[1][lo: lo + len(win)]
-                win[: len(piece)] = piece
-                win[len(piece):] = piece[-1] if len(piece) else 0
-                rot = (rotation[0], win)
-            t_d = obs_trace.now()
-            chaos.check("device.dispatch")
-            tensor = self._ptab is not None
-            rows, packed, lni_out = K.schedule_batch_uniform(
-                self._dev_nodes, dict(cls), chunk, lni_dev, n,
-                self.check_resources,
-                weights=self._union_weights if tensor else self.weights,
-                rotation=rot, extra_ok=extra_ok, ban=ban, mesh=self.mesh,
-                cap=cap, wtab=self._wtab() if tensor else None, pid=pid)
-            lni_dev = lni_out
-            self._dev_nodes = {**self._dev_nodes, **rows}
-            DEVICE_DISPATCH.labels("burst_uniform").inc()
-            WALK_NODES.labels("full").inc(chunk * n)
-            ph.close()   # dispatch (async; fetch waits)
-            inflight.append((ci, lo, chunk, self._submit_fetch(packed),
-                             t_d))
-            self.inflight_launches = len(inflight)
-
-        aborted = False
-        failed = False
-        faulted = False
-        depth = max(1, int(self.launch_depth))
-        next_ci = 1
-        try:
-            dispatch(0)
-            while inflight:
-                # N-deep launch queue: keep up to `depth` windows
-                # planned/encoded/dispatched while the oldest commits
-                # (depth=2 is the historical one-ahead pipeline)
-                while len(inflight) < depth and next_ci < len(chunks):
-                    dispatch(next_ci)
-                    next_ci += 1
-                ci, lo, chunk, fut, t_d = inflight.pop(0)
-                self.inflight_launches = len(inflight)
-                ph.open("fetch")
-                chaos.node_dead_point("dispatch-fetch")
-                chaos.check("device.fetch")
-                h = fut.result()  # ONE fetch per launch: selections + lni
-                chaos.node_dead_point("fetch-commit")
-                t_done = obs_trace.now()
-                DEVICE_FETCHES.labels("burst_uniform").inc()
-                DEVICE_FETCHED_BYTES.labels("burst_uniform").inc(h.nbytes)
-                # the launch in flight, from its dispatch to its block on
-                # the host: it overlaps the successor's dispatch and the
-                # predecessor's commit, so it is no scoped region and
-                # lives in the ring alone (the profiler's trace has the
-                # device's own lines for it)
-                obs_trace.add_span("burst.wave.device", t_d, t_done,
-                                   cat="device", args={"chunk": ci})
-                obs_flight.RECORDER.note_block(fl, h)
-                ph.close()
-                chunk_sel = h[:chunk].tolist()
-                bad = next((i for i, s in enumerate(chunk_sel) if s < 0),
-                           chunk)
-                if commit is not None and self.stale_scan is not None:
-                    # mid-burst node death: none of THIS chunk's decisions
-                    # have committed and its lni advance is not yet
-                    # applied, so earlier (already-committed) chunks stand
-                    # and this chunk refuses whole — the shell invalidates
-                    # the dead rows and replans the remainder post-churn
-                    decided = [b.names[s] for s in chunk_sel[:bad]]
-                    dead = self.stale_scan(decided, b.names[:n])
-                    if dead:
-                        for item in inflight:
-                            item[3].cancel()
-                        inflight.clear()
-                        self.discard_burst_folds()
-                        obs_flight.RECORDER.note_outcome(fl, {
-                            "hosts": [b.names[s] for s in sel],
-                            "failed": False, "aborted": True})
-                        raise StaleNodeRefusal(
-                            dead,
-                            max(1, sum(1 for hn in decided if hn in dead)))
-                lni_chunk_start = self.last_node_index
-                self.last_node_index += int(h[cap])
-                # commit consumes the single fetched block wave-by-wave
-                for wlo in range(0, bad, W):
-                    hi = min(wlo + W, bad)
-                    BURST_WAVES.labels("uniform").inc()
-                    sel.extend(chunk_sel[wlo:hi])
-                    if commit is not None:
-                        # crash-restart checkpoint marker (the shell's
-                        # recovery context source): exact walk counters at
-                        # this window's two boundaries where the block
-                        # carries them. The uniform kernel never advances
-                        # last_index, and the packed block only holds the
-                        # CHUNK's lni advance — so mid-chunk window
-                        # boundaries have no exact lni (None; recovery
-                        # degrades to reconcile-only there).
-                        self.commit_marker = {
-                            "li0": li_entry,
-                            "lni0": (lni_chunk_start if wlo == 0 else None),
-                            "li1": li_entry,
-                            "lni1": (self.last_node_index if hi == chunk
-                                     else None),
-                            "committed0": lo + wlo, "committed1": lo + hi,
-                        }
-                        with obs_trace.span("burst.wave.commit",
-                                            chunk=ci) as sp:
-                            ok = commit(
-                                lo + wlo,
-                                [b.names[s] for s in chunk_sel[wlo:hi]])
-                        if inflight:
-                            PIPELINE_OVERLAP.inc(sp.t1 - sp.t0)
-                        if not ok:
-                            aborted = True
-                            break
-                if bad < chunk or aborted:
-                    for item in inflight:
-                        item[3].cancel()
-                    inflight.clear()
-                    if aborted:
-                        self.discard_burst_folds()
-                    if bad < chunk:
-                        failed = True
-                    break
-        except _DEVICE_FAULTS as e:
-            # a failed launch/fetch: everything already committed stands
-            # (its counters landed with its chunk); the faulted chunk
-            # decided nothing, so the remainder of the burst degrades to
-            # the serial oracle path via the undecided-tail contract
-            self._device_fault(e)
-            ORACLE_FALLBACKS.labels("device-fault").inc()
-            for item in inflight:
-                item[3].cancel()
-            inflight.clear()
-            self.discard_burst_folds()
-            faulted = True
-            if commit is None:
-                # pure trial (gang): nothing was committed — rewind the
-                # walk counters consumed by already-fetched chunks and
-                # refuse outright, so the caller reruns the WHOLE trial
-                # through the serial referee instead of misreading the
-                # undecided tail as a rejected gang
-                self.last_index, self.last_node_index = li_entry, lni_entry
-                obs_flight.RECORDER.note_outcome(fl, {
-                    "hosts": [], "failed": False, "aborted": True})
-                return None
-        finally:
-            self.inflight_launches = 0
-        if not (failed or aborted or faulted):
-            self.breaker.record_success()
-        obs_flight.RECORDER.note_outcome(fl, {
+        def outcome(**flags) -> dict:
             # device-decided hosts up to the last commit/abort boundary;
             # `failed` marks that the NEXT pod found no node on device
-            "hosts": [b.names[s] for s in sel],
-            "failed": failed,
-            "aborted": aborted,
-        })
+            return {"hosts": [b.names[s] for s in sel],
+                    "failed": False, "aborted": False, **flags}
+
+        for ci, lo in enumerate(range(0, n_pods, cap)):
+            chunk = min(cap, n_pods - lo)
+
+            def dispatch():
+                rot = rotation
+                if rotation is not None:
+                    win = np.empty(cap + K.K_BATCH, dtype=np.int32)
+                    piece = rotation[1][lo: lo + len(win)]
+                    win[: len(piece)] = piece
+                    win[len(piece):] = piece[-1] if len(piece) else 0
+                    rot = (rotation[0], win)
+                rows, packed, _lni = K.schedule_batch_uniform(
+                    self._dev_nodes, dict(cls), chunk,
+                    self.last_node_index, n, self.check_resources,
+                    weights=self._union_weights if tensor else self.weights,
+                    rotation=rot, extra_ok=extra_ok, ban=ban,
+                    mesh=self.mesh, cap=cap,
+                    wtab=self._wtab() if tensor else None, pid=pid)
+                WALK_NODES.labels("full").inc(chunk * n)
+                return rows, packed
+
+            try:
+                rows, h = self._launch("burst_uniform", ph, fl, dispatch,
+                                       chunk=ci)
+            except _DEVICE_FAULTS as e:
+                # the faulted chunk decided nothing, and the rest of the
+                # burst degrades to the serial oracle path via the
+                # undecided-tail contract
+                if commit is None:
+                    # pure trial (gang): nothing was committed — rewind
+                    # the walk counters earlier chunks consumed and refuse
+                    # outright, so the caller reruns the WHOLE trial
+                    # through the serial referee instead of misreading the
+                    # undecided tail as a rejected gang
+                    self.last_index, self.last_node_index = \
+                        li_entry, lni_entry
+                    sel = []
+                self._refuse_launch(e, fl, outcome(aborted=True))
+                return None if commit is None else sel
+            self._dev_nodes = {**self._dev_nodes, **rows}
+            chunk_sel = h[:chunk].tolist()
+            bad = next((i for i, s in enumerate(chunk_sel) if s < 0), chunk)
+            if commit is not None and self.stale_scan is not None:
+                # mid-burst node death: none of THIS chunk's decisions
+                # have committed and its lni advance is not yet applied,
+                # so earlier (already-committed) chunks stand and this
+                # chunk refuses whole — the shell invalidates the dead
+                # rows and replans the remainder post-churn
+                decided = [b.names[s] for s in chunk_sel[:bad]]
+                dead = self.stale_scan(decided, b.names[:n])
+                if dead:
+                    self.discard_burst_folds()
+                    obs_flight.RECORDER.note_outcome(
+                        fl, outcome(aborted=True))
+                    raise StaleNodeRefusal(
+                        dead, max(1, sum(1 for hn in decided if hn in dead)))
+            lni_chunk_start = self.last_node_index
+            self.last_node_index += int(h[cap])
+            aborted = False
+            # commit consumes the fetched block wave-by-wave
+            for wlo in range(0, bad, W):
+                hi = min(wlo + W, bad)
+                BURST_WAVES.labels("uniform").inc()
+                sel.extend(chunk_sel[wlo:hi])
+                if commit is None:
+                    continue
+                # crash-restart checkpoint marker (the shell's recovery
+                # context source): exact walk counters at this window's
+                # two boundaries where the block carries them. The uniform
+                # kernel never advances last_index, and the packed block
+                # only holds the CHUNK's lni advance — so mid-chunk window
+                # boundaries have no exact lni (None; recovery degrades to
+                # reconcile-only there).
+                self.commit_marker = {
+                    "li0": li_entry,
+                    "lni0": (lni_chunk_start if wlo == 0 else None),
+                    "li1": li_entry,
+                    "lni1": (self.last_node_index if hi == chunk
+                             else None),
+                    "committed0": lo + wlo, "committed1": lo + hi,
+                }
+                with obs_trace.span("burst.wave.commit", chunk=ci):
+                    ok = commit(lo + wlo,
+                                [b.names[s] for s in chunk_sel[wlo:hi]])
+                if not ok:
+                    aborted = True
+                    break
+            if bad < chunk or aborted:
+                if aborted:
+                    self.discard_burst_folds()
+                obs_flight.RECORDER.note_outcome(
+                    fl, outcome(failed=bad < chunk, aborted=aborted))
+                return sel
+        self.breaker.record_success()
+        obs_flight.RECORDER.note_outcome(fl, outcome())
         return sel
 
     def _scan_waves(self, pods: list[Pod], b: NodeBatch, per_pod: list,
@@ -1848,10 +1778,8 @@ class TPUScheduler:
             group[:n_pods] = spread_groups[0]
             spread_groups = (group, spread_groups[1])
         sp.end(signatures=signatures)
-        ph.open("kernel")
-        t_d = obs_trace.now()
-        try:
-            chaos.check("device.dispatch")
+
+        def dispatch():
             state, _li_out, _lni_out, _spread, outs = K.schedule_batch(
                 self._dev_nodes, stacked, self.last_index,
                 self.last_node_index, num_to_find, n, z_pad,
@@ -1859,7 +1787,6 @@ class TPUScheduler:
                 rotation=rotation, spread0=spread0,
                 mesh=self.mesh, wtab=self._wtab() if tensor else None,
                 n_pods=n_pods, classes=classes, spread_groups=spread_groups)
-            DEVICE_DISPATCH.labels("burst_scan").inc()
             SCAN_STEPS.labels("real").inc(n_pods)
             SCAN_SPREAD_STEPS.labels(
                 "none" if spread0 is None else
@@ -1874,30 +1801,18 @@ class TPUScheduler:
                 "stacked" if signatures > 1 else "shared").inc(n_pods)
             SCAN_ORDER_STEPS.labels(
                 "axis" if rotation is None else "position").inc(n_pods)
-            ph.close()
-            ph.open("fetch")
-            chaos.node_dead_point("dispatch-fetch")
-            chaos.check("device.fetch")
-            h = np.asarray(self._submit_fetch(outs["packed"]).result())
-            chaos.node_dead_point("fetch-commit")
+            return state, outs["packed"]
+
+        try:
+            state, h = self._launch("burst_scan", ph, fl, dispatch)
         except _DEVICE_FAULTS as e:
-            # the single dispatch+fetch happens BEFORE any commit or
-            # counter update: refuse the whole burst — the shell reruns
-            # the pods serially (host twin under an open circuit) against
-            # the untouched host mirror, decisions identical
-            self._device_fault(e)
-            self.discard_burst_folds()
-            ORACLE_FALLBACKS.labels("device-fault").inc()
-            obs_flight.RECORDER.note_outcome(fl, {
-                "hosts": [], "failed": False, "aborted": True})
-            return None
+            # the launch precedes every commit and counter update: refuse
+            # the whole burst — the shell reruns the pods serially (host
+            # twin under an open circuit) against the untouched host
+            # mirror
+            return self._refuse_launch(
+                e, fl, {"hosts": [], "failed": False, "aborted": True})
         self.breaker.record_success()
-        t_done = obs_trace.now()
-        DEVICE_FETCHES.labels("burst_scan").inc()
-        DEVICE_FETCHED_BYTES.labels("burst_scan").inc(h.nbytes)
-        obs_trace.add_span("burst.wave.device", t_d, t_done, cat="device")
-        obs_flight.RECORDER.note_block(fl, h)
-        ph.close()
         sel_arr = h[:n_pods]
         li_after = h[B:2 * B]
         lni_delta = h[2 * B:3 * B]
@@ -2111,11 +2026,9 @@ class TPUScheduler:
         fl = obs_flight.RECORDER.begin("fused", self, segments,
                                        all_node_names, node_infos)
         ph.close()
-        ph.open("kernel")
-        t_d = obs_trace.now()
-        try:
-            chaos.check("device.dispatch")
-            tensor = self._ptab is not None
+        tensor = self._ptab is not None
+
+        def dispatch():
             state, _li, _lni, _spread, packed = K.schedule_batch_segments(
                 nodes, stacked, seg_start, gang, n_total, self.last_index,
                 self.last_node_index, num_to_find, n, z_pad,
@@ -2123,32 +2036,18 @@ class TPUScheduler:
                 rotation=rotation,
                 mesh=self.mesh, wtab=self._wtab() if tensor else None,
                 gang_score=self._gang_score)
-            DEVICE_DISPATCH.labels("burst_fused").inc()
-            ph.close()
-            ph.open("fetch")
-            chaos.node_dead_point("dispatch-fetch")
-            chaos.check("device.fetch")
-            h = np.asarray(self._submit_fetch(packed).result())
-            chaos.node_dead_point("fetch-commit")
+            return state, packed
+
+        try:
+            state, h = self._launch("burst_fused", ph, fl, dispatch)
         except _DEVICE_FAULTS as e:
-            # the single dispatch+fetch happens BEFORE any counter update
-            # or commit: refuse the window — the shell reruns every entry
-            # through the per-segment machinery against the untouched host
-            # mirror (which cascades to the serial loop under an open
-            # circuit), decisions identical
-            self._device_fault(e)
-            self.discard_burst_folds()
-            ORACLE_FALLBACKS.labels("device-fault").inc()
-            obs_flight.RECORDER.note_outcome(fl, {
-                "segments": [], "consumed": 0, "aborted": True})
-            return None
+            # the launch precedes every counter update and commit: refuse
+            # the window — the shell reruns every entry through the
+            # per-segment machinery against the untouched host mirror
+            # (which cascades to the serial loop under an open circuit)
+            return self._refuse_launch(
+                e, fl, {"segments": [], "consumed": 0, "aborted": True})
         self.breaker.record_success()
-        t_done = obs_trace.now()
-        DEVICE_FETCHES.labels("burst_fused").inc()
-        DEVICE_FETCHED_BYTES.labels("burst_fused").inc(h.nbytes)
-        obs_trace.add_span("burst.wave.device", t_d, t_done, cat="device")
-        obs_flight.RECORDER.note_block(fl, h)
-        ph.close()
         sel = h[:B]
         li_after = h[B:2 * B]
         lni_delta = h[2 * B:3 * B]

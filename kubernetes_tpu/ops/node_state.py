@@ -112,6 +112,10 @@ class NodeBatch:
     # rows rewritten by the latest encode(); None = full rebuild. Consumed by
     # the device mirror to upload only generation-dirty rows (SURVEY §2.4).
     dirty_rows: Optional[list] = None
+    # which batch of its encoder this is: every batch the encoder makes or
+    # permutes takes the next number, so a cache keyed on it never meets
+    # another world's rows (an address is reused once its object is freed)
+    serial: int = 0
 
 
 class NodeStateEncoder:
@@ -124,6 +128,7 @@ class NodeStateEncoder:
 
     def __init__(self):
         self._batch: Optional[NodeBatch] = None
+        self._batch_serial = 0
         self._generations: dict[str, int] = {}
         self._scalar_vocab: list[str] = []
         self._zone_vocab: list[str] = [""]
@@ -270,7 +275,9 @@ class NodeStateEncoder:
             out[:n_real] = arr[perm]
             return out
 
+        self._batch_serial += 1
         return NodeBatch(
+            serial=self._batch_serial,
             names=list(node_order),
             index={name: i for i, name in enumerate(node_order)},
             n_real=n_real, n_pad=b.n_pad,
@@ -289,7 +296,9 @@ class NodeStateEncoder:
 
     def _fresh(self, node_order: list[str], n_real: int, n_pad: int, s: int) -> NodeBatch:
         z = lambda dt=np.int64: np.zeros(n_pad, dtype=dt)
+        self._batch_serial += 1
         b = NodeBatch(
+            serial=self._batch_serial,
             names=list(node_order),
             index={name: i for i, name in enumerate(node_order)},
             n_real=n_real, n_pad=n_pad,

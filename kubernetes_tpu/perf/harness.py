@@ -365,8 +365,8 @@ def run_serve_cell(n_nodes: int = 1000, arrival_rate: float = 2000.0,
                    max_resident: Optional[int] = None) -> dict:
     """Arrival-driven serving cell: an
     ArrivalGenerator feeds pods at `arrival_rate`/s for `duration`
-    seconds while a ServeLoop (window_size=`window`, launch-queue depth
-    `depth`) cuts fused windows from the live activeQ, with a
+    seconds while a ServeLoop (window_size=`window`, `depth` windows'
+    worth of pods a step) cuts fused windows from the live activeQ, with a
     BackpressureGate shedding creates past `max_depth` (default: two
     seconds of arrivals) with 429 + Retry-After.
 

@@ -1919,13 +1919,9 @@ class Scheduler:
             return True
 
         try:
-            if getattr(self.algorithm, "supports_wave_commit", False):
-                hosts = self.algorithm.schedule_burst(
-                    pods, self._snapshot.node_infos, names, bucket=bucket,
-                    commit=commit_wave)
-            else:
-                hosts = self.algorithm.schedule_burst(
-                    pods, self._snapshot.node_infos, names, bucket=bucket)
+            hosts = self.algorithm.schedule_burst(
+                pods, self._snapshot.node_infos, names, bucket=bucket,
+                commit=commit_wave)
         except StaleNodeRefusal as e:
             # mid-burst node death (round 14): the launch's decision block
             # references vanished nodes and was refused before any of its

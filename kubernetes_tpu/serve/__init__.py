@@ -13,8 +13,8 @@ pipeline into a serving system:
   ServeLoop's decision stream bit-identical to a serial oracle observing
   the same arrivals at window boundaries).
 - `backpressure.BackpressureGate` is the explicit load-shedding contract:
-  pod creates are checked against activeQ-depth / in-flight-window
-  watermarks at the store/apiserver admission surface and shed with
+  pod creates are checked against the activeQ-depth watermark at the
+  store/apiserver admission surface and shed with
   429 + Retry-After (`store.BackpressureError`); `RemoteStore` honors the
   Retry-After with capped jittered backoff. Accepted creates stamp the
   lifecycle ledger's admission slot, so `pod_startup_seconds_p99` scores
@@ -23,11 +23,11 @@ pipeline into a serving system:
   creation at a target rate against any Store surface (embedded or
   remote), honoring 429 sheds exactly like a well-behaved client.
 
-The N-deep launch queue that hides the dispatch+fetch round trip at
-arrival rate lives in `core.tpu_scheduler` (TPUScheduler.launch_depth / launch_cap): while
-window k's decisions commit, windows k+1..k+N are already encoded and
-dispatched, and a refused/failed window discards its in-flight
-successors unfetched and replans from the packed-block boundaries.
+A window is one launch: `core.tpu_scheduler` runs each as ONE dispatch and
+ONE packed fetch (`TPUScheduler._launch`), the serve loop pins a uniform
+launch's chunk to its window size (`TPUScheduler.launch_cap`), and a
+refused or failed window decides nothing past its committed prefix: the
+shell replans from the packed-block boundaries.
 """
 from kubernetes_tpu.serve.backpressure import BackpressureGate  # noqa: F401
 from kubernetes_tpu.serve.loop import ServeLoop                 # noqa: F401

@@ -3,8 +3,8 @@
 The drain loops every bench ran before round 16 pop until the queue is
 empty and stop; a serving scheduler never stops. ServeLoop wraps a
 `Scheduler` and, per tick, pumps the informers (admission flows in over
-the store/apiserver watches WHILE the device executes) and cuts one
-launch-queue's worth of fused drain windows from the live activeQ:
+the store/apiserver watches WHILE the device executes) and cuts up to
+`depth` windows' worth of pods from the live activeQ:
 
     step():  pump -> schedule_burst(max_pods = window_size * depth)
 
@@ -14,24 +14,18 @@ window's decisions are oracle-parity by the existing contracts (the
 serve parity fuzz pins the stream against a serial oracle observing the
 same arrivals at window boundaries).
 
-Window pipelining: the loop sets the algorithm's `launch_cap` to
-`window_size` and `launch_depth` to `depth`, so a drain above one window
-chunks into window-sized launches of which up to `depth` are in flight —
-while window k's decisions commit, windows k+1..k+depth-1 are already
-encoded and dispatched, hiding the dispatch+fetch round trip at arrival
-rate rather than only inside one pre-built burst (the depth was chosen
-for a round trip much longer than a window's kernel; that premise is
-unmeasured on the local chip). Each window stays ONE
-dispatch + ONE packed fetch (TestDeviceFetchContract pins it at depth
->= 3), and the rewind contract extends unchanged: a refused or failed
-window discards its in-flight successors unfetched and replans from the
-packed-block boundaries.
+Windows and launches: the loop sets the algorithm's `launch_cap` to
+`window_size`, so a uniform drain above one window runs as window-sized
+launches, one after the other, each ONE dispatch + ONE packed fetch of
+one compiled shape (TestDeviceFetchContract pins it); `depth` multiplies
+the pop, so a step hands the shell up to `window_size * depth` pods. A
+refused or failed window decides nothing past its committed prefix and
+the shell replans from the packed-block boundaries.
 
 Backpressure closes the loop: `attach_gate` installs a
-`BackpressureGate` keyed on this loop's live activeQ depth and in-flight
-window count as the store's admission gate, so arrivals beyond what the
-device sustains are shed with 429 + Retry-After instead of eating the
-startup SLO in queue wait.
+`BackpressureGate` keyed on this loop's live activeQ depth as the store's
+admission gate, so arrivals beyond what the device sustains are shed with
+429 + Retry-After instead of eating the startup SLO in queue wait.
 """
 from __future__ import annotations
 
@@ -55,8 +49,8 @@ class ServeLoop:
     """Arrival-driven serving over one Scheduler (see module docstring).
 
     `window_size` is the commit/failure granularity (one launch window);
-    `depth` is the launch-queue depth — windows in flight while the
-    oldest commits. `tick_interval` paces idle ticks only: a tick that
+    `depth` is how many windows' worth of pods one step pops.
+    `tick_interval` paces idle ticks only: a tick that
     found work immediately cuts the next window (a saturated serve loop
     is a busy loop, exactly like the drain benches)."""
 
@@ -70,30 +64,19 @@ class ServeLoop:
         self.pods_bound = 0
         self.idle_ticks = 0
         self.gate: Optional[BackpressureGate] = None
-        # in-flight launch windows for the gate: the algorithm's driver
-        # owns the real count; between steps it is 0
         algo = scheduler.algorithm
-        if hasattr(algo, "launch_depth"):
-            algo.launch_depth = self.depth
-        if hasattr(algo, "launch_cap"):
+        if hasattr(algo, "launch_cap"):   # the oracle twin has no launches
             algo.launch_cap = self.window_size
-        if hasattr(algo, "wave_size"):
             # commit windows align with launch windows: one commit wave
             # per window keeps the failure granularity the issue names
             algo.wave_size = min(int(algo.wave_size), self.window_size)
 
     # -- backpressure wiring -------------------------------------------------
-    def inflight_windows(self) -> int:
-        """Launch windows planned/dispatched but not fully committed —
-        the N-deep launch queue's live occupancy (0 between steps)."""
-        return int(getattr(self.sched.algorithm, "inflight_launches", 0))
-
     def attach_gate(self, max_depth: int,
-                    max_inflight: Optional[int] = None,
                     retry_after_base: float = 0.05,
                     retry_after_max: float = 2.0) -> BackpressureGate:
-        """Install a BackpressureGate keyed on THIS loop's queue depth and
-        launch-queue occupancy as the scheduler store's admission gate
+        """Install a BackpressureGate keyed on THIS loop's queue depth as
+        the scheduler store's admission gate
         (embedded store: `Store.admission_gate`; behind an apiserver the
         same hook sheds HTTP creates with 429 + Retry-After).
 
@@ -112,9 +95,6 @@ class ServeLoop:
 
         gate = BackpressureGate(
             depth, max_depth=max_depth,
-            inflight_fn=self.inflight_windows,
-            max_inflight=(max_inflight if max_inflight is not None
-                          else 4 * self.depth),
             retry_after_base=retry_after_base,
             retry_after_max=retry_after_max)
         self.gate = gate
@@ -126,8 +106,8 @@ class ServeLoop:
     # -- the loop ------------------------------------------------------------
     def step(self) -> int:
         """One serve tick: deliver pending watch events, then cut up to
-        `depth` launch windows from the live activeQ. Returns pods bound
-        this tick."""
+        `depth` windows' worth of pods from the live activeQ. Returns pods
+        bound this tick."""
         self.sched.pump()
         bound = self.sched.schedule_burst(
             max_pods=self.window_size * self.depth)

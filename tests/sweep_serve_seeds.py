@@ -1,7 +1,7 @@
 """Serve-window parity seed sweep (the round-16 42-trial run).
 
 Not collected by pytest (no test_ prefix): run by hand after any serve
-loop, launch-queue, backpressure, or shell-burst change —
+loop, launch, backpressure, or shell-burst change —
 
     JAX_PLATFORMS=cpu python tests/sweep_serve_seeds.py [trials] [base_seed]
 

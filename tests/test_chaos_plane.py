@@ -700,7 +700,7 @@ class TestRealDeviceErrorsPropagate:
     and the circuit stays closed — the run fails instead of finishing on
     the host under a device metric's name."""
 
-    def _world(self, n_pods=12, gang=False):
+    def _world(self, n_pods=12, gang=False, mixed=False):
         s, sched = TestDeviceDegradation()._world(n_pods=0)
         sched.algorithm.serial_path = "device"
         labels = {}
@@ -711,7 +711,7 @@ class TestRealDeviceErrorsPropagate:
             s.create(PODGROUPS, PodGroup(name="g", min_member=n_pods))
             labels = {LABEL_POD_GROUP: "g"}
         for j in range(n_pods):
-            pod = mkpod(f"p{j}")
+            pod = mkpod(f"p{j}", cpu=(100, 300)[j % 2] if mixed else 100)
             pod.labels = {**pod.labels, **labels}
             s.create(PODS, pod)
         sched.pump()
@@ -795,14 +795,45 @@ class TestRealDeviceErrorsPropagate:
         with pytest.raises(JaxRuntimeError):
             run_preempt_cell(4, 8, n_preemptors=2)
 
-    def test_injected_fault_at_the_same_seam_still_degrades(self):
+    @pytest.mark.parametrize("driver", ["uniform", "scan", "fused"])
+    def test_injected_fault_at_the_same_seam_still_degrades(
+            self, monkeypatch, driver):
+        """The launch sequence's contract, one for the three drivers: a
+        launch lost to an injected `device.fetch` fault decides nothing,
+        books one fault and one `device-fault` fallback, leaves the walk
+        counters where they were and an aborted flight record; the shell
+        then binds every pod all the same."""
         from kubernetes_tpu.core.tpu_scheduler import ORACLE_FALLBACKS
+        from kubernetes_tpu.obs import flight
         before = fam_count(ORACLE_FALLBACKS, "device-fault")
-        s, sched = self._world()
+        s, sched = self._world(gang=driver == "fused",
+                               mixed=driver == "scan")
+        algo = sched.algorithm
+        entry = "schedule_burst_fused" if driver == "fused" \
+            else "schedule_burst"
+        real = getattr(algo, entry)
+        first = []
+
+        def spy(*a, **kw):
+            walk = (algo.last_index, algo.last_node_index)
+            out = real(*a, **kw)
+            if not first:
+                first.append((out,
+                              (algo.last_index, algo.last_node_index) == walk,
+                              flight.RECORDER.records()[-1]))
+            return out
+        monkeypatch.setattr(algo, entry, spy)
         chaos.plan(seed=0, rates={"device.fetch": 1.0}, limit=1)
         while sched.schedule_burst(max_pods=32):
             pass
         sched.pump()
+        out, walk_kept, rec = first[0]
+        assert rec.kind == driver and rec.blocks == []
+        assert not out or not any(out)      # None, or no pod decided
+        assert walk_kept
+        assert rec.outcome["aborted"] \
+            and not rec.outcome.get("hosts") \
+            and not rec.outcome.get("segments")
         assert all(p.node_name for p in s.list(PODS)[0])
         assert sched.algorithm.breaker.faults_total == 1
         assert fam_count(ORACLE_FALLBACKS, "device-fault") == before + 1

@@ -4,6 +4,7 @@ mid-burst node-death tolerance (stale-bind detection + requeue +
 invalidation)."""
 import threading
 
+import numpy as np
 import pytest
 
 from kubernetes_tpu.api.types import (
@@ -729,3 +730,57 @@ class TestChurnObsEagerRegistration:
         for family in ("node_lease_renew_total", "zone_disruption_state",
                        "evictions_total", "stale_bind_requeues_total"):
             assert f"# HELP {family} " in text, family
+
+
+class TestBatchSerialKeys:
+    """The rotation-row cache and the device mirror's key name a NodeBatch
+    by the serial its encoder gave it, not by its address: CPython hands a
+    freed object's address to the next one of its size."""
+
+    def _world(self, blocks: bool):
+        from kubernetes_tpu.cache.node_info import NodeInfo
+        from kubernetes_tpu.cache.node_tree import NodeTree
+        infos, tree = {}, NodeTree()
+        for i in range(7):       # zones of 3 / 2 / 2, striped or in blocks
+            zone = (0, 0, 0, 1, 1, 2, 2)[i] if blocks else i % 3
+            node = Node(name=f"n{i}",
+                        labels={LABEL_ZONE_FAILURE_DOMAIN: f"z{zone}"},
+                        allocatable={"cpu": 4000, "memory": 1 << 30,
+                                     "pods": 110})
+            infos[node.name] = NodeInfo(node)
+            tree.add_node(node)
+        return infos, tree
+
+    def test_released_batch_never_lends_its_rotation_rows(self):
+        """World A's rows are cached; a batch of another cluster follows
+        and goes unused by the rotation; then world B (A's names, other
+        zones) is encoded, perhaps at A's address. Its rows are those a
+        scheduler that never saw A makes."""
+        from kubernetes_tpu.core.tpu_scheduler import TPUScheduler
+        infos_b, tree_b = self._world(blocks=True)
+        ref = TPUScheduler(percentage_of_nodes_to_score=100,
+                           node_tree=tree_b)
+        want, want_seq = ref._generic_rotation(
+            ref.encoder.encode(infos_b, sorted(infos_b)), 8)
+        tpu = TPUScheduler(percentage_of_nodes_to_score=100)
+        serials = set()
+        for _ in range(40):
+            infos_a, tpu.node_tree = self._world(blocks=False)
+            b = tpu.encoder.encode(infos_a, sorted(infos_a))
+            stale, _seq = tpu._generic_rotation(b, 8)
+            assert not np.array_equal(stale, want)
+            serials.add(b.serial)
+            del b
+            other = {"m0": infos_a["n0"]}
+            serials.add(tpu.encoder.encode(other, ["m0"]).serial)
+            tpu.node_tree = tree_b
+            b = tpu.encoder.encode(infos_b, sorted(infos_b))
+            serials.add(b.serial)
+            got, got_seq = tpu._generic_rotation(b, 8)
+            assert np.array_equal(got, want)
+            assert np.array_equal(got_seq, want_seq)
+            tpu._node_arrays(b)
+            assert tpu._dev_key[-1] == b.serial
+            del b
+            serials.add(tpu.encoder.encode(other, ["m0"]).serial)
+        assert len(serials) == 160              # never one serial twice

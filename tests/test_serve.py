@@ -76,14 +76,6 @@ class TestBackpressureGate:
             - before == 3
         assert gate.rejected == 3
 
-    def test_inflight_windows_shed(self):
-        gate = BackpressureGate(lambda: 0, max_depth=100,
-                                inflight_fn=lambda: 4, max_inflight=4)
-        with pytest.raises(BackpressureError):
-            gate.admit(mkpod("shed"))
-        gate.max_inflight = 5
-        gate.admit(mkpod("ok"))
-
     def test_shed_evicts_ledger_record(self):
         """The round-16 bugfix, pinned at the gate: a shed pod's ledger
         record dies with the 429, so the readmit measures startup from
@@ -136,9 +128,9 @@ class TestServeLoop:
     def test_windows_cut_from_live_queue(self):
         store, sched = build_world()
         loop = ServeLoop(sched, window_size=4, depth=2)
-        # the loop pinned the launch-queue knobs on the algorithm
-        assert sched.algorithm.launch_depth == 2
+        # the loop pinned a launch, and a commit wave, to its window
         assert sched.algorithm.launch_cap == 4
+        assert sched.algorithm.wave_size == 4
         assert loop.step() == 0                  # nothing arrived yet
         for j in range(10):
             store.create(PODS, mkpod(f"p{j}"))

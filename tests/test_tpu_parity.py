@@ -1762,48 +1762,77 @@ class TestDeviceFetchContract:
         assert DEVICE_DISPATCH.labels("burst_fused").value - d0 == 1
         assert DEVICE_FETCHES.labels("burst_fused").value - f0 == 1
 
-    def test_launch_queue_depth3_one_fetch_per_window(self):
-        """Round 16: the N-deep launch queue at depth 3 with window-sized
-        chunks (launch_cap) — a 4-window burst is exactly 4 dispatches
-        and 4 fetches, ONE per window (never per wave or per pod), with
-        decisions bit-identical to the historical 2-deep pipeline."""
+    def test_chunked_uniform_burst_one_fetch_per_window(self):
+        """A uniform burst above `launch_cap` is a launch a window, one
+        after the other: 64 pods at a cap of 16 are exactly 4 dispatches
+        and 4 fetches (never one per wave or per pod), the commit windows
+        arrive in order, each after its own launch's fetch, and the hosts
+        are those of the same burst in one launch."""
         from kubernetes_tpu.core.tpu_scheduler import (DEVICE_DISPATCH,
                                                        DEVICE_FETCHES)
 
-        def mk_pods():
-            return [Pod(name=f"p{k}", labels={"app": "x"},
-                        containers=(Container.make(
-                            name="c", requests={"cpu": 100}),))
-                    for k in range(64)]
-
-        def run_world(depth):
+        def run_world(launch_cap):
             infos, names = self._uniform_world()
             tpu = TPUScheduler(percentage_of_nodes_to_score=100)
-            tpu.launch_depth = depth
-            tpu.launch_cap = 16          # 64 pods -> 4 launch windows
+            tpu.launch_cap = launch_cap
             tpu.wave_size = 16           # commit windows = launch windows
             d0 = DEVICE_DISPATCH.labels("burst_uniform").value
             f0 = DEVICE_FETCHES.labels("burst_uniform").value
-            occupancy = []
+            windows = []
             hosts = tpu.schedule_burst(
-                pods=mk_pods(), node_infos=infos, all_node_names=names,
-                commit=lambda lo, hs:
-                occupancy.append(tpu.inflight_launches) or True)
+                pods=[Pod(name=f"p{k}", labels={"app": "x"},
+                          containers=(Container.make(
+                              name="c", requests={"cpu": 100}),))
+                      for k in range(64)],
+                node_infos=infos, all_node_names=names,
+                commit=lambda lo, hs: windows.append(
+                    (lo, len(hs),
+                     DEVICE_FETCHES.labels("burst_uniform").value - f0))
+                or True)
             assert hosts is not None and all(h is not None for h in hosts)
             d = DEVICE_DISPATCH.labels("burst_uniform").value - d0
             f = DEVICE_FETCHES.labels("burst_uniform").value - f0
-            return hosts, d, f, occupancy, tpu
+            return hosts, d, f, windows
 
-        deep_hosts, d, f, occupancy, tpu = run_world(3)
+        hosts, d, f, windows = run_world(16)
         assert d == 4 and f == 4, (d, f)   # 1 dispatch + 1 fetch / window
-        # the launch queue actually ran deep: while the first window
-        # committed, BOTH successors were already dispatched (depth 3 =
-        # the consumed window's 2 in-flight successors)
-        assert max(occupancy) == 2, occupancy
-        assert tpu.inflight_launches == 0   # drained at return
-        base_hosts, d2, f2, _occ, _t = run_world(2)
-        assert d2 == 4 and f2 == 4
-        assert deep_hosts == base_hosts    # depth changes latency, not bits
+        # window k commits when k+1 launches have been fetched: no launch
+        # runs ahead of the commit before it
+        assert windows == [(0, 16, 1), (16, 16, 2), (32, 16, 3),
+                           (48, 16, 4)]
+        one_hosts, d1, f1, one_windows = run_world(None)
+        assert d1 == 1 and f1 == 1
+        assert [(lo, k) for lo, k, _f in one_windows] \
+            == [(lo, k) for lo, k, _f in windows]
+        assert hosts == one_hosts    # chunking changes launches, not bits
+
+    @pytest.mark.parametrize("driver", ["uniform", "scan", "fused"])
+    def test_burst_starts_no_thread(self, driver):
+        """A burst through each driver fetches its block on the calling
+        thread: no `tpu-fetch` worker, and no other thread, is left
+        behind."""
+        import threading
+        from kubernetes_tpu.obs import flight
+        infos, names = self._uniform_world()
+        tpu = TPUScheduler(percentage_of_nodes_to_score=100)
+        before = set(threading.enumerate())
+        pods = [Pod(name=f"p{k}", labels={"app": "x"},
+                    containers=(Container.make(
+                        name="c",
+                        requests={"cpu": 100 if driver != "scan"
+                                  else (100, 300)[k % 2]}),))
+                for k in range(8)]
+        if driver == "fused":
+            assert tpu.schedule_burst_fused(
+                [(pods[:4], False), (pods[4:], True)], infos, names) \
+                is not None
+        else:
+            hosts = tpu.schedule_burst(pods, infos, names)
+            assert hosts is not None and all(hosts)
+        assert flight.RECORDER.records()[-1].kind == driver
+        after = threading.enumerate()
+        assert not [t for t in after if t.name.startswith("tpu-fetch")]
+        assert set(after) <= before
 
     def test_fused_gang_burst_one_fetch(self):
         """A drain window containing gang segments — one decided, one
