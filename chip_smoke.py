@@ -30,6 +30,12 @@ Stages, all in this one process (a chip belongs to one process):
           hundred pending pods of the eight interleaved pod by pod: one
           burst segment, one launch whose scan carries a count row a
           Service, replayed through the serial oracle.
+- colocated  that cluster again, the pending pods 0.7 replicas of the eight
+          Services and 0.3 Jobs' pods that nothing selects, of three sizes,
+          interleaved pod by pod: the planner hands a run over at every
+          change of kind, a burst segment and a launch a run (no spread
+          carry, one vector, a row a Service), every launch replayed
+          through the serial oracle.
 - serve-groups  that cluster holding 24 Services' pods behind a ServeLoop:
           windows of 3, 20 and 200 pods drawn Zipf over the 24, each window
           on the scan, the large one cut where a 17th Service comes, each
@@ -71,6 +77,7 @@ REAL = {
     "preempt_victims": 10000, "preemptors": 128,
     "serial_nodes": 1000, "serial_cycles": 12,
     "walk_nodes": 1000, "walk_pods": 600, "groups_pods": 400,
+    "colocated_pods": 400,
     "serve_groups_windows": (3, 20, 200),
     "serve_nodes": 1000, "serve_rate": 2000.0, "serve_seconds": 5.0,
     "serve_window": 2048, "serve_parity_pods": 256,
@@ -82,6 +89,7 @@ REHEARSAL = {
     "preempt_victims": 320, "preemptors": 8,
     "serial_nodes": 60, "serial_cycles": 6,
     "walk_nodes": 250, "walk_pods": 40, "groups_pods": 40,
+    "colocated_pods": 40,
     "serve_groups_windows": (3, 20, 80),
     "serve_nodes": 90, "serve_rate": 300.0, "serve_seconds": 2.0,
     "serve_window": 128, "serve_parity_pods": 48,
@@ -215,12 +223,13 @@ def dispatch_delta(before: dict) -> dict:
     return delta(dispatch_counts(), before)
 
 
-def replayed(run_fn) -> tuple[int, list]:
+def replayed(run_fn, capacity: int = 8) -> tuple[int, list]:
     """Run `run_fn` with the flight recorder in replay mode and re-derive
-    every captured launch through the serial oracle (the repo's referee).
-    Returns (launches replayed, mismatches)."""
+    every captured launch through the serial oracle (the repo's referee);
+    `capacity` is how many launches the recorder keeps. Returns (launches
+    replayed, mismatches)."""
     from kubernetes_tpu.obs import flight
-    flight.RECORDER.configure(mode="replay", capacity=8)
+    flight.RECORDER.configure(mode="replay", capacity=capacity)
     flight.RECORDER.clear()
     try:
         run_fn()
@@ -634,6 +643,75 @@ def stage_groups(smoke: Smoke):
             "launches_replayed": launches}
 
 
+def stage_colocated(smoke: Smoke):
+    """Services' replicas and Jobs' pods in one drain pass: the planner
+    hands a run over at every change of kind, so the pass is a burst
+    segment a run, each launch with the spread carry its run needs (none
+    for a run of Jobs' pods), all against one board."""
+    import random
+    from kubernetes_tpu.core import tpu_scheduler as T
+    from kubernetes_tpu.models.hollow import MI, PodStrategy, \
+        make_pods as _pods
+    from kubernetes_tpu.scheduler import SEGMENT_CUTS, Scheduler
+    from kubernetes_tpu.store.store import PODS, Store
+    s = smoke.sizes
+    n, n_pods, k = s["walk_nodes"], s["colocated_pods"], 8
+    assert n % 3, "the zones have to be uneven for the order to rotate"
+    rng = random.Random(47)
+    store = Store(watch_log_size=1 << 16)
+    build_services_cluster(store, n, k, rng)
+    sched = Scheduler(store, use_tpu=True, percentage_of_nodes_to_score=0)
+    sched.sync()
+    # the benchmark's mix: 0.7 replicas of eight Services (100m / 500Mi),
+    # 0.3 Jobs' pods that nothing selects, of three sizes at 3 : 2 : 1
+    jobs = ((100, 128 * MI),) * 3 + ((250, 512 * MI),) * 2 \
+        + ((500, 1024 * MI),)
+    kinds = []
+    for j in range(n_pods):
+        if rng.random() < 0.3:
+            cpu, mem = rng.choice(jobs)
+            pod, = _pods(PodStrategy(count=1, cpu=cpu, mem=mem, labels={}), j)
+        else:
+            pod, = _pods(PodStrategy(
+                count=1, labels={"app": f"svc-{rng.randrange(k)}"}), j)
+        kinds.append(bool(pod.labels))
+        store.create(PODS, pod)
+    changes = sum(a != b for a, b in zip(kinds, kinds[1:]))
+    sched.pump()
+    d0 = dispatch_counts()
+    steps0, cuts0 = family(T.SCAN_SPREAD_STEPS), family(SEGMENT_CUTS)
+    f0 = fallback_counts()
+
+    def run():
+        while sched.schedule_burst(max_pods=512):
+            pass
+    launches, mism = replayed(run, capacity=n_pods)
+    sched.pump()
+    ops = dispatch_delta(d0)
+    steps = delta(family(T.SCAN_SPREAD_STEPS), steps0)
+    cuts = delta(family(SEGMENT_CUTS), cuts0)
+    smoke.check("colocated.all_bound",
+                all(p.node_name for p in store.list(PODS)[0]))
+    smoke.check("colocated.a_segment_a_run",
+                changes > n_pods // 5
+                and cuts == {"plan": changes, "end": changes + 1}
+                and ops.get("burst_scan", 0) == changes + 1
+                and "burst_uniform" not in ops, f"{changes} {cuts} {ops}")
+    smoke.check("colocated.every_carry",
+                steps.get("none", 0) == kinds.count(False)
+                and steps.get("single", 0) > 0 and steps.get("grouped", 0) > 0
+                and sum(steps.values()) == n_pods, steps)
+    smoke.check("colocated.no_refusal",
+                not delta(fallback_counts(), f0), delta(fallback_counts(), f0))
+    smoke.check("colocated.replay_parity",
+                launches == changes + 1 and not mism,
+                f"{launches} launches replayed"
+                + (f", {mism[:2]}" if mism else ""))
+    return {"nodes": n, "pods": n_pods, "services": k,
+            "jobs_pods": kinds.count(False), "changes_of_kind": changes,
+            "device_ops": ops, "launches_replayed": launches}
+
+
 def stage_serve_groups(smoke: Smoke):
     """A serve loop whose windows hold many Services' pods: every window on
     the scan, a window of more Services than one launch carries cut by the
@@ -855,7 +933,7 @@ def main(argv=None) -> int:
     stages += [("lanes.gang", stage_gang), ("lanes.preempt", stage_preempt),
                ("lanes.preempt_scan", stage_preempt_scan),
                ("serial", stage_serial), ("walk", stage_walk),
-               ("groups", stage_groups),
+               ("groups", stage_groups), ("colocated", stage_colocated),
                ("serve-groups", stage_serve_groups), ("serve", stage_serve)]
     if len(dev) > 1:
         stages.append(("mesh", stage_mesh))

@@ -85,12 +85,17 @@ BURST_CLASS = obs.counter(
     "counts.", ("result",))
 SEGMENT_CUTS = obs.counter(
     "scheduler_burst_segment_cuts_total",
-    "Burst segments _schedule_singletons_burst closed, by what ended the "
-    "run of pods: class (the next pod's burst class differs), groups (the "
-    "next pod's selector group would be one more than the algorithm's "
-    "spread_group_cap carries in a launch), nominated (a nomination became "
-    "active), unburstable (the next pod carries volumes), end (the run was "
-    "out of pods). One count a segment.",
+    "Burst segments the shell closed, by what ended the run of pods. "
+    "_schedule_singletons_burst books one count a segment: class (the next "
+    "pod's burst class differs), groups (the next pod's selector group "
+    "would be one more than the algorithm's spread_group_cap carries in a "
+    "launch), nominated (a nomination became active), unburstable (the "
+    "next pod carries volumes), end (the run it was handed was out of "
+    "pods). _burst_pass_planned books plan, once each time it hands over "
+    "a run before the pass is out of items because the next item goes the "
+    "other way (a label-free burstable pod or a fusable gang to the fused "
+    "window, anything else to the singleton path): the run it cut ends on "
+    "end, so end counts segments and plan says why there are so many.",
     ("cause",))
 GANG_WAIT = obs.histogram(
     "gang_wait_duration_seconds",
@@ -1301,11 +1306,13 @@ class Scheduler:
                 window.append(("run", list(wrun)))
                 wrun.clear()
 
-        def flush_window() -> None:
+        def flush_window(cut: bool = False) -> None:
             nonlocal bound
             close_wrun()
             if not window:
                 return
+            if cut:
+                SEGMENT_CUTS.labels("plan").inc()
             if any(e[0] == "gang" for e in window):
                 bound += self._fused_window(window, max_pods)
             else:
@@ -1314,16 +1321,20 @@ class Scheduler:
                 bound += singletons([pr for e in window for pr in e[1]])
             window.clear()
 
-        def flush_srun() -> None:
+        def flush_srun(cut: bool = False) -> None:
             nonlocal bound
             if srun:
+                if cut:
+                    SEGMENT_CUTS.labels("plan").inc()
                 bound += singletons(list(srun))
                 srun.clear()
 
+        # a flush inside the loop cuts a run because the next item goes the
+        # other way (`plan`); the two after it find the pass out of items
         for it in items:
             if isinstance(it, list):
                 gk, members = it
-                flush_srun()
+                flush_srun(cut=True)
                 group = None
                 if fuse_ok and not self.queue.nominated.has_any() \
                         and all(plain_burstable(p) for p, _c in members):
@@ -1332,15 +1343,15 @@ class Scheduler:
                     close_wrun()
                     window.append(("gang", gk, group, members))
                 else:
-                    flush_window()
+                    flush_window(cut=True)
                     bound += self._gang_segment(gk, members,
                                                 bucket=max_pods)
             elif fuse_ok and not self.queue.nominated.has_any() \
                     and plain_burstable(it[0]):
-                flush_srun()
+                flush_srun(cut=True)
                 wrun.append(it)
             else:
-                flush_window()
+                flush_window(cut=True)
                 srun.append(it)
         flush_srun()
         flush_window()
@@ -1395,7 +1406,9 @@ class Scheduler:
                     groups.add(group)
                 j += 1
             SEGMENT_CUTS.labels(cut).inc()
-            bound += self._burst_segment(pods[i:j], cycles[i:j], bucket)
+            bound += self._burst_segment(
+                pods[i:j], cycles[i:j], bucket,
+                seg_class if seg_class in (_PLAIN, _SPREAD) else "class")
             i = j
         return bound
 
@@ -1885,9 +1898,12 @@ class Scheduler:
         return bound
 
     def _burst_segment(self, pods: list[Pod], cycles: list[int],
-                       bucket: int) -> int:
-        """Schedule one burst segment; returns pods bound."""
-        with obs.trace.span("burst.snapshot"):
+                       bucket: int, run: str) -> int:
+        """Schedule one burst segment; returns pods bound. `run` is the
+        kind of run the segment was cut from (the burst class: plain,
+        spread, or class for a signature's own), for the trace: the spans
+        from one `burst.snapshot` to the next are one segment's."""
+        with obs.trace.span("burst.snapshot", run=run):
             self._snapshot = self.cache.update_snapshot(self._snapshot)
             tree_chk = self.cache.node_tree.checkpoint()
             names = self.cache.node_tree.list_names()
@@ -1942,7 +1958,7 @@ class Scheduler:
             for h in e.dead:
                 self._invalidate_dead_node(h)
             return progress["bound"] + self._burst_segment(
-                pods[done:], cycles[done:], bucket)
+                pods[done:], cycles[done:], bucket, run)
         if hosts is None:
             # the algorithm refused the whole burst (it can't reproduce the
             # serial walk for this cluster/workload; refusals happen before
@@ -1985,7 +2001,7 @@ class Scheduler:
                 # schedule the remainder as a fresh segment against a fresh
                 # snapshot and enumeration (the forgotten pods re-queued)
                 return bound + self._burst_segment(pods[kf:], cycles[kf:],
-                                                   bucket)
+                                                   bucket, run)
             # the tail's first pod rides one fresh enumeration (or the
             # segment's own when the kernel decided nothing) whether it runs
             # batched or serial

@@ -23,6 +23,7 @@ DENSITY_ADAPTIVE = "density-5000n-150k-adaptive.rollout-1k"
 MIXED = "inuse-15000n-135k.backlog-10k-mixed"
 LOAD = "load-5000n-150k.rollouts-1k-8svc"
 SERVICES = "services-5000n-150k.arrivals-zipf-64svc"
+COLOCATED = "colocated-5000n-150k.rollouts-1k-8svc-jobs"
 # the scan cells whose 15,000 nodes reach kernels.SCORE_BOARD_MIN_ROWS
 BOARD = (ADAPTIVE, MIXED)
 
@@ -38,6 +39,8 @@ BOARD = (ADAPTIVE, MIXED)
 # services cell takes the same 250 nodes and 80 Services, 64 of which its mix
 # names, at a rate whose windows hold one pod to a few (the warm-up's first
 # pass holds 232 pods: more than sixteen Services, so the shell cuts it).
+# The colocated cell takes the load cell's sizes: its mix names the same
+# eight Services, and Jobs' pods that nothing selects between their replicas.
 AT_50 = {"nodes": {"count": 50},
          "check": {"first_binds": 200, "sampled_binds": 60}}
 SMALL = {
@@ -67,6 +70,7 @@ SMALL = {
                {"arrival": {"rate_per_s": 200.0}, "lifetime_s": 0.4,
                 "serve": {"window_size": 64}}),
 }
+SMALL[COLOCATED] = SMALL[LOAD]
 # the control of an adaptive cell: the program scores every node while the
 # reference judges at the file's default percentage
 EVERY_NODE = {"scheduler": {"percentage_of_nodes_to_score": 100}}
@@ -148,10 +152,14 @@ def counter_metric(name, res, rep, pods=None, moved=None):
     # 64 Services' replicas through the serve loop: windows on the scan
     (SERVICES, 2**31 + 91, None, None),
     (SERVICES, 2**31 + 91, None, EVERY_NODE),           # its control
+    # eight Services' replicas and Jobs' pods interleaved: a segment a run
+    (COLOCATED, 2**31 + 83, None, None),
+    (COLOCATED, 2**31 + 83, None, EVERY_NODE),          # its control
 ], ids=["backlog", "rollout", "arrivals", "adaptive", "altered-binding",
         "density-adaptive", "density-adaptive-control", "mixed",
         "load", "load-altered-binding", "load-control",
-        "services", "services-control"])
+        "services", "services-control",
+        "colocated", "colocated-control"])
 def test_rehearsed_cell(monkeypatch, execute, cell, seed, hook, program):
     if cell in BOARD:
         # these cells hold 16,384 node rows, enough for the scan to carry
@@ -161,7 +169,7 @@ def test_rehearsed_cell(monkeypatch, execute, cell, seed, hook, program):
         monkeypatch.setattr(kernels, "SCORE_BOARD_MIN_ROWS", 1)
     broken = hook is not None or program is not None
     before = {}
-    if cell in (LOAD, SERVICES) and not broken:
+    if cell in (LOAD, SERVICES, COLOCATED) and not broken:
         # the shell's counters are not in the report: take the whole run's
         from lib import counters
         hook = lambda sched, store: before.update(counters.snapshot())
@@ -174,7 +182,8 @@ def test_rehearsed_cell(monkeypatch, execute, cell, seed, hook, program):
     assert res["correct"] is True and res["failed"] == 0
     assert res["attempted"] > 0
     assert rep["compiles_in_window"] == 0
-    if cell in (ROLLOUT, ADAPTIVE, DENSITY_ADAPTIVE, MIXED, LOAD, SERVICES):
+    if cell in (ROLLOUT, ADAPTIVE, DENSITY_ADAPTIVE, MIXED, LOAD, SERVICES,
+                COLOCATED):
         # the generic scan's cells: at 16,384 rows every launch carries the
         # score board (one pod class, or up to eight in the mixed cell), at
         # the density cells' 8192 every step rescores every row
@@ -250,6 +259,39 @@ def test_rehearsed_cell(monkeypatch, execute, cell, seed, hook, program):
         assert cuts == {("end",): pods / backlog}
         assert counter_metric("segment_class_cuts_per_pod.backlog",
                               res, rep, pods, whole) == 0.0
+    if cell == COLOCATED:
+        moved = rep["counters"]
+        # the planner keeps the kinds apart, so no launch is refused for
+        # holding a pod without spread counts beside one with
+        assert "tpu_oracle_fallback_total" not in moved
+        assert "burst_uniform" not in moved["tpu_device_dispatch_total"]
+        assert moved["tpu_walk_nodes_evaluated_total"] == \
+            {"truncated": 120 * res["attempted"]}
+        # a step of every carry: none for a Job's pod, one vector where a
+        # run of replicas is one Service's, a row a Service where several's
+        steps = moved["tpu_scan_spread_steps_total"]
+        assert set(steps) == {"none", "single", "grouped"}
+        assert sum(steps.values()) == res["attempted"]
+        assert 0.15 < steps["none"] / res["attempted"] < 0.45
+        # the shell's side, over warm-up (two cycles) and window: the
+        # planner hands a run over at every change of kind, each run is one
+        # segment and one launch, and a segment ends where its run is out
+        whole = counters.delta(counters.snapshot(), before)
+        backlog = SMALL[COLOCATED][1]["backlog"]
+        pods = 2 * backlog + res["attempted"]
+        cuts = whole["scheduler_burst_segment_cuts_total"]
+        assert set(cuts) == {("plan",), ("end",)}
+        assert cuts[("end",)] == cuts[("plan",)] + pods / backlog \
+            == whole["tpu_device_dispatch_total"][("burst_scan",)]
+        plan = counter_metric("segment_plan_cuts_per_pod.backlog",
+                              res, rep, pods, whole)
+        assert plan == cuts[("plan",)] / pods and 0.3 < plan < 0.55
+        assert counter_metric("segment_end_cuts_per_pod.backlog",
+                              res, rep, pods, whole) == cuts[("end",)] / pods
+        assert counter_metric("segment_class_cuts_per_pod.backlog",
+                              res, rep, pods, whole) == 0.0
+        assert counter_metric("pods_per_dispatch.backlog", res, rep) == \
+            res["attempted"] / moved["tpu_device_dispatch_total"]["burst_scan"]
     if cell == SERVICES:
         moved = rep["counters"]
         pods = res["attempted"]

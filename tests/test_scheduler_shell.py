@@ -460,7 +460,7 @@ class TestBurstClassDecision:
         """Replace the two things a cut leads to with recorders: a burst
         segment's pods, a serial cycle's pod."""
         cuts = []
-        sched._burst_segment = lambda pods, cycles, bucket: cuts.append(
+        sched._burst_segment = lambda pods, cycles, bucket, run: cuts.append(
             ("burst", [p.name for p in pods])) or 0
         sched._process_one = lambda pod, cycle: cuts.append(
             ("serial", [pod.name])) or False
