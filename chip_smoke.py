@@ -32,10 +32,9 @@ Stages, all in this one process (a chip belongs to one process):
           Service, replayed through the serial oracle.
 - colocated  that cluster again, the pending pods 0.7 replicas of the eight
           Services and 0.3 Jobs' pods that nothing selects, of three sizes,
-          interleaved pod by pod: the planner hands a run over at every
-          change of kind, a burst segment and a launch a run (no spread
-          carry, one vector, a row a Service), every launch replayed
-          through the serial oracle.
+          interleaved pod by pod: one burst segment, one launch whose
+          scan carries a count row a Service with the Jobs' pods under no
+          row (group index -1), replayed through the serial oracle.
 - serve-groups  that cluster holding 24 Services' pods behind a ServeLoop:
           windows of 3, 20 and 200 pods drawn Zipf over the 24, each window
           on the scan, the large one cut where a 17th Service comes, each
@@ -644,10 +643,11 @@ def stage_groups(smoke: Smoke):
 
 
 def stage_colocated(smoke: Smoke):
-    """Services' replicas and Jobs' pods in one drain pass: the planner
-    hands a run over at every change of kind, so the pass is a burst
-    segment a run, each launch with the spread carry its run needs (none
-    for a run of Jobs' pods), all against one board."""
+    """Services' replicas and Jobs' pods in one drain pass: no gang in it,
+    so the planner hands it over whole, the segmenter keeps both kinds in
+    one burst segment, and the one launch carries the Services' count
+    rows; a Job's pod reads none of them (zeros: SelectorSpread's
+    constant) and moves none."""
     import random
     from kubernetes_tpu.core import tpu_scheduler as T
     from kubernetes_tpu.models.hollow import MI, PodStrategy, \
@@ -680,31 +680,30 @@ def stage_colocated(smoke: Smoke):
     sched.pump()
     d0 = dispatch_counts()
     steps0, cuts0 = family(T.SCAN_SPREAD_STEPS), family(SEGMENT_CUTS)
+    bare0 = family_total(T.SCAN_SPREAD_UNSELECTED_STEPS)
     f0 = fallback_counts()
 
     def run():
         while sched.schedule_burst(max_pods=512):
             pass
-    launches, mism = replayed(run, capacity=n_pods)
+    launches, mism = replayed(run)
     sched.pump()
     ops = dispatch_delta(d0)
     steps = delta(family(T.SCAN_SPREAD_STEPS), steps0)
+    bare = int(family_total(T.SCAN_SPREAD_UNSELECTED_STEPS) - bare0)
     cuts = delta(family(SEGMENT_CUTS), cuts0)
     smoke.check("colocated.all_bound",
                 all(p.node_name for p in store.list(PODS)[0]))
-    smoke.check("colocated.a_segment_a_run",
+    smoke.check("colocated.one_segment_one_launch",
                 changes > n_pods // 5
-                and cuts == {"plan": changes, "end": changes + 1}
-                and ops.get("burst_scan", 0) == changes + 1
+                and cuts == {"end": 1} and ops.get("burst_scan", 0) == 1
                 and "burst_uniform" not in ops, f"{changes} {cuts} {ops}")
-    smoke.check("colocated.every_carry",
-                steps.get("none", 0) == kinds.count(False)
-                and steps.get("single", 0) > 0 and steps.get("grouped", 0) > 0
-                and sum(steps.values()) == n_pods, steps)
+    smoke.check("colocated.every_step_grouped",
+                steps == {"grouped": n_pods}
+                and bare == kinds.count(False), f"{steps} {bare}")
     smoke.check("colocated.no_refusal",
                 not delta(fallback_counts(), f0), delta(fallback_counts(), f0))
-    smoke.check("colocated.replay_parity",
-                launches == changes + 1 and not mism,
+    smoke.check("colocated.replay_parity", launches == 1 and not mism,
                 f"{launches} launches replayed"
                 + (f", {mism[:2]}" if mism else ""))
     return {"nodes": n, "pods": n_pods, "services": k,

@@ -149,16 +149,24 @@ SCAN_SPREAD_STEPS = obs.counter(
     "carries selector-spread counts: 'none' (no Service or ReplicaSet "
     "selects its pods), 'single' (one set of them selects every pod: one "
     "[N] count vector in the loop's carry) or 'grouped' (pods of 2 to "
-    "kernels.SPREAD_GROUP_CAP selector groups: one count row a group, a "
-    "step reads its pod's row and adds a column). Booked once a launch, "
-    "beside tpu_scan_steps_total.", ("carry",))
+    "kernels.SPREAD_GROUP_CAP selector groups, or of 1 and up beside pods "
+    "that nothing selects: one count row a group, a step reads its pod's "
+    "row and adds a column). Booked once a launch, beside "
+    "tpu_scan_steps_total.", ("carry",))
 SCAN_SPREAD_GROUPS = obs.counter(
     "tpu_scan_spread_groups_total",
     "Selector groups whose spread counts schedule_burst's generic scan "
-    "launches carried: 1 for a 'single' launch, the 2 to "
+    "launches carried: 1 for a 'single' launch, the 1 to "
     "kernels.SPREAD_GROUP_CAP count rows its pods read for a 'grouped' "
     "one (the spare rows of the padded carry are not counted), nothing "
     "for 'none'. Booked once a launch, beside "
+    "tpu_scan_spread_steps_total.")
+SCAN_SPREAD_UNSELECTED_STEPS = obs.counter(
+    "tpu_scan_spread_unselected_steps_total",
+    "Steps of the 'grouped' launches of tpu_scan_spread_steps_total whose "
+    "pod no Service or ReplicaSet selects: it rides the count rows with "
+    "none of its own (group index -1), reads zeros and adds its binding "
+    "to no row. Booked once a launch, beside "
     "tpu_scan_spread_steps_total.")
 PICK_TIED_NODES = obs.counter(
     "tpu_pick_tied_nodes_total",
@@ -1552,35 +1560,49 @@ class TPUScheduler:
         alone share a group, and the group's counts are the vector the
         encoder made for any pod of it.
 
-        One group: (its [n_pad] vector, None), the launch every burst of
-        one Service's pods has always been. Up to K.SPREAD_GROUP_CAP:
-        ([G_pad, n_pad] rows, (group of each pod [len(feats)] int32,
-        counts_for [G_pad, G_pad] bool)), G_pad a power of two so that a
-        stream of launches of about as many groups runs one program; the
-        spare rows are zero and no pod reads or moves them. Behind a serve
-        loop (`launch_cap` pinned) the group count changes from window to
-        window, and a program first met there is a compile inside a window
-        that pods wait on: G_pad is then the cap itself, so the loop runs
-        two scan programs (one vector, the cap's rows) and its first large
-        window has met both. None where the carry cannot be made exact: a
-        pod that nothing selects among pods that something does (its
-        constant score is not a row of zeros'), more groups than the cap,
-        or counts off the node axis."""
+        One group and nothing else: (its [n_pad] vector, None), the launch
+        every burst of one Service's pods has always been. Up to
+        K.SPREAD_GROUP_CAP groups: ([G_pad, n_pad] rows, (group of each pod
+        [len(feats)] int32, counts_for [G_pad, G_pad] bool)), G_pad a power
+        of two so that a stream of launches of about as many groups runs
+        one program; the spare rows are zero and no pod reads or moves
+        them. A pod that nothing selects (no counts, no group) rides the
+        rows with none of its own, under group index -1: a step's masked
+        sum over the group axis then reads it zeros and adds its binding
+        to no row, and zeros score SelectorSpread's constant bit for bit
+        on every node, zoned or not (`_fit_scores`; held by
+        tests/test_grouped_spread_carry.py's
+        test_zero_counts_score_the_inert_constant). Such a launch is
+        always the rank-2 one, also beside one group: the rank-1 program
+        hands every pod the one vector. Behind a serve loop (`launch_cap`
+        pinned) the group count changes from window to window, and a
+        program first met there is a compile inside a window that pods
+        wait on: G_pad is then the cap itself, so the loop runs two scan
+        programs (one vector, the cap's rows) and its first large window
+        has met both. None where the carry cannot be made exact: more
+        groups than the cap, counts off the node axis, or a group without
+        counts."""
         keys: dict = {}
         rows = []
         group_of_feat: dict = {}
+        unselected = False
         for f in feats:
             if id(f) in group_of_feat:
                 continue
-            if f.spread_counts is None \
-                    or f.spread_counts.shape[-1] != n_pad:
+            if f.spread_counts is None:
+                if f.spread_group is not None:
+                    return None
+                group_of_feat[id(f)] = -1
+                unselected = True
+                continue
+            if f.spread_counts.shape[-1] != n_pad:
                 return None
             g = keys.get(f.spread_group)
             if g is None:
                 g = keys[f.spread_group] = len(rows)
                 rows.append(f.spread_counts)
             group_of_feat[id(f)] = g
-        if len(rows) == 1:
+        if len(rows) == 1 and not unselected:
             return rows[0], None
         if len(rows) > K.SPREAD_GROUP_CAP:
             return None
@@ -1792,9 +1814,14 @@ class TPUScheduler:
                 "none" if spread0 is None else
                 "single" if spread_groups is None else "grouped").inc(n_pods)
             if spread0 is not None:
+                # an unselected pod's -1 lies under every row's index, and
+                # a carried launch holds a selected pod: max() + 1 is rows
                 SCAN_SPREAD_GROUPS.inc(
                     1 if spread_groups is None
                     else int(spread_groups[0][:n_pods].max()) + 1)
+            if spread_groups is not None:
+                SCAN_SPREAD_UNSELECTED_STEPS.inc(
+                    int((spread_groups[0][:n_pods] < 0).sum()))
             SCAN_SCORE_STEPS.labels(
                 "full" if classes is None else "carried").inc(n_pods)
             SCAN_POD_ROWS.labels(

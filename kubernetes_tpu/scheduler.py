@@ -56,7 +56,7 @@ DEFAULT_SCHEDULER_NAME = "default-scheduler"
 _PLAIN = "plain"
 #: ... and of a pod whose one such feature is selector spread: the scan
 #: carries the counts of several selector groups, so unlike Services' pods
-#: share a segment
+#: share a segment, and `_PLAIN` pods with them (no group, no count row)
 _SPREAD = "spread"
 
 #: per-process scheduler instance sequence: wave dedupe tokens must be
@@ -87,15 +87,18 @@ SEGMENT_CUTS = obs.counter(
     "scheduler_burst_segment_cuts_total",
     "Burst segments the shell closed, by what ended the run of pods. "
     "_schedule_singletons_burst books one count a segment: class (the next "
-    "pod's burst class differs), groups (the next pod's selector group "
+    "pod's burst class differs, and not merely as a pod that a Service "
+    "selects from one that nothing does where the algorithm carries both "
+    "in a launch), groups (the next pod's selector group "
     "would be one more than the algorithm's spread_group_cap carries in a "
     "launch), nominated (a nomination became active), unburstable (the "
     "next pod carries volumes), end (the run it was handed was out of "
-    "pods). _burst_pass_planned books plan, once each time it hands over "
-    "a run before the pass is out of items because the next item goes the "
-    "other way (a label-free burstable pod or a fusable gang to the fused "
-    "window, anything else to the singleton path): the run it cut ends on "
-    "end, so end counts segments and plan says why there are so many.",
+    "pods). _burst_pass_planned books plan, in a pass that holds a gang, "
+    "once each time it hands over a run before the pass is out of items "
+    "because the next item goes the other way (a label-free burstable pod "
+    "or a fusable gang to the fused window, anything else to the singleton "
+    "path): the run it cut ends on end, so end counts segments and plan "
+    "says why there are so many. A pass without a gang is one run.",
     ("cause",))
 GANG_WAIT = obs.histogram(
     "gang_wait_duration_seconds",
@@ -1296,6 +1299,12 @@ class Scheduler:
                 pairs, max_pods,
                 [class_of[id(p)] for p, _c in pairs] if can_burst else None)
 
+        if not gang_at:
+            # no gang in the pass: the fused window would hand its runs to
+            # the singleton path anyway, so every pod goes there in one
+            # run, in queue order, and what cuts it is the segmenter's
+            return singletons(items), len(drained)
+
         bound = 0
         window: list = []   # fused entries in queue order:
         wrun: list = []     # ("run", pairs) | ("gang", gk, group, members)
@@ -1371,8 +1380,11 @@ class Scheduler:
         if can_burst and classes is None:
             classes = self._burst_classes(pods)
         # selector groups the algorithm carries spread counts for in one
-        # launch; one that does not say carries one
+        # launch; one that does not say carries one. A carry of more than
+        # one also takes the pod that nothing selects (no group, no row of
+        # its own), so `_PLAIN` and `_SPREAD` pods share a segment there
         group_cap = getattr(self.algorithm, "spread_group_cap", 1)
+        carried = (_PLAIN, _SPREAD) if group_cap > 1 else ()
         bound = 0
         i = 0
         while i < len(pods):
@@ -1396,7 +1408,8 @@ class Scheduler:
                     cut = "unburstable"
                     break
                 cls, group = classes[j]
-                if cls is not seg_class:
+                if cls is not seg_class and not (
+                        cls in carried and seg_class in carried):
                     cut = "class"
                     break
                 if group is not None and group not in groups:
@@ -1408,7 +1421,8 @@ class Scheduler:
             SEGMENT_CUTS.labels(cut).inc()
             bound += self._burst_segment(
                 pods[i:j], cycles[i:j], bucket,
-                seg_class if seg_class in (_PLAIN, _SPREAD) else "class")
+                "class" if seg_class not in (_PLAIN, _SPREAD)
+                else _SPREAD if groups else _PLAIN)
             i = j
         return bound
 

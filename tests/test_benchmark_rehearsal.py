@@ -239,6 +239,9 @@ def test_rehearsed_cell(monkeypatch, execute, cell, seed, hook, program):
             {"grouped": res["attempted"]}
         assert counter_metric("spread_grouped_steps_per_pod.backlog",
                               res, rep) == 1.0
+        # ... and a Service selects every pod
+        assert counter_metric("spread_unselected_steps_per_pod.backlog",
+                              res, rep) == 0.0
         assert moved["tpu_scan_pod_rows_total"] == \
             {"stacked": res["attempted"]}
         # a row built a signature (eight) and the pad's: 150 pods in 256
@@ -261,37 +264,43 @@ def test_rehearsed_cell(monkeypatch, execute, cell, seed, hook, program):
                               res, rep, pods, whole) == 0.0
     if cell == COLOCATED:
         moved = rep["counters"]
-        # the planner keeps the kinds apart, so no launch is refused for
-        # holding a pod without spread counts beside one with
+        # a launch that holds a pod without spread counts beside pods with
+        # is carried, not refused
         assert "tpu_oracle_fallback_total" not in moved
         assert "burst_uniform" not in moved["tpu_device_dispatch_total"]
         assert moved["tpu_walk_nodes_evaluated_total"] == \
             {"truncated": 120 * res["attempted"]}
-        # a step of every carry: none for a Job's pod, one vector where a
-        # run of replicas is one Service's, a row a Service where several's
-        steps = moved["tpu_scan_spread_steps_total"]
-        assert set(steps) == {"none", "single", "grouped"}
-        assert sum(steps.values()) == res["attempted"]
-        assert 0.15 < steps["none"] / res["attempted"] < 0.45
-        # the shell's side, over warm-up (two cycles) and window: the
-        # planner hands a run over at every change of kind, each run is one
-        # segment and one launch, and a segment ends where its run is out
-        whole = counters.delta(counters.snapshot(), before)
+        # a pass is one launch and every step of it a grouped one: a row a
+        # Service, and the Jobs' pods, three in ten, ride them with none
         backlog = SMALL[COLOCATED][1]["backlog"]
+        launches = moved["tpu_device_dispatch_total"]["burst_scan"]
+        assert launches == res["attempted"] / backlog
+        assert counter_metric("pods_per_dispatch.backlog", res, rep) == backlog
+        assert moved["tpu_scan_spread_steps_total"] == \
+            {"grouped": res["attempted"]}
+        assert counter_metric("spread_grouped_steps_per_pod.backlog",
+                              res, rep) == 1.0
+        assert moved["tpu_scan_spread_groups_total"] == {"": 8 * launches}
+        unselected = counter_metric(
+            "spread_unselected_steps_per_pod.backlog", res, rep)
+        assert unselected == moved[
+            "tpu_scan_spread_unselected_steps_total"][""] / res["attempted"]
+        assert 0.15 < unselected < 0.45
+        assert moved["tpu_spread_count_encodes_total"] == {"": 8 * launches}
+        # the shell's side, over warm-up (two cycles) and window: no gang
+        # in a pass, so the planner hands it over whole, and it ends where
+        # it is out of pods and nowhere else
+        whole = counters.delta(counters.snapshot(), before)
         pods = 2 * backlog + res["attempted"]
         cuts = whole["scheduler_burst_segment_cuts_total"]
-        assert set(cuts) == {("plan",), ("end",)}
-        assert cuts[("end",)] == cuts[("plan",)] + pods / backlog \
-            == whole["tpu_device_dispatch_total"][("burst_scan",)]
-        plan = counter_metric("segment_plan_cuts_per_pod.backlog",
-                              res, rep, pods, whole)
-        assert plan == cuts[("plan",)] / pods and 0.3 < plan < 0.55
+        assert cuts == {("end",): pods / backlog}
+        assert whole["tpu_device_dispatch_total"][("burst_scan",)] \
+            == pods / backlog
+        for name in ("segment_plan_cuts_per_pod.backlog",
+                     "segment_class_cuts_per_pod.backlog"):
+            assert counter_metric(name, res, rep, pods, whole) == 0.0
         assert counter_metric("segment_end_cuts_per_pod.backlog",
-                              res, rep, pods, whole) == cuts[("end",)] / pods
-        assert counter_metric("segment_class_cuts_per_pod.backlog",
-                              res, rep, pods, whole) == 0.0
-        assert counter_metric("pods_per_dispatch.backlog", res, rep) == \
-            res["attempted"] / moved["tpu_device_dispatch_total"]["burst_scan"]
+                              res, rep, pods, whole) == 1 / backlog
     if cell == SERVICES:
         moved = rep["counters"]
         pods = res["attempted"]

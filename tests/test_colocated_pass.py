@@ -4,19 +4,24 @@ Such a pass is what the scheduler's queue holds on a cluster that runs
 services and batch together (the benchmark's cell
 `colocated-5000n-150k.rollouts-1k-8svc-jobs`): a Deployment's replicas behind
 a Service are `_SPREAD`, the pods of a Job, which nothing selects, `_PLAIN`.
-`Scheduler._burst_pass_planned` sends the first kind down the singleton path
-and the second to the fused window's run, and hands a run over wherever the
-next pod is of the other kind: a burst segment a run. Held here, on the cell's
-own data files at a small size: every binding is the serial oracle's and the
-benchmark's plain reference's, a run is one segment, and
-`scheduler_burst_segment_cuts_total{plan}` counts the changes of kind.
+With no gang in the pass `Scheduler._burst_pass_planned` hands all of it to
+`_schedule_singletons_burst` as one run, which keeps the two kinds in one
+burst segment, and the launch carries the Services' count rows with the
+Jobs' pods under group index -1 (`TPUScheduler._spread_carry`): they read
+zeros and move no row. Held here, on the cell's own data files at a small
+size: every binding is the serial oracle's and the benchmark's plain
+reference's, a pass is one segment whatever it holds (no `plan` cut, no
+`class` cut, one `end` a pass), every step of a mixed pass is a `grouped`
+one, its Jobs' pods the unselected steps, and no launch is refused
+(`burst-spread-mixed` stands still).
 """
 import os
 import sys
 
 import pytest
 
-from kubernetes_tpu.core.tpu_scheduler import ORACLE_FALLBACKS
+from kubernetes_tpu.core.tpu_scheduler import (
+    ORACLE_FALLBACKS, SCAN_SPREAD_STEPS, SCAN_SPREAD_UNSELECTED_STEPS)
 from kubernetes_tpu.oracle.generic_scheduler import num_feasible_nodes_to_find
 from kubernetes_tpu.scheduler import SEGMENT_CUTS, Scheduler
 from kubernetes_tpu.store.store import PODS
@@ -130,6 +135,8 @@ def test_colocated_pass_binds_as_oracle_and_reference(bench, jobs_share,
     sched._burst_segment = watched_segment
     cuts0 = {c: SEGMENT_CUTS.labels(c).value for c in CAUSES}
     mixed0 = ORACLE_FALLBACKS.labels("burst-spread-mixed").value
+    grouped0 = SCAN_SPREAD_STEPS.labels("grouped").value
+    unselected0 = SCAN_SPREAD_UNSELECTED_STEPS.value
     while sched.schedule_burst(max_pods=MAX_PODS):
         pass
     sched.pump()
@@ -149,25 +156,27 @@ def test_colocated_pass_binds_as_oracle_and_reference(bench, jobs_share,
         assert ref.decide(desc_of[name]) == got[name], name
         ref.place(desc_of[name], got[name])
 
-    # what the passes held, and how the planner cut them: a run of one kind
-    # is one segment, of the run's kind
+    # what the passes held, and how they were cut: a pass is one segment,
+    # traced as `spread` where it holds a Service's pod
     kind = {p.name: "spread" if p.labels else "plain" for p, _d in made}
-    runs = []
-    for p in passes:
-        for name in p:
-            if runs and runs[-1][0] == kind[name] and name != p[0]:
-                runs[-1][1].append(name)
-            else:
-                runs.append((kind[name], [name]))
-    assert segments == runs
-    changes = len(runs) - len(passes)
+    changes = sum(kind[a] != kind[b] for p in passes for a, b in zip(p, p[1:]))
     assert (changes == 0) == (jobs_share != 0.3)
     if jobs_share == 0.3:
         # the kind changes 2 x 0.3 x 0.7 = 0.42 times a pod in the mean
         assert 0.25 * N_PODS < changes < 0.6 * N_PODS
+    assert segments == [("plain" if jobs_share == 1 else "spread", p)
+                        for p in passes]
     cuts = {c: SEGMENT_CUTS.labels(c).value - cuts0[c] for c in CAUSES}
-    assert cuts == {"plan": changes, "class": 0, "groups": 0, "nominated": 0,
-                    "unburstable": 0, "end": len(runs)}
-    # the planner keeps the kinds apart, so no launch holds a pod without
-    # spread counts beside one with
+    assert cuts == {"plan": 0, "class": 0, "groups": 0, "nominated": 0,
+                    "unburstable": 0, "end": len(passes)}
+    # the Services' pods carry their rows, the Jobs' pods ride them with
+    # none: every step of a pass that holds a Service's pod is grouped
+    # (eight Services, so also without a Job's pod), and no launch that
+    # holds both kinds is refused
+    jobs = sum(k == "plain" for k in kind.values())
+    assert (jobs == 0, jobs == N_PODS) == (jobs_share == 0, jobs_share == 1)
+    assert SCAN_SPREAD_STEPS.labels("grouped").value - grouped0 \
+        == (0 if jobs_share == 1 else N_PODS)
+    assert SCAN_SPREAD_UNSELECTED_STEPS.value - unselected0 \
+        == (0 if jobs_share == 1 else jobs)
     assert ORACLE_FALLBACKS.labels("burst-spread-mixed").value == mixed0

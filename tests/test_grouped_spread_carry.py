@@ -4,31 +4,38 @@ A burst segment may hold pods of several selector groups (a group: a
 namespace and the set of Services / ReplicaSets that select a pod). The scan
 then carries one count row a group (`TPUScheduler._spread_carry`,
 `kernels._batch_core`): a step scores its pod against its group's row, and a
-bound pod is added to every row whose selectors all match it. Every case
-here is a drain through the normal shell compared, binding for binding, with
-the serial oracle's one cycle a pod; what the shell and the launch did is
-read off their counters.
+bound pod is added to every row whose selectors all match it. A pod that
+nothing selects rides the rows with none of its own (group index -1): it
+reads zeros, which score SelectorSpread's constant, and moves no row. Every
+case here is a drain through the normal shell compared, binding for binding,
+with the serial oracle's one cycle a pod; what the shell and the launch did
+is read off their counters.
 """
 import random
 
+import numpy as np
 import pytest
 
 from kubernetes_tpu.api.types import (
     Container, LABEL_HOSTNAME, LabelSelector, Node, Pod, ReplicaSet, Service)
 from kubernetes_tpu.core.tpu_scheduler import (
-    ORACLE_FALLBACKS, SCAN_POD_ROWS, SCAN_SPREAD_STEPS)
+    ORACLE_FALLBACKS, SCAN_POD_ROWS, SCAN_SPREAD_GROUPS, SCAN_SPREAD_STEPS,
+    SCAN_SPREAD_UNSELECTED_STEPS, TPUScheduler)
+from kubernetes_tpu.cache.node_info import NodeInfo
+from kubernetes_tpu.coscheduling.types import LABEL_POD_GROUP, PodGroup
 from kubernetes_tpu.ops import kernels as K
+from kubernetes_tpu.ops.node_state import NodeStateEncoder, PodEncoder
 from kubernetes_tpu.parallel import sharding as S
 from kubernetes_tpu.scheduler import SEGMENT_CUTS, Scheduler
 from kubernetes_tpu.store.store import (
-    NODES, PODS, REPLICASETS, SERVICES, Store)
+    NODES, PODGROUPS, PODS, REPLICASETS, SERVICES, Store)
 
 GI = 1024 ** 3
 ZONE = "failure-domain.beta.kubernetes.io/zone"
 REGION = "failure-domain.beta.kubernetes.io/region"
 UNEVEN = 130           # zones of 44/43/43: the NodeTree's order rotates
 EVEN = 129             # 43/43/43: every cycle walks the device axis
-CAUSES = ("class", "groups", "nominated", "unburstable", "end")
+CAUSES = ("plan", "class", "groups", "nominated", "unburstable", "end")
 CARRIES = ("none", "single", "grouped")
 
 
@@ -54,13 +61,17 @@ class World:
     def pending(self, rng) -> list:
         raise NotImplementedError
 
+    def zoned(self, i: int) -> bool:
+        """Whether node `i` says which zone it is in."""
+        return True
+
     def build(self, seed: int) -> Store:
         rng = random.Random(seed)
         s = Store(watch_log_size=65536)
         for i in range(self.n_nodes):
+            zone = {ZONE: f"z{i % 3}", REGION: "r1"} if self.zoned(i) else {}
             s.create(NODES, Node(
-                name=f"n{i}", labels={LABEL_HOSTNAME: f"n{i}",
-                                      ZONE: f"z{i % 3}", REGION: "r1"},
+                name=f"n{i}", labels={LABEL_HOSTNAME: f"n{i}", **zone},
                 allocatable={"cpu": 4000, "memory": 32 * GI, "pods": 110}))
         self.selectors(s)
         for i in range(self.n_nodes):
@@ -190,12 +201,49 @@ class SeventeenServices(World):
 
 class PlainBetween(Interleaved):
     """A pod that nothing selects among Services' pods: `_PLAIN` among
-    `_SPREAD`, a cut on either side of it."""
+    `_SPREAD`, in their segment."""
     k = 4
 
     def pending(self, rng):
         pods = super().pending(rng)
         pods[15] = Pod(name=pods[15].name, containers=box())
+        return pods
+
+
+class WithUnselected(World):
+    """Pods of `k` Services and, three in ten, pods that nothing selects,
+    in two sizes, drawn pod by pod."""
+    k = 1
+
+    def pending(self, rng):
+        return [Pod(name=f"p{j:03d}", containers=box(rng.choice((100, 300))))
+                if rng.random() < 0.3 else
+                Pod(name=f"p{j:03d}", containers=box(),
+                    labels={"app": f"svc-{rng.randrange(self.k)}"})
+                for j in range(48)]
+
+
+class Unzoned(WithUnselected):
+    def zoned(self, i):
+        return False
+
+
+class HalfZoned(WithUnselected):
+    def zoned(self, i):
+        return i % 2 == 0
+
+
+class CapAndUnselected(WithUnselected):
+    """As many Services as a launch carries rows for, each met before the
+    pass is half out, and unselected pods among theirs."""
+    k = K.SPREAD_GROUP_CAP
+
+    def pending(self, rng):
+        pods = super().pending(rng)
+        for g in range(self.k):
+            pods[g] = Pod(name=pods[g].name, containers=box(),
+                          labels={"app": f"svc-{g}"})
+        pods[self.k] = Pod(name=pods[self.k].name, containers=box(300))
         return pods
 
 
@@ -216,11 +264,12 @@ def serial(world: World, seed: int, percentage: int) -> list:
     return bindings(s)
 
 
-def drained(world: World, seed: int, percentage: int, mesh=None):
+def drained(world: World, seed: int, percentage: int, mesh=None,
+            use_tpu=True):
     """The normal drain: (bindings, the pods of each burst segment, what the
     counters moved by)."""
     s = world.build(seed)
-    sched = Scheduler(s, use_tpu=True, mesh=mesh,
+    sched = Scheduler(s, use_tpu=use_tpu, mesh=mesh,
                       percentage_of_nodes_to_score=percentage)
     sched.sync()
     world.submit(s, seed)
@@ -248,6 +297,8 @@ def counters() -> dict:
                 for c in CARRIES})
     out["stacked"] = SCAN_POD_ROWS.labels("stacked").value
     out["refused"] = ORACLE_FALLBACKS.labels("burst-spread-mixed").value
+    out["rows"] = SCAN_SPREAD_GROUPS.value
+    out["unselected"] = SCAN_SPREAD_UNSELECTED_STEPS.value
     return out
 
 
@@ -353,38 +404,309 @@ def test_more_services_than_rows_cut_the_segment(percentage):
 
 
 @pytest.mark.parametrize("percentage", [0, 100])
-def test_plain_pod_between_services_pods_still_cuts(percentage):
+def test_plain_pod_between_services_pods_rides_their_segment(percentage):
     world = PlainBetween()
     want = serial(world, 5, percentage)
     got, segments, moved = drained(world, 5, percentage)
     assert got == want
-    # the pass's planner already parts the run at the plain pod (it may
-    # ride a fused window, a Service's pod may not), so each of the three
-    # runs ends as a run out of pods
-    assert [len(seg) for seg in segments] == [15, 1, 32]
-    assert moved[("cut", "end")] == 3 and moved[("cut", "class")] == 0
-    assert moved[("steps", "grouped")] == 47
-    assert moved[("steps", "single")] == 0
+    # no gang in the pass, so the planner hands it over whole, and the
+    # segmenter keeps the plain pod with the Services' pods: it takes no
+    # count row and its step is a grouped one like theirs
+    assert [len(seg) for seg in segments] == [48]
+    assert moved[("cut", "end")] == 1
+    assert not any(moved[("cut", c)] for c in CAUSES if c != "end")
+    assert moved[("steps", "grouped")] == 48
+    assert moved[("steps", "single")] == moved[("steps", "none")] == 0
+    assert moved["unselected"] == 1 and moved["rows"] == 4
+    assert moved["refused"] == 0
 
 
-def test_the_seam_refuses_what_it_cannot_carry():
+@pytest.mark.parametrize("percentage", [0, 100])
+@pytest.mark.parametrize("world", [WithUnselected, Unzoned, HalfZoned],
+                         ids=lambda w: w.__name__)
+def test_one_service_and_unselected_pods_carry_two_rows(world, percentage,
+                                                        monkeypatch):
+    """One Service's pods beside pods that nothing selects: the rank-2
+    launch with `G_pad` 2 (the rank-1 program would hand every pod the one
+    vector), on nodes that all, none or half say their zone, the three
+    shapes of SelectorSpread's blend."""
+    shipped = []
+    real = K.schedule_batch
+
+    def spy(*a, **kw):
+        shipped.append((kw["spread0"].shape, kw["spread_groups"]))
+        return real(*a, **kw)
+
+    world = world()
+    want = serial(world, 5, percentage)
+    assert all(node for _key, node in want)
+    monkeypatch.setattr(K, "schedule_batch", spy)
+    got, segments, moved = drained(world, 5, percentage)
+    assert got == want
+    assert [len(seg) for seg in segments] == [48]
+    bare = [not p.labels for p in segments[0]]
+    assert 5 < sum(bare) < 25
+    ((shape, (group, counts_for)),) = shipped
+    assert shape[0] == 2 and counts_for.shape == (2, 2)
+    assert counts_for.tolist() == [[True, False], [False, False]]
+    assert (group[:48] == np.where(bare, -1, 0)).all()
+    assert moved[("steps", "grouped")] == 48
+    assert moved["unselected"] == sum(bare) and moved["rows"] == 1
+    assert not any(moved[("cut", c)] for c in CAUSES if c != "end")
+    assert moved["refused"] == 0
+
+
+@pytest.mark.parametrize("percentage", [0, 100])
+def test_unselected_pods_take_no_row_of_the_cap(percentage):
+    """`SPREAD_GROUP_CAP` Services' pods and unselected pods in one
+    segment: the pod without a group does not count against the cap in
+    the shell, and takes none of the launch's rows."""
+    world = CapAndUnselected()
+    want = serial(world, 5, percentage)
+    got, segments, moved = drained(world, 5, percentage)
+    assert got == want
+    assert [len(seg) for seg in segments] == [48]
+    assert len(groups_of(p for p in segments[0] if p.labels)) \
+        == K.SPREAD_GROUP_CAP
+    assert moved[("cut", "groups")] == moved[("cut", "class")] == 0
+    assert moved[("steps", "grouped")] == 48
+    assert moved["rows"] == K.SPREAD_GROUP_CAP
+    assert moved["unselected"] == sum(not p.labels for p in segments[0]) > 0
+    assert moved["refused"] == 0
+
+
+@pytest.mark.parametrize("zones", ["zoned", "unzoned", "half"])
+def test_zero_counts_score_the_inert_constant(zones):
+    """What lets the unselected pod ride the carry: `_fit_scores` gives a
+    full-width vector of zeros the score it gives the inert default, on
+    every node, bit for bit. Node and zone fractions are both 10.0 there,
+    and 10.0 * (1 - 2/3) + (2/3) * 10.0 truncates to 10 in the kernels'
+    softfloat as it does in Go."""
+    infos, names = {}, []
+    for i in range(37):
+        zoned = zones == "zoned" or (zones == "half" and i % 2 == 0)
+        node = Node(name=f"n{i}", labels={
+            LABEL_HOSTNAME: f"n{i}",
+            **({ZONE: f"z{i % 3}", REGION: "r1"} if zoned else {})},
+            allocatable={"cpu": 4000, "memory": 32 * GI, "pods": 110})
+        infos[node.name] = NodeInfo(node)
+        names.append(node.name)
+        for j in range(i % 3):
+            infos[node.name].add_pod(Pod(
+                name=f"res-{i}-{j}", node_name=node.name, containers=box()))
+    batch = NodeStateEncoder().encode(infos, names)
+    algo = TPUScheduler(percentage_of_nodes_to_score=100)
+    pod = Pod(name="p", containers=box())
+    arrays = algo._pod_arrays(PodEncoder(infos, batch).encode(pod),
+                              batch.n_pad)
+    nodes = algo._node_arrays(batch)
+    assert K._inert(arrays["spread_counts"])
+    zeros = {**arrays, "spread_counts": np.zeros(batch.n_pad, np.int64)}
+    z_pad = 4
+    valid = np.asarray(nodes["valid"])
+    some = valid & (np.arange(batch.n_pad) % 5 != 0)
+    only = {**{k: 0 for k in K.DEFAULT_WEIGHTS}, "selector_spread": 1}
+    for weights in (K.DEFAULT_WEIGHTS, only):
+        for kept in (valid, some):
+            inert = np.asarray(K._fit_scores(nodes, arrays, kept, weights,
+                                             z_pad))
+            carried = np.asarray(K._fit_scores(nodes, zeros, kept, weights,
+                                               z_pad))
+            assert (inert == carried).all()
+    assert (carried[:37] == K.MAX_PRIORITY).all()
+
+
+@pytest.mark.parametrize("percentage", [0, 100])
+def test_behind_a_serve_loop_the_mix_carries_the_caps_rows(percentage,
+                                                           monkeypatch):
+    """The serve-loop form of the mix: a window of one Service's pods and
+    pods that nothing selects runs the cap's rows (the program the loop's
+    first large window has met), not the one vector and not two rows."""
+    from kubernetes_tpu.serve import ServeLoop
+    shapes = []
+    real = K.schedule_batch
+
+    def spy(*a, **kw):
+        shapes.append(kw["spread0"].shape[:-1])
+        return real(*a, **kw)
+
+    world = WithUnselected()
+    want = serial(world, 5, percentage)
+    s = world.build(5)
+    sched = Scheduler(s, use_tpu=True,
+                      percentage_of_nodes_to_score=percentage)
+    sched.sync()
+    loop = ServeLoop(sched, window_size=64, depth=1)
+    world.submit(s, 5)
+    monkeypatch.setattr(K, "schedule_batch", spy)
+    before = counters()
+    assert loop.step() == 48
+    sched.pump()
+    after = counters()
+    assert bindings(s) == want
+    assert shapes == [(K.SPREAD_GROUP_CAP,)]
+    assert after[("steps", "grouped")] - before[("steps", "grouped")] == 48
+    assert after["unselected"] - before["unselected"] > 5
+    assert after["refused"] == before["refused"]
+
+
+@pytest.mark.parametrize("launch_cap,groups,bare,want", [
+    (None, 1, 0, None), (None, 1, 1, 2), (None, 2, 1, 2), (None, 3, 2, 4),
+    (None, 8, 1, 8), (None, 16, 3, 16), (None, 17, 1, "refused"),
+    (2048, 1, 0, None), (2048, 1, 1, 16), (2048, 5, 1, 16)])
+def test_the_carrys_rows_by_what_the_launch_holds(launch_cap, groups, bare,
+                                                  want):
+    """`_spread_carry` alone: `groups` selector groups and `bare` unselected
+    signatures give the one vector (None), `want` rows, or a refusal; an
+    unselected pod takes index -1 and counts toward no row; a group without
+    counts is refused as before."""
+    import types
+    feats = [types.SimpleNamespace(
+        spread_counts=np.full(16, g + 1, np.int64),
+        spread_group=("default", frozenset({(("app", f"s{g}"),)})))
+        for g in range(groups)]
+    feats[1:1] = [types.SimpleNamespace(spread_counts=None, spread_group=None)
+                  for _ in range(bare)]
+    algo = TPUScheduler.__new__(TPUScheduler)
+    algo.launch_cap = launch_cap
+    carried = algo._spread_carry(feats, 16)
+    if want == "refused":
+        assert carried is None
+        return
+    spread0, spread_groups = carried
+    if want is None:
+        assert spread0.shape == (16,) and spread_groups is None
+        return
+    group, counts_for = spread_groups
+    assert spread0.shape == (want, 16) and counts_for.shape == (want, want)
+    assert group.tolist() == [0] + [-1] * bare + list(range(1, groups))
+    assert (spread0[:groups, 0] == np.arange(1, groups + 1)).all()
+    assert not spread0[groups:].any()
+    assert (counts_for == np.eye(want, dtype=bool)
+            & (np.arange(want) < groups)[:, None]).all()
+    feats[0].spread_counts = None
+    assert algo._spread_carry(feats, 16) is None
+
+
+class GangAmid(WithUnselected):
+    """A gang of label-free pods amid Services' pods and Jobs' pods."""
+    k = 3
+
+    def pending(self, rng):
+        pods = super().pending(rng)
+        for j in (20, 21, 22, 23):
+            pods[j] = Pod(name=pods[j].name, containers=box(),
+                          labels={LABEL_POD_GROUP: "g"})
+        return pods
+
+    def selectors(self, s):
+        super().selectors(s)
+        s.create(PODGROUPS, PodGroup(name="g", min_member=4))
+
+
+class GangOfBothKinds(GangAmid):
+    """... and a gang whose members are one Service's pod, another's and
+    two that nothing selects: its trial hands the seam all four."""
+
+    def pending(self, rng):
+        pods = super().pending(rng)
+        for j, app in ((20, "svc-0"), (22, "svc-1")):
+            pods[j] = Pod(name=pods[j].name, containers=box(),
+                          labels={LABEL_POD_GROUP: "g", "app": app})
+        return pods
+
+
+@pytest.mark.parametrize("percentage", [0, 100])
+@pytest.mark.parametrize("world", [GangAmid, GangOfBothKinds],
+                         ids=lambda w: w.__name__)
+def test_a_gang_in_the_pass_keeps_the_planners_cuts(world, percentage):
+    """Where the pass holds a gang the planner is what it was: label-free
+    pods go to the fused window's run, Services' pods down the singleton
+    path, and a run is handed over (`plan`) wherever the next item goes
+    the other way. Both worlds drain by `schedule_burst`, the serial one
+    with the referee's gang trial."""
+    world = world()
+
+    want, _segments, _moved = drained(world, 5, percentage, use_tpu=False)
+    assert all(node for _key, node in want)
+    got, segments, moved = drained(world, 5, percentage)
+    assert got == want
+    pods = world.pending(random.Random(5 ^ 0x7AF1C))
+    # the route each item takes: the gang is one item where its first
+    # member stood, fusable when nothing selects any member
+    fusable = not isinstance(world, GangOfBothKinds)
+    routes = []
+    for p in pods:
+        if LABEL_POD_GROUP in p.labels:
+            if p.name == "p020":
+                routes.append("window" if fusable else "gang")
+        else:
+            routes.append("singleton" if p.labels else "window")
+    # an unfusable gang flushes both runs; otherwise a hand-over wherever
+    # the route changes
+    plan = 0
+    open_runs = set()
+    for r in routes:
+        if r == "gang":
+            plan += len(open_runs)
+            open_runs.clear()
+            continue
+        other = {"window": "singleton", "singleton": "window"}[r]
+        if other in open_runs:
+            plan += 1
+            open_runs.discard(other)
+        open_runs.add(r)
+    assert moved[("cut", "plan")] == plan > 10
+    assert moved[("cut", "class")] == 0
+    # no burst segment holds a Service's pod beside a pod nothing selects
+    for seg in segments:
+        assert len({bool(set(p.labels) - {LABEL_POD_GROUP})
+                    for p in seg}) == 1
+    # ... and the one launch that does is the trial of the gang of both
+    # kinds, carried where the seam once sent it to the serial referee
+    assert moved["unselected"] == (0 if fusable else 2)
+    assert moved["refused"] == 0
+
+
+class ThreeAndBare(SeventeenServices):
+    """Three Services' pods and one that nothing selects."""
+
+    def pending(self, rng):
+        return [Pod(name=f"q{j}", containers=box(),
+                    labels={"app": f"svc-{j}"}) for j in range(3)] \
+            + [Pod(name="bare", containers=box())]
+
+
+def test_the_seam_refuses_more_groups_than_rows_and_carries_the_mix():
     """Handed directly (a gang's trial does so) a launch of more groups
-    than rows, or one mixing selected and unselected pods, the seam books
-    `burst-spread-mixed` and returns None: a refusal stays a refusal."""
-    world = SeventeenServices()
+    than rows, the seam books `burst-spread-mixed` and returns None: a
+    refusal stays a refusal. One mixing selected and unselected pods it
+    carries, to the serial oracle's bindings."""
+    world = ThreeAndBare()
+    want = dict(serial(world, 5, 0))
     s = world.build(5)
     sched = Scheduler(s, use_tpu=True, percentage_of_nodes_to_score=0)
     sched.sync()
     snap = sched.cache.update_snapshot(sched._snapshot)
     names = sched.cache.node_tree.list_names()
-    many = [Pod(name=f"q{j}", containers=box(),
+    many = [Pod(name=f"r{j}", containers=box(),
                 labels={"app": f"svc-{j}"}) for j in range(17)]
-    mixed = many[:3] + [Pod(name="bare", containers=box())]
-    for pods in (many, mixed):
-        before = counters()["refused"]
-        assert sched.algorithm.schedule_burst(
-            pods, snap.node_infos, names, bucket=32) is None
-        assert counters()["refused"] == before + 1
+    before = counters()
+    assert sched.algorithm.schedule_burst(
+        many, snap.node_infos, names, bucket=32) is None
+    assert counters()["refused"] == before["refused"] + 1
+    before = counters()
+    mixed = world.pending(None)
+    hosts = sched.algorithm.schedule_burst(
+        mixed, snap.node_infos, names, bucket=32)
+    after = counters()
+    assert hosts is not None and all(hosts)
+    assert [want[p.key] for p in mixed] == hosts
+    assert after["refused"] == before["refused"]
+    assert after[("steps", "grouped")] - before[("steps", "grouped")] == 4
+    assert after["unselected"] - before["unselected"] == 1
+    assert after["rows"] - before["rows"] == 3
+    # as many groups as rows are carried
     assert sched.algorithm.schedule_burst(
         many[:16], snap.node_infos, names, bucket=32) is not None
 

@@ -482,10 +482,18 @@ class TestBurstClassDecision:
     @staticmethod
     def _reference_cuts(pods, services, replicasets):
         """The per-pod walk the shell made before the per-pass decision:
-        the class function on every pod, twice, compared by value."""
+        the class function on every pod, twice, compared by value. The
+        scan's grouped carry takes a pod that nothing selects beside pods
+        that Services select, so `plain` and `spread` do not part a
+        segment; a signature's own class does."""
+        carried = ("plain", "spread")
+
         def burst_class(pod):
             return TestBurstClassDecision._reference_class(
                 pod, services, replicasets)[0]
+
+        def together(a, b):
+            return a == b or (a in carried and b in carried)
 
         cuts, i = [], 0
         while i < len(pods):
@@ -496,7 +504,7 @@ class TestBurstClassDecision:
             seg_class = burst_class(pods[i])
             j = i
             while j < len(pods) and not pods[j].volumes \
-                    and burst_class(pods[j]) == seg_class:
+                    and together(burst_class(pods[j]), seg_class):
                 j += 1
             cuts.append((i, j, seg_class))
             i = j
@@ -593,9 +601,13 @@ class TestBurstClassDecision:
 
     def test_service_between_passes_reclassifies(self):
         """Nothing outlives a pass: the same pod shapes are one plain
-        segment before the Service exists and two segments after."""
+        segment before the Service exists and, after, one that holds the
+        Service's two pods (`spread`) with the two that nothing selects.
+        An algorithm that carries one selector group still parts them."""
         store, sched = self._cluster(services=(), replicaset=False)
-        cuts = self._record_cuts(sched)
+        cuts = []
+        sched._burst_segment = lambda pods, cycles, bucket, run: cuts.append(
+            (run, len(pods))) or 0
 
         def one_pass(tag):
             for j, kind in enumerate(["plain", "plain", "svc-a", "svc-a"]):
@@ -603,11 +615,13 @@ class TestBurstClassDecision:
             sched.pump()
             cuts.clear()
             sched._burst_pass_planned(4)
-            return [len(seg) for _how, seg in cuts]
+            return list(cuts)
 
-        assert one_pass("x") == [4]
+        assert one_pass("x") == [("plain", 4)]
         store.create(SERVICES, Service(name="svc-a", selector={"app": "a"}))
-        assert one_pass("y") == [2, 2]
+        assert one_pass("y") == [("spread", 4)]
+        sched.algorithm.spread_group_cap = 1
+        assert one_pass("z") == [("plain", 2), ("spread", 2)]
 
     def test_gang_fallback_without_classes_binds_as_before(self):
         """`_gang_segment`'s degraded path hands `_schedule_singletons_burst`
