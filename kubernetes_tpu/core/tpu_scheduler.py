@@ -456,9 +456,9 @@ class TPUScheduler:
 
     def _profile_ids(self, pods: list):
         """Per-pod profile-id vector for a window (None off the tensor
-        path). Gathered columnar from the encode-at-admission row cache
-        when every row is live; the per-pod fallback is bit-identical by
-        the row contract."""
+        path). One np.take from the pod-row cache's profile_id column,
+        stored at delivery, when every pod's slot is live; the per-pod
+        fallback is bit-identical by the row contract."""
         if self._ptab is None:
             return None
         rc = self.pod_rows
@@ -948,8 +948,8 @@ class TPUScheduler:
     def _class_signature(pod: Pod):
         """Spec fields that determine a pod's device features against a fixed
         snapshot — equal signatures imply identical encoder output. The
-        canonical definition lives in ops.pod_rows (the encode-at-admission
-        row cache stores it); this staticmethod stays the public twin the
+        canonical definition lives in ops.pod_rows (the pod-row cache
+        stores it at delivery); this staticmethod stays the public twin the
         parity tests pin against the native batch."""
         from kubernetes_tpu.ops.pod_rows import pod_class_signature
         return pod_class_signature(pod)
@@ -958,19 +958,16 @@ class TPUScheduler:
     def class_signatures(pods: list) -> list:
         """Batched _class_signature — the burst encode prologue's per-pod
         tuple build as ONE native call (commitcore.class_signatures) when
-        the extension is built, with this module's per-pod static method as
-        the twin (tuples are equal element-for-element by construction;
-        pinned by the commit-core parity tests)."""
-        from kubernetes_tpu import native
-        mod = native.load("commitcore")
-        if mod is not None:
-            return mod.class_signatures(pods)
-        sig = TPUScheduler._class_signature
-        return [sig(p) for p in pods]
+        the extension is built, with the per-pod function as the twin
+        (tuples are equal element-for-element by construction; pinned by
+        the commit-core parity tests). The one definition lives in
+        ops.pod_rows, where a run of creates calls it at delivery."""
+        from kubernetes_tpu.ops.pod_rows import class_signatures
+        return class_signatures(pods)
 
     def _signatures(self, pods: list) -> list:
-        """Window-prologue signatures: gathered from the encode-at-
-        admission row cache when the shell attached one (interned — equal
+        """Window-prologue signatures: gathered from the pod-row cache,
+        which stored them at delivery, when the shell attached one (interned — equal
         sigs are the SAME tuple object, so uniformity checks and the
         per-sig memos below hit by identity), else the batched native
         build. Values are bit-identical either way (pod_rows fuzz)."""
@@ -1323,10 +1320,11 @@ class TPUScheduler:
     # it to its window size, so a window is one launch of one compiled
     # shape
     launch_cap: Optional[int] = None
-    # encode-at-admission pod-row cache (ops.pod_rows.PodRowCache),
-    # attached by the scheduler shell: window planning gathers prebuilt
-    # per-pod rows/signatures instead of re-encoding at line rate. None =
-    # the pre-round-17 per-window encode (identical decisions either way)
+    # pod-row cache (ops.pod_rows.PodRowCache), attached by the scheduler
+    # shell: window planning gathers the interned signatures (and, in
+    # tensor mode, the profile ids) stored at delivery instead of building
+    # them at line rate. None = the per-window build (identical decisions
+    # either way)
     pod_rows = None
 
     def _launch(self, op: str, ph: _BurstPhases, fl, dispatch,
@@ -1441,8 +1439,8 @@ class TPUScheduler:
         bucket = _pad_pow2(bucket if bucket else len(pods), 16)
         uniform = None
         feats: Optional[list] = None
-        # signatures from the encode-at-admission row cache (interned —
-        # the identity fast path below) or the batched native build
+        # signatures from the pod-row cache (interned at delivery — the
+        # identity fast path below) or the batched native build
         sigs = self._signatures(pods)
         s0 = sigs[0]
         uniform_spec = all(s is s0 or s == s0 for s in sigs)

@@ -124,7 +124,8 @@ class FleetInstance:
                 and shard_of(p.namespace, self.n_shards) == shard]
         if pods:
             # the informer batch-delivery verb: one queue lock + one
-            # heap push + row-cache encode per batch, same as arrival
+            # heap push + one row-cache signature pass per batch, same as
+            # arrival
             self.sched._add_pods_to_queue(pods)
         return len(pods)
 

@@ -1,25 +1,24 @@
-"""Encode-at-admission pod-row cache — the window prologue's gather source.
+"""Pod-row cache: what a drain pass reads about a pod, stored at delivery.
 
-PROFILE round-16's serve phase split puts the host prologue (per-pod
-feature extraction + class-signature tuples, re-run on EVERY window that
-drains a pod) second only to the pipelined-away device fetch. The numbers
-a window needs about a pod are pure functions of the pod's SPEC, which is
-immutable between resourceVersions — so this cache computes each pod's
-feature row ONCE, at informer delivery, and window planning gathers
-prebuilt rows (one `np.take` per field) instead of re-running the per-pod
-encode loop at line rate.
+A drain pass asks one thing about every pod it pops: its class signature,
+INTERNED, so that the pass's class decision, the window's uniformity test
+and the per-signature memos of the burst drivers compare by identity. The
+signature is a pure function of the pod's SPEC, which is immutable between
+resourceVersions, so the cache derives it ONCE, at informer delivery, a run
+of pods at a time: one batched signature call, one interning pass, a slot a
+uid, one assignment into the id column (and, in tensor mode, the pod's
+profile index beside it: the one column a window gathers). Nothing else is
+derived at delivery. The feature row (`encode_row`) is still this module's
+to define, and `lookup_row` / `gather` answer with it, derived from the pod
+they are handed.
 
-Rows are keyed by (uid, resourceVersion): an update-in-place (same uid,
-new rv) re-encodes on the spot, a delete frees the slot, and a stale or
-missing row falls back to a fresh encode (counted, never wrong). The
-bit-identity contract — a cached row equals a fresh `encode_row` for
-every pod, field for field — is what keeps burst decisions oracle-parity
-by construction; tests/test_pod_rows.py fuzz-pins it, and the serve
-parity sweep drives it with mid-window pod updates.
-
-Class signatures are INTERNED: equal signatures share one tuple object,
-so the window's uniformity test degenerates to pointer compares and the
-per-sig feature/array memos in the burst drivers hit by identity.
+Slots are keyed by (uid, resourceVersion): an update-in-place (same uid,
+new rv) overwrites its slot, a delete frees it, and a stale or missing slot
+falls back to a fresh derivation (counted, never wrong). The bit-identity
+contract (what the cache answers equals a fresh `encode_row` /
+`pod_class_signature`, field for field) keeps burst decisions oracle-parity
+by construction; tests/test_pod_rows.py fuzz-pins it, and the serve parity
+sweep drives it with mid-window pod updates.
 """
 from __future__ import annotations
 
@@ -27,7 +26,7 @@ from typing import Optional
 
 import numpy as np
 
-from kubernetes_tpu import obs
+from kubernetes_tpu import native, obs
 from kubernetes_tpu.api.types import (
     Pod, get_container_ports, get_pod_nonzero_requests, get_resource_request,
     has_pod_affinity_terms,
@@ -35,10 +34,18 @@ from kubernetes_tpu.api.types import (
 
 ROW_CACHE_HITS = obs.counter(
     "pod_row_cache_hits_total",
-    "Pod-row cache lookups by outcome: hit (row served at the cached "
+    "Pod-row cache lookups by outcome: hit (slot live at the pod's "
     "(uid, resourceVersion)), miss (pod never delivered through the "
-    "informer — encoded fresh on the spot), stale (the cached row's "
-    "resourceVersion lags the pod's — re-encoded fresh).", ("outcome",))
+    "informer: derived fresh on the spot), stale (the slot's "
+    "resourceVersion lags the pod's: derived fresh).", ("outcome",))
+ROW_CACHE_ENCODES = obs.counter(
+    "pod_row_cache_encodes_total",
+    "Pods the pod-row cache derived something for, by what: signature (a "
+    "pod delivered through insert / insert_many, which stores its interned "
+    "class signature and nothing else of its spec; booked once a run), "
+    "columns (a pod whose encode_row was derived because lookup_row or "
+    "gather asked for a field of it).",
+    ("derived",))
 ROW_CACHE_ROWS = obs.gauge(
     "pod_row_cache_rows",
     "Live rows in the most recently constructed pod-row cache.")
@@ -56,33 +63,47 @@ def pod_class_signature(pod: Pod) -> tuple:
             pod.init_containers)
 
 
-#: columnar int64 fields, in row order (gather() does one np.take each);
-#: profile_id (round 19) is the pod's scheduling-profile index — filled at
-#: admission like every other flag, gathered per window so mixed-tenant
-#: windows select their weight-tensor rows without touching the pod specs
+def class_signatures(pods) -> list:
+    """`pod_class_signature` of a run of pods: ONE native call
+    (commitcore.class_signatures) where the extension is built, the
+    per-pod function otherwise. The tuples are equal element for element
+    either way (pinned by the commit-core parity tests)."""
+    mod = native.load("commitcore")
+    if mod is not None:
+        return mod.class_signatures(pods)
+    return [pod_class_signature(p) for p in pods]
+
+
+#: int64 fields of a row, in row order; profile_id (round 19) is the pod's
+#: scheduling-profile index: the one column the cache keeps (tensor mode
+#: gathers it a window, so mixed-tenant windows select their weight-tensor
+#: rows without touching the pod specs)
 _I64_FIELDS = ("req_cpu", "req_mem", "req_eph", "nz_cpu", "nz_mem",
                "upd_cpu", "upd_mem", "upd_eph", "priority", "profile_id")
-#: columnar bool fields
+#: bool fields of a row
 _BOOL_FIELDS = ("has_request", "has_scalar", "has_aff_terms", "has_ports",
                 "has_volumes")
 
 
+def _profile_id(pod: Pod, profile_fn) -> int:
+    """`profile_fn(scheduler_name) -> Optional[int]` (profiles.ProfileSet
+    .index_of); None/unset resolves to 0, the default profile row."""
+    pid = profile_fn(pod.scheduler_name) if profile_fn is not None else None
+    return 0 if pid is None else int(pid)
+
+
 def encode_row(pod: Pod, profile_fn=None) -> dict:
-    """THE per-pod feature row: every spec-derived scalar the window
-    prologue reads, in one place — insert() stores exactly this, the
-    lookup fallback recomputes exactly this, and the bit-identity fuzz
-    compares the two. Scalar (extended-resource) requests are kept as
-    sorted name->quantity items, NOT vocab-aligned arrays: the scalar
-    vocab belongs to the node snapshot, so alignment happens at the
-    window (cheap — scalar pods are rare) while the row stays
-    snapshot-independent. `profile_fn(scheduler_name) -> Optional[int]`
-    maps the pod to its scheduling-profile index (profiles.ProfileSet
-    .index_of); None/unset resolves to 0 — the default profile row."""
+    """THE per-pod feature row: every spec-derived scalar of a pod, in one
+    place; `lookup_row` and `gather` answer with exactly this, and the
+    bit-identity fuzz compares them to it. Scalar (extended-resource)
+    requests are kept as sorted name->quantity items, NOT vocab-aligned
+    arrays: the scalar vocab belongs to the node snapshot, so alignment
+    happens at the window (cheap — scalar pods are rare) while the row
+    stays snapshot-independent."""
     from kubernetes_tpu.cache.node_info import calculate_resource
     req = get_resource_request(pod)
     upd = calculate_resource(pod)
     nz_cpu, nz_mem = get_pod_nonzero_requests(pod)
-    pid = profile_fn(pod.scheduler_name) if profile_fn is not None else 0
     return {
         "req_cpu": req.milli_cpu, "req_mem": req.memory,
         "req_eph": req.ephemeral_storage,
@@ -90,7 +111,7 @@ def encode_row(pod: Pod, profile_fn=None) -> dict:
         "upd_cpu": upd.milli_cpu, "upd_mem": upd.memory,
         "upd_eph": upd.ephemeral_storage,
         "priority": pod.priority,
-        "profile_id": 0 if pid is None else int(pid),
+        "profile_id": _profile_id(pod, profile_fn),
         "has_request": bool(req.milli_cpu or req.memory
                             or req.ephemeral_storage or req.scalar),
         "has_scalar": bool(req.scalar or upd.scalar),
@@ -104,38 +125,37 @@ def encode_row(pod: Pod, profile_fn=None) -> dict:
 
 
 class PodRowCache:
-    """Columnar cache of pod feature rows keyed by (uid, resourceVersion).
+    """Interned class signatures (and profile indices) of pending pods,
+    keyed by (uid, resourceVersion).
 
     Filled at informer delivery (insert/insert_many on the pending-pod
-    handlers), re-encoded on update (same uid, new rv), freed on delete.
-    `lookup_rows`/`signatures`/`gather` serve the window prologue; a miss
-    or stale row falls back to `encode_row` — identical values by the
-    bit-identity contract, so the cache can only be fast, never wrong.
+    handlers), overwritten on update (same uid, new rv), freed on delete.
+    `signatures` serves the drain pass and `gather(pods, ("profile_id",))`
+    tensor mode's window from what delivery stored; `lookup_row` and
+    `gather` of any other field derive `encode_row` from the pod in hand.
+    A miss or stale slot falls back to a fresh derivation — identical
+    values by the bit-identity contract, so the cache can only be fast,
+    never wrong.
 
     Capacity-bounded: past `capacity` live rows, the oldest insertion is
-    evicted (the window falls back to fresh encodes for it — the same
+    evicted (the window falls back to fresh derivations for it — the same
     degradation as a miss)."""
 
     def __init__(self, capacity: int = 1 << 17, profile_fn=None):
         self.capacity = int(capacity)
         #: scheduling-profile resolver (profiles.ProfileSet.index_of);
-        #: applied at insert AND at the lookup fallback so the
-        #: bit-identity contract holds column-for-column
+        #: applied at delivery AND wherever a row is derived, so the
+        #: bit-identity contract holds column-for-column; without one the
+        #: profile_id column stays the zeros it is made as
         self.profile_fn = profile_fn
         cap0 = 1024
         self._cap = cap0
-        for f in _I64_FIELDS:
-            setattr(self, "_" + f, np.zeros(cap0, dtype=np.int64))
-        for f in _BOOL_FIELDS:
-            setattr(self, "_" + f, np.zeros(cap0, dtype=bool))
+        self._profile_id = np.zeros(cap0, dtype=np.int64)
         self._sig_id = np.full(cap0, -1, dtype=np.int32)
         # signature interning: equal sigs share ONE tuple object, so the
         # window's uniformity check is a pointer compare
         self._sig_of: dict = {}          # sig tuple -> id
         self._sigs: list = []            # id -> interned sig tuple
-        # sparse side table: slot -> (req_scalar_items, upd_scalar_items);
-        # only pods with extended-resource requests have an entry
-        self._scalars: dict[int, tuple] = {}
         # slot map: uid -> (slot, rv); insertion-ordered for the capacity
         # eviction (dict preserves insertion order)
         self._slot_of: dict[str, tuple[int, int]] = {}
@@ -148,11 +168,9 @@ class PodRowCache:
     # -- maintenance (informer delivery) -------------------------------------
     def _grow(self) -> None:
         new_cap = self._cap * 2
-        for f in _I64_FIELDS + _BOOL_FIELDS:
-            arr = getattr(self, "_" + f)
-            grown = np.zeros(new_cap, dtype=arr.dtype)
-            grown[: self._cap] = arr
-            setattr(self, "_" + f, grown)
+        pid = np.zeros(new_cap, dtype=np.int64)
+        pid[: self._cap] = self._profile_id
+        self._profile_id = pid
         sid = np.full(new_cap, -1, dtype=np.int32)
         sid[: self._cap] = self._sig_id
         self._sig_id = sid
@@ -167,45 +185,56 @@ class PodRowCache:
         return sid
 
     def insert(self, pod: Pod) -> None:
-        """Encode `pod`'s row at its current (uid, resourceVersion) —
-        called at informer delivery (add and update both land here; an
-        existing row for the uid is overwritten in place)."""
-        uid = pod.uid
-        existing = self._slot_of.pop(uid, None)
-        if existing is not None:
-            slot = existing[0]
-        else:
-            if len(self._slot_of) >= self.capacity:
-                # bound the table: evict the oldest insertion (it decays
-                # to the miss path, never to a wrong row)
-                self.invalidate_uid(next(iter(self._slot_of)))
-            if not self._free:
-                self._grow()
-            slot = self._free.pop()
-        self._write(slot, encode_row(pod, self.profile_fn))
-        # (re-)append so eviction order stays oldest-write-first
-        self._slot_of[uid] = (slot, pod.resource_version)
+        """Deliver one pod: `insert_many`'s run of one."""
+        self.insert_many((pod,))
 
-    def _write(self, slot: int, row: dict) -> None:
-        for f in _I64_FIELDS + _BOOL_FIELDS:
-            getattr(self, "_" + f)[slot] = row[f]
-        self._sig_id[slot] = self._intern_sig(row["signature"])
-        if row["req_scalar_items"] or row["upd_scalar_items"]:
-            self._scalars[slot] = (row["req_scalar_items"],
-                                   row["upd_scalar_items"])
-        else:
-            self._scalars.pop(slot, None)
-
-    def insert_many(self, pods: list) -> None:
-        for pod in pods:
-            self.insert(pod)
+    def insert_many(self, pods) -> None:
+        """Deliver a run of pods at their current (uid, resourceVersion) —
+        called at informer delivery (adds and updates both land here; an
+        existing slot for a uid is overwritten in place). The run's
+        signatures come from ONE batched call and go into the id column
+        with one assignment; nothing else of a pod's spec is derived."""
+        if not pods:
+            return
+        # interning first: an unhashable spec raises here, before any slot
+        # of the run is taken
+        sigs = class_signatures(pods)
+        ids = list(map(self._sig_of.get, sigs))
+        if None in ids:
+            ids = [self._intern_sig(sig) for sig in sigs]
+        slot_of, free, profile_fn = self._slot_of, self._free, self.profile_fn
+        # the run's writes by slot: a slot written twice in one run (a uid
+        # delivered twice, a slot freed by eviction and retaken) keeps its
+        # last value, as a pod-by-pod delivery would
+        sid_at: dict[int, int] = {}
+        for pod, sid in zip(pods, ids):
+            uid = pod.uid
+            existing = slot_of.pop(uid, None)
+            if existing is not None:
+                slot = existing[0]
+            else:
+                if len(slot_of) >= self.capacity:
+                    # bound the table: evict the oldest insertion (it decays
+                    # to the miss path, never to a wrong row)
+                    oldest = next(iter(slot_of))
+                    sid_at.pop(slot_of[oldest][0], None)
+                    self.invalidate_uid(oldest)
+                if not free:
+                    self._grow()
+                slot = free.pop()
+            # (re-)append so eviction order stays oldest-write-first
+            slot_of[uid] = (slot, pod.resource_version)
+            sid_at[slot] = sid
+            if profile_fn is not None:
+                self._profile_id[slot] = _profile_id(pod, profile_fn)
+        self._sig_id[list(sid_at)] = list(sid_at.values())
+        ROW_CACHE_ENCODES.labels("signature").inc(len(pods))
 
     def invalidate_uid(self, uid: str) -> None:
         got = self._slot_of.pop(uid, None)
         if got is not None:
             slot = got[0]
             self._sig_id[slot] = -1
-            self._scalars.pop(slot, None)
             self._free.append(slot)
 
     def invalidate(self, pod: Pod) -> None:
@@ -218,9 +247,9 @@ class PodRowCache:
         for pod in pods:
             self.invalidate_uid(pod.uid)
 
-    # -- window-prologue reads ------------------------------------------------
+    # -- drain-pass reads ------------------------------------------------------
     def _slot(self, pod: Pod) -> int:
-        """Row slot for `pod` at its exact resourceVersion, or -1 (miss /
+        """Slot for `pod` at its exact resourceVersion, or -1 (miss /
         stale). Books the outcome counter."""
         got = self._slot_of.get(pod.uid)
         if got is None:
@@ -236,7 +265,7 @@ class PodRowCache:
     def signatures(self, pods: list) -> list:
         """Per-pod class signatures, interned: cache hits gather the
         shared tuple by id (equal sigs are the SAME object — the window's
-        uniformity check becomes identity); misses encode fresh through
+        uniformity check becomes identity); misses derive fresh through
         the canonical function and intern the result, so the returned
         list is bit-identical to a per-pod `pod_class_signature` pass."""
         slot_of = self._slot_of
@@ -262,26 +291,27 @@ class PodRowCache:
         sigs = self._sigs
         return [sigs[i] for i in ids]
 
+    def _derive(self, pods: list) -> list:
+        rows = [encode_row(pod, self.profile_fn) for pod in pods]
+        ROW_CACHE_ENCODES.labels("columns").inc(len(rows))
+        return rows
+
     def lookup_row(self, pod: Pod) -> dict:
-        """One pod's row — cached when live at the pod's rv, else a fresh
-        `encode_row` (identical values; the fallback is the contract)."""
+        """One pod's row: `encode_row` of the pod in hand, with the
+        INTERNED signature object where the pod's slot is live (identical
+        values either way; the derivation is the contract)."""
         slot = self._slot(pod)
-        if slot < 0:
-            return encode_row(pod, self.profile_fn)
-        row = {f: getattr(self, "_" + f)[slot].item()
-               for f in _I64_FIELDS}
-        for f in _BOOL_FIELDS:
-            row[f] = bool(getattr(self, "_" + f)[slot])
-        req_s, upd_s = self._scalars.get(slot, ((), ()))
-        row["req_scalar_items"] = req_s
-        row["upd_scalar_items"] = upd_s
-        row["signature"] = self._sigs[self._sig_id[slot]]
+        row, = self._derive([pod])
+        if slot >= 0:
+            row["signature"] = self._sigs[self._sig_id[slot]]
         return row
 
     def gather(self, pods: list, fields: tuple = _BOOL_FIELDS) -> Optional[dict]:
-        """Columnar gather for a window's pods: ONE np.take per requested
-        field. Returns None when any pod misses (the caller falls back to
-        its per-pod path — correctness never depends on the cache)."""
+        """Columnar read for a window's pods: `profile_id`, the column
+        delivery stores, by ONE np.take; any other field derived from the
+        pods in hand. Returns None when any pod misses (the caller falls
+        back to its per-pod path — correctness never depends on the
+        cache)."""
         slots = np.empty(len(pods), dtype=np.int64)
         slot_of = self._slot_of
         for i, pod in enumerate(pods):
@@ -292,9 +322,19 @@ class PodRowCache:
                 return None
             slots[i] = got[0]
         ROW_CACHE_HITS.labels("hit").inc(len(pods))
-        return {f: np.take(getattr(self, "_" + f), slots) for f in fields}
+        out = {}
+        rows = None
+        for f in fields:
+            if f == "profile_id":
+                out[f] = np.take(self._profile_id, slots)
+                continue
+            if rows is None:
+                rows = self._derive(pods)
+            out[f] = np.fromiter(
+                (row[f] for row in rows), count=len(rows),
+                dtype=np.int64 if f in _I64_FIELDS else bool)
+        return out
 
     def debug_state(self) -> dict:
         return {"rows": len(self._slot_of), "capacity": self.capacity,
-                "signatures_interned": len(self._sigs),
-                "scalar_rows": len(self._scalars)}
+                "signatures_interned": len(self._sigs)}

@@ -311,13 +311,14 @@ class Scheduler:
         self._gang_first_seen: dict[str, float] = {}
         self._predicate_names = predicate_names
         self._priority_weights = priority_weights
-        # encode-at-admission pod-row cache (round 17): per-pod feature
-        # rows + interned class signatures are computed ONCE at informer
-        # delivery and gathered at window planning, instead of re-encoded
-        # on every window's critical path. Only the TPU burst algorithm
-        # reads it (the oracle shell decides per pod anyway); the
-        # bit-identity contract (cached row == fresh encode, pod_rows
-        # fuzz) keeps decisions oracle-parity by construction.
+        # pod-row cache (round 17; PR 49): a pod's interned class
+        # signature (and, in tensor mode, its profile index) is derived
+        # ONCE, at informer delivery, a run of pods at a time, and gathered
+        # by every drain pass that pops the pod; nothing else of its spec
+        # is derived there. Only the TPU burst path reads it (the oracle
+        # shell decides per pod anyway); the bit-identity contract (what
+        # the cache answers == a fresh derivation, pod_rows fuzz) keeps
+        # decisions oracle-parity by construction.
         self.pod_rows = None
         self.extenders = extenders or []
         self._extender_binder = next(
@@ -537,8 +538,8 @@ class Scheduler:
         # unassigned pods owned by this scheduler -> queue (adds, updates,
         # and deletes all arrive in informer run batches: one queue lock +
         # one native heap push / row-cache pass per batch, and the pod-row
-        # cache encodes each row here — at delivery — so window planning
-        # gathers instead of re-encoding)
+        # cache stores each pod's interned signature here — at delivery,
+        # one batched signature call a run — so a drain pass gathers it)
         pods.add_event_handler(
             on_add=self._add_pod_to_queue,
             on_add_many=self._add_pods_to_queue,
@@ -615,22 +616,23 @@ class Scheduler:
         self.queue.add(pod)
 
     def _add_pods_to_queue(self, pods: list) -> None:
-        """Batched informer delivery: encode every row once, then ONE
-        queue lock + one heap-core push for the whole batch."""
+        """Batched informer delivery: ONE signature pass into the row
+        cache, then ONE queue lock + one heap-core push for the whole
+        batch."""
         if self.pod_rows is not None:
             self.pod_rows.insert_many(pods)
         self.queue.add_many(pods)
 
     def _update_pod_in_queue(self, old: Pod, new: Pod) -> None:
         if self.pod_rows is not None:
-            # update-in-place: same uid, new resourceVersion — re-encode
-            # at delivery so the window gathers the NEW spec's row
+            # update-in-place: same uid, new resourceVersion — overwrite
+            # at delivery so the window gathers the NEW spec's signature
             self.pod_rows.insert(new)
         self.queue.update(old, new)
 
     def _update_pods_in_queue(self, pairs: list) -> None:
-        """Batched informer update run (round 23): re-encode every row
-        once, then ONE queue lock for the whole run."""
+        """Batched informer update run (round 23): ONE signature pass
+        over the new sides, then ONE queue lock for the whole run."""
         if self.pod_rows is not None:
             self.pod_rows.insert_many([new for _old, new in pairs])
         self.queue.update_many(pairs)

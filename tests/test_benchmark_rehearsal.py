@@ -301,6 +301,12 @@ def test_rehearsed_cell(monkeypatch, execute, cell, seed, hook, program):
             assert counter_metric(name, res, rep, pods, whole) == 0.0
         assert counter_metric("segment_end_cuts_per_pod.backlog",
                               res, rep, pods, whole) == 1 / backlog
+        # every create reached the pod-row cache once, in a run, and left
+        # its interned signature there and no derived column
+        assert whole["pod_row_cache_encodes_total"] == \
+            {("signature",): pods}
+        assert counter_metric("pod_rows_signature_only_per_pod.backlog",
+                              res, rep, pods, whole) == 1.0
     if cell == SERVICES:
         moved = rep["counters"]
         pods = res["attempted"]

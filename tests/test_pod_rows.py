@@ -188,6 +188,206 @@ class TestInvalidation:
             assert rc.lookup_row(p)["req_cpu"] == 100
 
 
+def _interned(rc, pods):
+    """The interned signature object behind each pod's slot."""
+    return [rc._sigs[rc._sig_id[rc._slot_of[p.uid][0]]] for p in pods]
+
+
+def _same_state(run, single):
+    """A cache filled a run at a time against one filled pod by pod: same
+    slots in the same eviction order, same id column, same intern table."""
+    assert list(run._slot_of.items()) == list(single._slot_of.items())
+    assert run._free == single._free
+    assert run._cap == single._cap
+    assert run._sig_id.tolist() == single._sig_id.tolist()
+    assert run._profile_id.tolist() == single._profile_id.tolist()
+    assert run._sigs == single._sigs
+
+
+def _encodes():
+    from kubernetes_tpu.ops.pod_rows import ROW_CACHE_ENCODES
+    return {k: ROW_CACHE_ENCODES.labels(k).value
+            for k in ("signature", "columns")}
+
+
+class TestDeliveryStoresTheSignature:
+    """PR 49: delivery derives what a drain pass reads — the interned
+    signature of a run in one pass (and the profile index where the cache
+    has a resolver) — and every other field only when it is asked for."""
+
+    @pytest.mark.parametrize("profiled", [False, True])
+    def test_a_run_equals_its_pods_one_by_one(self, profiled):
+        from kubernetes_tpu.core.tpu_scheduler import TPUScheduler
+        rng = random.Random(17)
+        pods = [fuzz_pod(rng, j) for j in range(1500)]   # past cap0: grows
+        for j, p in enumerate(pods):
+            p.scheduler_name = ("tenant", "default-scheduler", "other")[j % 3]
+        fn = ({"default-scheduler": 0, "tenant": 1}.get if profiled
+              else None)
+        run, single = PodRowCache(profile_fn=fn), PodRowCache(profile_fn=fn)
+        run.insert_many(pods)
+        for p in pods:
+            single.insert(p)
+        _same_state(run, single)
+        sigs = run.signatures(pods)
+        assert sigs == TPUScheduler.class_signatures(pods)
+        assert sigs == [pod_class_signature(p) for p in pods]
+        # the SAME interned objects: one object a distinct value, and it is
+        # the one the slot holds
+        by_val = {}
+        for got, held in zip(sigs, _interned(run, pods)):
+            assert got is held
+            assert by_val.setdefault(got, got) is got
+        want = [0 if fn is None else (fn(p.scheduler_name) or 0)
+                for p in pods]
+        assert run.gather(pods, ("profile_id",))["profile_id"].tolist() \
+            == want
+
+    def test_a_run_that_holds_an_update_in_place(self):
+        """The same uid twice in one run (an add and its update, two
+        updates): the slot keeps the LAST delivery, as pod by pod."""
+        rng = random.Random(23)
+        pods = [fuzz_pod(rng, j) for j in range(40)]
+        updated = []
+        for p in pods[5:25:3]:
+            q = p.clone()
+            q.resource_version = p.resource_version + 1
+            q.labels["upd"] = "y"
+            updated.append(q)
+        seq = pods + updated + [pods[0]]
+        run, single = PodRowCache(), PodRowCache()
+        run.insert_many(seq[:10])
+        run.insert_many(seq[10:])
+        for p in seq:
+            single.insert(p)
+        _same_state(run, single)
+        assert len(run) == len(pods)
+        for q in updated:
+            assert run._slot_of[q.uid][1] == q.resource_version
+            assert run.signatures([q])[0] is _interned(run, [q])[0]
+            assert run.signatures([q])[0] == pod_class_signature(q)
+        # the re-delivered first pod moved to the young end
+        assert next(reversed(run._slot_of)) == pods[0].uid
+
+    @pytest.mark.parametrize("capacity,first,second", [
+        (8, 12, 0),      # one run crosses capacity, evicting its own head
+        (8, 5, 9),       # a second run evicts the first's pods, then its own
+        (16, 16, 3),     # exactly full, then past it
+    ])
+    def test_a_run_that_crosses_capacity(self, capacity, first, second):
+        rng = random.Random(29)
+        pods = [fuzz_pod(rng, j) for j in range(first + second)]
+        run = PodRowCache(capacity=capacity)
+        single = PodRowCache(capacity=capacity)
+        run.insert_many(pods[:first])
+        if second:
+            run.insert_many(pods[first:])
+        for p in pods:
+            single.insert(p)
+        _same_state(run, single)
+        assert len(run) == capacity
+        kept = pods[-capacity:]
+        assert list(run._slot_of) == [p.uid for p in kept]
+        assert run.signatures(kept) == [pod_class_signature(p) for p in kept]
+        # a freed slot nobody retook reads as free
+        live = {slot for slot, _rv in run._slot_of.values()}
+        for slot in range(run._cap):
+            assert (run._sig_id[slot] >= 0) == (slot in live)
+        # an evicted pod decays to the miss path, still right
+        for p in pods[:-capacity]:
+            assert run.lookup_row(p) == encode_row(p)
+
+    def test_native_batch_and_python_twin_intern_to_the_same_objects(
+            self, monkeypatch):
+        from kubernetes_tpu import native
+        from kubernetes_tpu.ops import pod_rows
+        if native.load("commitcore") is None:
+            pytest.skip("commit core not built: " +
+                        str(native.load_error("commitcore")))
+        rng = random.Random(31)
+        pods = [fuzz_pod(rng, j) for j in range(200)]
+        twins = [p.clone() for p in pods]     # equal specs, other objects
+        for t in twins:
+            t.uid += "-twin"
+        rc = PodRowCache()
+        rc.insert_many(pods)                   # the native batch
+        interned = len(rc._sigs)
+        real_load = native.load
+        monkeypatch.setattr(
+            native, "load",
+            lambda name: None if name == "commitcore" else real_load(name))
+        assert pod_rows.class_signatures(twins) \
+            == [pod_class_signature(t) for t in twins]
+        rc.insert_many(twins)                  # the Python twin
+        monkeypatch.undo()
+        assert len(rc._sigs) == interned       # nothing new to intern
+        for a, b in zip(rc.signatures(pods), rc.signatures(twins)):
+            assert a is b
+
+    @pytest.mark.parametrize("profiled", [False, True])
+    def test_gather_of_every_field_equals_encode_row(self, profiled):
+        from kubernetes_tpu.ops.pod_rows import _BOOL_FIELDS, _I64_FIELDS
+        rng = random.Random(37)
+        fn = {"default-scheduler": 0, "tenant": 2}.get if profiled else None
+        pods = [fuzz_pod(rng, j) for j in range(80)]
+        for j, p in enumerate(pods):
+            if j % 4 == 0:
+                p.scheduler_name = "tenant"
+        rc = PodRowCache(profile_fn=fn)
+        rc.insert_many(pods)
+        g = rc.gather(pods, _I64_FIELDS + _BOOL_FIELDS)
+        rows = [encode_row(p, fn) for p in pods]
+        for f in _I64_FIELDS:
+            assert g[f].dtype == np.int64
+            assert g[f].tolist() == [r[f] for r in rows], f
+        for f in _BOOL_FIELDS:
+            assert g[f].dtype == np.bool_
+            assert g[f].tolist() == [r[f] for r in rows], f
+        for p, r in zip(pods, rows):
+            got = rc.lookup_row(p)
+            assert got == r
+            assert got["signature"] is _interned(rc, [p])[0]
+            assert all(type(got[f]) is int for f in _I64_FIELDS)
+            assert all(type(got[f]) is bool for f in _BOOL_FIELDS)
+
+    def test_counter_books_what_was_derived(self):
+        rc = PodRowCache()
+        pods = [mkpod(f"c{j}", rv=1) for j in range(30)]
+        c0 = _encodes()
+        rc.insert_many(pods[:20])
+        rc.insert(pods[20])
+        rc.insert_many([])
+        c1 = _encodes()
+        # once a delivered pod, and no column derived at delivery
+        assert c1["signature"] - c0["signature"] == 21
+        assert c1["columns"] == c0["columns"]
+        # a drain pass's reads derive no column either
+        rc.signatures(pods)
+        assert rc.gather(pods[:21], ("profile_id",)) is not None
+        assert _encodes() == c1
+        # a field asked for is derived then, a pod at a time
+        rc.lookup_row(pods[0])
+        rc.lookup_row(pods[25])                      # a miss derives too
+        assert _encodes()["columns"] - c1["columns"] == 2
+        assert rc.gather(pods[:21], ("has_ports", "req_cpu")) is not None
+        assert _encodes()["columns"] - c1["columns"] == 2 + 21
+        assert rc.gather(pods, ("has_ports",)) is None   # a miss: no row made
+        assert _encodes()["columns"] - c1["columns"] == 2 + 21
+        assert _encodes()["signature"] == c1["signature"]
+
+    def test_unhashable_spec_leaves_the_cache_as_it_was(self):
+        rc = PodRowCache()
+        good = [mkpod(f"g{j}", rv=1) for j in range(4)]
+        rc.insert_many(good)
+        bad = mkpod("bad", rv=1)
+        bad.tolerations = [Toleration(key="k", effect=NO_SCHEDULE)]
+        before = list(rc._slot_of.items())
+        with pytest.raises(TypeError):
+            rc.insert_many([mkpod("g9", rv=1), bad])
+        assert list(rc._slot_of.items()) == before
+        assert rc.signatures(good) == [pod_class_signature(p) for p in good]
+
+
 class TestSchedulerWiring:
     """The shell fills/invalidates the cache at informer delivery and the
     burst prologue gathers from it — end to end on a live scheduler."""
@@ -234,6 +434,49 @@ class TestSchedulerWiring:
         sched.pump()
         got = sched.pod_rows.lookup_row(store.get(PODS, "default/u"))
         assert got["req_cpu"] == 800
+
+
+    def test_create_run_leaves_what_per_pod_delivery_leaves(self):
+        """1000 creates delivered as informer runs (on_add_many ->
+        insert_many) against the same pods delivered one event a pump
+        (on_add -> insert): the same queue, the same cache, the same
+        burst decisions."""
+        from kubernetes_tpu.store.informer import DELIVERED
+        rng = random.Random(41)
+        pods = []
+        for j in range(1000):
+            p = mkpod(f"r{j}", cpu=rng.choice([100, 200]),
+                      labels={"app": f"svc-{j % 5}"} if j % 3 else {})
+            p.priority = rng.randint(0, 2)
+            pods.append(p)
+
+        def batched():
+            return DELIVERED.labels("batched", "pods", "queue").value
+
+        store_a, run = self._world(n_nodes=40)
+        b0 = batched()
+        store_a.create_many(PODS, [p.clone() for p in pods])
+        run.pump()
+        assert batched() - b0 == 1000
+        store_b, single = self._world(n_nodes=40)
+        b0 = batched()
+        for p in pods:
+            store_b.create(PODS, p.clone())
+            single.pump()
+        assert batched() == b0
+        _same_state(run.pod_rows, single.pod_rows)
+        assert len(run.pod_rows) == 1000
+        pend_a = run.queue.pending_pods()["active"]
+        pend_b = single.queue.pending_pods()["active"]
+        assert [(p.key, p.uid, p.resource_version) for p in pend_a] \
+            == [(p.key, p.uid, p.resource_version) for p in pend_b]
+        assert run.pod_rows.signatures(pend_a) \
+            == [pod_class_signature(p) for p in pend_a]
+        assert run.schedule_burst(max_pods=2048) \
+            == single.schedule_burst(max_pods=2048) == 1000
+        bound_a = {p.key: p.node_name for p in store_a.list(PODS)[0]}
+        bound_b = {p.key: p.node_name for p in store_b.list(PODS)[0]}
+        assert bound_a == bound_b and all(bound_a.values())
 
 
 class TestBatchedIngest:
