@@ -35,6 +35,14 @@ Stages, all in this one process (a chip belongs to one process):
           interleaved pod by pod: one burst segment, one launch whose
           scan carries a count row a Service with the Jobs' pods under no
           row (group index -1), replayed through the serial oracle.
+- loadmix  that cluster holding 48 Services' pods, and 400 pending pods in
+          the load test's controller mix (one Service a quarter of them,
+          eight 3% each, the rest sharing a half), interleaved pod by pod:
+          one drain pass that the shell cuts wherever a 17th Service comes,
+          every segment a launch with a count row a Service, the segments
+          after a cut on the cap's 16 rows whatever they hold (one scan
+          program a cut pass), every launch replayed through the serial
+          oracle.
 - serve-groups  that cluster holding 24 Services' pods behind a ServeLoop:
           windows of 3, 20 and 200 pods drawn Zipf over the 24, each window
           on the scan, the large one cut where a 17th Service comes, each
@@ -76,7 +84,7 @@ REAL = {
     "preempt_victims": 10000, "preemptors": 128,
     "serial_nodes": 1000, "serial_cycles": 12,
     "walk_nodes": 1000, "walk_pods": 600, "groups_pods": 400,
-    "colocated_pods": 400,
+    "colocated_pods": 400, "loadmix_pods": 400,
     "serve_groups_windows": (3, 20, 200),
     "serve_nodes": 1000, "serve_rate": 2000.0, "serve_seconds": 5.0,
     "serve_window": 2048, "serve_parity_pods": 256,
@@ -88,7 +96,7 @@ REHEARSAL = {
     "preempt_victims": 320, "preemptors": 8,
     "serial_nodes": 60, "serial_cycles": 6,
     "walk_nodes": 250, "walk_pods": 40, "groups_pods": 40,
-    "colocated_pods": 40,
+    "colocated_pods": 40, "loadmix_pods": 80,
     "serve_groups_windows": (3, 20, 80),
     "serve_nodes": 90, "serve_rate": 300.0, "serve_seconds": 2.0,
     "serve_window": 128, "serve_parity_pods": 48,
@@ -273,6 +281,22 @@ def build_services_cluster(store, n_nodes: int, k: int, rng) -> None:
         pod.node_name = f"node-{rng.randrange(n_nodes)}"
         pod.labels = {"app": f"svc-{rng.randrange(k)}"}
         store.create(PODS, pod)
+
+
+def group_cuts(drawn: list) -> tuple:
+    """What the shell makes of one drain pass whose pods are each selected
+    by the one Service `drawn` names: a segment ends before the pod whose
+    Service would be one more than a launch carries. (`groups` cuts,
+    Services summed over the segments, Services of the last segment)."""
+    from kubernetes_tpu.ops.kernels import SPREAD_GROUP_CAP
+    cuts, groups, seen = 0, 0, set()
+    for j in drawn:
+        if j not in seen and len(seen) == SPREAD_GROUP_CAP:
+            cuts += 1
+            groups += len(seen)
+            seen = set()
+        seen.add(j)
+    return cuts, groups + len(seen), len(seen)
 
 
 def drain(smoke: Smoke, tag: str, device: bool, n_pods: int, **sched_kw):
@@ -711,6 +735,77 @@ def stage_colocated(smoke: Smoke):
             "device_ops": ops, "launches_replayed": launches}
 
 
+def stage_loadmix(smoke: Smoke):
+    """A drain pass of far more Services' pods than a launch carries, in
+    the load test's controller mix: the shell cuts it at the group cap, and
+    every segment after a cut runs the cut ones' scan program."""
+    import random
+    from kubernetes_tpu.core import tpu_scheduler as T
+    from kubernetes_tpu.models.hollow import PodStrategy, make_pods as _pods
+    from kubernetes_tpu.ops.kernels import SPREAD_GROUP_CAP
+    from kubernetes_tpu.scheduler import SEGMENT_CUTS, Scheduler
+    from kubernetes_tpu.store.store import PODS, Store
+    s = smoke.sizes
+    n, n_pods, k = s["walk_nodes"], s["loadmix_pods"], 48
+    assert n % 3, "the zones have to be uneven for the order to rotate"
+    # a seed whose last segment holds 3 Services of 400 pods' 48 (and 4 at
+    # the rehearsal's size): unpadded, another scan program than the cut
+    # segments'
+    rng = random.Random(60)
+    # load.go's computePodCounts: a big controller owns a quarter of the
+    # pods, eight medium ones 3% each, the small ones share the other half
+    weights = [0.25] + [0.03] * 8 + [0.51 / (k - 9)] * (k - 9)
+    store = Store(watch_log_size=1 << 16)
+    build_services_cluster(store, n, k, rng)
+    sched = Scheduler(store, use_tpu=True, percentage_of_nodes_to_score=0)
+    sched.sync()
+    drawn = rng.choices(range(k), weights, k=n_pods)
+    want_cuts, want_groups, last = group_cuts(drawn)
+    for pod, j in zip(_pods(PodStrategy(count=n_pods)), drawn):
+        pod.labels = {"app": f"svc-{j}"}
+        store.create(PODS, pod)
+    sched.pump()
+    d0 = dispatch_counts()
+    steps0, cuts0 = family(T.SCAN_SPREAD_STEPS), family(SEGMENT_CUTS)
+    rows0 = family(T.SCAN_SPREAD_CARRY_LAUNCHES)
+    groups0 = T.SCAN_SPREAD_GROUPS.value
+    f0 = fallback_counts()
+
+    def run():
+        while sched.schedule_burst(max_pods=512):
+            pass
+    launches, mism = replayed(run, capacity=want_cuts + 1)
+    sched.pump()
+    ops = dispatch_delta(d0)
+    steps = delta(family(T.SCAN_SPREAD_STEPS), steps0)
+    cuts = delta(family(SEGMENT_CUTS), cuts0)
+    rows = delta(family(T.SCAN_SPREAD_CARRY_LAUNCHES), rows0)
+    groups = int(T.SCAN_SPREAD_GROUPS.value - groups0)
+    smoke.check("loadmix.all_bound",
+                all(p.node_name for p in store.list(PODS)[0]))
+    smoke.check("loadmix.cut_at_the_group_cap",
+                want_cuts >= 2 and cuts == {"groups": want_cuts, "end": 1}
+                and ops.get("burst_scan", 0) == want_cuts + 1
+                and "burst_uniform" not in ops,
+                f"{cuts} {ops}, {want_cuts} cuts expected")
+    smoke.check("loadmix.a_count_row_a_service",
+                steps == {"grouped": n_pods} and groups == want_groups,
+                f"{steps}, {groups} groups carried, {want_groups} expected")
+    smoke.check("loadmix.one_program_a_cut_pass",
+                last <= SPREAD_GROUP_CAP // 2
+                and rows == {str(SPREAD_GROUP_CAP): want_cuts + 1},
+                f"{rows}, {last} Services in the last segment")
+    smoke.check("loadmix.no_refusal",
+                not delta(fallback_counts(), f0), delta(fallback_counts(), f0))
+    smoke.check("loadmix.replay_parity",
+                launches == want_cuts + 1 and not mism,
+                f"{launches} launches replayed"
+                + (f", {mism[:2]}" if mism else ""))
+    return {"nodes": n, "pods": n_pods, "services": k,
+            "group_cuts": want_cuts, "last_segment_groups": last,
+            "device_ops": ops, "launches_replayed": launches}
+
+
 def stage_serve_groups(smoke: Smoke):
     """A serve loop whose windows hold many Services' pods: every window on
     the scan, a window of more Services than one launch carries cut by the
@@ -718,7 +813,6 @@ def stage_serve_groups(smoke: Smoke):
     import random
     from kubernetes_tpu.core import tpu_scheduler as T
     from kubernetes_tpu.models.hollow import PodStrategy, make_pods as _pods
-    from kubernetes_tpu.ops.kernels import SPREAD_GROUP_CAP
     from kubernetes_tpu.scheduler import SEGMENT_CUTS, Scheduler
     from kubernetes_tpu.serve import ServeLoop
     from kubernetes_tpu.store.store import PODS, Store
@@ -751,16 +845,9 @@ def stage_serve_groups(smoke: Smoke):
     launches, mism, bound, want_cuts, want_groups = 0, [], 0, 0, 0
     for w, size in enumerate(windows):
         drawn = rng.choices(range(k), weights, k=size)
-        # what the shell makes of the window: a segment ends before the pod
-        # whose Service would be one more than a launch carries
-        seen: set = set()
-        for j in drawn:
-            if j not in seen and len(seen) == SPREAD_GROUP_CAP:
-                want_cuts += 1
-                want_groups += len(seen)
-                seen = set()
-            seen.add(j)
-        want_groups += len(seen)
+        n_cuts, n_groups, _last = group_cuts(drawn)
+        want_cuts += n_cuts
+        want_groups += n_groups
         for pod, j in zip(_pods(PodStrategy(count=size,
                                             name_prefix=f"w{w}")), drawn):
             pod.labels = {"app": f"svc-{j}"}
@@ -933,6 +1020,7 @@ def main(argv=None) -> int:
                ("lanes.preempt_scan", stage_preempt_scan),
                ("serial", stage_serial), ("walk", stage_walk),
                ("groups", stage_groups), ("colocated", stage_colocated),
+               ("loadmix", stage_loadmix),
                ("serve-groups", stage_serve_groups), ("serve", stage_serve)]
     if len(dev) > 1:
         stages.append(("mesh", stage_mesh))

@@ -161,6 +161,16 @@ SCAN_SPREAD_GROUPS = obs.counter(
     "one (the spare rows of the padded carry are not counted), nothing "
     "for 'none'. Booked once a launch, beside "
     "tpu_scan_spread_steps_total.")
+SCAN_SPREAD_CARRY_LAUNCHES = obs.counter(
+    "tpu_scan_spread_carry_launches_total",
+    "schedule_burst's generic scan launches that carried selector-spread "
+    "counts, by the padded row count of the launch's carry, which names "
+    "the scan program it ran: '1' (the one [N] vector) or '2', '4', '8', "
+    "'16' (count rows, the spare ones included: a power of two of the "
+    "groups held, or kernels.SPREAD_GROUP_CAP behind a serve loop and for "
+    "a segment that follows a groups cut of its drain pass); nothing for "
+    "a launch that carries none. Booked once a launch, beside "
+    "tpu_scan_spread_groups_total.", ("rows",))
 SCAN_SPREAD_UNSELECTED_STEPS = obs.counter(
     "tpu_scan_spread_unselected_steps_total",
     "Steps of the 'grouped' launches of tpu_scan_spread_steps_total whose "
@@ -1372,7 +1382,8 @@ class TPUScheduler:
     def schedule_burst(self, pods: list[Pod], node_infos: dict[str, NodeInfo],
                        all_node_names: list[str],
                        bucket: Optional[int] = None,
-                       commit=None) -> Optional[list[Optional[str]]]:
+                       commit=None, full_carry: bool = False
+                       ) -> Optional[list[Optional[str]]]:
         """Schedule `pods` against one snapshot; returns per-pod host (or
         None when unschedulable). Decisions are serially equivalent to
         calling schedule() per pod with cache assumes in between. Returns
@@ -1387,6 +1398,11 @@ class TPUScheduler:
         a warm-up burst of any size in the bucket compiles the program a
         later burst runs); the device steps over `len(pods)` of them, not
         over the bucket.
+
+        `full_carry` pads a selector-spread carry to the cap's rows
+        whatever the pods hold (`_spread_carry`): the shell sets it for a
+        segment that follows a `groups` cut of its drain pass, so that a
+        cut pass runs one scan program. It changes no decision.
 
         `commit(lo, hosts) -> bool` (optional) is the wave-window sink:
         the whole burst is ONE dispatch and ONE packed fetch, and `commit`
@@ -1415,14 +1431,15 @@ class TPUScheduler:
         ph.open("encode")
         try:
             return self._burst_phases(ph, pods, node_infos, all_node_names,
-                                      bucket, commit)
+                                      bucket, commit, full_carry)
         finally:
             ph.abandon()   # a refusal or an error inside a phase
 
     def _burst_phases(self, ph: _BurstPhases, pods: list[Pod],
                       node_infos: dict[str, NodeInfo],
                       all_node_names: list[str], bucket: Optional[int],
-                      commit) -> Optional[list[Optional[str]]]:
+                      commit, full_carry: bool
+                      ) -> Optional[list[Optional[str]]]:
         """schedule_burst from the open encode phase on: encode, then the
         wave driver's dispatch and fetch phases."""
         # stable-axis mode: keep the resident mirror/device axis when this
@@ -1511,7 +1528,7 @@ class TPUScheduler:
         spread0 = spread_groups = None
         carry_spread = any(f.spread_counts is not None for f in feats)
         if carry_spread:
-            carried = self._spread_carry(feats, b.n_pad)
+            carried = self._spread_carry(feats, b.n_pad, full_carry)
             if carried is None:
                 ORACLE_FALLBACKS.labels("burst-spread-mixed").inc()
                 return None
@@ -1549,7 +1566,8 @@ class TPUScheduler:
                                 spread_groups=spread_groups,
                                 profile_ids=pids)
 
-    def _spread_carry(self, feats: list, n_pad: int) -> Optional[tuple]:
+    def _spread_carry(self, feats: list, n_pad: int,
+                      full: bool = False) -> Optional[tuple]:
         """(spread0, spread_groups) for a generic scan launch whose pods
         carry selector-spread counts; `feats` are the pods' features, one
         object a signature. A pod's group is what SelectorSpread can tell
@@ -1577,7 +1595,16 @@ class TPUScheduler:
         program first met there is a compile inside a window that pods
         wait on: G_pad is then the cap itself, so the loop runs two scan
         programs (one vector, the cap's rows) and its first large window
-        has met both. None where the carry cannot be made exact: more
+        has met both. `full` (a segment that follows a `groups` cut of its
+        drain pass, `Scheduler._schedule_singletons_burst`) gives a closed
+        loop the cap's rows too, and also for ONE group: every segment
+        before it held the cap's groups, and the pass's last holds whatever
+        is left, 1 to the cap, so a loop of cut passes would meet the
+        vector program and every power of two one pass or another, each a
+        compile where it is first met; padded, a cut pass runs one program,
+        which its first segment has met (a serve loop's two are met
+        already, and it keeps them). None where the carry cannot be made
+        exact: more
         groups than the cap, counts off the node axis, or a group without
         counts."""
         keys: dict = {}
@@ -1600,11 +1627,12 @@ class TPUScheduler:
                 g = keys[f.spread_group] = len(rows)
                 rows.append(f.spread_counts)
             group_of_feat[id(f)] = g
-        if len(rows) == 1 and not unselected:
+        full = full and not self.launch_cap
+        if len(rows) == 1 and not unselected and not full:
             return rows[0], None
         if len(rows) > K.SPREAD_GROUP_CAP:
             return None
-        g_pad = K.SPREAD_GROUP_CAP if self.launch_cap \
+        g_pad = K.SPREAD_GROUP_CAP if self.launch_cap or full \
             else _pad_pow2(len(rows), 2)
         spread0 = np.zeros((g_pad, n_pad), np.int64)
         spread0[:len(rows)] = rows
@@ -1817,6 +1845,9 @@ class TPUScheduler:
                 SCAN_SPREAD_GROUPS.inc(
                     1 if spread_groups is None
                     else int(spread_groups[0][:n_pods].max()) + 1)
+                SCAN_SPREAD_CARRY_LAUNCHES.labels(
+                    "1" if spread_groups is None
+                    else str(spread0.shape[0])).inc()
             if spread_groups is not None:
                 SCAN_SPREAD_UNSELECTED_STEPS.inc(
                     int((spread_groups[0][:n_pods] < 0).sum()))

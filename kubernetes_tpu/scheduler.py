@@ -1387,6 +1387,11 @@ class Scheduler:
         # its own), so `_PLAIN` and `_SPREAD` pods share a segment there
         group_cap = getattr(self.algorithm, "spread_group_cap", 1)
         carried = (_PLAIN, _SPREAD) if group_cap > 1 else ()
+        # a `groups` cut has ended a segment of this run: the segments
+        # after it pad their spread carry to the cap's rows, so that the
+        # pass's last segment, which holds whatever groups are left, runs
+        # the program the cut ones ran (TPUScheduler._spread_carry)
+        after_cut = False
         bound = 0
         i = 0
         while i < len(pods):
@@ -1424,7 +1429,8 @@ class Scheduler:
             bound += self._burst_segment(
                 pods[i:j], cycles[i:j], bucket,
                 "class" if seg_class not in (_PLAIN, _SPREAD)
-                else _SPREAD if groups else _PLAIN)
+                else _SPREAD if groups else _PLAIN, full_carry=after_cut)
+            after_cut = after_cut or cut == "groups"
             i = j
         return bound
 
@@ -1914,11 +1920,14 @@ class Scheduler:
         return bound
 
     def _burst_segment(self, pods: list[Pod], cycles: list[int],
-                       bucket: int, run: str) -> int:
+                       bucket: int, run: str,
+                       full_carry: bool = False) -> int:
         """Schedule one burst segment; returns pods bound. `run` is the
         kind of run the segment was cut from (the burst class: plain,
         spread, or class for a signature's own), for the trace: the spans
-        from one `burst.snapshot` to the next are one segment's."""
+        from one `burst.snapshot` to the next are one segment's.
+        `full_carry`: the segment follows a `groups` cut of its pass, and
+        the algorithm pads its spread carry to the cap it declared."""
         with obs.trace.span("burst.snapshot", run=run):
             self._snapshot = self.cache.update_snapshot(self._snapshot)
             tree_chk = self.cache.node_tree.checkpoint()
@@ -1953,7 +1962,7 @@ class Scheduler:
         try:
             hosts = self.algorithm.schedule_burst(
                 pods, self._snapshot.node_infos, names, bucket=bucket,
-                commit=commit_wave)
+                commit=commit_wave, full_carry=full_carry)
         except StaleNodeRefusal as e:
             # mid-burst node death (round 14): the launch's decision block
             # references vanished nodes and was refused before any of its
@@ -1974,7 +1983,7 @@ class Scheduler:
             for h in e.dead:
                 self._invalidate_dead_node(h)
             return progress["bound"] + self._burst_segment(
-                pods[done:], cycles[done:], bucket, run)
+                pods[done:], cycles[done:], bucket, run, full_carry)
         if hosts is None:
             # the algorithm refused the whole burst (it can't reproduce the
             # serial walk for this cluster/workload; refusals happen before
@@ -2017,7 +2026,7 @@ class Scheduler:
                 # schedule the remainder as a fresh segment against a fresh
                 # snapshot and enumeration (the forgotten pods re-queued)
                 return bound + self._burst_segment(pods[kf:], cycles[kf:],
-                                                   bucket, run)
+                                                   bucket, run, full_carry)
             # the tail's first pod rides one fresh enumeration (or the
             # segment's own when the kernel decided nothing) whether it runs
             # batched or serial

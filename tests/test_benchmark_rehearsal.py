@@ -24,6 +24,7 @@ MIXED = "inuse-15000n-135k.backlog-10k-mixed"
 LOAD = "load-5000n-150k.rollouts-1k-8svc"
 SERVICES = "services-5000n-150k.arrivals-zipf-64svc"
 COLOCATED = "colocated-5000n-150k.rollouts-1k-8svc-jobs"
+LOADMIX = "loadmix-5000n-150k.rollouts-1k-111svc"
 # the scan cells whose 15,000 nodes reach kernels.SCORE_BOARD_MIN_ROWS
 BOARD = (ADAPTIVE, MIXED)
 
@@ -41,6 +42,9 @@ BOARD = (ADAPTIVE, MIXED)
 # pass holds 232 pods: more than sixteen Services, so the shell cuts it).
 # The colocated cell takes the load cell's sizes: its mix names the same
 # eight Services, and Jobs' pods that nothing selects between their replicas.
+# The loadmix cell takes the same 250 nodes and 120 Services, 111 of which its
+# mix names, and passes of 250 pods: about 80 Services a pass, so the shell
+# cuts every pass about eleven times at the 16-group cap.
 AT_50 = {"nodes": {"count": 50},
          "check": {"first_binds": 200, "sampled_binds": 60}}
 SMALL = {
@@ -71,6 +75,10 @@ SMALL = {
                 "serve": {"window_size": 64}}),
 }
 SMALL[COLOCATED] = SMALL[LOAD]
+SMALL[LOADMIX] = ({"nodes": {"count": 250},
+                   "resident": {"pods_per_node": 6, "services": 120},
+                   "check": {"first_binds": 300, "sampled_binds": 200}},
+                  {"warm_binds": 0, "backlog": 250})
 # the control of an adaptive cell: the program scores every node while the
 # reference judges at the file's default percentage
 EVERY_NODE = {"scheduler": {"percentage_of_nodes_to_score": 100}}
@@ -155,11 +163,15 @@ def counter_metric(name, res, rep, pods=None, moved=None):
     # eight Services' replicas and Jobs' pods interleaved: a segment a run
     (COLOCATED, 2**31 + 83, None, None),
     (COLOCATED, 2**31 + 83, None, EVERY_NODE),          # its control
+    # 111 Services' replicas interleaved: a segment every 16 Services
+    (LOADMIX, 2**31 + 50, None, None),
+    (LOADMIX, 2**31 + 50, None, EVERY_NODE),            # its control
 ], ids=["backlog", "rollout", "arrivals", "adaptive", "altered-binding",
         "density-adaptive", "density-adaptive-control", "mixed",
         "load", "load-altered-binding", "load-control",
         "services", "services-control",
-        "colocated", "colocated-control"])
+        "colocated", "colocated-control",
+        "loadmix", "loadmix-control"])
 def test_rehearsed_cell(monkeypatch, execute, cell, seed, hook, program):
     if cell in BOARD:
         # these cells hold 16,384 node rows, enough for the scan to carry
@@ -169,7 +181,7 @@ def test_rehearsed_cell(monkeypatch, execute, cell, seed, hook, program):
         monkeypatch.setattr(kernels, "SCORE_BOARD_MIN_ROWS", 1)
     broken = hook is not None or program is not None
     before = {}
-    if cell in (LOAD, SERVICES, COLOCATED) and not broken:
+    if cell in (LOAD, SERVICES, COLOCATED, LOADMIX) and not broken:
         # the shell's counters are not in the report: take the whole run's
         from lib import counters
         hook = lambda sched, store: before.update(counters.snapshot())
@@ -183,7 +195,7 @@ def test_rehearsed_cell(monkeypatch, execute, cell, seed, hook, program):
     assert res["attempted"] > 0
     assert rep["compiles_in_window"] == 0
     if cell in (ROLLOUT, ADAPTIVE, DENSITY_ADAPTIVE, MIXED, LOAD, SERVICES,
-                COLOCATED):
+                COLOCATED, LOADMIX):
         # the generic scan's cells: at 16,384 rows every launch carries the
         # score board (one pod class, or up to eight in the mixed cell), at
         # the density cells' 8192 every step rescores every row
@@ -307,6 +319,51 @@ def test_rehearsed_cell(monkeypatch, execute, cell, seed, hook, program):
             {("signature",): pods}
         assert counter_metric("pod_rows_signature_only_per_pod.backlog",
                               res, rep, pods, whole) == 1.0
+    if cell == LOADMIX:
+        moved = rep["counters"]
+        pods = res["attempted"]
+        # every segment goes to the scan with a count row a Service it
+        # holds, none is refused, and no pod goes uncounted
+        assert "tpu_oracle_fallback_total" not in moved
+        assert "burst_uniform" not in moved["tpu_device_dispatch_total"]
+        assert moved["tpu_walk_nodes_evaluated_total"] == \
+            {"truncated": 120 * pods}
+        assert moved["tpu_scan_spread_steps_total"] == {"grouped": pods}
+        launches = moved["tpu_device_dispatch_total"]["burst_scan"]
+        backlog = SMALL[LOADMIX][1]["backlog"]
+        assert launches > 8 * pods / backlog
+        assert counter_metric("pods_per_dispatch.backlog", res, rep) \
+            == pods / launches
+        # one count pass and one lookup in the selector index a Service a
+        # segment, the lookup testing the one Service under the pod's label
+        groups = moved["tpu_scan_spread_groups_total"][""]
+        assert groups == moved["tpu_spread_count_encodes_total"][""]
+        for name in ("spread_groups_per_pod.backlog",
+                     "spread_encodes_per_pod.backlog",
+                     "selector_services_tested_per_pod.backlog"):
+            assert counter_metric(name, res, rep) == groups / pods
+        assert 16 * (launches - pods / backlog) < groups <= 16 * launches
+        # a cut pass runs one scan program: every carry has the cap's rows,
+        # whatever the pass's last segment held (no compile in the window,
+        # above: the warm-up's first segment met the program)
+        assert moved["tpu_scan_spread_carry_launches_total"] == \
+            {"16": launches}
+        assert counter_metric("spread_carry_full_launch_share.backlog",
+                              res, rep) == 100.0
+        # the shell's side, over warm-up (two cycles) and window: a pass is
+        # cut where a 17th Service comes and ends where it is out of pods
+        whole = counters.delta(counters.snapshot(), before)
+        pods = 2 * backlog + res["attempted"]
+        cuts = whole["scheduler_burst_segment_cuts_total"]
+        assert set(cuts) == {("groups",), ("end",)}
+        assert cuts[("end",)] == pods / backlog
+        assert cuts[("groups",)] + cuts[("end",)] == \
+            whole["tpu_device_dispatch_total"][("burst_scan",)]
+        assert counter_metric("segment_group_cuts_per_pod.backlog", res, rep,
+                              pods, whole) == cuts[("groups",)] / pods > 0.03
+        for name in ("segment_plan_cuts_per_pod.backlog",
+                     "segment_class_cuts_per_pod.backlog"):
+            assert counter_metric(name, res, rep, pods, whole) == 0.0
     if cell == SERVICES:
         moved = rep["counters"]
         pods = res["attempted"]

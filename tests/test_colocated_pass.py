@@ -127,9 +127,9 @@ def test_colocated_pass_binds_as_oracle_and_reference(bench, jobs_share,
             passes.append([p.name for p, _c in out])
         return out
 
-    def watched_segment(pods, cycles, bucket, run):
+    def watched_segment(pods, cycles, bucket, run, **kw):
         segments.append((run, [p.name for p in pods]))
-        return segment(pods, cycles, bucket, run)
+        return segment(pods, cycles, bucket, run, **kw)
 
     sched.queue.pop_burst = watched_pop
     sched._burst_segment = watched_segment

@@ -460,7 +460,7 @@ class TestBurstClassDecision:
         """Replace the two things a cut leads to with recorders: a burst
         segment's pods, a serial cycle's pod."""
         cuts = []
-        sched._burst_segment = lambda pods, cycles, bucket, run: cuts.append(
+        sched._burst_segment = lambda pods, *_a, **_kw: cuts.append(
             ("burst", [p.name for p in pods])) or 0
         sched._process_one = lambda pod, cycle: cuts.append(
             ("serial", [pod.name])) or False
@@ -606,8 +606,9 @@ class TestBurstClassDecision:
         An algorithm that carries one selector group still parts them."""
         store, sched = self._cluster(services=(), replicaset=False)
         cuts = []
-        sched._burst_segment = lambda pods, cycles, bucket, run: cuts.append(
-            (run, len(pods))) or 0
+        sched._burst_segment = \
+            lambda pods, cycles, bucket, run, **_kw: cuts.append(
+                (run, len(pods))) or 0
 
         def one_pass(tag):
             for j, kind in enumerate(["plain", "plain", "svc-a", "svc-a"]):
