@@ -38,16 +38,16 @@ Stages, all in this one process (a chip belongs to one process):
 - loadmix  that cluster holding 48 Services' pods, and 400 pending pods in
           the load test's controller mix (one Service a quarter of them,
           eight 3% each, the rest sharing a half), interleaved pod by pod:
-          one drain pass that the shell cuts wherever a 17th Service comes,
-          every segment a launch with a count row a Service, the segments
-          after a cut on the cap's 16 rows whatever they hold (one scan
-          program a cut pass), every launch replayed through the serial
-          oracle.
+          one drain pass of more Services than 16, which a closed loop
+          runs as one burst segment and one launch on the wide carry
+          (kernels.SPREAD_GROUP_WIDE count rows), replayed through the
+          serial oracle.
 - serve-groups  that cluster holding 24 Services' pods behind a ServeLoop:
           windows of 3, 20 and 200 pods drawn Zipf over the 24, each window
-          on the scan, the large one cut where a 17th Service comes, each
-          launch's pod operand built from a row a signature, every launch
-          replayed through the serial oracle.
+          on the scan, the large one cut where a 17th Service comes (a
+          serve loop's cap, and its carries' rows, stay 16), each launch's
+          pod operand built from a row a signature, every launch replayed
+          through the serial oracle.
 - serve   perf.harness.run_serve_cell: arrivals -> admission gate ->
           ServeLoop windows -> commit -> watch, with its two audits.
 - mesh    only with more than one device: the drain again with the node
@@ -283,15 +283,15 @@ def build_services_cluster(store, n_nodes: int, k: int, rng) -> None:
         store.create(PODS, pod)
 
 
-def group_cuts(drawn: list) -> tuple:
+def group_cuts(drawn: list, cap: int) -> tuple:
     """What the shell makes of one drain pass whose pods are each selected
     by the one Service `drawn` names: a segment ends before the pod whose
-    Service would be one more than a launch carries. (`groups` cuts,
-    Services summed over the segments, Services of the last segment)."""
-    from kubernetes_tpu.ops.kernels import SPREAD_GROUP_CAP
+    Service would be one more than the `cap` a launch carries. (`groups`
+    cuts, Services summed over the segments, Services of the last
+    segment)."""
     cuts, groups, seen = 0, 0, set()
     for j in drawn:
-        if j not in seen and len(seen) == SPREAD_GROUP_CAP:
+        if j not in seen and len(seen) == cap:
             cuts += 1
             groups += len(seen)
             seen = set()
@@ -736,21 +736,19 @@ def stage_colocated(smoke: Smoke):
 
 
 def stage_loadmix(smoke: Smoke):
-    """A drain pass of far more Services' pods than a launch carries, in
-    the load test's controller mix: the shell cuts it at the group cap, and
-    every segment after a cut runs the cut ones' scan program."""
+    """A drain pass of far more Services' pods than 16, in the load test's
+    controller mix: a closed loop's launch carries them all on the wide
+    carry, so the pass is one segment and one launch (the serve-loop form,
+    cut at the 16-group cap, is the stage `serve-groups`)."""
     import random
     from kubernetes_tpu.core import tpu_scheduler as T
     from kubernetes_tpu.models.hollow import PodStrategy, make_pods as _pods
-    from kubernetes_tpu.ops.kernels import SPREAD_GROUP_CAP
+    from kubernetes_tpu.ops.kernels import SPREAD_GROUP_CAP, SPREAD_GROUP_WIDE
     from kubernetes_tpu.scheduler import SEGMENT_CUTS, Scheduler
     from kubernetes_tpu.store.store import PODS, Store
     s = smoke.sizes
     n, n_pods, k = s["walk_nodes"], s["loadmix_pods"], 48
     assert n % 3, "the zones have to be uneven for the order to rotate"
-    # a seed whose last segment holds 3 Services of 400 pods' 48 (and 4 at
-    # the rehearsal's size): unpadded, another scan program than the cut
-    # segments'
     rng = random.Random(60)
     # load.go's computePodCounts: a big controller owns a quarter of the
     # pods, eight medium ones 3% each, the small ones share the other half
@@ -760,7 +758,7 @@ def stage_loadmix(smoke: Smoke):
     sched = Scheduler(store, use_tpu=True, percentage_of_nodes_to_score=0)
     sched.sync()
     drawn = rng.choices(range(k), weights, k=n_pods)
-    want_cuts, want_groups, last = group_cuts(drawn)
+    held = len(set(drawn))
     for pod, j in zip(_pods(PodStrategy(count=n_pods)), drawn):
         pod.labels = {"app": f"svc-{j}"}
         store.create(PODS, pod)
@@ -774,7 +772,7 @@ def stage_loadmix(smoke: Smoke):
     def run():
         while sched.schedule_burst(max_pods=512):
             pass
-    launches, mism = replayed(run, capacity=want_cuts + 1)
+    launches, mism = replayed(run)
     sched.pump()
     ops = dispatch_delta(d0)
     steps = delta(family(T.SCAN_SPREAD_STEPS), steps0)
@@ -783,26 +781,23 @@ def stage_loadmix(smoke: Smoke):
     groups = int(T.SCAN_SPREAD_GROUPS.value - groups0)
     smoke.check("loadmix.all_bound",
                 all(p.node_name for p in store.list(PODS)[0]))
-    smoke.check("loadmix.cut_at_the_group_cap",
-                want_cuts >= 2 and cuts == {"groups": want_cuts, "end": 1}
-                and ops.get("burst_scan", 0) == want_cuts + 1
+    smoke.check("loadmix.one_segment_one_launch",
+                SPREAD_GROUP_CAP < held <= SPREAD_GROUP_WIDE
+                and cuts == {"end": 1} and ops.get("burst_scan", 0) == 1
                 and "burst_uniform" not in ops,
-                f"{cuts} {ops}, {want_cuts} cuts expected")
+                f"{cuts} {ops}, {held} Services in the pass")
     smoke.check("loadmix.a_count_row_a_service",
-                steps == {"grouped": n_pods} and groups == want_groups,
-                f"{steps}, {groups} groups carried, {want_groups} expected")
-    smoke.check("loadmix.one_program_a_cut_pass",
-                last <= SPREAD_GROUP_CAP // 2
-                and rows == {str(SPREAD_GROUP_CAP): want_cuts + 1},
-                f"{rows}, {last} Services in the last segment")
+                steps == {"grouped": n_pods} and groups == held,
+                f"{steps}, {groups} groups carried, {held} expected")
+    smoke.check("loadmix.on_the_wide_carry",
+                rows == {str(SPREAD_GROUP_WIDE): 1}, rows)
     smoke.check("loadmix.no_refusal",
                 not delta(fallback_counts(), f0), delta(fallback_counts(), f0))
-    smoke.check("loadmix.replay_parity",
-                launches == want_cuts + 1 and not mism,
+    smoke.check("loadmix.replay_parity", launches == 1 and not mism,
                 f"{launches} launches replayed"
                 + (f", {mism[:2]}" if mism else ""))
     return {"nodes": n, "pods": n_pods, "services": k,
-            "group_cuts": want_cuts, "last_segment_groups": last,
+            "services_in_the_pass": held, "carry_rows": rows,
             "device_ops": ops, "launches_replayed": launches}
 
 
@@ -813,6 +808,7 @@ def stage_serve_groups(smoke: Smoke):
     import random
     from kubernetes_tpu.core import tpu_scheduler as T
     from kubernetes_tpu.models.hollow import PodStrategy, make_pods as _pods
+    from kubernetes_tpu.ops.kernels import SPREAD_GROUP_CAP
     from kubernetes_tpu.scheduler import SEGMENT_CUTS, Scheduler
     from kubernetes_tpu.serve import ServeLoop
     from kubernetes_tpu.store.store import PODS, Store
@@ -840,12 +836,13 @@ def stage_serve_groups(smoke: Smoke):
     sched.algorithm._stack_pods = watched
     d0 = dispatch_counts()
     steps0, cuts0 = family(T.SCAN_SPREAD_STEPS), family(SEGMENT_CUTS)
+    rows0 = family(T.SCAN_SPREAD_CARRY_LAUNCHES)
     groups0 = T.SCAN_SPREAD_GROUPS.value
     f0 = fallback_counts()
     launches, mism, bound, want_cuts, want_groups = 0, [], 0, 0, 0
     for w, size in enumerate(windows):
         drawn = rng.choices(range(k), weights, k=size)
-        n_cuts, n_groups, _last = group_cuts(drawn)
+        n_cuts, n_groups, _last = group_cuts(drawn, SPREAD_GROUP_CAP)
         want_cuts += n_cuts
         want_groups += n_groups
         for pod, j in zip(_pods(PodStrategy(count=size,
@@ -863,6 +860,7 @@ def stage_serve_groups(smoke: Smoke):
     ops = dispatch_delta(d0)
     steps = delta(family(T.SCAN_SPREAD_STEPS), steps0)
     cuts = delta(family(SEGMENT_CUTS), cuts0)
+    rows = delta(family(T.SCAN_SPREAD_CARRY_LAUNCHES), rows0)
     groups = int(T.SCAN_SPREAD_GROUPS.value - groups0)
     smoke.check("serve_groups.all_bound", bound == sum(windows)
                 and all(p.node_name for p in store.list(PODS)[0]), bound)
@@ -873,6 +871,11 @@ def stage_serve_groups(smoke: Smoke):
                 want_cuts > 0 and cuts == {"groups": want_cuts,
                                            "end": len(windows)},
                 f"{cuts}, {want_cuts} expected")
+    # the loop's two scan programs, and not the closed loop's wide carry
+    smoke.check("serve_groups.on_the_caps_rows",
+                set(rows) <= {"1", str(SPREAD_GROUP_CAP)}
+                and rows.get(str(SPREAD_GROUP_CAP), 0) >= want_cuts
+                and sum(rows.values()) == len(windows) + want_cuts, rows)
     smoke.check("serve_groups.a_count_row_a_service",
                 set(steps) <= {"grouped", "single"}
                 and sum(steps.values()) == sum(windows)

@@ -149,7 +149,7 @@ SCAN_SPREAD_STEPS = obs.counter(
     "carries selector-spread counts: 'none' (no Service or ReplicaSet "
     "selects its pods), 'single' (one set of them selects every pod: one "
     "[N] count vector in the loop's carry) or 'grouped' (pods of 2 to "
-    "kernels.SPREAD_GROUP_CAP selector groups, or of 1 and up beside pods "
+    "spread_group_cap selector groups, or of 1 and up beside pods "
     "that nothing selects: one count row a group, a step reads its pod's "
     "row and adds a column). Booked once a launch, beside "
     "tpu_scan_steps_total.", ("carry",))
@@ -157,7 +157,7 @@ SCAN_SPREAD_GROUPS = obs.counter(
     "tpu_scan_spread_groups_total",
     "Selector groups whose spread counts schedule_burst's generic scan "
     "launches carried: 1 for a 'single' launch, the 1 to "
-    "kernels.SPREAD_GROUP_CAP count rows its pods read for a 'grouped' "
+    "spread_group_cap count rows its pods read for a 'grouped' "
     "one (the spare rows of the padded carry are not counted), nothing "
     "for 'none'. Booked once a launch, beside "
     "tpu_scan_spread_steps_total.")
@@ -165,12 +165,14 @@ SCAN_SPREAD_CARRY_LAUNCHES = obs.counter(
     "tpu_scan_spread_carry_launches_total",
     "schedule_burst's generic scan launches that carried selector-spread "
     "counts, by the padded row count of the launch's carry, which names "
-    "the scan program it ran: '1' (the one [N] vector) or '2', '4', '8', "
+    "the scan program it ran: '1' (the one [N] vector), '2', '4', '8', "
     "'16' (count rows, the spare ones included: a power of two of the "
-    "groups held, or kernels.SPREAD_GROUP_CAP behind a serve loop and for "
-    "a segment that follows a groups cut of its drain pass); nothing for "
-    "a launch that carries none. Booked once a launch, beside "
-    "tpu_scan_spread_groups_total.", ("rows",))
+    "groups held up to kernels.SPREAD_GROUP_CAP, which is also what every "
+    f"grouped launch behind a serve loop carries) or '{K.SPREAD_GROUP_WIDE}' "
+    "(kernels.SPREAD_GROUP_WIDE: a closed loop's launch of more groups "
+    "than 16, and a segment that follows a groups cut of its drain "
+    "pass); nothing for a launch that carries none. Booked once a "
+    "launch, beside tpu_scan_spread_groups_total.", ("rows",))
 SCAN_SPREAD_UNSELECTED_STEPS = obs.counter(
     "tpu_scan_spread_unselected_steps_total",
     "Steps of the 'grouped' launches of tpu_scan_spread_steps_total whose "
@@ -1323,13 +1325,27 @@ class TPUScheduler:
     # launch after the other: a chunk's decisions land before the next
     # chunk is dispatched.
     wave_size = 4096
-    # ... and a segment may hold pods of this many selector groups
-    # (Scheduler._schedule_singletons_burst; _spread_carry)
-    spread_group_cap = K.SPREAD_GROUP_CAP
     # cap on a uniform launch's chunk (None = B_CAP): the serve loop pins
     # it to its window size, so a window is one launch of one compiled
     # shape
     launch_cap: Optional[int] = None
+
+    @property
+    def spread_group_cap(self) -> int:
+        """Selector groups whose pods a segment may hold
+        (`Scheduler._schedule_singletons_burst` cuts there; `_spread_carry`
+        carries as many rows). A closed loop carries a whole pass's groups,
+        K.SPREAD_GROUP_WIDE. Behind a serve loop (`launch_cap` pinned) it
+        stays K.SPREAD_GROUP_CAP: a loop pads EVERY window's carry to its
+        cap so that no window compiles, which at the wide width is a
+        [128, 8192] int64 (8 MB) zero fill and upload for windows that
+        hold two or three groups (cell 10: 2.3, and no `groups` cut in its
+        window: PERF_LEDGER, PR 50), paid out of `startup_p50_ms` for
+        nothing; past a loop's knee, where windows hold hundreds of pods,
+        the wide carry would save cuts, and no cell sends that yet
+        (ROADMAP.md R2/R3)."""
+        return K.SPREAD_GROUP_CAP if self.launch_cap else K.SPREAD_GROUP_WIDE
+
     # pod-row cache (ops.pod_rows.PodRowCache), attached by the scheduler
     # shell: window planning gathers the interned signatures (and, in
     # tensor mode, the profile ids) stored at delivery instead of building
@@ -1578,15 +1594,18 @@ class TPUScheduler:
 
         One group and nothing else: (its [n_pad] vector, None), the launch
         every burst of one Service's pods has always been. Up to
-        K.SPREAD_GROUP_CAP groups: ([G_pad, n_pad] rows, (group of each pod
-        [len(feats)] int32, counts_for [G_pad, G_pad] bool)), G_pad a power
-        of two so that a stream of launches of about as many groups runs
-        one program; the spare rows are zero and no pod reads or moves
-        them. A pod that nothing selects (no counts, no group) rides the
-        rows with none of its own, under group index -1: a step's masked
-        sum over the group axis then reads it zeros and adds its binding
-        to no row, and zeros score SelectorSpread's constant bit for bit
-        on every node, zoned or not (`_fit_scores`; held by
+        `spread_group_cap` groups: ([G_pad, n_pad] rows, (group of each pod
+        [len(feats)] int32, counts_for [G_pad, G_pad] bool)); the spare
+        rows are zero and no pod reads or moves them. G_pad, which names
+        the scan program: up to K.SPREAD_GROUP_CAP groups a power of two,
+        so that a stream of launches of about as many groups runs one
+        program; above it (a closed loop alone) K.SPREAD_GROUP_WIDE
+        whatever the launch holds, 17 or 111, so a process meets ONE more
+        program. A pod that nothing selects (no counts, no group) rides
+        the rows with none of its own, under group index -1: a step's
+        masked sum over the group axis then reads it zeros and adds its
+        binding to no row, and zeros score SelectorSpread's constant bit
+        for bit on every node, zoned or not (`_fit_scores`; held by
         tests/test_grouped_spread_carry.py's
         test_zero_counts_score_the_inert_constant). Such a launch is
         always the rank-2 one, also beside one group: the rank-1 program
@@ -1627,13 +1646,14 @@ class TPUScheduler:
                 g = keys[f.spread_group] = len(rows)
                 rows.append(f.spread_counts)
             group_of_feat[id(f)] = g
+        cap = self.spread_group_cap
         full = full and not self.launch_cap
         if len(rows) == 1 and not unselected and not full:
             return rows[0], None
-        if len(rows) > K.SPREAD_GROUP_CAP:
+        if len(rows) > cap:
             return None
-        g_pad = K.SPREAD_GROUP_CAP if self.launch_cap or full \
-            else _pad_pow2(len(rows), 2)
+        g_pad = cap if self.launch_cap or full \
+            or len(rows) > K.SPREAD_GROUP_CAP else _pad_pow2(len(rows), 2)
         spread0 = np.zeros((g_pad, n_pad), np.int64)
         spread0[:len(rows)] = rows
         counts_for = np.zeros((g_pad, g_pad), bool)

@@ -724,14 +724,39 @@ SCORE_CLASS_CAP = 16
 # the two was measured; the bound is the size that was seen to win.
 SCORE_BOARD_MIN_ROWS = 16384
 
-# The generic scan carries the selector-spread counts of at most this many
-# selector groups a launch (a group: a namespace and the set of Services /
-# ReplicaSets that select a pod), one [n_pad] int64 row each. A step reads
-# its pod's row as the board's is read and adds one column; the cap is the
-# board's for the board's reasons: the carry's size and the programs a
-# process can compile (one per power of two: 2, 4, 8, 16; a launch of one
-# group carries one vector, the program of every launch before the rows).
+# The generic scan carries the selector-spread counts of a launch's selector
+# groups (a group: a namespace and the set of Services / ReplicaSets that
+# select a pod), one [n_pad] int64 row each; a step reads its pod's row as
+# the board's is read and adds one column. The carry has two widths. Up to
+# this many groups it is padded to a power of two (2, 4, 8, 16; a launch of
+# one group carries one vector, the program of every launch before the
+# rows), so a stream of launches of about as many groups runs one program.
+# It is also all a launch behind a serve loop carries
+# (`TPUScheduler.spread_group_cap`): a loop pads EVERY window's carry to its
+# cap so that no window compiles.
 SPREAD_GROUP_CAP = SCORE_CLASS_CAP
+
+# ... and a closed loop's launch of more groups than that carries this many
+# rows whatever it holds (17 or 111), so a process meets ONE more scan
+# program. Neither number is a semantic limit: the bindings are the serial
+# oracle's however a pass is cut. The shell ends a burst segment before the
+# pod whose group would be one more than a launch carries, and every segment
+# pays a snapshot, the pod table's upkeep, an encode, a stack, a launch, a
+# fetch and a commit wave: at 16 rows the load test's own 1000-pod pass (111
+# Services named, ~103 met a pass) was 43 segments of 51 ms of which the
+# device worked 3, 436 pods/s where a pass of eight Services read 3396
+# (PERF_LEDGER, PR 50); at 128 it is one segment and 3073 (PERF.md, PR 51).
+# Why 128: it holds that pass with room, and its price is the host's, a
+# [128, 8192] int64 (8 MB) zero fill and upload a launch; 256 read no faster
+# (3029 against 3062 pods/s on one seed, a traced pass 0.33 s against 0.30)
+# and no cell sends more groups. On the device the width costs nothing that
+# shows: a step reads its pod's row as a masked sum over the group axis at
+# every G_pad, and at 128 rows x 8192 a TPU v5e runs the step in 137.5 us,
+# the 8-row step of a pass of eight Services (137.5), where one dynamic
+# slice for the row and one for `counts_for[h]`, masked for the pod nothing
+# selects, ran it in 141.4, the 3.9 us under `filter` (PERF.md, PR 51: two
+# seeds, both orders).
+SPREAD_GROUP_WIDE = 128
 
 
 def score_classes(nz_cpu, nz_mem, n_pods):
@@ -818,7 +843,9 @@ def _batch_core(nodes, mut0, pods, n_pods, last_index, last_node_index,
     reads its pod's row as the board's row is read, hands it to
     `_cycle_core` as the one vector it has always scored, and after the
     fold adds `counts_for[h]` to column `sel`. The rank is a static shape:
-    a launch of one group is the rank-1 program, operand for operand."""
+    a launch of one group is the rank-1 program, operand for operand, and
+    so is G_pad: the same read and the same add at 2 rows and at
+    SPREAD_GROUP_WIDE."""
     if constrain is None:
         constrain = lambda v: v
     assert score_tab is None or wtab is None
@@ -874,7 +901,9 @@ def _batch_core(nodes, mut0, pods, n_pods, last_index, last_node_index,
         if grouped:
             with jax.named_scope("spread"):
                 # the pod's row, a masked sum over the group axis like the
-                # board's above
+                # board's above, at SPREAD_GROUP_WIDE rows too (a slice
+                # there is slower, above); group -1, the pod nothing
+                # selects, matches no row: it reads zeros and moves none
                 mine = jnp.arange(len(counts_for)) == pod["spread_group"]
                 pod = {**pod, "spread_counts": jnp.sum(
                     jnp.where(mine[:, None], spread, 0), axis=0)}
@@ -1000,7 +1029,7 @@ def schedule_batch(nodes, pods, last_index, last_node_index, num_to_find, n_real
     of Services / ReplicaSets selects every pod, or [G_pad, n_pad] with
     `spread_groups` = (group[B] int32, counts_for[G_pad, G_pad] bool), one
     row a selector group (`_batch_core`; G_pad a power of two from 2 to
-    SPREAD_GROUP_CAP).
+    SPREAD_GROUP_CAP, or SPREAD_GROUP_WIDE).
 
     `carry_in` = (mut_state, spread) chains a pipelined wave straight off
     the previous wave's device-resident carry (no host round trip):

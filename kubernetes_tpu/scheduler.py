@@ -91,8 +91,10 @@ SEGMENT_CUTS = obs.counter(
     "selects from one that nothing does where the algorithm carries both "
     "in a launch), groups (the next pod's selector group "
     "would be one more than the algorithm's spread_group_cap carries in a "
-    "launch), nominated (a nomination became active), unburstable (the "
-    "next pod carries volumes), end (the run it was handed was out of "
+    "launch: TPUScheduler's is kernels.SPREAD_GROUP_WIDE in a closed loop "
+    "and kernels.SPREAD_GROUP_CAP behind a serve loop), nominated (a "
+    "nomination became active), unburstable (the next pod carries "
+    "volumes), end (the run it was handed was out of "
     "pods). _burst_pass_planned books plan, in a pass that holds a gang, "
     "once each time it hands over a run before the pass is out of items "
     "because the next item goes the other way (a label-free burstable pod "

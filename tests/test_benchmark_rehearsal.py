@@ -43,8 +43,8 @@ BOARD = (ADAPTIVE, MIXED)
 # The colocated cell takes the load cell's sizes: its mix names the same
 # eight Services, and Jobs' pods that nothing selects between their replicas.
 # The loadmix cell takes the same 250 nodes and 120 Services, 111 of which its
-# mix names, and passes of 250 pods: about 80 Services a pass, so the shell
-# cuts every pass about eleven times at the 16-group cap.
+# mix names, and passes of 250 pods: about 80 Services a pass, more than the
+# power-of-two carry's 16 rows, so every pass is one launch on the wide carry.
 AT_50 = {"nodes": {"count": 50},
          "check": {"first_binds": 200, "sampled_binds": 60}}
 SMALL = {
@@ -320,9 +320,10 @@ def test_rehearsed_cell(monkeypatch, execute, cell, seed, hook, program):
         assert counter_metric("pod_rows_signature_only_per_pod.backlog",
                               res, rep, pods, whole) == 1.0
     if cell == LOADMIX:
+        from kubernetes_tpu.ops.kernels import SPREAD_GROUP_WIDE
         moved = rep["counters"]
         pods = res["attempted"]
-        # every segment goes to the scan with a count row a Service it
+        # every pass goes to the scan whole, with a count row a Service it
         # holds, none is refused, and no pod goes uncounted
         assert "tpu_oracle_fallback_total" not in moved
         assert "burst_uniform" not in moved["tpu_device_dispatch_total"]
@@ -331,37 +332,38 @@ def test_rehearsed_cell(monkeypatch, execute, cell, seed, hook, program):
         assert moved["tpu_scan_spread_steps_total"] == {"grouped": pods}
         launches = moved["tpu_device_dispatch_total"]["burst_scan"]
         backlog = SMALL[LOADMIX][1]["backlog"]
-        assert launches > 8 * pods / backlog
+        assert launches == pods / backlog
         assert counter_metric("pods_per_dispatch.backlog", res, rep) \
-            == pods / launches
+            == backlog
         # one count pass and one lookup in the selector index a Service a
-        # segment, the lookup testing the one Service under the pod's label
+        # pass, the lookup testing the one Service under the pod's label
         groups = moved["tpu_scan_spread_groups_total"][""]
         assert groups == moved["tpu_spread_count_encodes_total"][""]
         for name in ("spread_groups_per_pod.backlog",
                      "spread_encodes_per_pod.backlog",
                      "selector_services_tested_per_pod.backlog"):
             assert counter_metric(name, res, rep) == groups / pods
-        assert 16 * (launches - pods / backlog) < groups <= 16 * launches
-        # a cut pass runs one scan program: every carry has the cap's rows,
-        # whatever the pass's last segment held (no compile in the window,
-        # above: the warm-up's first segment met the program)
+        assert 16 * launches < groups <= 111 * launches
+        # a pass of more Services than the narrow carry's 16 rows is one launch
+        # on the wide carry, whatever it holds: one scan program (no compile
+        # in the window, above: the warm-up's passes met it); the metric
+        # that read the 16-row label has nothing left to count
         assert moved["tpu_scan_spread_carry_launches_total"] == \
-            {"16": launches}
-        assert counter_metric("spread_carry_full_launch_share.backlog",
+            {str(SPREAD_GROUP_WIDE): launches}
+        assert counter_metric("spread_carry_wide_launch_share.backlog",
                               res, rep) == 100.0
+        assert counter_metric("spread_carry_full_launch_share.backlog",
+                              res, rep) == 0.0
         # the shell's side, over warm-up (two cycles) and window: a pass is
-        # cut where a 17th Service comes and ends where it is out of pods
+        # never cut and ends where it is out of pods
         whole = counters.delta(counters.snapshot(), before)
         pods = 2 * backlog + res["attempted"]
         cuts = whole["scheduler_burst_segment_cuts_total"]
-        assert set(cuts) == {("groups",), ("end",)}
-        assert cuts[("end",)] == pods / backlog
-        assert cuts[("groups",)] + cuts[("end",)] == \
-            whole["tpu_device_dispatch_total"][("burst_scan",)]
-        assert counter_metric("segment_group_cuts_per_pod.backlog", res, rep,
-                              pods, whole) == cuts[("groups",)] / pods > 0.03
-        for name in ("segment_plan_cuts_per_pod.backlog",
+        assert cuts == {("end",): pods / backlog}
+        assert whole["tpu_device_dispatch_total"][("burst_scan",)] \
+            == pods / backlog
+        for name in ("segment_group_cuts_per_pod.backlog",
+                     "segment_plan_cuts_per_pod.backlog",
                      "segment_class_cuts_per_pod.backlog"):
             assert counter_metric(name, res, rep, pods, whole) == 0.0
     if cell == SERVICES:

@@ -6,10 +6,14 @@ then carries one count row a group (`TPUScheduler._spread_carry`,
 `kernels._batch_core`): a step scores its pod against its group's row, and a
 bound pod is added to every row whose selectors all match it. A pod that
 nothing selects rides the rows with none of its own (group index -1): it
-reads zeros, which score SelectorSpread's constant, and moves no row. Every
-case here is a drain through the normal shell compared, binding for binding,
-with the serial oracle's one cycle a pod; what the shell and the launch did
-is read off their counters.
+reads zeros, which score SelectorSpread's constant, and moves no row. The
+carry has two widths: up to `kernels.SPREAD_GROUP_CAP` groups a power of two
+of rows; above it, in a closed loop, `kernels.SPREAD_GROUP_WIDE` rows
+whatever the launch holds; a step reads its pod's row as a masked sum at
+either.
+Every case here is a drain through the normal shell compared, binding for
+binding, with the serial oracle's one cycle a pod; what the shell and the
+launch did is read off their counters.
 """
 import random
 
@@ -19,8 +23,9 @@ import pytest
 from kubernetes_tpu.api.types import (
     Container, LABEL_HOSTNAME, LabelSelector, Node, Pod, ReplicaSet, Service)
 from kubernetes_tpu.core.tpu_scheduler import (
-    ORACLE_FALLBACKS, SCAN_POD_ROWS, SCAN_SPREAD_GROUPS, SCAN_SPREAD_STEPS,
-    SCAN_SPREAD_UNSELECTED_STEPS, TPUScheduler)
+    ORACLE_FALLBACKS, SCAN_POD_ROWS, SCAN_SPREAD_CARRY_LAUNCHES,
+    SCAN_SPREAD_GROUPS, SCAN_SPREAD_STEPS, SCAN_SPREAD_UNSELECTED_STEPS,
+    TPUScheduler)
 from kubernetes_tpu.cache.node_info import NodeInfo
 from kubernetes_tpu.coscheduling.types import LABEL_POD_GROUP, PodGroup
 from kubernetes_tpu.ops import kernels as K
@@ -37,6 +42,8 @@ UNEVEN = 130           # zones of 44/43/43: the NodeTree's order rotates
 EVEN = 129             # 43/43/43: every cycle walks the device axis
 CAUSES = ("plan", "class", "groups", "nominated", "unburstable", "end")
 CARRIES = ("none", "single", "grouped")
+CAP, WIDE = K.SPREAD_GROUP_CAP, K.SPREAD_GROUP_WIDE
+ROWS = ("1", "2", "4", "8", str(CAP), str(WIDE))
 
 
 def box(cpu=100):
@@ -191,12 +198,61 @@ class NoNodeMidLaunch(Interleaved):
         return pods
 
 
-class SeventeenServices(World):
-    """More Services in a pass than a launch carries rows for."""
+class ManyServices(World):
+    """`k` Services' pods in one pass, a pod of each in turn: more than the
+    narrow carry's `CAP` rows hold, and (`WIDE` + 1) more than a launch does."""
+    max_pods = 256
+
+    def __init__(self, k: int = 17, n_pods: int = 40):
+        self.k, self.n_pods = k, n_pods
+
+    def selectors(self, s):
+        for j in range(max(20, self.k)):
+            s.create(SERVICES, Service(name=f"svc-{j}",
+                                       selector={"app": f"svc-{j}"}))
+
+    def resident_labels(self, rng):
+        return "default", {"app": f"svc-{rng.randrange(self.k)}"}
 
     def pending(self, rng):
         return [Pod(name=f"p{j:03d}", containers=box(),
-                    labels={"app": f"svc-{j % 17}"}) for j in range(40)]
+                    labels={"app": f"svc-{j % self.k}"})
+                for j in range(self.n_pods)]
+
+
+class WideWithUnselected(ManyServices):
+    """Twenty Services' pods, the first twenty one of each, so the launch
+    is a wide one; then, drawn pod by pod: three in ten pods that nothing
+    selects (group -1, which an indexed read would clamp onto row 0), a quarter
+    svc-0's, whose residents are many (row 0 is not zeros), and pods that
+    svc-18 and `web` both select beside pods of either alone (`counts_for`
+    off the diagonal at rows past the narrow carry's)."""
+
+    def __init__(self):
+        super().__init__(k=20, n_pods=72)
+
+    def selectors(self, s):
+        super().selectors(s)
+        s.create(SERVICES, Service(name="web", selector={"tier": "web"}))
+
+    def resident_labels(self, rng):
+        if rng.random() < 0.5:
+            return "default", {"app": "svc-0"}
+        return "default", dict(rng.choice((
+            {"tier": "web"}, {"app": "svc-18", "tier": "web"},
+            {"app": f"svc-{rng.randrange(20)}"})))
+
+    def pending(self, rng):
+        def draw(j):
+            r = rng.random()
+            if r < 0.3:
+                return Pod(name=f"p{j:03d}",
+                           containers=box(rng.choice((100, 300))))
+            labels = {"app": "svc-0"} if r < 0.55 else dict(rng.choice((
+                {"tier": "web"}, {"app": "svc-18", "tier": "web"},
+                {"app": "svc-18"}, {"app": f"svc-{rng.randrange(20)}"})))
+            return Pod(name=f"p{j:03d}", containers=box(), labels=labels)
+        return super().pending(rng)[:20] + [draw(j) for j in range(20, 72)]
 
 
 class PlainBetween(Interleaved):
@@ -295,6 +351,8 @@ def counters() -> dict:
     out = {("cut", c): SEGMENT_CUTS.labels(c).value for c in CAUSES}
     out.update({("steps", c): SCAN_SPREAD_STEPS.labels(c).value
                 for c in CARRIES})
+    out.update({("carry", r): SCAN_SPREAD_CARRY_LAUNCHES.labels(r).value
+                for r in ROWS})
     out["stacked"] = SCAN_POD_ROWS.labels("stacked").value
     out["refused"] = ORACLE_FALLBACKS.labels("burst-spread-mixed").value
     out["rows"] = SCAN_SPREAD_GROUPS.value
@@ -387,20 +445,74 @@ def test_pod_without_a_node_mid_launch(percentage):
 
 
 @pytest.mark.parametrize("percentage", [0, 100])
-def test_more_services_than_rows_cut_the_segment(percentage):
-    world = SeventeenServices()
-    want = serial(world, 5, percentage)
+@pytest.mark.parametrize("k,n_pods,want", [
+    (CAP + 1, 40, [40]), (2 * CAP + 1, 70, [70]), (111, 130, [130]),
+    (WIDE, WIDE + 12, [WIDE + 12]), (WIDE + 1, WIDE + 12, [WIDE, 12])])
+def test_more_services_than_rows_cut_the_segment(k, n_pods, want,
+                                                 percentage):
+    """A closed loop's pass of 17, 33, 111 or `WIDE` Services is ONE segment
+    and one launch on the wide carry; the Service that would be one more
+    than it carries opens the next segment, which runs the same program."""
+    world = ManyServices(k, n_pods)
+    bound = serial(world, 5, percentage)
     got, segments, moved = drained(world, 5, percentage)
-    assert got == want
-    # pods 0..15 are sixteen Services'; the 17th opens the next segment,
-    # which holds sixteen again before svc-15 returns
-    assert [len(seg) for seg in segments] == [16, 16, 8]
-    assert all(len(groups_of(seg)) <= K.SPREAD_GROUP_CAP
-               for seg in segments)
-    assert moved[("cut", "groups")] == 2 and moved[("cut", "end")] == 1
-    assert moved[("cut", "class")] == 0
-    assert moved[("steps", "grouped")] == 40
+    assert got == bound
+    assert [len(seg) for seg in segments] == want
+    assert all(len(groups_of(seg)) <= WIDE for seg in segments)
+    assert moved[("cut", "groups")] == len(want) - 1
+    assert moved[("cut", "end")] == 1 and moved[("cut", "class")] == 0
+    assert moved[("steps", "grouped")] == n_pods
+    assert moved["rows"] == sum(len(groups_of(seg)) for seg in segments)
+    assert {r: moved[("carry", r)] for r in ROWS if moved[("carry", r)]} \
+        == {str(WIDE): len(want)}
     assert moved["refused"] == 0
+
+
+@pytest.mark.parametrize("mesh", [None, 4], ids=["one-device", "mesh-4"])
+@pytest.mark.parametrize("percentage", [0, 100])
+def test_on_the_wide_carry_an_unselected_pod_moves_no_row(percentage, mesh,
+                                                          monkeypatch):
+    """A wide launch whose pods are Services' and pods that nothing selects
+    (group -1) beside a heavy group 0: the bindings are the serial oracle's
+    (a -1 clamped onto row 0 would score svc-0's counts), the carry the
+    launch returns has moved by `counts_for[h]` at the column of each bound
+    pod of a group h and by nothing for a pod of none, and `counts_for`
+    holds pairs off its diagonal at rows past the narrow carry's."""
+    launches = []
+    real = K.schedule_batch
+
+    def spy(*a, **kw):
+        out = real(*a, **kw)
+        launches.append((kw["spread0"], kw["spread_groups"], kw["n_pods"],
+                         np.asarray(out[3]), np.asarray(out[4]["selected"])))
+        return out
+
+    world = WideWithUnselected()
+    want = serial(world, 5, percentage)
+    assert all(node for _key, node in want)
+    monkeypatch.setattr(K, "schedule_batch", spy)
+    got, segments, moved = drained(
+        world, 5, percentage, mesh=S.make_mesh(mesh) if mesh else None)
+    assert got == want
+    assert [len(seg) for seg in segments] == [72]
+    ((spread0, (group, counts_for), n_pods, spread, selected),) = launches
+    assert spread0.shape[0] == WIDE and counts_for.shape == (WIDE, WIDE)
+    held = int(group.max()) + 1
+    assert held > CAP + 2 and n_pods == 72
+    bare = [not p.labels for p in segments[0]]
+    assert (group[:72] == -1).tolist() == bare and 10 < sum(bare) < 35
+    assert (group[:72] == 0).sum() > 8 and spread0[0].sum() > 40
+    off = counts_for & ~np.eye(WIDE, dtype=bool)
+    rows, cols = np.nonzero(off)
+    assert len(rows) == 2 and (rows >= CAP).all() and (cols >= CAP).all()
+    assert not counts_for[held:].any() and not counts_for[:, held:].any()
+    moved_by = np.zeros_like(spread0)
+    for g, sel in zip(group[:72], selected[:72]):
+        if g >= 0:
+            moved_by[:, sel] += counts_for[g]
+    assert (spread - spread0 == moved_by).all()
+    assert moved["unselected"] == sum(bare) and moved["rows"] == held
+    assert moved[("carry", str(WIDE))] == 1 and moved["refused"] == 0
 
 
 @pytest.mark.parametrize("percentage", [0, 100])
@@ -552,8 +664,11 @@ def test_behind_a_serve_loop_the_mix_carries_the_caps_rows(percentage,
 
 @pytest.mark.parametrize("launch_cap,groups,bare,want", [
     (None, 1, 0, None), (None, 1, 1, 2), (None, 2, 1, 2), (None, 3, 2, 4),
-    (None, 8, 1, 8), (None, 16, 3, 16), (None, 17, 1, "refused"),
-    (2048, 1, 0, None), (2048, 1, 1, 16), (2048, 5, 1, 16)])
+    (None, 8, 1, 8), (None, CAP, 3, CAP), (None, CAP + 1, 1, WIDE),
+    (None, 111, 2, WIDE), (None, WIDE, 1, WIDE),
+    (None, WIDE + 1, 1, "refused"),
+    (2048, 1, 0, None), (2048, 1, 1, CAP), (2048, 5, 1, CAP),
+    (2048, CAP + 1, 1, "refused")])
 def test_the_carrys_rows_by_what_the_launch_holds(launch_cap, groups, bare,
                                                   want):
     """`_spread_carry` alone: `groups` selector groups and `bare` unselected
@@ -668,8 +783,12 @@ def test_a_gang_in_the_pass_keeps_the_planners_cuts(world, percentage):
     assert moved["refused"] == 0
 
 
-class ThreeAndBare(SeventeenServices):
-    """Three Services' pods and one that nothing selects."""
+class ThreeAndBare(ManyServices):
+    """Three Services' pods and one that nothing selects, on a cluster of
+    more Services than a launch carries rows for."""
+
+    def __init__(self):
+        super().__init__(k=WIDE + 1)
 
     def pending(self, rng):
         return [Pod(name=f"q{j}", containers=box(),
@@ -690,10 +809,10 @@ def test_the_seam_refuses_more_groups_than_rows_and_carries_the_mix():
     snap = sched.cache.update_snapshot(sched._snapshot)
     names = sched.cache.node_tree.list_names()
     many = [Pod(name=f"r{j}", containers=box(),
-                labels={"app": f"svc-{j}"}) for j in range(17)]
+                labels={"app": f"svc-{j}"}) for j in range(WIDE + 1)]
     before = counters()
     assert sched.algorithm.schedule_burst(
-        many, snap.node_infos, names, bucket=32) is None
+        many, snap.node_infos, names, bucket=256) is None
     assert counters()["refused"] == before["refused"] + 1
     before = counters()
     mixed = world.pending(None)
@@ -706,22 +825,35 @@ def test_the_seam_refuses_more_groups_than_rows_and_carries_the_mix():
     assert after[("steps", "grouped")] - before[("steps", "grouped")] == 4
     assert after["unselected"] - before["unselected"] == 1
     assert after["rows"] - before["rows"] == 3
-    # as many groups as rows are carried
+    # as many groups as rows are carried; behind a serve loop the rows
+    # are the narrow carry's
     assert sched.algorithm.schedule_burst(
-        many[:16], snap.node_infos, names, bucket=32) is not None
+        many[:WIDE], snap.node_infos, names, bucket=256) is not None
+    sched.algorithm.discard_burst_folds()
+    sched.algorithm.launch_cap = 256
+    before = counters()
+    assert sched.algorithm.schedule_burst(
+        many[:CAP + 1], snap.node_infos, names, bucket=256) is None
+    assert counters()["refused"] == before["refused"] + 1
+    assert sched.algorithm.schedule_burst(
+        many[:CAP], snap.node_infos, names, bucket=256) is not None
 
 
 @pytest.mark.parametrize("percentage", [0, 100])
-def test_grouped_launch_on_a_mesh_of_four(percentage):
+@pytest.mark.parametrize("world,rows", [
+    (Interleaved(), "8"), (ManyServices(2 * CAP + 1, 70), str(WIDE))],
+    ids=["eight-rows", "wide"])
+def test_grouped_launch_on_a_mesh_of_four(world, rows, percentage):
     """The same launch with the node axis over four of the virtual host
-    devices: the count rows are pinned on their last axis."""
+    devices: the count rows are pinned on their last axis, at a power of
+    two of rows and at the wide carry's."""
     mesh = S.make_mesh(4)
-    world = Interleaved()
     want = serial(world, 5, percentage)
     got, segments, moved = drained(world, 5, percentage, mesh=mesh)
     assert got == want
     assert len(segments) == 1
-    assert moved[("steps", "grouped")] == 48
+    assert moved[("steps", "grouped")] == len(segments[0])
+    assert moved[("carry", rows)] == 1
     assert moved["refused"] == 0
 
 

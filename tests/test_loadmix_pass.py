@@ -1,4 +1,4 @@
-"""A drain pass that holds far more Services' pods than one launch carries.
+"""A drain pass that holds the pods of a hundred Services.
 
 Such a pass is what the scheduler's queue holds when SIG-scalability's load
 test creates its controllers (the benchmark's cell
@@ -6,16 +6,22 @@ test creates its controllers (the benchmark's cell
 eight of 30 and 102 of 5, each behind a Service, their pods interleaved in
 creation order), or when a node-pool drain hands the scheduler the pods of a
 hundred Deployments. `Scheduler._schedule_singletons_burst` ends a burst
-segment before the pod whose selector group would be the 17th
-(`kernels.SPREAD_GROUP_CAP`), so a pass is many segments, each a launch with
-one count row a Service, and the pass's last segment holds whatever groups
-are left, 1 to 16. Held here, on the cell's own data files at a small size:
-every binding is the serial oracle's and the benchmark's plain reference's
+segment before the pod whose selector group would be one more than the
+algorithm's `spread_group_cap` carries in a launch, and that cap has two
+widths (`TPUScheduler.spread_group_cap`). A closed loop carries
+`kernels.SPREAD_GROUP_WIDE` count rows: the pass is ONE segment and one
+launch, its carry padded to that width whatever it holds above
+`kernels.SPREAD_GROUP_CAP` groups (17 or 111), so a process meets one scan
+program more; a pass of still more groups is cut there, and the segments
+after the cut run the same program. Behind a serve loop (`launch_cap`
+pinned) the cut stays at `SPREAD_GROUP_CAP` and every grouped carry has that
+many rows. Held here, on the cell's own data files at a small size: every
+binding is the serial oracle's and the benchmark's plain reference's
 however the pass is cut; the `groups` cuts are what the cut rule gives for
-the pod order; every segment after a cut pads its carry to the cap's rows,
-so a cut pass runs ONE scan program whatever its last segment holds
-(`tpu_scan_spread_carry_launches_total{rows}`); and a pass that is never cut
-(cells 9 and 11) keeps the power-of-two carry it had.
+the pod order; the carries' rows are the width's
+(`tpu_scan_spread_carry_launches_total{rows}`); and a pass of at most
+`SPREAD_GROUP_CAP` groups (cells 9 and 11) keeps the power-of-two carry it
+had.
 """
 import os
 import sys
@@ -45,11 +51,12 @@ SMALL = {"nodes": {"count": N_NODES},
 N_PODS = 250
 MAX_PODS = 256          # so the 250 pods are one drain pass
 CAUSES = ("plan", "class", "groups", "nominated", "unburstable", "end")
-ROWS = ("1", "2", "4", "8", "16")
-CAP = K.SPREAD_GROUP_CAP
-# a seed for every carry a last segment would run unpadded: the groups its
-# pods hold (1, 2, 3-4, 5-8, 9-16) name the vector program and the four
-# count-row ones (test_the_seeds_meet_every_carry_size holds them to it)
+CAP, WIDE = K.SPREAD_GROUP_CAP, K.SPREAD_GROUP_WIDE
+ROWS = ("1", "2", "4", "8", str(CAP), str(WIDE))
+# a seed for every carry the last segment of a pass cut at CAP groups would
+# run unpadded: the groups its pods hold (1, 2, 3-4, 5-8, 9-16) name the
+# vector program and the four count-row ones
+# (test_the_seeds_meet_every_carry_size holds them to it)
 SEEDS = {2**31 + 20: 1, 6: 2, 2**31 + 45: 4, 2**31 + 8: 8, 2**31 + 5: 16}
 
 
@@ -86,14 +93,15 @@ def world(bench, cfg: dict, traffic: dict, seed: int, n_pods: int = N_PODS):
     return store, rows, residents, services, made
 
 
-def cut_rule(apps: list) -> tuple:
+def cut_rule(apps: list, cap: int = CAP) -> tuple:
     """What `_schedule_singletons_burst` makes of one pass whose pods are
-    each selected by the one Service `apps` names: (`groups` cuts, distinct
-    Services summed over the segments, Services of the last segment)."""
+    each selected by the one Service `apps` names, where a launch carries
+    `cap` groups: (`groups` cuts, distinct Services summed over the
+    segments, Services of the last segment)."""
     cuts = groups = 0
     seen: set = set()
     for app in apps:
-        if app not in seen and len(seen) == CAP:
+        if app not in seen and len(seen) == cap:
             cuts += 1
             groups += len(seen)
             seen = set()
@@ -124,8 +132,8 @@ def delta(before: dict) -> dict:
     return {k: v - before[k] for k, v in counters().items()}
 
 
-def serial_oracle(bench, cfg, traffic, seed, n_pods=N_PODS) -> dict:
-    store, *_rest, made = world(bench, cfg, traffic, seed, n_pods)
+def oracle_drain(store, made: list) -> dict:
+    """The bindings of the serial oracle, one cycle a pod of `made`."""
     oracle = Scheduler(store, use_tpu=False, percentage_of_nodes_to_score=0)
     oracle.sync()
     store.create_many(PODS, [p for p, _d in made])
@@ -134,14 +142,21 @@ def serial_oracle(bench, cfg, traffic, seed, n_pods=N_PODS) -> dict:
         pass
     oracle.pump()
     want = bindings(store)
-    assert len(want) == n_pods and all(want.values())
+    assert len(want) == len(made) and all(want.values())
     return want
 
 
+def serial_oracle(bench, cfg, traffic, seed, n_pods=N_PODS) -> dict:
+    store, *_rest, made = world(bench, cfg, traffic, seed, n_pods)
+    return oracle_drain(store, made)
+
+
 def test_the_seeds_meet_every_carry_size(bench):
-    """Over the parametrised seeds the last segment of the pass holds 1, 2,
-    3-4, 5-8 and 9-16 Services: unpadded, the vector program and each of
-    the four count-row programs; and every pass is cut many times."""
+    """Over the parametrised seeds the last segment of the pass, cut at
+    `CAP` groups as behind a serve loop, holds 1, 2, 3-4, 5-8 and 9-16
+    Services: unpadded, the vector program and each of the four count-row
+    programs; every pass is cut many times there, and holds fewer groups
+    than a closed loop's launch carries."""
     _cfg, traffic = files(bench["spec"])
     for seed, rows in SEEDS.items():
         factory = bench["PodFactory"](traffic, 120, seed)
@@ -149,22 +164,18 @@ def test_the_seeds_meet_every_carry_size(bench):
         apps = [dict(factory.make(f"p-{j}")[1]["labels"])["app"]
                 for j in range(N_PODS)]
         cuts, _groups, last = cut_rule(apps)
-        assert cuts >= 8 and len(set(apps)) > 4 * CAP
+        assert cuts >= 8 and 4 * CAP < len(set(apps)) <= WIDE
         assert rows == (1 if last == 1
                         else max(2, 1 << (last - 1).bit_length()))
 
 
-@pytest.mark.parametrize("seed", list(SEEDS))
-def test_loadmix_pass_binds_as_oracle_and_reference(bench, seed):
-    cfg, traffic = files(bench["spec"])
-    want = serial_oracle(bench, cfg, traffic, seed)
-
-    # the normal drain pass
-    store, rows, residents, services, made = world(bench, cfg, traffic, seed)
-    desc_of = {p.name: d for p, d in made}
-    app_of = {p.name: p.labels["app"] for p, _d in made}
-    sched = Scheduler(store, use_tpu=True, percentage_of_nodes_to_score=0)
+def drain_watched(sched, store, made, serve: bool):
+    """The normal drain pass over `made`, a closed loop's or (`serve`) with
+    the launch cap a serve loop pins: (bindings, the segments as (run,
+    full_carry, pod names), what the counters moved by)."""
     sched.sync()
+    if serve:
+        sched.algorithm.launch_cap = MAX_PODS      # what ServeLoop sets
     store.create_many(PODS, [p for p, _d in made])
     sched.pump()
     segments = []
@@ -179,11 +190,15 @@ def test_loadmix_pass_binds_as_oracle_and_reference(bench, seed):
     while sched.schedule_burst(max_pods=MAX_PODS):
         pass
     sched.pump()
-    got = bindings(store)
-    assert got == want
+    return bindings(store), segments, delta(before)
 
-    # the benchmark's plain reference, given the binds in the order the
-    # queue popped the pods, which is the order they were created in
+
+def held_to_the_reference(bench, cfg, rows, residents, services, made,
+                          segments, got) -> list:
+    """The benchmark's plain reference, given the binds in the order the
+    queue popped the pods, which is the order they were created in;
+    returns that order."""
+    desc_of = {p.name: d for p, d in made}
     popped = [name for _run, _full, names in segments for name in names]
     assert popped == [p.name for p, _d in made]
     ref = bench["check"].make_reference(cfg, rows, residents, services)
@@ -191,28 +206,101 @@ def test_loadmix_pass_binds_as_oracle_and_reference(bench, seed):
     for name in popped:
         assert ref.decide(desc_of[name]) == got[name], name
         ref.place(desc_of[name], got[name])
+    return popped
 
-    # how the pass was cut: where the 17th Service of a segment came, and
-    # nowhere else; every segment is one launch with a count row a Service
-    cuts, groups, last = cut_rule([app_of[name] for name in popped])
-    d = delta(before)
+
+@pytest.mark.parametrize("loop", ["closed", "serve"])
+@pytest.mark.parametrize("seed", list(SEEDS))
+def test_loadmix_pass_binds_as_oracle_and_reference(bench, seed, loop):
+    cfg, traffic = files(bench["spec"])
+    want = serial_oracle(bench, cfg, traffic, seed)
+
+    store, rows, residents, services, made = world(bench, cfg, traffic, seed)
+    app_of = {p.name: p.labels["app"] for p, _d in made}
+    sched = Scheduler(store, use_tpu=True, percentage_of_nodes_to_score=0)
+    got, segments, d = drain_watched(sched, store, made, loop == "serve")
+    assert got == want
+    popped = held_to_the_reference(bench, cfg, rows, residents, services,
+                                   made, segments, got)
+
+    # how the pass was cut: a closed loop's not at all (its launch carries
+    # the pass's Services with room), a serve loop's where the 17th Service
+    # of a segment came, and nowhere else; every segment is one launch with
+    # a count row a Service
+    cap = CAP if loop == "serve" else WIDE
+    cuts, groups, last = cut_rule([app_of[name] for name in popped], cap)
+    assert cuts == 0 if loop == "closed" else cuts >= 8
     assert {c: d[("cut", c)] for c in CAUSES} == {
         "plan": 0, "class": 0, "groups": cuts, "nominated": 0,
         "unburstable": 0, "end": 1}
     assert len(segments) == cuts + 1 == d["launches"]
     assert all(run == "spread" for run, _full, _names in segments)
     for _run, _full, names in segments[:-1]:
-        assert len({app_of[name] for name in names}) == CAP
+        assert len({app_of[name] for name in names}) == cap
     assert len({app_of[name] for name in segments[-1][2]}) == last
     assert d["groups"] == groups and d["refused"] == 0
-    # the segments after the first cut pad their carry, so the last one,
-    # whatever it holds (a seed a carry size), runs the cut ones' program:
-    # the counter's labels sum to the carrying launches, all under 16
     assert [full for _run, full, _names in segments] == [False] + [True] * cuts
-    assert {r: d[("rows", r)] for r in ROWS} == {
-        "1": 0, "2": 0, "4": 0, "8": 0, "16": cuts + 1}
-    assert d[("steps", "grouped")] == N_PODS
-    assert d[("steps", "single")] == d[("steps", "none")] == 0
+    # the counter's labels sum to the carrying launches: a closed loop's one
+    # on the wide carry; a serve loop's on its cap's rows whatever a segment
+    # holds (a seed a carry size), but the one vector for one Service, its
+    # two programs
+    vector = int(loop == "serve" and last == 1)
+    assert {r: d[("rows", r)] for r in ROWS if d[("rows", r)]} == {
+        k: v for k, v in ((str(cap), cuts + 1 - vector), ("1", vector)) if v}
+    assert d[("steps", "grouped")] == N_PODS - (
+        len(segments[-1][2]) if vector else 0)
+    assert d[("steps", "single")] == (len(segments[-1][2]) if vector else 0)
+    assert d[("steps", "none")] == 0
+
+
+def dealt(bench, traffic: dict, k: int, n_pods: int) -> list:
+    """`n_pods` pods of the mix's shape, pod j a replica of Service j mod k:
+    a pass of exactly `k` selector groups."""
+    shape = traffic["pod_shapes"][0]
+    factories = [bench["PodFactory"](
+        {**traffic, "pod_shapes": [{
+            **shape, "share": 1.0,
+            "labels": bench["cluster"].service_label(j)}]}, k, 0)
+        for j in range(k)]
+    return [factories[j % k].make(f"p-{j:03d}") for j in range(n_pods)]
+
+
+@pytest.mark.parametrize("k,n_pods,want", [
+    (CAP + 1, 60, [60]), (2 * CAP + 1, 80, [80]), (111, 140, [140]),
+    (WIDE + 1, WIDE + 20, [WIDE, 20])])
+def test_a_closed_loops_pass_of_k_groups(bench, k, n_pods, want):
+    """A closed loop's pass of 17, 33 and 111 selector groups is ONE segment
+    on the wide carry; one of a group more than it carries is cut once, and
+    both segments run the wide program. Every binding is the serial
+    oracle's and the benchmark's plain reference's."""
+    seed = 2**31 + 51
+    spec = bench["spec"]
+    cfg, traffic = files(spec)
+    cfg = spec.overlaid(cfg, {"resident": {"services": WIDE + 12}})
+
+    def built():
+        store, rows, residents, services = bench["cluster"].build(cfg, seed)
+        return store, rows, residents, services, dealt(bench, traffic, k,
+                                                       n_pods)
+
+    store, *_rest, made = built()
+    bound = oracle_drain(store, made)
+
+    store, rows, residents, services, made = built()
+    sched = Scheduler(store, use_tpu=True, percentage_of_nodes_to_score=0)
+    got, segments, d = drain_watched(sched, store, made, serve=False)
+    assert got == bound
+    held_to_the_reference(bench, cfg, rows, residents, services, made,
+                          segments, got)
+    assert [len(names) for _run, _full, names in segments] == want
+    assert [full for _run, full, _names in segments] \
+        == [False] + [True] * (len(want) - 1)
+    assert d[("cut", "groups")] == len(want) - 1 and d[("cut", "end")] == 1
+    assert d["launches"] == len(want) and d["refused"] == 0
+    assert d["groups"] == sum(min(k, n) for n in want)
+    assert {r: d[("rows", r)] for r in ROWS if d[("rows", r)]} \
+        == {str(WIDE): len(want)}
+    assert d[("steps", "grouped")] == n_pods
 
 
 @pytest.mark.parametrize("cell,n_pods,rows", [
@@ -245,14 +333,19 @@ def test_a_pass_that_is_not_cut_keeps_its_power_of_two_carry(bench, cell,
 
 
 @pytest.mark.parametrize("launch_cap,full,groups,want", [
-    (None, True, 1, CAP), (None, True, 2, CAP), (None, True, 5, CAP),
-    (None, True, CAP, CAP), (None, True, CAP + 1, "refused"),
-    (None, False, 1, None), (None, False, 5, 8),
+    (None, True, 1, WIDE), (None, True, 2, WIDE), (None, True, 5, WIDE),
+    (None, True, CAP, WIDE), (None, True, CAP + 1, WIDE),
+    (None, True, WIDE, WIDE), (None, True, WIDE + 1, "refused"),
+    (None, False, 1, None), (None, False, 5, 8), (None, False, CAP, CAP),
+    (None, False, CAP + 1, WIDE), (None, False, 111, WIDE),
     # a serve loop's two programs stand: the vector for one group
-    (2048, True, 1, None), (2048, True, 5, CAP), (2048, False, 5, CAP)])
+    (2048, True, 1, None), (2048, True, 5, CAP), (2048, False, 5, CAP),
+    (2048, False, CAP + 1, "refused")])
 def test_the_carrys_rows_after_a_cut(launch_cap, full, groups, want):
     """`_spread_carry` alone: after a `groups` cut a closed loop's carry has
-    the cap's rows whatever the segment holds, one group included; the
+    its cap's rows, the wide width, whatever the segment holds, one group
+    included, as has any launch of more groups than the narrow carry's
+    `CAP`; behind a serve loop the cap and the rows are the narrow one's; the
     spare rows are zero and count toward nothing."""
     feats = [types.SimpleNamespace(
         spread_counts=np.full(16, g + 1, np.int64),

@@ -599,7 +599,7 @@ class TestBurstClassDecision:
         assert BURST_CLASS.labels("decided").value - decided0 == 3
         assert BURST_CLASS.labels("shared").value - shared0 == 9
 
-    def test_service_between_passes_reclassifies(self):
+    def test_service_between_passes_reclassifies(self, monkeypatch):
         """Nothing outlives a pass: the same pod shapes are one plain
         segment before the Service exists and, after, one that holds the
         Service's two pods (`spread`) with the two that nothing selects.
@@ -621,7 +621,7 @@ class TestBurstClassDecision:
         assert one_pass("x") == [("plain", 4)]
         store.create(SERVICES, Service(name="svc-a", selector={"app": "a"}))
         assert one_pass("y") == [("spread", 4)]
-        sched.algorithm.spread_group_cap = 1
+        monkeypatch.setattr(type(sched.algorithm), "spread_group_cap", 1)
         assert one_pass("z") == [("plain", 2), ("spread", 2)]
 
     def test_gang_fallback_without_classes_binds_as_before(self):
