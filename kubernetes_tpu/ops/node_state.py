@@ -58,11 +58,24 @@ POD_TABLE_CALLS = obs.counter(
     "tpu_pod_table_calls_total",
     "pod_table calls by the way they left: returned (nothing moved against "
     "the same batch: the previous table itself), shared (every row where "
-    "it was: the cached columns shared, the batch-dependent ones rebuilt), "
-    "gathered (the pod list rebuilt and every cached column gathered from "
-    "the previous rows and the extracted ones), built (no previous table). "
-    "One count a call, the path its burst.encode.table span carries.",
+    "it was, whatever generations moved: the cached columns and the pod "
+    "list shared, the batch-dependent columns rebuilt), spliced (a few "
+    "nodes' pods joined or left, or a node did: every cached column is the "
+    "previous one's unchanged row ranges copied around those nodes' rows), "
+    "gathered (the same delta where many nodes changed at once: every "
+    "cached column one gather over all rows of the previous rows and the "
+    "extracted ones), built (no previous table). One count a call, the "
+    "path its burst.encode.table span carries.",
     ("path",))
+POD_TABLE_MOVED_NODES = obs.counter(
+    "tpu_pod_table_moved_nodes_total",
+    "Nodes whose NodeInfo generation had moved when pod_table met them, by "
+    "what their join stamps said: kept (the stamps are the cached ones: the "
+    "same pods held without a break, the node keeps its row range), "
+    "changed (a pod joined or left, or the node is new: its rows are put "
+    "together again by _pt_block). Booked once per pod_table call that "
+    "found a moved generation, beside tpu_pod_table_rows_total.",
+    ("result",))
 SPREAD_COUNT_ENCODES = obs.counter(
     "tpu_spread_count_encodes_total",
     "Selector-spread count passes: one PodEncoder.encode of a pod that a "
@@ -417,11 +430,13 @@ class NodeStateEncoder:
 
     def _pt_block(self, name: str, ni: NodeInfo, cached, rows: list,
                   fresh: list) -> None:
-        """One node whose block is not cached at its generation: append to
-        `rows`, per pod of ni.pods in order, the row of the previous table
-        that describes it, or -(k+1) where k is its place in `fresh`, the
-        (pod, has_affinity, named_holder) triples _pt_extract will derive
-        in Python.
+        """One CHANGED node (its join stamps are not the cached ones, or it
+        has no block yet; a node whose generation moved under unchanged
+        stamps never comes here, _pt_delta's whole-list compare keeps its
+        range by the argument below): append to `rows`, per pod of ni.pods
+        in order, the row of the previous table that describes it, or
+        -(k+1) where k is its place in `fresh`, the (pod, has_affinity,
+        named_holder) triples _pt_extract will derive in Python.
 
         A previous row is reused when the node's previous block has a row
         cut for the same JOIN STAMP (NodeInfo.pod_gens). A stamp is a
@@ -513,20 +528,38 @@ class NodeStateEncoder:
 
         The cache is that previous table plus, per node, the generation its
         rows were cut at, where they lie and their join stamps. A node at
-        its cached generation hands over its row range; a node whose
-        generation moved goes through _pt_block, which reuses the rows of
-        pods it still holds and sends only the pods that joined to
-        _pt_extract. Every
-        cached column of the new table is then ONE numpy gather from
-        (previous rows ++ extracted rows): no Python loop touches a row
-        that did not change, and the from-scratch build is just the first
-        call, when every row is extracted. The three columns that depend on
-        the batch and the snapshot, not on the pod (holder_row,
-        holder_has_obj, name_row), are derived on every call. The result
-        equals, field for field and row for row, what build_pod_table makes
-        from the same snapshot. When nothing moved against the same batch
-        the previous table itself is returned. Callers that feed the table
-        to the vectorized matchers assume the batch axis covers the
+        its cached generation keeps its row range, and so does a node whose
+        generation moved while its join stamps are still the cached ones
+        (a pod bound and deleted again, an update of the Node object: the
+        same pods held without a break, _pt_block's argument). Only a node
+        whose stamps differ is CHANGED: _pt_block reuses the rows of the
+        pods it still holds and sends the pods that joined to _pt_extract.
+        The call leaves on one of five paths, the `path` of its
+        burst.encode.table span and of tpu_pod_table_calls_total:
+
+        - returned: nothing moved against the same batch; the previous
+          table itself.
+        - shared: every row is where it was (no node changed, none with
+          rows joined or left the snapshot): the cached columns and the pod
+          list are the previous table's, no row is looked at.
+        - spliced: a few nodes changed, joined or left: each cached column
+          is the previous one's unchanged row ranges (contiguous slices)
+          copied around the changed nodes' rows, the pod list likewise; no
+          index over the whole table, one copy.
+        - gathered: the same delta where many nodes changed at once (a
+          relist, a reordered snapshot): so many ranges that one gather a
+          column from (previous rows ++ extracted rows) is cheaper than
+          copying them one by one. Chosen by the number of ranges against
+          the node count, nothing else.
+        - built: no previous table; every row is extracted.
+
+        The three columns that depend on the batch and the snapshot, not on
+        the pod (holder_row, holder_has_obj, name_row), are derived on
+        every call but `returned`. The result equals, field for field and
+        row for row, what build_pod_table makes from the same snapshot; no
+        path writes into an array or list a previous table holds, so a
+        table handed out earlier reads what it read. Callers that feed the
+        table to the vectorized matchers assume the batch axis covers the
         snapshot (node_infos keys ⊆ batch names), which is how every
         encoder consumer builds it."""
         # one span and one count a call, by the path it left on. The name
@@ -534,88 +567,121 @@ class NodeStateEncoder:
         # prefixes only; the serial cycle and the preemption path reach
         # this encoder too and open the same span, outside any burst.
         sp = obs.trace.begin("burst.encode.table")
-        out, path, moved, fresh = self._pt_delta(node_infos, b)
+        out, path, kept, changed, fresh = self._pt_delta(node_infos, b)
         POD_TABLE_CALLS.labels(path).inc()
-        sp.end(path=path, rows=len(out.pods), moved=moved, fresh=fresh)
+        sp.end(path=path, rows=len(out.pods), moved=kept + changed,
+               kept=kept, fresh=fresh)
         return out
 
     def _pt_delta(self, node_infos: dict[str, NodeInfo],
                   b: NodeBatch) -> tuple:
         """pod_table's work: (the table, the path the call left on, nodes
-        whose generation moved, rows extracted from Pod objects)."""
+        whose generation moved with their cached join stamps, nodes whose
+        stamps changed, rows extracted from Pod objects).
+
+        One walk over the snapshot decides per node, by generation and then
+        by join stamps, whether its rows are where they were, and writes
+        the new table down as `pieces` in row order: (lo, hi), a range of
+        the previous table's rows that stays together (neighbouring
+        unchanged nodes merge into one), or (-1, n), the next n entries of
+        `rows`, a changed node's. Everything after the walk works on the
+        pieces, which are few when little changed."""
         prev = self._pt_built
         prev_pods = prev.pods if prev is not None else []
         cache = self._pt_blocks
         blocks = {}
-        starts, counts = [], []      # per node: first source row, rows
-        rows: list = []              # source row per pod of a moved node
-        moved: list = []             # which nodes (positions) those are
+        counts: list = []            # rows per node
+        pieces: list = []
+        rows: list = []              # source row per pod of a changed node
+        joined: list = []            # those nodes' pod lists
         fresh: list = []
-        total = 0
+        kept = changed = reused = total = 0
+        lo = hi = 0                  # the open range of previous rows
         for name, ni in node_infos.items():
             cached = cache.get(name)
-            if cached is not None and cached[0] == ni.generation:
-                start, stamps = cached[1:]
+            gen = ni.generation
+            if cached is not None and (
+                    cached[0] == gen or cached[2] == ni.pod_gens):
+                cut_at, start, stamps = cached
+                n = len(stamps)
+                if cut_at != gen:
+                    kept += 1
+                    reused += n
+                if cut_at != gen or start != total:
+                    cached = (gen, total, stamps)
+                if start != hi:
+                    if hi > lo:
+                        pieces.append((lo, hi))
+                    lo = start
+                hi = start + n
             else:
-                moved.append(len(counts))
-                start, stamps = 0, list(ni.pod_gens)
-                self._pt_block(name, ni, cached, rows, fresh)
-            blocks[name] = (ni.generation, total, stamps)
-            starts.append(start)
-            counts.append(len(stamps))
-            total += len(stamps)
+                changed += 1
+                stamps = list(ni.pod_gens)
+                n = len(stamps)
+                if n or (cached is not None and cached[2]):
+                    if hi > lo:
+                        pieces.append((lo, hi))
+                    lo = hi = -1     # no start continues a closed range
+                    pieces.append((-1, n))
+                    self._pt_block(name, ni, cached, rows, fresh)
+                    joined.append(ni.pods)
+                cached = (gen, total, stamps)
+            blocks[name] = cached
+            counts.append(n)
+            total += n
+        if hi > lo:
+            pieces.append((lo, hi))
         self._pt_blocks = blocks      # prunes nodes that left the snapshot
-        if moved:
+        if kept or changed:
             POD_TABLE_ROWS.labels("extracted").inc(len(fresh))
-            POD_TABLE_ROWS.labels("reused").inc(len(rows) - len(fresh))
-        counts = np.asarray(counts, np.int64)
-        offs = np.cumsum(counts) - counts
-        # source row of every new row: a node's cached range, then the
-        # moved nodes' per-pod rows scattered over theirs (extracted rows
-        # lie behind the previous table's)
-        src = np.repeat(np.asarray(starts, np.int64) - offs, counts) \
-            + np.arange(total)
-        if rows:
-            m = np.asarray(moved, np.int64)
-            mc = counts[m]
-            r = np.asarray(rows, np.int64)
-            src[np.repeat(offs[m] - (np.cumsum(mc) - mc), mc)
-                + np.arange(r.size)] = np.where(
-                    r >= 0, r, len(prev_pods) - 1 - r)
-        if prev is not None and total == len(prev_pods) \
-                and np.array_equal(src, np.arange(total)):
+            POD_TABLE_ROWS.labels("reused").inc(
+                reused + len(rows) - len(fresh))
+            POD_TABLE_MOVED_NODES.labels("kept").inc(kept)
+            POD_TABLE_MOVED_NODES.labels("changed").inc(changed)
+        if prev is not None and pieces in ([], [(0, len(prev_pods))]) \
+                and total == len(prev_pods):
             # every row is where it was and describes the pod it did: the
             # cached columns ARE the previous table's (shared: no consumer
             # writes a table), and so is the rest when no generation moved
             # and no node left or joined against the same batch
-            if not moved and len(blocks) == len(cache) \
+            if not (kept or changed) and len(blocks) == len(cache) \
                     and self._pt_batch is b:
-                return prev, "returned", 0, 0
+                return prev, "returned", 0, 0, 0
             path = "shared"
             pods = prev_pods
             cols = {f: getattr(prev, f) for f in _PT_CACHED}
         else:
-            path = "gathered" if prev is not None else "built"
-            pods = [pd for ni in node_infos.values() for pd in ni.pods]
             got = self._pt_extract(
                 fresh, prev.key_ids.shape[1] if prev is not None else 1)
+            r = np.asarray(rows, np.int64)
+            pods = src = None
+            if prev is not None and len(pieces) \
+                    * _PT_SPLICE_NODES_A_PIECE <= len(counts):
+                pods = self._pt_patched_pods(prev_pods, pieces, joined)
+            if pods is not None:
+                path = "spliced"
+            else:
+                path = "gathered" if prev is not None else "built"
+                pods = [pd for ni in node_infos.values() for pd in ni.pods]
+                src = self._pt_source_rows(pieces, r, len(prev_pods))
             cols = {}
             for f in _PT_CACHED:
-                col = getattr(prev, f) if prev is not None else got[f][:0]
-                if fresh:
-                    if col.ndim == 2 and col.shape[1] < got[f].shape[1]:
-                        col = np.pad(col, ((0, 0), (
-                            0, got[f].shape[1] - col.shape[1])),
-                            constant_values=-1)
-                    col = np.concatenate((col, got[f]))
-                cols[f] = col[src]
-            # label columns as wide as the widest row left, as a fresh
-            # build's are
-            used = np.flatnonzero((cols["key_ids"] >= 0).any(axis=0))
-            w = int(used[-1]) + 1 if used.size else 1
-            if w < cols["key_ids"].shape[1]:
+                col, ext = (getattr(prev, f) if prev is not None
+                            else got[f][:0]), got[f]
+                if col.ndim == 2 and col.shape[1] < ext.shape[1]:
+                    col = np.pad(col, ((0, 0), (
+                        0, ext.shape[1] - col.shape[1])), constant_values=-1)
+                cols[f] = self._pt_splice(col, ext, pieces, r) \
+                    if src is None else np.concatenate((col, ext))[src]
+            if prev is not None and self._pt_may_narrow(
+                    prev.key_ids, got["key_ids"], pieces, r):
+                # label columns as wide as the widest row left, as a fresh
+                # build's are
+                used = np.flatnonzero((cols["key_ids"] >= 0).any(axis=0))
+                w = int(used[-1]) + 1 if used.size else 1
                 for f in ("key_ids", "val_ids"):
                     cols[f] = np.ascontiguousarray(cols[f][:, :w])
+        counts = np.asarray(counts, np.int64)
         index = b.index
         holder_row = np.repeat(
             np.fromiter((index.get(name, -1) for name in node_infos),
@@ -637,7 +703,87 @@ class NodeStateEncoder:
             val_ints=np.asarray(self._pt_val_ints, dtype=np.float64), **cols)
         self._pt_built = out
         self._pt_batch = b
-        return out, path, len(moved), len(fresh)
+        return out, path, kept, changed, len(fresh)
+
+    @staticmethod
+    def _pt_patched_pods(prev_pods: list, pieces: list, joined: list):
+        """The new table's pod list as a copy of the previous one, patched
+        from the back where pieces say so: what lies between two ranges
+        that stay (a changed node's old rows, a node that left) gives way
+        to the changed nodes' pod lists that come between them. None when
+        the ranges do not come in the previous table's order (a reordered
+        snapshot): the caller then takes the path that asks nothing."""
+        lists = iter(joined)
+        patches = []
+        pos, ins = 0, []
+        for lo, hi in pieces:
+            if lo < 0:
+                ins = ins + next(lists)
+            elif lo < pos:
+                return None
+            else:
+                patches.append((pos, lo, ins))
+                pos, ins = hi, []
+        pods = prev_pods.copy()
+        pods[pos:] = ins
+        for a, b, ins in reversed(patches):
+            pods[a:b] = ins
+        return pods
+
+    @staticmethod
+    def _pt_splice(col: np.ndarray, ext: np.ndarray, pieces: list,
+                   r: np.ndarray) -> np.ndarray:
+        """One cached column of the new table from few pieces: the previous
+        column's ranges as slices, the changed nodes' rows (`r`: a previous
+        row, or -(k+1) for row k of the extracted `ext`) between them. One
+        copy of the column, no index over it."""
+        held = r >= 0
+        blk = np.empty((r.size,) + ext.shape[1:], ext.dtype)
+        blk[held] = col[r[held]]
+        blk[~held] = ext
+        parts = []
+        at = 0
+        for lo, hi in pieces:
+            if lo < 0:
+                parts.append(blk[at:at + hi])
+                at += hi
+            else:
+                parts.append(col[lo:hi])
+        return np.concatenate(parts)
+
+    @staticmethod
+    def _pt_source_rows(pieces: list, r: np.ndarray,
+                        n_prev: int) -> np.ndarray:
+        """Where the pieces are many or out of order, a column is ONE
+        gather from (previous rows ++ extracted rows): the source row of
+        every new row, a range's rows counted up from its start, a changed
+        node's read from `r`."""
+        p = np.asarray(pieces, np.int64).reshape(-1, 2)
+        own = p[:, 0] < 0
+        n = np.where(own, p[:, 1], p[:, 1] - p[:, 0])
+        offs = np.cumsum(n) - n
+        src = np.repeat(p[:, 0] - offs, n) + np.arange(int(n.sum()))
+        src[np.repeat(own, n)] = np.where(r >= 0, r, n_prev - 1 - r)
+        return src
+
+    @staticmethod
+    def _pt_may_narrow(key_prev: np.ndarray, key_ext: np.ndarray,
+                       pieces: list, r: np.ndarray) -> bool:
+        """Whether the label columns may have to shrink: the previous
+        table's widest rows were not outdone by an extracted one, and a row
+        that left was as wide as the table. The rows that left are the ones
+        no piece kept and no changed node reused: few, so only they are
+        read; the scan of the whole table that finds the new width is paid
+        when this says so."""
+        w = key_prev.shape[1]
+        if w == 1 or key_ext.shape[1] > w or (key_ext[:, w - 1] >= 0).any():
+            return False
+        edges = [0] + [e for p in sorted(p for p in pieces if p[0] >= 0)
+                       for e in p] + [len(key_prev)]
+        left = np.setdiff1d(np.concatenate(
+            [np.arange(a, b) for a, b in zip(edges[::2], edges[1::2])]),
+            r[r >= 0])
+        return bool((key_prev[left, w - 1] >= 0).any())
 
     # -- persistent victim table --------------------------------------------
     def victim_table(self, node_infos: dict[str, NodeInfo], b: NodeBatch,
@@ -903,6 +1049,9 @@ class PodTable:
 _PT_CACHED = ("ns_id", "deleted", "has_affinity", "named_holder", "key_ids",
               "val_ids", "prio", "start", "res_cpu", "res_mem", "res_eph",
               "has_scalar", "has_aff_terms", "has_ports")
+# pod_table copies a column piece by piece (spliced) while the snapshot has
+# at least this many nodes a piece, and gathers it in one index otherwise
+_PT_SPLICE_NODES_A_PIECE = 16
 
 
 @dataclass

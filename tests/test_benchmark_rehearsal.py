@@ -272,6 +272,11 @@ def test_rehearsed_cell(monkeypatch, execute, cell, seed, hook, program):
         assert moved["tpu_pod_table_calls_total"] == {"shared": launches}
         assert counter_metric("pod_table_gathered_call_share.backlog",
                               res, rep) == 0.0
+        # ... and every node whose generation moved kept its row range by
+        # its join stamps (PR 53): none is put together again
+        assert set(moved["tpu_pod_table_moved_nodes_total"]) == {"kept"}
+        assert counter_metric("pod_table_kept_node_share.backlog",
+                              res, rep) == 100.0
         # the shell's side, over warm-up (two cycles) and window: a pass
         # ends where it is out of pods and nowhere else
         whole = counters.delta(counters.snapshot(), before)
@@ -412,13 +417,19 @@ def test_rehearsed_cell(monkeypatch, execute, cell, seed, hook, program):
                               res, rep) > 0
         assert counter_metric("pod_table_rows_reused_per_pod.arrivals",
                               res, rep) > 0
-        # ... one call a launch, and where a pod joined or left since the
-        # call before, every cached column is gathered over all rows
+        # ... one call a launch, and where pods joined or left since the
+        # call before, the changed nodes' rows are spliced into the
+        # previous columns (PR 53); on 250 nodes a window's binds can be
+        # too many pieces for that, and such a call gathers
         calls = moved["tpu_pod_table_calls_total"]
         assert sum(calls.values()) == launches
-        assert set(calls) <= {"gathered", "shared"}
+        assert set(calls) <= {"spliced", "gathered", "shared"}
+        assert calls["spliced"] > 0
+        assert counter_metric("pod_table_spliced_call_share.arrivals",
+                              res, rep) == 100.0 * calls["spliced"] / launches
         assert counter_metric("pod_table_gathered_call_share.arrivals",
-                              res, rep) == 100.0 * calls["gathered"] / launches
+                              res, rep) \
+            == 100.0 * calls.get("gathered", 0) / launches
         # the shell's side, over warm-up and window: the warm-up's 232-pod
         # pass holds more than sixteen Services and is cut; no class cut
         whole = counters.delta(counters.snapshot(), before)
@@ -488,11 +499,21 @@ ENCODE_OPENED["pod_table_gathered_call_share"] = (
     "counter_label_share",
     {"family": "tpu_pod_table_calls_total", "labels": ["gathered"]},
     "ops/node_state.py")
+# every metric of the opened encode by name, and the table's delta by rows
+# (PR 53): one metric the open loop, one the closed
+OPENED = {f"{stem}.{suffix}": v for stem, v in ENCODE_OPENED.items()
+          for suffix in ("backlog", "arrivals")}
+OPENED["pod_table_spliced_call_share.arrivals"] = (
+    "counter_label_share",
+    {"family": "tpu_pod_table_calls_total", "labels": ["spliced"]},
+    "ops/node_state.py")
+OPENED["pod_table_kept_node_share.backlog"] = (
+    "counter_label_share",
+    {"family": "tpu_pod_table_moved_nodes_total", "labels": ["kept"]},
+    "ops/node_state.py")
 
 
-@pytest.mark.parametrize("name", [f"{stem}.{suffix}"
-                                  for stem in ENCODE_OPENED
-                                  for suffix in ("backlog", "arrivals")])
+@pytest.mark.parametrize("name", list(OPENED))
 def test_encode_opened_metric_is_read_where_it_is_listed(execute, name):
     """Each new metric's file loads, names a reader that is there and the
     span or family the program books, and every cell its entry names lists
@@ -501,7 +522,7 @@ def test_encode_opened_metric_is_read_where_it_is_listed(execute, name):
     bench = spec.load_benchmark()
     (entry,) = [m for m in bench["per_layer"] if m["name"] == name]
     mf = spec.load_metric(name)
-    reader, args, source = ENCODE_OPENED[name.rsplit(".", 1)[0]]
+    reader, args, source = OPENED[name]
     assert (mf["reader"], mf["args"]) == (reader, args)
     assert callable(importlib.import_module(f"readers.{reader}").read)
     booked = (args.get("spans") or [args.get("family")])[0]

@@ -380,6 +380,67 @@ def rand_held_pod(rng, j, names):
     return p
 
 
+def table_paths():
+    """{path: calls} of tpu_pod_table_calls_total."""
+    from kubernetes_tpu.ops.node_state import POD_TABLE_CALLS
+    return {p: POD_TABLE_CALLS.labels(p).value
+            for p in ("returned", "shared", "spliced", "gathered", "built")}
+
+
+def plain_node(name):
+    return Node(name=name, labels={LABEL_HOSTNAME: name},
+                allocatable={"cpu": 64000, "memory": 64 * GI, "pods": 110})
+
+
+def held_pod(name, host, labels=None):
+    p = Pod(name=name, namespace="default",
+            labels={"app": "web"} if labels is None else labels,
+            containers=(Container.make(name="c", requests={"cpu": 50}),))
+    p.node_name = host
+    return p
+
+
+def cluster(n_nodes, per=3, empty=()):
+    """`n_nodes` nodes of `per` pods each (none on `empty`) and an encoder
+    that has cut its first table of them."""
+    infos = {f"n{i}": NodeInfo(plain_node(f"n{i}")) for i in range(n_nodes)}
+    for h, ni in infos.items():
+        for j in range(0 if h in empty else per):
+            ni.add_pod(held_pod(f"{h}-{j}", h))
+    names = list(infos)
+    enc = NodeStateEncoder()
+    assert checked_table(enc, infos, names)[1] == "built"
+    return infos, names, enc
+
+
+def checked_table(enc, infos, names):
+    """(the encoder's table of the snapshot, the path the call left on,
+    what the call booked of moved nodes and their rows), the table held to
+    a fresh build and to the plain loop."""
+    from kubernetes_tpu.ops.node_state import (
+        POD_TABLE_MOVED_NODES, POD_TABLE_ROWS)
+
+    def booked():
+        return {r: fam.labels(r).value
+                for fam, rs in ((POD_TABLE_MOVED_NODES, ("kept", "changed")),
+                                (POD_TABLE_ROWS, ("reused", "extracted")))
+                for r in rs}
+
+    b = enc.encode(infos, names)
+    calls, before = table_paths(), booked()
+    t = enc.pod_table(infos, b)
+    (path,) = [k for k, v in table_paths().items() if v != calls[k]]
+    moved = {r: v - before[r] for r, v in booked().items()}
+    fresh = build_pod_table(infos, b)
+    want = plain_rows(infos, b)
+    assert table_rows(t) == want
+    assert table_rows(fresh) == want
+    assert t.key_ids.shape == fresh.key_ids.shape
+    for f in ("key_ids", "val_ids", "ns_id", "prio", "start"):
+        assert getattr(t, f).flags["C_CONTIGUOUS"], f
+    return t, path, moved
+
+
 class TestPodTableCache:
     def test_generation_cache_reuses_blocks_and_tracks_changes(self):
         rng = random.Random(7)
@@ -419,12 +480,15 @@ class TestPodTableCache:
     @pytest.mark.parametrize("seed", range(8))
     def test_delta_table_equals_fresh_build(self, seed):
         """Random adds, removes, replacements with changed labels,
-        deletion marks and nodes leaving and joining: after every step the
-        delta-kept table equals a from-scratch build_pod_table, and both
-        equal the plain row-by-row loop, field for field in row order."""
+        deletion marks, pods bound and gone again, re-snapshotted nodes and
+        nodes leaving and joining, one to six of them between two tables:
+        after every step the delta-kept table equals a from-scratch
+        build_pod_table, and both equal the plain row-by-row loop, field
+        for field in row order; and the call left on the path the step
+        asks for (tpu_pod_table_calls_total)."""
         rng = random.Random(seed)
         infos, names = {}, []
-        spare = [f"n{i}" for i in range(9)]
+        spare = [f"n{i}" for i in range(80)]
 
         def join():
             name = spare.pop(0)
@@ -439,19 +503,24 @@ class TestPodTableCache:
                 p.node_name = host
             infos[host].add_pod(p)
 
-        for _ in range(6):
+        for _ in range(70):
             join()
         serial = iter(range(10 ** 6))
-        for _ in range(40):
+        for _ in range(150):
             add(rng.choice(names), rand_held_pod(rng, next(serial), names))
-        enc = NodeStateEncoder()
-        for step in range(40):
+
+        def mutate(op, step):
             held = [(h, p) for h in names for p in infos[h].pods]
-            op = rng.choice(["add", "add", "remove", "relabel", "inplace",
-                             "delete", "leave", "join", "resnap", "none"])
             if op == "add":
                 add(rng.choice(names),
                     rand_held_pod(rng, next(serial), names))
+            elif op == "bump":
+                # bound and gone again between two tables: the generation
+                # moved twice, the join stamps are what they were
+                h = rng.choice(names)
+                p = rand_held_pod(rng, next(serial), names)
+                add(h, p)
+                infos[h].remove_pod(p)
             elif op == "join" and spare:
                 join()
                 for _ in range(rng.randint(0, 3)):
@@ -464,7 +533,7 @@ class TestPodTableCache:
                 # what update_snapshot does to a changed node
                 h = rng.choice(names)
                 infos[h] = infos[h].clone()
-            elif held and op != "none":
+            elif held and op not in ("none", "join", "leave"):
                 h, p = rng.choice(held)
                 infos[h].remove_pod(p)
                 if op == "relabel":      # the store's way: a new object
@@ -479,14 +548,175 @@ class TestPodTableCache:
                     p.deleted = True
                 if op != "remove":
                     infos[h].add_pod(p)
+
+        def stamps():
+            return {h: list(ni.pod_gens) for h, ni in infos.items()
+                    if ni.pods}
+
+        enc = NodeStateEncoder()
+        was = None               # (generations, stamps, batch) last call
+        for step in range(40):
+            ops = [rng.choice(["add", "add", "remove", "relabel", "inplace",
+                               "delete", "leave", "join", "resnap", "bump",
+                               "bump", "none"])
+                   for _ in range(rng.choice([1, 1, 1, 2, 3, 6]))]
+            for op in ops:
+                mutate(op, step)
             b = enc.encode(infos, names)
+            calls = table_paths()
             t = enc.pod_table(infos, b)
+            (path,) = [k for k, v in table_paths().items() if v != calls[k]]
+            now = ({h: ni.generation for h, ni in infos.items()}, stamps(), b)
+            if was is None:
+                assert path == "built"
+            elif list(now[1].items()) == list(was[1].items()):
+                # every row where it was: nothing is copied, whatever
+                # generations moved and whichever empty nodes came or went
+                assert path == ("returned" if now[0] == was[0]
+                                and b is was[2] else "shared"), (seed, ops)
+            else:
+                assert path in ("spliced", "gathered"), (seed, ops)
+                # a node whose rows changed, came or went is at most two
+                # more pieces; few enough of them are spliced
+                k = sum(now[1].get(h) != was[1].get(h)
+                        for h in now[1].keys() | was[1].keys())
+                if (2 * k + 1) * 16 <= len(infos):
+                    assert path == "spliced", (seed, ops, k)
+            was = now
             fresh = build_pod_table(infos, b)
             want = plain_rows(infos, b)
-            assert table_rows(t) == want, (seed, step, op)
-            assert table_rows(fresh) == want, (seed, step, op)
+            assert table_rows(t) == want, (seed, step, ops)
+            assert table_rows(fresh) == want, (seed, step, ops)
             assert t.key_ids.shape == fresh.key_ids.shape
             assert enc.pod_table(infos, b) is t
+
+    @pytest.mark.parametrize("where", [
+        "first", "last", "emptied", "first_pod", "swapped", "node_left",
+        "node_joined", "two_nodes"])
+    def test_splice_at_the_edges(self, where):
+        """One or two nodes of 64 change between two tables, at every place
+        a splice has an edge case: the call is `spliced` and the table is a
+        fresh build's."""
+        infos, names, enc = cluster(64, empty=("n7",))
+        if where in ("first", "last"):
+            host = names[0 if where == "first" else -1]
+            infos[host].add_pod(held_pod("new", host))
+        elif where == "emptied":
+            for p in list(infos["n5"].pods):
+                infos["n5"].remove_pod(p)
+        elif where == "first_pod":
+            infos["n7"].add_pod(held_pod("new", "n7"))
+        elif where == "swapped":             # as many rows as before
+            infos["n9"].remove_pod(infos["n9"].pods[1])
+            infos["n9"].add_pod(held_pod("new", "n9"))
+        elif where == "node_left":
+            del infos["n11"]
+            names.remove("n11")
+        elif where == "node_joined":
+            infos["n64"] = NodeInfo(plain_node("n64"))
+            names.append("n64")
+            infos["n64"].add_pod(held_pod("new", "n64"))
+        elif where == "two_nodes":           # neighbours: no range between
+            infos["n20"].add_pod(held_pod("new-a", "n20"))
+            infos["n21"].remove_pod(infos["n21"].pods[0])
+        assert checked_table(enc, infos, names)[1] == "spliced"
+        assert checked_table(enc, infos, names)[1] == "returned"
+
+    @pytest.mark.parametrize("how", ["many_nodes", "reordered"])
+    def test_gathered_is_for_what_a_splice_is_wrong_for(self, how):
+        """Every fourth node changed at once, or the snapshot in another
+        order: one gather a column, and still a fresh build's table."""
+        infos, names, enc = cluster(48)
+        if how == "many_nodes":
+            for h in names[::4]:
+                infos[h].add_pod(held_pod(f"new-{h}", h))
+        else:
+            infos["n0"] = infos.pop("n0")    # the first node goes last
+            infos["n3"].add_pod(held_pod("new", "n3"))
+        assert checked_table(enc, infos, names)[1] == "gathered"
+
+    @pytest.mark.parametrize("step,width", [
+        ("widest_leaves", 1), ("one_of_two_widest_leaves", 4),
+        ("wider_joins", 5), ("widest_leaves_narrower_joins", 2),
+        ("widest_leaves_as_wide_joins", 4), ("narrow_leaves", 4)])
+    def test_label_width_follows_the_widest_row_left(self, step, width):
+        """The label columns are as wide as the widest row of the table, as
+        a fresh build's are, through a splice: narrower when the widest row
+        left, wider when a wider one joined."""
+        wide = {f"k{i}": "v" for i in range(4)}
+        infos, names, enc = cluster(96)
+        infos["n4"].add_pod(held_pod("wide", "n4", labels=wide))
+        if step == "one_of_two_widest_leaves":
+            infos["n30"].add_pod(held_pod("wide-2", "n30", labels=wide))
+        t, _, _ = checked_table(enc, infos, names)
+        assert t.key_ids.shape[1] == 4
+        if step == "narrow_leaves":
+            infos["n4"].remove_pod(infos["n4"].pods[0])
+        elif step != "wider_joins":
+            infos["n4"].remove_pod(infos["n4"].pods[-1])
+        labels = {"wider_joins": {f"j{i}": "v" for i in range(5)},
+                  "widest_leaves_narrower_joins": {"a": "1", "b": "2"},
+                  "widest_leaves_as_wide_joins": {
+                      f"j{i}": "v" for i in range(4)}}.get(step)
+        if labels:
+            infos["n40"].add_pod(held_pod("joins", "n40", labels=labels))
+        t, path, _ = checked_table(enc, infos, names)
+        assert path == "spliced"
+        assert t.key_ids.shape[1] == t.val_ids.shape[1] == width
+
+    def test_a_table_handed_out_before_a_splice_reads_what_it_read(self):
+        """PodEncoder keeps its table a segment, the victim stack keeps one
+        between scans: a later call copies, it never writes into the arrays
+        or the pod list an earlier table holds."""
+        infos, names, enc = cluster(96)
+        t1, _, _ = checked_table(enc, infos, names)
+        rows1, pods1 = table_rows(t1), list(t1.pods)
+        infos["n6"].add_pod(held_pod("new", "n6", labels={"a": "b", "c": "d"}))
+        t2, path, _ = checked_table(enc, infos, names)
+        assert path == "spliced" and t2 is not t1
+        rows2 = table_rows(t2)
+        infos["n6"].remove_pod(infos["n6"].pods[0])
+        infos["n30"].remove_pod(infos["n30"].pods[-1])
+        t3, path, _ = checked_table(enc, infos, names)
+        assert path == "spliced"
+        assert table_rows(t1) == rows1 and t1.pods == pods1
+        assert table_rows(t2) == rows2
+        assert len(t1.pods) + 1 == len(t2.pods) == len(t3.pods) + 2
+        # a generation alone shares the columns and the list, and copies
+        # nothing: the two tables are one set of arrays
+        infos["n2"].set_node(plain_node("n2"))
+        t4, path, _ = checked_table(enc, infos, names)
+        assert path == "shared"
+        assert t4.pods is t3.pods and t4.key_ids is t3.key_ids
+
+    def test_moved_nodes_are_booked_by_what_their_stamps_said(self):
+        """A closed loop's pass between two tables (pods bound and deleted
+        again on most nodes) and a window's delta (a pod joined here, one
+        left there): tpu_pod_table_moved_nodes_total says how many moved
+        nodes kept their range, tpu_pod_table_rows_total counts their rows
+        as reused."""
+        infos, names, enc = cluster(96)
+
+        def moved(want_path):
+            _, path, booked = checked_table(enc, infos, names)
+            assert path == want_path
+            return booked
+
+        for h in names[:40]:
+            p = held_pod(f"pass-{h}", h)
+            infos[h].add_pod(p)
+            infos[h].remove_pod(p)
+        assert moved("shared") == {"kept": 40, "changed": 0,
+                                   "reused": 120, "extracted": 0}
+        infos["n1"].add_pod(held_pod("joined", "n1"))
+        infos["n2"].remove_pod(infos["n2"].pods[0])
+        p = held_pod("pass", "n3")
+        infos["n3"].add_pod(p)
+        infos["n3"].remove_pod(p)
+        assert moved("spliced") == {"kept": 1, "changed": 2,
+                                    "reused": 3 + 3 + 2, "extracted": 1}
+        assert moved("returned") == {"kept": 0, "changed": 0,
+                                     "reused": 0, "extracted": 0}
 
     def test_cost_follows_change(self):
         """One pod added to one node of a snapshot: one row is extracted,
@@ -542,10 +772,15 @@ class TestPodTableCache:
         _, moved = table()
         assert moved == {"extracted": 0, "reused": 0}
 
-    def test_victim_table_after_delta_equals_fresh(self):
+    @pytest.mark.parametrize("n_nodes,joins,path", [
+        (6, 20, "gathered"), (400, 6, "spliced")])
+    def test_victim_table_after_delta_equals_fresh(self, n_nodes, joins,
+                                                   path):
+        """The victim stack reads the cached victim columns and t.pods by
+        row: after a delta on either path it is a fresh encoder's."""
         from kubernetes_tpu.api.types import PodDisruptionBudget
         rng = random.Random(12)
-        infos, names = rand_snapshot(rng, n_nodes=6, n_pods=0)
+        infos, names = rand_snapshot(rng, n_nodes=n_nodes, n_pods=0)
         for j in range(50):
             host = rng.choice(names)
             p = rand_held_pod(rng, j, names)
@@ -556,7 +791,7 @@ class TestPodTableCache:
             selector=LabelSelector(match_labels=(("app", "web"),)))]
         enc = NodeStateEncoder()
         enc.victim_table(infos, enc.encode(infos, names), pdbs)
-        for j in range(50, 70):
+        for j in range(50, 50 + joins):
             host = rng.choice(names)
             if infos[host].pods and rng.random() < 0.5:
                 infos[host].remove_pod(rng.choice(infos[host].pods))
@@ -564,7 +799,9 @@ class TestPodTableCache:
             p.node_name = host
             infos[host].add_pod(p)
         b = enc.encode(infos, names)
+        calls = table_paths()
         got = enc.victim_table(infos, b, pdbs)
+        assert table_paths()[path] == calls[path] + 1
         fresh_enc = NodeStateEncoder()
         want = fresh_enc.victim_table(
             infos, fresh_enc.encode(infos, names), pdbs)

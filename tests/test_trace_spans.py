@@ -40,7 +40,7 @@ PUMP_CHILDREN = {
 ENCODE_CHILDREN = {"burst.encode.table": "burst.encode.pods",
                    "burst.encode.count": "burst.encode.pods",
                    "burst.encode.carry": "burst.encode"}
-TABLE_PATHS = ("returned", "shared", "gathered", "built")
+TABLE_PATHS = ("returned", "shared", "spliced", "gathered", "built")
 
 
 def mknode(name: str) -> Node:
@@ -473,11 +473,13 @@ class TestEncodeSpans:
         assert carry["args"]["groups"] == 8 and carry["args"]["rows"] == 8
         # the binds of the pass before joined the table's rows
         (table,) = [e for e in evs if e["name"] == "burst.encode.table"]
+        # (on eight nodes a changed node is no small share: one gather)
         assert table["args"]["path"] == "gathered"
         assert table["args"]["fresh"] == 16 and table["args"]["moved"] >= 1
+        assert 0 <= table["args"]["kept"] < table["args"]["moved"]
         moved = {p: v - calls[p] for p, v in table_calls().items()}
-        assert moved == {"returned": 0, "shared": 0, "gathered": 1,
-                         "built": 0}
+        assert moved == {"returned": 0, "shared": 0, "spliced": 0,
+                         "gathered": 1, "built": 0}
 
     def test_one_service_carries_the_vector(self):
         store, sched = make_service_sched(k=1)
@@ -488,19 +490,23 @@ class TestEncodeSpans:
                     if e["name"] == "burst.encode.table"]) == 1
 
     def test_pod_table_calls_are_counted_by_the_path_they_left_on(self):
-        """built, then returned / shared / gathered for a second call with
-        nothing moved / a generation moved with every row in place / a pod
-        joined; the span carries the label that was booked, and the four
-        labels sum to the calls."""
+        """built, then returned / shared / spliced / gathered for a call
+        with nothing moved / generations moved with every row in place (a
+        Node update; a pod bound and gone again: the span's `kept`) / a pod
+        joined on one node of many / a pod joined on every third node; the
+        span carries the label that was booked, the five labels sum to the
+        calls, and the moved nodes are booked by what their stamps said."""
         from kubernetes_tpu.cache.node_info import NodeInfo
         infos = {}
-        for i in range(3):
+        for i in range(48):
             ni = infos[f"n{i}"] = NodeInfo(mknode(f"n{i}"))
             for j in range(2):
                 ni.add_pod(mkpod(f"r{i}-{j}", node_name=f"n{i}"))
         enc = NS.NodeStateEncoder()
         b = enc.encode(infos, sorted(infos))
         before = table_calls()
+        nodes = {r: NS.POD_TABLE_MOVED_NODES.labels(r).value
+                 for r in ("kept", "changed")}
         obs.trace.clear()
 
         def call() -> dict:
@@ -510,22 +516,34 @@ class TestEncodeSpans:
             return {k: v for k, v in last["args"].items()
                     if k not in ("parent", "window")}
 
-        assert call() == {"path": "built", "rows": 6, "moved": 3,
-                          "fresh": 6}
-        assert call() == {"path": "returned", "rows": 6, "moved": 0,
-                          "fresh": 0}
+        assert call() == {"path": "built", "rows": 96, "moved": 48,
+                          "kept": 0, "fresh": 96}
+        assert call() == {"path": "returned", "rows": 96, "moved": 0,
+                          "kept": 0, "fresh": 0}
         infos["n1"].set_node(mknode("n1"))       # a generation, no row
-        assert call() == {"path": "shared", "rows": 6, "moved": 1,
-                          "fresh": 0}
+        assert call() == {"path": "shared", "rows": 96, "moved": 1,
+                          "kept": 1, "fresh": 0}
+        gone = mkpod("bound-and-gone", node_name="n3")
+        infos["n3"].add_pod(gone)
+        infos["n3"].remove_pod(gone)             # two generations, no row
+        assert call() == {"path": "shared", "rows": 96, "moved": 1,
+                          "kept": 1, "fresh": 0}
         infos["n2"].add_pod(mkpod("joined", node_name="n2"))
-        assert call() == {"path": "gathered", "rows": 7, "moved": 1,
-                          "fresh": 1}
+        infos["n1"].set_node(mknode("n1"))
+        assert call() == {"path": "spliced", "rows": 97, "moved": 2,
+                          "kept": 1, "fresh": 1}
+        for i in range(0, 48, 3):
+            infos[f"n{i}"].add_pod(mkpod(f"wave-{i}", node_name=f"n{i}"))
+        assert call() == {"path": "gathered", "rows": 113, "moved": 16,
+                          "kept": 0, "fresh": 16}
         assert call()["path"] == "returned"
         moved = {p: v - before[p] for p, v in table_calls().items()}
-        assert moved == {"built": 1, "returned": 2, "shared": 1,
-                         "gathered": 1}
+        assert moved == {"built": 1, "returned": 2, "shared": 2,
+                         "spliced": 1, "gathered": 1}
+        assert {r: NS.POD_TABLE_MOVED_NODES.labels(r).value - v
+                for r, v in nodes.items()} == {"kept": 3, "changed": 65}
         spans = [e["args"]["path"] for e in obs.trace.events()]
-        assert sum(moved.values()) == len(spans) == 5
+        assert sum(moved.values()) == len(spans) == 7
         assert {p: spans.count(p) for p in TABLE_PATHS} == moved
         # an encoder without a state encoder builds its table in one shot
         NS.build_pod_table(infos, b)
