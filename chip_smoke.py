@@ -26,6 +26,12 @@ Stages, all in this one process (a chip belongs to one process):
           percentageOfNodesToScore: the scan's truncated walk on shipped
           positions (a sort of feasible positions a step, a sort of tie
           positions); the launches are replayed through the serial oracle.
+- fill    those nodes with two pod slots each and two pods a node plus a
+          handful pending: ONE launch fills every slot (walks past their
+          quota over full nodes, walks over every node that keep fewer than
+          the quota) and meets the first pod that finds no node, after
+          which it commits nothing: the handful stay pending; replayed
+          through the serial oracle, tpu_walk_ended_total read.
 - groups  the walk stage's cluster holding eight Services' pods, and a few
           hundred pending pods of the eight interleaved pod by pod: one
           burst segment, one launch whose scan carries a count row a
@@ -84,6 +90,7 @@ REAL = {
     "preempt_victims": 10000, "preemptors": 128,
     "serial_nodes": 1000, "serial_cycles": 12,
     "walk_nodes": 1000, "walk_pods": 600, "groups_pods": 400,
+    "fill_beyond": 5,
     "colocated_pods": 400, "loadmix_pods": 400,
     "serve_groups_windows": (3, 20, 200),
     "serve_nodes": 1000, "serve_rate": 2000.0, "serve_seconds": 5.0,
@@ -96,6 +103,7 @@ REHEARSAL = {
     "preempt_victims": 320, "preemptors": 8,
     "serial_nodes": 60, "serial_cycles": 6,
     "walk_nodes": 250, "walk_pods": 40, "groups_pods": 40,
+    "fill_beyond": 3,
     "colocated_pods": 40, "loadmix_pods": 80,
     "serve_groups_windows": (3, 20, 80),
     "serve_nodes": 90, "serve_rate": 300.0, "serve_seconds": 2.0,
@@ -617,6 +625,81 @@ def stage_walk(smoke: Smoke):
             "device_ops": ops, "launches_replayed": launches}
 
 
+def stage_fill(smoke: Smoke):
+    """A cluster filled to its last pod slot and a handful of pods beyond,
+    in ONE launch on a rotating order under a truncated walk: walks past
+    their quota over full nodes, walks over every node that keep fewer than
+    the quota, and the first pod that finds none, after which the launch
+    commits nothing and its pods stay pending (no benchmark cell can hold
+    those: it counts an unbound pod as lost)."""
+    from kubernetes_tpu.core import tpu_scheduler as T
+    from kubernetes_tpu.models.hollow import NodeStrategy, populate_store
+    from kubernetes_tpu.oracle.generic_scheduler import \
+        num_feasible_nodes_to_find
+    from kubernetes_tpu.scheduler import Scheduler
+    from kubernetes_tpu.store.store import PODS, Store
+    s = smoke.sizes
+    n, beyond = s["walk_nodes"], s["fill_beyond"]
+    assert n % 3, "the zones have to be uneven for the order to rotate"
+    quota = num_feasible_nodes_to_find(n, 0)
+    slots = 2 * n
+    store = Store(watch_log_size=1 << 16)
+    # the headline's nodes with two pod slots each: the slots are what binds
+    populate_store(store, [NodeStrategy(count=n, zones=3, pods=2,
+                                        name_prefix="node")])
+    sched = Scheduler(store, use_tpu=True, percentage_of_nodes_to_score=0)
+    sched.sync()
+    make_pods(store, slots + beyond)
+    sched.pump()
+    d0 = dispatch_counts()
+    ended0, folds0 = family(T.WALK_ENDED), family_total(T.DISCARDED_FOLDS)
+    walked0, f0 = family(T.WALK_NODES), fallback_counts()
+
+    def run():
+        while sched.schedule_burst(max_pods=slots + beyond):
+            pass
+    launches, mism = replayed(run)
+    sched.pump()
+    ops = dispatch_delta(d0)
+    ended = delta(family(T.WALK_ENDED), ended0)
+    walked = delta(family(T.WALK_NODES), walked0)
+    pods = store.list(PODS)[0]
+    pending = [p for p in pods if not p.node_name]
+    per_node: dict = {}
+    for p in pods:
+        if p.node_name:
+            per_node[p.node_name] = per_node.get(p.node_name, 0) + 1
+    smoke.check("fill.every_slot_taken",
+                len(per_node) == n and set(per_node.values()) == {2},
+                f"{len(per_node)} nodes hold {sum(per_node.values())} pods")
+    smoke.check("fill.the_rest_pending", len(pending) == beyond,
+                f"{len(pending)} pending")
+    smoke.check("fill.one_launch",
+                ops.get("burst_scan", 0) == 1 and "burst_uniform" not in ops,
+                ops)
+    # every decision of the launch up to the first that found no node: some
+    # stopped at the quota, the last of the fill tested every node for
+    # fewer, one found none; what the launch decided after it never counted
+    smoke.check("fill.walks_ended",
+                ended.get("none") == 1 and ended.get("quota", 0) > 0
+                and ended.get("nodes", 0) >= quota // 2
+                and sum(ended.values()) == slots + 1,
+                f"quota {quota} of {n}: {ended}")
+    smoke.check("fill.walks_past_the_quota",
+                walked.get("truncated", 0) > quota * (slots + beyond), walked)
+    smoke.check("fill.launch_rewound",
+                family_total(T.DISCARDED_FOLDS) - folds0 == 1)
+    left = {k: v for k, v in delta(fallback_counts(), f0).items()
+            if k[0] in ("device-fault", "circuit-open")}
+    smoke.check("fill.no_device_fault", not left, left)
+    smoke.check("fill.replay_parity", launches == 1 and not mism,
+                f"{launches} launches replayed"
+                + (f", {mism[:2]}" if mism else ""))
+    return {"nodes": n, "pods": slots + beyond, "num_to_find": quota,
+            "walks_ended": ended, "device_ops": ops,
+            "launches_replayed": launches}
+
+
 def stage_groups(smoke: Smoke):
     """Unlike Services' pods in one launch: the scan carries one
     selector-spread count row a Service, on uneven zones under a truncated
@@ -1022,8 +1105,8 @@ def main(argv=None) -> int:
     stages += [("lanes.gang", stage_gang), ("lanes.preempt", stage_preempt),
                ("lanes.preempt_scan", stage_preempt_scan),
                ("serial", stage_serial), ("walk", stage_walk),
-               ("groups", stage_groups), ("colocated", stage_colocated),
-               ("loadmix", stage_loadmix),
+               ("fill", stage_fill), ("groups", stage_groups),
+               ("colocated", stage_colocated), ("loadmix", stage_loadmix),
                ("serve-groups", stage_serve_groups), ("serve", stage_serve)]
     if len(dev) > 1:
         stages.append(("mesh", stage_mesh))

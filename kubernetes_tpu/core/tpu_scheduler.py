@@ -97,6 +97,17 @@ WALK_NODES = obs.counter(
     "(num_to_find < n: the walk stops at its quota; read from the li_after "
     "block the scan launch fetches anyway) or 'full' (every node, n per "
     "pod).", ("regime",))
+WALK_ENDED = obs.counter(
+    "tpu_walk_ended_total",
+    "Decisions of schedule_burst's generic scan launches under a truncated "
+    "walk (num_to_find < n), by what ended the walk: 'quota' (it stopped at "
+    "its num_to_find-th fitting node), 'nodes' (it tested every node and "
+    "kept at least one, fewer than the quota) or 'none' (it tested every "
+    "node and kept none: the pod is unschedulable, and what the launch "
+    "decided after it is discarded and not counted). A launch that scores "
+    "every node books nothing: its walks are over all nodes by rule. Read "
+    "from the packed block the launch fetches anyway (nodes tested minus "
+    "nodes rejected is nodes kept).", ("by",))
 SCAN_STEPS = obs.counter(
     "tpu_scan_steps_total",
     "Steps the device ran in schedule_burst's generic scan launches, by "
@@ -228,10 +239,10 @@ class _BurstPhases:
         self._phase = phase
         self._span = obs_trace.begin(name, cat=cat)
 
-    def close(self) -> None:
+    def close(self, **args) -> None:
         phase, span = self._phase, self._span
         self._phase = self._span = None
-        now = span.end()
+        now = span.end(**args)
         if self._metrics is not None:
             self._metrics.observe_phase(phase, now - span.t0)
         obs_ledger.LEDGER.stamp_many(self._keys, _PHASES[phase][2], t=now)
@@ -1354,13 +1365,15 @@ class TPUScheduler:
     pod_rows = None
 
     def _launch(self, op: str, ph: _BurstPhases, fl, dispatch,
-                **span_args) -> tuple:
+                read=None, **span_args) -> tuple:
         """One launch of a burst driver: ONE dispatch and ONE packed fetch.
         `dispatch()` calls the kernel, books what only its driver counts and
         returns (what the driver keeps of the call, the packed block on the
         device). Returns (that, the block on the host). Nothing of the
         launch has reached the walk counters or a commit when a chaos seam
-        raises here: the caller decides what stands (`_refuse_launch`)."""
+        raises here: the caller decides what stands (`_refuse_launch`).
+        `read(block)` books what the driver counts from the block itself
+        and returns what the `burst.fetch` span says of it (its args)."""
         ph.open("kernel")
         t_d = obs_trace.now()
         chaos.check("device.dispatch")
@@ -1380,7 +1393,7 @@ class TPUScheduler:
         obs_trace.add_span("burst.wave.device", t_d, t_done, cat="device",
                            args=span_args or None)
         obs_flight.RECORDER.note_block(fl, h)
-        ph.close()
+        ph.close(**(read(h) if read is not None else {}))
         return kept, h
 
     def _refuse_launch(self, exc: BaseException, fl, outcome: dict) -> None:
@@ -1884,8 +1897,36 @@ class TPUScheduler:
                 "axis" if rotation is None else "position").inc(n_pods)
             return state, outs["packed"]
 
+        def read_walks(h) -> dict:
+            PICK_TIED_NODES.inc(
+                int(h[3 * B:3 * B + n_pods].sum(dtype=np.int64)))
+            rejected = h[4 * B:4 * B + n_pods]
+            FILTER_REJECTED_NODES.inc(int(rejected.sum(dtype=np.int64)))
+            if num_to_find >= n:
+                WALK_NODES.labels("full").inc(n_pods * n)
+                return {}
+            # a walk tests 1..n nodes and moves last_index by that many
+            # mod n, so a step of 0 is a walk over all n
+            moved = np.diff(h[B:B + n_pods].astype(np.int64),
+                            prepend=self.last_index % n) % n
+            tested = np.where(moved == 0, n, moved)
+            WALK_NODES.labels("truncated").inc(int(tested.sum()))
+            # how each walk ended, up to the first pod that found no node
+            # (what the launch decided after it is discarded): nodes
+            # tested minus nodes rejected is nodes kept
+            none = h[:n_pods] < 0
+            d = int(np.argmax(none)) + 1 if none.any() else n_pods
+            kept = tested[:d] - rejected[:d]
+            ended = {"quota": int((kept >= num_to_find).sum()),
+                     "none": int((kept == 0).sum())}
+            ended["nodes"] = d - ended["quota"] - ended["none"]
+            for by, count in ended.items():
+                WALK_ENDED.labels(by).inc(count)
+            return ended
+
         try:
-            state, h = self._launch("burst_scan", ph, fl, dispatch)
+            state, h = self._launch("burst_scan", ph, fl, dispatch,
+                                    read=read_walks)
         except _DEVICE_FAULTS as e:
             # the launch precedes every commit and counter update: refuse
             # the whole burst — the shell reruns the pods serially (host
@@ -1898,18 +1939,6 @@ class TPUScheduler:
         li_after = h[B:2 * B]
         lni_delta = h[2 * B:3 * B]
         lni0 = self.last_node_index
-        if num_to_find < n:
-            # a walk tests 1..n nodes and moves last_index by that many
-            # mod n, so a step of 0 is a walk over all n
-            moved = np.diff(li_after[:n_pods].astype(np.int64),
-                            prepend=self.last_index % n) % n
-            WALK_NODES.labels("truncated").inc(
-                int(np.where(moved == 0, n, moved).sum()))
-        else:
-            WALK_NODES.labels("full").inc(n_pods * n)
-        PICK_TIED_NODES.inc(int(h[3 * B:3 * B + n_pods].sum(dtype=np.int64)))
-        FILTER_REJECTED_NODES.inc(
-            int(h[4 * B:4 * B + n_pods].sum(dtype=np.int64)))
         neg = sel_arr < 0
         bad = int(np.argmax(neg)) if neg.any() else n_pods
         committed = bad

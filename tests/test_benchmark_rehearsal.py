@@ -25,6 +25,7 @@ LOAD = "load-5000n-150k.rollouts-1k-8svc"
 SERVICES = "services-5000n-150k.arrivals-zipf-64svc"
 COLOCATED = "colocated-5000n-150k.rollouts-1k-8svc-jobs"
 LOADMIX = "loadmix-5000n-150k.rollouts-1k-111svc"
+PODCAP = "podcap-5000n-150k.backlog-9900-fill"
 # the scan cells whose 15,000 nodes reach kernels.SCORE_BOARD_MIN_ROWS
 BOARD = (ADAPTIVE, MIXED)
 
@@ -45,6 +46,9 @@ BOARD = (ADAPTIVE, MIXED)
 # The loadmix cell takes the same 250 nodes and 120 Services, 111 of which its
 # mix names, and passes of 250 pods: about 80 Services a pass, more than the
 # power-of-two carry's 16 rows, so every pass is one launch on the wide carry.
+# The podcap cell takes the same 250 nodes with 6 resident pods on nodes of 8
+# slots, and passes of 495 pods for the 500 free slots: a pass's last walks
+# test all 250 nodes and keep fewer than the quota's 120.
 AT_50 = {"nodes": {"count": 50},
          "check": {"first_binds": 200, "sampled_binds": 60}}
 SMALL = {
@@ -79,6 +83,10 @@ SMALL[LOADMIX] = ({"nodes": {"count": 250},
                    "resident": {"pods_per_node": 6, "services": 120},
                    "check": {"first_binds": 300, "sampled_binds": 200}},
                   {"warm_binds": 0, "backlog": 250})
+SMALL[PODCAP] = ({"nodes": {"count": 250, "allocatable": {"pods": 8}},
+                  "resident": {"pods_per_node": 6, "services": 5},
+                  "check": {"first_binds": 600, "sampled_binds": 300}},
+                 {"backlog": 495})
 # the control of an adaptive cell: the program scores every node while the
 # reference judges at the file's default percentage
 EVERY_NODE = {"scheduler": {"percentage_of_nodes_to_score": 100}}
@@ -166,12 +174,16 @@ def counter_metric(name, res, rep, pods=None, moved=None):
     # 111 Services' replicas interleaved: a segment every 16 Services
     (LOADMIX, 2**31 + 50, None, None),
     (LOADMIX, 2**31 + 50, None, EVERY_NODE),            # its control
+    # label-free pods of three sizes for the last pod slots: full nodes
+    (PODCAP, 2**31 + 54, None, None),
+    (PODCAP, 2**31 + 54, None, EVERY_NODE),             # its control
 ], ids=["backlog", "rollout", "arrivals", "adaptive", "altered-binding",
         "density-adaptive", "density-adaptive-control", "mixed",
         "load", "load-altered-binding", "load-control",
         "services", "services-control",
         "colocated", "colocated-control",
-        "loadmix", "loadmix-control"])
+        "loadmix", "loadmix-control",
+        "podcap", "podcap-control"])
 def test_rehearsed_cell(monkeypatch, execute, cell, seed, hook, program):
     if cell in BOARD:
         # these cells hold 16,384 node rows, enough for the scan to carry
@@ -195,7 +207,7 @@ def test_rehearsed_cell(monkeypatch, execute, cell, seed, hook, program):
     assert res["attempted"] > 0
     assert rep["compiles_in_window"] == 0
     if cell in (ROLLOUT, ADAPTIVE, DENSITY_ADAPTIVE, MIXED, LOAD, SERVICES,
-                COLOCATED, LOADMIX):
+                COLOCATED, LOADMIX, PODCAP):
         # the generic scan's cells: at 16,384 rows every launch carries the
         # score board (one pod class, or up to eight in the mixed cell), at
         # the density cells' 8192 every step rescores every row
@@ -234,6 +246,43 @@ def test_rehearsed_cell(monkeypatch, execute, cell, seed, hook, program):
         # a walk stops at its quota: 120 of 250 nodes, none of them full
         assert moved["tpu_walk_nodes_evaluated_total"] == \
             {"truncated": 120 * res["attempted"]}
+        # ... so every walk ended there, none on the last node
+        assert moved["tpu_walk_ended_total"] == {"quota": res["attempted"]}
+        assert counter_metric("walk_exhausted_share.backlog", res, rep) == 0.0
+        assert counter_metric("walk_unschedulable_per_pod.backlog",
+                              res, rep) == 0.0
+    if cell == PODCAP:
+        moved = rep["counters"]
+        pods = res["attempted"]
+        backlog = SMALL[PODCAP][1]["backlog"]
+        # a pass is one segment of plain pods of three sizes and one launch
+        # of the scan on shipped positions, no spread carry, rows stacked
+        assert "tpu_oracle_fallback_total" not in moved
+        assert "burst_uniform" not in moved["tpu_device_dispatch_total"]
+        assert moved["tpu_device_dispatch_total"]["burst_scan"] \
+            == pods / backlog
+        assert moved["tpu_scan_order_steps_total"] == {"position": pods}
+        assert moved["tpu_scan_spread_steps_total"] == {"none": pods}
+        assert moved["tpu_scan_pod_rows_total"] == {"stacked": pods}
+        # walks pass their quota's 120 positions over full nodes, the
+        # pod-count filter says no, and a pass's last walks come up short:
+        # with 500 - k slots left fewer than 120 nodes fit from pod 382 on
+        # whatever the seed, and from pod 263 on at the earliest
+        tested = counter_metric("walk_nodes_per_pod.backlog", res, rep)
+        rejected = counter_metric("filter_rejected_nodes_per_pod.backlog",
+                                  res, rep)
+        assert 120 < tested < 250 and 0 < rejected < tested - 1
+        ended = moved["tpu_walk_ended_total"]
+        assert set(ended) == {"quota", "nodes"}
+        assert sum(ended.values()) == pods
+        short = counter_metric("walk_exhausted_share.backlog", res, rep)
+        assert short == 100.0 * ended["nodes"] / pods
+        assert 100.0 * 114 / backlog <= short <= 100.0 * 233 / backlog
+        # every pod of every pass found a node
+        assert counter_metric("walk_unschedulable_per_pod.backlog",
+                              res, rep) == 0.0
+        # the band selectHost chooses from is a few nodes at a pass's end
+        assert moved["tpu_pick_tied_nodes_total"][""] / pods < 120
     if cell == LOAD:
         moved = rep["counters"]
         # no window is refused: a pass of eight Services' pods is one
@@ -511,6 +560,15 @@ OPENED["pod_table_kept_node_share.backlog"] = (
     "counter_label_share",
     {"family": "tpu_pod_table_moved_nodes_total", "labels": ["kept"]},
     "ops/node_state.py")
+# how a truncated walk ended (PR 54)
+OPENED["walk_exhausted_share.backlog"] = (
+    "counter_label_share",
+    {"family": "tpu_walk_ended_total", "labels": ["nodes", "none"]},
+    "core/tpu_scheduler.py")
+OPENED["walk_unschedulable_per_pod.backlog"] = (
+    "counter_delta_per_pod",
+    {"family": "tpu_walk_ended_total", "labels": ["none"]},
+    "core/tpu_scheduler.py")
 
 
 @pytest.mark.parametrize("name", list(OPENED))
