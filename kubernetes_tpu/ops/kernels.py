@@ -476,7 +476,50 @@ def _kth_smallest(mask, rel, k):
     return jax.lax.dynamic_slice(ranked, (k,), (1,))[0]
 
 
-def _cycle_core(nodes, pod, last_index, last_node_index, num_to_find, n_real,
+def _walk_origin(last_index, n_real):
+    """The walk's origin as a cycle uses it: `last_index` reduced into
+    [0, n), int32. A launch calls this ONCE, at its head and outside its
+    loop: last_index persists across cycles while the cluster may shrink and
+    the oracle's walk is modulo n (generic_scheduler.py:148), so the one
+    int64 remainder a launch stays, here. From then on the origin is
+    carried reduced (`_cycle_core` hands the next one back reduced), and no
+    step divides."""
+    return (_i64(last_index) % jnp.maximum(n_real, 1)).astype(jnp.int32)
+
+
+def _tie_index(last_node_index, num_ties):
+    """`last_node_index mod num_ties` as an int32 (selectHost :292), exact
+    for any non-negative int64 counter. The TPU has no 64-bit divide: XLA
+    unrolls an int64 `rem` by a traced scalar into some 1800 scalar
+    instructions of u32 long division, 18 us of every step of a loop on a
+    v5e (PERF.md section 6, PR 55). upstream's counter grows by one a pod
+    for the life of the process, so it is below 2**31 for the first two
+    thousand million pods, and `num_ties` is at most the node count: the
+    branch taken then is one 32-bit remainder, and only a counter past
+    2**31 pays for the long division, in a branch of its own. A `cond`,
+    not a `where`: a select would compute both."""
+    return jax.lax.cond(
+        last_node_index < 2 ** 31,
+        lambda: jax.lax.rem(last_node_index.astype(jnp.int32),
+                            num_ties.astype(jnp.int32)),
+        lambda: (last_node_index % num_ties.astype(jnp.int64)).astype(
+            jnp.int32))
+
+
+def _one_cycle(nodes, pod, last_index, last_node_index, num_to_find, n_real,
+               weights, z_pad, **step):
+    """A launch of ONE cycle (`schedule_cycle`, the mesh's
+    `sharded_cycle_fn`): the head every launch has, the walk's origin
+    reduced once, then the step the loops run. `step` is `_cycle_core`'s
+    keywords; `next_last_index` goes back as the int64 the boundary has
+    always had."""
+    out = _cycle_core(nodes, pod, _walk_origin(last_index, n_real),
+                      _i64(last_node_index), num_to_find, n_real, weights,
+                      z_pad, **step)
+    return {**out, "next_last_index": _i64(out["next_last_index"])}
+
+
+def _cycle_core(nodes, pod, li, last_node_index, num_to_find, n_real,
                 weights, z_pad, pos=None, full_scan=False, ghost=None,
                 wtab=None, gang=None, local=None):
     """One fused cycle. The reference's sequential walk from last_index
@@ -485,6 +528,17 @@ def _cycle_core(nodes, pod, last_index, last_node_index, num_to_find, n_real,
     order among feasible nodes is S[j]-pre (j >= li) or F-pre+S[j] (j < li),
     where S is the natural-order feasibility cumsum, pre = S[li-1], F = S[-1]
     — no gathers, int32 counters (TPU has no native int64).
+
+    That holds for the two walk counters too. `li` is the walk's origin
+    ALREADY REDUCED into [0, n), int32: every launch reduces its
+    `last_index` once at its head (`_walk_origin`) and carries what this
+    cycle hands back, `next_last_index`, which is reduced by construction
+    (li < n and evaluated <= n, so one compare-and-subtract is the whole
+    modulo). `last_node_index` stays the int64 it is upstream (an int64
+    add is four scalar instructions); what a cycle needs of it is
+    `_tie_index`, exact past 2**31 and 32-bit below. No 64-bit division or
+    remainder by a traced scalar is left in a step
+    (tests/test_walk_counters.py holds the loop bodies to that).
 
     When the per-cycle NodeTree enumeration differs from the device axis
     (uneven zones rotate the zone-interleaved order between cycles —
@@ -523,11 +577,6 @@ def _cycle_core(nodes, pod, last_index, last_node_index, num_to_find, n_real,
     i32 = jnp.int32
     i = jnp.arange(n_pad, dtype=i32)
     nr = jnp.asarray(n_real, i32)
-    n_safe = jnp.maximum(n_real, 1)
-    # last_index persists across cycles while the cluster may shrink; the
-    # oracle's walk is modulo n (generic_scheduler.py:148), so clamp the
-    # rotation origin before use or ranks go negative after node removals
-    li = jnp.asarray(last_index % n_safe, i32)
     ntf = jnp.asarray(num_to_find, i32)
     in_range = i < nr
 
@@ -579,7 +628,7 @@ def _cycle_core(nodes, pod, last_index, last_node_index, num_to_find, n_real,
             stop = jnp.where(jstar >= li, jstar - li, nr - li + jstar)
             evaluated = jnp.where(F >= ntf, stop + 1, nr)
         # a skip (bucket-padding) pod consumes no rotation state
-        evaluated = jnp.where(pod["skip"], 0, evaluated).astype(jnp.int64)
+        evaluated = jnp.where(pod["skip"], 0, evaluated)
 
     wrow = None if wtab is None else wtab[pod["profile_id"]]
     total = _fit_scores(nodes, pod, kept, weights, z_pad, wrow=wrow,
@@ -591,7 +640,7 @@ def _cycle_core(nodes, pod, last_index, last_node_index, num_to_find, n_real,
         is_tie = kept & (tmask == max_score)
         num_ties = jnp.maximum(jnp.sum(is_tie.astype(i32)), 1)
         # round-robin k-th tie in rotation order (selectHost :286-295)
-        k = (last_node_index % num_ties.astype(jnp.int64)).astype(i32)
+        k = _tie_index(last_node_index, num_ties)
         if pos is not None:
             # k-th tie by enumeration position relative to the walk origin;
             # ties exclude invalid rows
@@ -605,11 +654,12 @@ def _cycle_core(nodes, pod, last_index, last_node_index, num_to_find, n_real,
             trank = jnp.where(after, T - preT, T[-1] - preT + T)
             sel = jnp.argmax(is_tie & (trank == k + 1)).astype(jnp.int64)
     selected = jnp.where(found > 0, sel, -1)
+    nxt, n_safe = li + evaluated, jnp.maximum(nr, 1)
 
     return {
         "selected": selected,
         "found": found.astype(jnp.int64),
-        "evaluated": evaluated,
+        "evaluated": evaluated.astype(jnp.int64),
         "max_score": jnp.where(found > 0, max_score, 0),
         # how wide the top band was: the nodes selectHost chose among
         "num_ties": jnp.where(found > 0, num_ties, 0),
@@ -618,7 +668,9 @@ def _cycle_core(nodes, pod, last_index, last_node_index, num_to_find, n_real,
         "feasible": feasible,
         "fail_first": fail_first,
         "general_bits": general_bits,
-        "next_last_index": (last_index + evaluated) % n_safe,
+        # (li + evaluated) mod n: both are at most n (an empty cluster walks
+        # modulo 1, as `_walk_origin` does)
+        "next_last_index": jnp.where(nxt >= n_safe, nxt - n_safe, nxt),
         # selectHost is skipped when only one node is feasible
         # (generic_scheduler.go:244-250), so the tie counter doesn't move
         "next_last_node_index": last_node_index + jnp.where(found > 1, 1, 0),
@@ -629,15 +681,15 @@ def _cycle_core(nodes, pod, last_index, last_node_index, num_to_find, n_real,
 def _schedule_cycle_jit(nodes, pod, last_index, last_node_index, num_to_find,
                         n_real, z_pad, weights_tuple):
     weights = dict(weights_tuple)
-    return _cycle_core(nodes, pod, last_index, last_node_index, num_to_find,
-                       n_real, weights, z_pad)
+    return _one_cycle(nodes, pod, last_index, last_node_index, num_to_find,
+                      n_real, weights, z_pad)
 
 
 @partial(jax.jit, static_argnames=("z_pad", "weights_tuple"))
 def _schedule_cycle_wtab_jit(nodes, pod, wtab, last_index, last_node_index,
                              num_to_find, n_real, z_pad, weights_tuple):
-    return _cycle_core(nodes, pod, last_index, last_node_index, num_to_find,
-                       n_real, dict(weights_tuple), z_pad, wtab=wtab)
+    return _one_cycle(nodes, pod, last_index, last_node_index, num_to_find,
+                      n_real, dict(weights_tuple), z_pad, wtab=wtab)
 
 
 def schedule_cycle(nodes, pod, last_index, last_node_index, num_to_find, n_real,
@@ -935,7 +987,7 @@ def _batch_core(nodes, mut0, pods, n_pods, last_index, last_node_index,
                 board.at[:, idx].set(class_scores(new_state, idx)))
         li, lni = out["next_last_index"], out["next_last_node_index"]
         packed = packed.at[:, i].set(jnp.stack([
-            sel.astype(i32), li.astype(i32),
+            sel.astype(i32), li,
             (lni - last_node_index).astype(i32),
             out["num_ties"].astype(i32),
             (out["evaluated"] - out["found"]).astype(i32)]))
@@ -945,14 +997,17 @@ def _batch_core(nodes, mut0, pods, n_pods, last_index, last_node_index,
 
     board0 = None if score_tab is None \
         else constrain_board(class_scores(mut0))
-    init = (constrain(mut0), last_index, last_node_index,
-            constrain_spread(spread0),
+    init = (constrain(mut0), _walk_origin(last_index, n_real),
+            last_node_index, constrain_spread(spread0),
             board0, jnp.full((5, B), -1, i32), jnp.zeros((4, B), jnp.int64))
     state, li, lni, spread, _board, packed, aux = jax.lax.fori_loop(
         jnp.int32(0), jnp.asarray(n_pods, i32), body, init)
     # ONE packed fetch block [5B] i32: selections, then the walk counters
-    # AFTER each pod (li absolute — it is < n; lni as a delta from the
-    # launch's start so it fits i32) — a mid-burst failure's prefix rewind
+    # AFTER each pod (li absolute — it is < n, and the loop carries it as
+    # the int32 it is written as: reduced once above, `_walk_origin`, and by
+    # a compare-and-subtract a step; lni as a delta from the launch's start
+    # so it fits i32, the carry keeping the int64, whose tie index is exact
+    # past 2**31, `_tie_index`) — a mid-burst failure's prefix rewind
     # reads the counters straight out of the single fetched block instead
     # of paying a second round trip for the evaluated/found vectors — then
     # two words a pod for the host's counters: the nodes that tied for the
@@ -960,7 +1015,7 @@ def _batch_core(nodes, mut0, pods, n_pods, last_index, last_node_index,
     outs = {"selected": packed[0].astype(jnp.int64), "li_after": packed[1],
             "found": aux[0], "evaluated": aux[1], "max_score": aux[2],
             "lni_after": aux[3], "packed": packed.reshape(5 * B)}
-    return state, li, lni, spread, outs
+    return state, li.astype(jnp.int64), lni, spread, outs
 
 
 @partial(jax.jit, static_argnames=("z_pad", "weights_tuple", "rotate",
@@ -1245,16 +1300,17 @@ def _segments_core(nodes, mut0, pods, seg_start, gang, n_pods,
         li2, lni2 = cur2[1], cur2[2]
         col = jnp.stack([
             jnp.where(hit & ~eskip, sel, jnp.int64(-1)).astype(i32),
-            li2.astype(i32),
+            li2,
             (lni2 - last_node_index).astype(i32),
             t2])
         return (cur2, chk, t2, chk_t, failed, i + 1, out.at[:, i].set(col))
 
+    li0 = _walk_origin(last_index, n_real)
     if gang_score:
-        init_cur = (constrain(mut0), last_index, last_node_index,
+        init_cur = (constrain(mut0), li0, last_node_index,
                     constrain(spread0), jnp.zeros(z_pad, jnp.int64))
     else:
-        init_cur = (constrain(mut0), last_index, last_node_index,
+        init_cur = (constrain(mut0), li0, last_node_index,
                     constrain(spread0))
     out0 = jnp.full((4, B), -1, i32)
     init = (init_cur, init_cur, jnp.int32(0), jnp.int32(0),
@@ -1262,7 +1318,8 @@ def _segments_core(nodes, mut0, pods, seg_start, gang, n_pods,
     Bn = jnp.asarray(n_pods, i32)
     (cur, _chk, _t, _ct, _f, _i, out) = jax.lax.while_loop(
         lambda c: c[5] < Bn, body, init)
-    state, li, lni, spread = cur[0], cur[1], cur[2], cur[3]
+    state, li, lni, spread = (cur[0], cur[1].astype(jnp.int64), cur[2],
+                              cur[3])
     # ONE packed fetch block [4B] i32: selections (−1 = miss / rewound gang
     # member / padding), then the post-pod walk counters and the consumed-
     # enumeration count — every boundary the host commit needs (decided
@@ -2112,9 +2169,10 @@ def _pressure_core(nodes, mut0, ghost0, pods, vic, last_index,
             "victims": victims[w].astype(jnp.int8),
         })
 
-    init = (constrain(mut0), constrain(ghost0), last_index, last_node_index)
+    init = (constrain(mut0), constrain(ghost0),
+            _walk_origin(last_index, n_real), last_node_index)
     (mut, ghost, li, lni), outs = jax.lax.scan(step, init, pods)
-    return mut, ghost, li, lni, outs
+    return mut, ghost, li.astype(jnp.int64), lni, outs
 
 
 @partial(jax.jit, static_argnames=("z_pad", "weights_tuple"))

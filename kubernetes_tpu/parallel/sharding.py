@@ -131,16 +131,16 @@ def sharded_cycle_fn(mesh: Mesh, z_pad: int, weights=None,
         def fn(nodes, pod, last_index, last_node_index, num_to_find,
                n_real, wtab):
             nodes = _constrain_nodes(mesh, nodes)
-            return K._cycle_core(nodes, pod, last_index, last_node_index,
-                                 num_to_find, n_real, dict(weights_tuple),
-                                 z_pad, wtab=wtab)
+            return K._one_cycle(nodes, pod, last_index, last_node_index,
+                                num_to_find, n_real, dict(weights_tuple),
+                                z_pad, wtab=wtab)
     else:
         def fn(nodes, pod, last_index, last_node_index, num_to_find,
                n_real):
             nodes = _constrain_nodes(mesh, nodes)
-            return K._cycle_core(nodes, pod, last_index, last_node_index,
-                                 num_to_find, n_real, dict(weights_tuple),
-                                 z_pad)
+            return K._one_cycle(nodes, pod, last_index, last_node_index,
+                                num_to_find, n_real, dict(weights_tuple),
+                                z_pad)
 
     return jax.jit(fn)
 

@@ -75,9 +75,9 @@ def _setup(mode, batch, bucket):
 
 @partial(jax.jit, static_argnames=("full_scan",))
 def _rotated_cycle(nodes, pod, li, lni, ntf, n_real, pos, full_scan):
-    out = K._cycle_core(nodes, pod, li, lni, ntf, n_real,
-                        dict(K.DEFAULT_WEIGHTS), Z_PAD, pos=pos,
-                        full_scan=full_scan)
+    out = K._one_cycle(nodes, pod, li, lni, ntf, n_real,
+                       dict(K.DEFAULT_WEIGHTS), Z_PAD, pos=pos,
+                       full_scan=full_scan)
     return {k: out[k] for k in ("selected", "next_last_index",
                                 "next_last_node_index", "num_ties",
                                 "found", "evaluated")}

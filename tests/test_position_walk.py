@@ -158,7 +158,7 @@ def test_truncated_walk_on_positions_is_the_serial_walk(
     pod = _with(per_pod[0], n_pad, fit, skip=name == "skip-pod")
     i64 = partial(np.asarray, dtype=np.int64)
 
-    out = jax.jit(partial(K._cycle_core, weights=dict(K.DEFAULT_WEIGHTS),
+    out = jax.jit(partial(K._one_cycle, weights=dict(K.DEFAULT_WEIGHTS),
                           z_pad=Z_PAD))(
         nodes, pod, i64(li), i64(lni), i64(ntf), i64(n), pos=pos)
     want = _referee(nodes, pod, li, lni, ntf, n, perm, pos)
@@ -350,7 +350,7 @@ def test_the_launch_reads_its_regime_off_its_own_operands(world, monkeypatch):
     def sorts(full_scan):
         i64 = partial(np.asarray, dtype=np.int64)
         text = jax.jit(partial(
-            K._cycle_core, weights=dict(K.DEFAULT_WEIGHTS), z_pad=Z_PAD,
+            K._one_cycle, weights=dict(K.DEFAULT_WEIGHTS), z_pad=Z_PAD,
             full_scan=full_scan)).lower(
             node_arrays, per_pod[0], i64(0), i64(0), i64(10), i64(n),
             pos=positions[1]).as_text(debug_info=True)
