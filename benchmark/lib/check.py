@@ -15,14 +15,15 @@ every bind the node's summed requests and pod count stay within allocatable.
 """
 from __future__ import annotations
 
-import importlib
 import random
 
+from lib import spec
 from lib.client import ADD, BIND, DELETE
 
 
-def make_reference(cfg: dict, rows: list, residents: list, services: list):
-    mod = importlib.import_module(f"reference.{cfg['reference']}")
+def make_reference(cfg: dict, rows: list, residents: list, services: list,
+                   root: str = spec.ROOT):
+    mod = spec.load_module("reference", cfg["reference"], root)
     ref = mod.Reference(rows, {"default": services},
                         cfg["scheduler"]["percentage_of_nodes_to_score"])
     for desc, node in residents:

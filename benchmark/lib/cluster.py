@@ -1,10 +1,10 @@
 """The cluster a configuration describes, built from the seed.
 
-Copied in structure from `bench.py:41-62` (`build_cluster`/`make_pods`): the
-scheduler_perf node shape, zone labels by `i % zones`, pods created through
-the store's batched verbs. The same pass yields what the plain reference is
-given: the nodes as plain dicts and the resident pods' placements. Nothing
-here reads anything back from the program.
+The scheduler_perf node shape, zone labels by `i % zones`, pods created
+through the store's batched verbs (the structure of the pre-chip `bench.py`'s
+`build_cluster`/`make_pods`, which PR 30 deleted). The same pass yields what
+the plain reference is given: the nodes as plain dicts and the resident pods'
+placements. Nothing here reads anything back from the program.
 """
 from __future__ import annotations
 

@@ -3,13 +3,17 @@
 Everything that belongs to one cell is data: `BENCHMARK.json` names the cell,
 its configuration and its traffic mix; `configs/<name>.json`,
 `traffic/<name>.json` and `metrics/<name>.json` hold the rest. An unknown key
-anywhere is an error, so a typo cannot silently fall back to a default.
+anywhere is an error, so a typo cannot silently fall back to a default. What a
+data file names in code (a pod shape kind, an arrival process, a reference) is
+a file too, found by that name (`load_module`).
 """
 from __future__ import annotations
 
+import importlib.util
 import json
 import os
 import re
+import sys
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 BENCH_DIR = os.path.join(ROOT, "benchmark")
@@ -76,6 +80,31 @@ def overlaid(base: dict, over: dict | None) -> dict:
         else:
             out[key] = val
     return out
+
+
+def load_module(group: str, name: str, root: str = ROOT):
+    """The code a data file names: `benchmark/<group>/<name>.py` of `root`
+    (a `-` of the name is a `_` of the file's), imported as `<group>.<name>`.
+    A name without its file is a SpecError that says which file was looked
+    for, so a new kind is a new file and never an edit here."""
+    stem = check_name(name, group).replace("-", "_")
+    path = os.path.abspath(os.path.join(root, "benchmark", group, stem + ".py"))
+    if not os.path.isfile(path):
+        raise SpecError(f"no {group} {name!r}: looked for {path}")
+    modname = f"{group}.{stem}"
+    mod = sys.modules.get(modname)
+    if mod is None or getattr(mod, "__file__", None) != path:
+        # by path, not by `sys.path`: a test lays a root of its own over the
+        # tree, and the file of that root is the one its data names
+        mspec = importlib.util.spec_from_file_location(modname, path)
+        mod = importlib.util.module_from_spec(mspec)
+        sys.modules[modname] = mod
+        try:
+            mspec.loader.exec_module(mod)
+        except BaseException:
+            del sys.modules[modname]
+            raise
+    return mod
 
 
 # -- BENCHMARK.json ----------------------------------------------------------
@@ -184,9 +213,12 @@ def load_config(bench: dict, name: str, root: str = ROOT) -> dict:
 
 
 # -- traffic -----------------------------------------------------------------
-# The kinds a committed cell drives and `reference/default_provider.py`
-# states. A kind comes in with the cell and the reference that need it.
-SHAPE_KINDS = ("plain", "spread-by-service")
+def _named_module(group: str, obj, key: str, what: str, root: str):
+    """The module that `obj[key]` names, before `obj`'s other keys are
+    checked against what that module states."""
+    if not isinstance(obj, dict) or not isinstance(obj.get(key), str):
+        raise SpecError(f"{what}: expected an object with a {key}")
+    return load_module(group, obj[key], root)
 
 
 def load_traffic(name: str, root: str = ROOT) -> dict:
@@ -203,10 +235,11 @@ def load_traffic(name: str, root: str = ROOT) -> dict:
     if not tr["pod_shapes"]:
         raise SpecError(f"traffic {name}: no pod shapes")
     for sh in tr["pod_shapes"]:
-        check_keys(sh, {"kind": str, "share": (int, float), "requests": dict},
-                   {"labels": dict}, "pod_shape")
-        if sh["kind"] not in SHAPE_KINDS:
-            raise SpecError(f"pod shape kind {sh['kind']!r}; known: {SHAPE_KINDS}")
+        # a kind is `shapes/<kind>.py`, which states the keys of its own
+        kind = _named_module("shapes", sh, "kind", "pod_shape", root)
+        check_keys(sh, {"kind": str, "share": (int, float), "requests": dict,
+                        **kind.REQUIRED},
+                   {"labels": dict, **kind.OPTIONAL}, f"pod_shape {sh['kind']}")
         check_keys(sh["requests"], _RES, {}, "pod_shape.requests")
     if abs(sum(sh["share"] for sh in tr["pod_shapes"]) - 1.0) > 1e-9:
         raise SpecError(f"traffic {name}: pod shape shares do not sum to 1")
@@ -227,10 +260,13 @@ def load_traffic(name: str, root: str = ROOT) -> dict:
         if tr.get("service_choice"):
             raise SpecError(f"traffic {name}: per-cycle service choice needs "
                             f"the cycles of a closed_backlog")
-        check_keys(tr["arrival"], {"process": str, "rate_per_s": (int, float)},
-                   {}, "traffic.arrival")
-        if tr["arrival"]["process"] != "poisson":
-            raise SpecError("arrival.process is 'poisson'")
+        # a process is `arrivals/<process>.py`; `rate_per_s` is every
+        # process's mean rate (the admission gate is sized from it)
+        process = _named_module("arrivals", tr["arrival"], "process",
+                                "traffic.arrival", root)
+        check_keys(tr["arrival"], {"process": str, "rate_per_s": (int, float),
+                                   **process.REQUIRED}, process.OPTIONAL,
+                   f"traffic.arrival {tr['arrival']['process']}")
         check_keys(tr["serve"], {"window_size": int, "depth": int,
                                  "gate_seconds": (int, float),
                                  "retry_after_base_s": (int, float),

@@ -11,7 +11,9 @@ cluster from the seed; drives the program through its public entry points
 `ServeLoop`, `Store.create_many/delete_many/watch`) with the benchmark's own
 client; and prints, as the last line of standard output, one JSON object:
 `correct`, `attempted`, `failed`, `metrics`, `device` (and `breakdown` when
-traced). Everything else it prints comes on earlier lines.
+traced), then `compared`: each number `correct` was decided from, beside its
+limit, which are also the run's last lines on standard error. Everything else
+it prints comes on earlier lines.
 
 It runs on a TPU only. `--rehearse` is the one way to run it on the CPU
 backend (tiny cells, for tests): a rehearsal prints its report and no result
@@ -278,7 +280,7 @@ def execute(cell_name: str, seed: int, seconds: float, trace: bool,
     if hook:
         hook(sched, store)
     client = Client(store, tracing=trace)
-    factory = PodFactory(traffic, len(services), seed)
+    factory = PodFactory(traffic, len(services), seed, root)
     kind = traffic["kind"]
     loop = None
     made = due = None
@@ -296,7 +298,7 @@ def execute(cell_name: str, seed: int, seconds: float, trace: bool,
             max_depth=max(4 * serve["window_size"],
                           int(serve["gate_seconds"] * rate)),
             retry_after_base=serve["retry_after_base_s"])
-        due = due_times(traffic["arrival"], seconds, seed)
+        due = due_times(traffic["arrival"], seconds, seed, root)
         made = [factory.make(f"arr-{j}") for j in range(len(due))]
     warmup_s = time.perf_counter() - t0
 
@@ -410,7 +412,7 @@ def execute(cell_name: str, seed: int, seconds: float, trace: bool,
 
     # -- correct ---------------------------------------------------------------
     t0 = time.perf_counter()
-    ref = check.make_reference(cfg, rows, residents, services)
+    ref = check.make_reference(cfg, rows, residents, services, root)
     rep = check.replay(client, ref, mark, end, cfg["check"]["first_binds"],
                        cfg["check"]["sampled_binds"], seed)
     check_s = time.perf_counter() - t0
@@ -421,28 +423,35 @@ def execute(cell_name: str, seed: int, seconds: float, trace: bool,
     bound_twice = sum(1 for c in client.bind_count if c > 1)
     gave_up = win.get("gave_up", 0)
     compared = [
-        # (what, value, limit): passes when value <= limit
-        ("bindings that differ from the reference's", len(rep["mismatches"]), 0),
-        ("pods bound twice", bound_twice, 0),
-        ("binds that put a node over its allocatable", rep["over_allocatable"], 0),
-        ("pods lost (attempted - bound and seen - given up)",
+        # (short name, what, value, limit): passes when value <= limit
+        ("bindings_differ", "bindings that differ from the reference's",
+         len(rep["mismatches"]), 0),
+        ("bound_twice", "pods bound twice", bound_twice, 0),
+        ("over_allocatable", "binds that put a node over its allocatable",
+         rep["over_allocatable"], 0),
+        ("pods_lost", "pods lost (attempted - bound and seen - given up)",
          attempted - win["bound_seen"] - gave_up, 0),
-        ("pods shed and given up", gave_up, 0),
-        ("watch events for pods the client never made", client.unknown_events, 0),
-        ("oracle fallbacks by device-fault or open breaker", left_device, 0),
-        ("commit waves on the twin (non-native) core", twin_waves, 0),
-        ("algorithm is not a TPUScheduler",
+        ("shed_given_up", "pods shed and given up", gave_up, 0),
+        ("unknown_watch_events", "watch events for pods the client never made",
+         client.unknown_events, 0),
+        ("device_fallbacks", "oracle fallbacks by device-fault or open breaker",
+         left_device, 0),
+        ("twin_commit_waves", "commit waves on the twin (non-native) core",
+         twin_waves, 0),
+        ("not_tpu_scheduler", "algorithm is not a TPUScheduler",
          0 if isinstance(sched.algorithm, TPUScheduler) else 1, 0),
-        ("store core is not native", 0 if store.core_impl == "native" else 1, 0),
-        ("window without a compared binding", 0 if rep["compared"] else 1, 0),
+        ("store_not_native", "store core is not native",
+         0 if store.core_impl == "native" else 1, 0),
+        ("no_compared_binding", "window without a compared binding",
+         0 if rep["compared"] else 1, 0),
     ]
     if rehearse:
         # a CPU rehearsal may run without the native cores
-        compared = [c for c in compared if "native" not in c[0]]
+        compared = [c for c in compared if "native" not in c[1]]
     say(f"  {rep['compared']} of {rep['window_binds']} window binds compared "
         f"with the reference in {check_s:.2f} s")
     correct = True
-    for what, value, limit in compared:
+    for _name, what, value, limit in compared:
         ok = value <= limit
         correct &= ok
         say(f"  [{'ok' if ok else 'FAIL'}] {what}: {value} (limit {limit})")
@@ -458,6 +467,10 @@ def execute(cell_name: str, seed: int, seconds: float, trace: bool,
     if trace and reduction is not None:
         result["breakdown"] = {"device_ops": reduction["device_ops"],
                                "idle_gaps": reduction["idle_gaps"]}
+    # each number compared beside its limit, last in the line; `main` prints
+    # the same as the run's last lines on standard error
+    result["compared"] = {name: {"value": int(value), "limit": limit}
+                          for name, _what, value, limit in compared}
     report = {
         "values": values, "window_s": window_s, "pending_s": pending_s,
         "build_s": build_s,
@@ -509,6 +522,9 @@ def main(argv=None) -> int:
         say("rehearsal on the CPU backend: no result line")
         return 0 if out["result"]["correct"] else 1
     print(json.dumps(out["result"]), flush=True)
+    for name, c in out["result"]["compared"].items():
+        print(f"compared {name}: {c['value']} (limit {c['limit']})",
+              file=sys.stderr, flush=True)
     return 0
 
 

@@ -45,6 +45,9 @@ def test_sound_run_is_correct(cell, seed):
     assert out["report"]["compiles_in_window"] == 0
     assert set(res) >= {"correct", "attempted", "failed", "metrics", "device"}
     assert "setup_s" in res["metrics"] and len(res["metrics"]) >= 2
+    # each number compared beside its limit, last in the line
+    assert list(res)[-1] == "compared" and "bindings_differ" in res["compared"]
+    assert all(c["value"] <= c["limit"] for c in res["compared"].values())
     rep = out["report"]
     if "pods_per_s" in res["metrics"]:
         # all the window's binds over all the window's time
@@ -76,6 +79,9 @@ def altered_binding(prefix):
 def test_altered_binding_is_not_correct(cell, prefix):
     out = go(cell, 11, hook=altered_binding(prefix))
     assert out["result"]["correct"] is False
+    over = {k for k, c in out["result"]["compared"].items()
+            if c["value"] > c["limit"]}
+    assert "bindings_differ" in over
 
 
 def test_control_half_the_nodes_scored_is_not_correct():

@@ -33,7 +33,8 @@ def test_sound_run_is_correct():
         117 * res["attempted"]
     steps = moved["tpu_scan_steps_total"]
     assert steps["real"] == res["attempted"]
-    assert steps["pad"] == (256 - 150) * res["attempted"] // 150
+    # the pod count is the loop's trip count: no step runs for a pad row
+    assert steps.get("pad", 0) == 0
 
 
 def test_altered_binding_is_not_correct():

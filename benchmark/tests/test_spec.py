@@ -10,11 +10,12 @@ from lib import spec
 
 @pytest.fixture()
 def root(tmp_path):
-    """A copy of the benchmark's data files that a test may spoil."""
+    """A copy of the benchmark's data files that a test may spoil, with the
+    code files the traffic mixes name (their kinds and processes)."""
     dst = tmp_path / "repo"
     os.makedirs(dst / "benchmark")
     shutil.copy(os.path.join(spec.ROOT, "BENCHMARK.json"), dst)
-    for d in ("configs", "traffic", "metrics"):
+    for d in ("configs", "traffic", "metrics", "shapes", "arrivals"):
         shutil.copytree(os.path.join(spec.BENCH_DIR, d), dst / "benchmark" / d)
     shutil.copy(os.path.join(spec.BENCH_DIR, "peaks.json"), dst / "benchmark")
     return str(dst)

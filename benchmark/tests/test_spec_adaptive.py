@@ -2,6 +2,7 @@
 import json
 import os
 
+from cells import reporting, reports
 from lib import spec
 
 ADAPTIVE = "headline-15000n-adaptive.backlog-10k"
@@ -38,17 +39,22 @@ def test_adaptive_cell_reports_its_metrics():
     first = [m["name"] for m in spec.metrics_for(
         bench, spec.find_cell(bench, "headline-15000n.backlog-10k"),
         "per_layer")]
-    # cell 1's metrics, with the scan's roofline in place of the K-batch one
-    assert set(layer) ^ set(first) == {
-        "schedule_batch_roofline.backlog",
+    # cell 1's metrics, with the scan's roofline in place of the K-batch
+    # one, and what only a scan launch or a truncated walk books
+    assert set(first) - set(layer) == {
         "schedule_batch_uniform_roofline.backlog"}
+    assert "schedule_batch_roofline.backlog" in set(layer) - set(first)
+    assert not reports(bench, ADAPTIVE,
+                       "schedule_batch_uniform_roofline.backlog")
     for name in layer:
         spec.load_metric(name)
     for name in ("walk_nodes_per_pod.backlog", "scan_steps_per_pod.backlog",
                  "stack_wall_share.backlog"):
         m = next(m for m in bench["per_layer"] if m["name"] == name)
         assert m["moves"] == "pods_per_s" and name in layer
-        assert m["workloads"] == [ADAPTIVE, "headline-15000n.backlog-10k"]
+        # cell 1 reads them too (nothing booked there), the arrival cells not
+        assert reports(bench, "headline-15000n.backlog-10k", name)
+        assert NEAR_KNEE not in reporting(bench, name)
 
 
 def test_near_knee_traffic_is_arrivals_steady_at_another_rate():
